@@ -17,10 +17,12 @@ new_executor/interpretercore.cc).  TPU-native realization, three tiers:
   jitted ``jax.vjp`` forward whose vjp closure round-trips through jit as
   a ``jax.tree_util.Partial`` pytree carrying the residuals).
 - **Tier 2** (`ensure_compile_cache`): JAX's persistent XLA compilation
-  cache, wired behind ``FLAGS_compile_cache_dir`` and applied uniformly
-  wherever this framework builds executables (jit/tracer.py,
-  static/__init__.py, jit/sot.py, onnx/load.py, bench.py, tier-1
-  misses), so re-runs skip XLA recompiles across processes.
+  cache, on by default and applied uniformly wherever this framework
+  builds executables (jit/tracer.py, static/__init__.py, jit/sot.py,
+  onnx/load.py, the compiled train step and serving tick, tier-1
+  misses), so re-runs skip XLA recompiles across processes.  One way to
+  place it: ``JAX_COMPILATION_CACHE_DIR`` (JAX reads it itself); unset,
+  the cache lives in ``<checkout>/.jax_cache``.
 - **Tier 3**: observability — hit/miss/evict/bytes counters per tier,
   surfaced through ``paddle_tpu.utils.cache_stats()`` and as
   ``cache_hit`` annotations on profiler op spans.
@@ -99,8 +101,7 @@ _T2_STATS = {
                         f"persistent XLA compile cache {k}")
     for k in ("hits", "misses")
 }
-_T2_APPLIED = None        # cache dir currently applied to jax.config
-_T2_LISTENING = False
+_T2_DIR = None            # the resolved cache dir, once armed
 
 
 def _freeze(v):
@@ -279,45 +280,48 @@ def _t2_listener(event, **kwargs):
         _T2_STATS["misses"].inc()
 
 
+#: where the cache lives when the environment does not place it: next
+#: to the package, absolute and the same in every process of a checkout
+#: (never a temp name, a pid or a time — a directory that moves never
+#: hits).  ``.gitignore`` lists it.
+_DEFAULT_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), ".jax_cache")
+
+
 def ensure_compile_cache():
-    """Apply ``FLAGS_compile_cache_dir`` to JAX's persistent compilation
-    cache.  Idempotent and cheap when already applied (or unset) — every
-    executable-building seam calls it right before compiling.  Returns
-    True when the persistent cache is active."""
-    global _T2_APPLIED, _T2_LISTENING
-    d = _flag("FLAGS_compile_cache_dir") or ""
-    d = str(d)
-    if not d:
-        return False
-    if _T2_APPLIED == d:
-        return True
-    try:
-        jax.config.update("jax_compilation_cache_dir", d)
-        # jax latches its cache object (or its absence) at the FIRST
-        # compile: any compile before this point — framework import
-        # triggers several — froze the old dir (or disabled state), and
-        # the dir update alone is ignored until the latch is reset
-        from jax._src import compilation_cache as _cc
-        _cc.reset_cache()
-    except Exception:
-        return False
-    # cache everything: the defaults skip sub-second compiles, which is
-    # every compile in the CPU test mesh and most eager-op programs
-    for knob, val in (("jax_persistent_cache_min_compile_time_secs", 0.0),
-                      ("jax_persistent_cache_min_entry_size_bytes", 0)):
-        try:
-            jax.config.update(knob, val)
-        except Exception:
-            pass
-    if not _T2_LISTENING:
-        try:
-            from jax._src import monitoring as _mon
-            _mon.register_event_listener(_t2_listener)
-            _T2_LISTENING = True
-        except Exception:
-            pass
-    _T2_APPLIED = d
-    return True
+    """Arm JAX's persistent compilation cache and return its directory.
+    Idempotent and cheap once armed — every executable-building seam
+    calls it right before compiling.
+
+    ``JAX_COMPILATION_CACHE_DIR`` set: JAX's own reading of it stands
+    and nothing here touches the directory option.  Unset: the cache is
+    placed in ``<checkout>/.jax_cache``."""
+    global _T2_DIR
+    if _T2_DIR is not None:
+        return _T2_DIR
+    with _LOCK:
+        if _T2_DIR is not None:
+            return _T2_DIR
+        d = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+        if not d:
+            d = _DEFAULT_CACHE_DIR
+            jax.config.update("jax_compilation_cache_dir", d)
+            # jax latches its cache object (or its absence) at the FIRST
+            # compile: any compile before this point — framework import
+            # triggers several — froze the disabled state, and the dir
+            # update alone is ignored until the latch is reset
+            from jax.experimental.compilation_cache import \
+                compilation_cache as _cc
+            _cc.reset_cache()
+        # cache everything: the defaults skip sub-second compiles, which
+        # is every compile in the CPU test mesh and most eager-op
+        # programs (hundreds of them on a cold chip run)
+        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+        jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+        jax.monitoring.register_event_listener(_t2_listener)
+        _T2_DIR = d
+    return d
 
 
 # ---------------------------------------------------------------------------
@@ -341,9 +345,9 @@ def cache_stats():
                              or 4096)
         t1["skipped_ops"] = sorted(_SKIP_OPS)
         t2 = {k: c.value for k, c in _T2_STATS.items()}
-    d = str(_flag("FLAGS_compile_cache_dir") or "")
-    t2["enabled"] = bool(d) and _T2_APPLIED == d
-    t2["dir"] = d or None
+    d = _T2_DIR
+    t2["enabled"] = d is not None
+    t2["dir"] = d
     entries = 0
     nbytes = 0
     if d and os.path.isdir(d):

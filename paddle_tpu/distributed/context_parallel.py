@@ -27,15 +27,12 @@ import math
 import jax
 import jax.numpy as jnp
 from jax import lax
-import warnings as _warnings
-with _warnings.catch_warnings():
-    _warnings.simplefilter("ignore", DeprecationWarning)
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from ..core.tensor import Tensor
 from ..core.dispatch import apply_op
-from .mesh import get_mesh
+from .mesh import get_mesh, inside_manual_region
 
 
 def _ring_attention_local(q, k, v, axis, causal, scale):
@@ -84,21 +81,6 @@ def _ring_attention_local(q, k, v, axis, causal, scale):
     return out.transpose(0, 2, 1, 3).astype(q.dtype)        # [B,S,H,D]
 
 
-def _inside_manual_region():
-    """True when tracing inside an already-manual shard_map region (the
-    pp collective-permute pipeline, pipeline_spmd.py).  Nesting another
-    manual shard_map there trips Shardy's 'parent bounding this axis as
-    manual' verifier, so seq-parallel attention falls back to the XLA
-    attention path and lets GSPMD auto-shard over sep instead — correct,
-    and still sharded, just without the explicit ring streaming."""
-    try:
-        am = jax.sharding.get_abstract_mesh()
-        return (am is not None and not am.empty
-                and jax.sharding.AxisType.Manual in am.axis_types)
-    except Exception:
-        return False
-
-
 def ring_flash_attention(query, key, value, axis="sep", mesh=None,
                          causal=True, scale=None):
     """Tensor-level ring attention op: [B, S, H, D], S sharded over `axis`.
@@ -106,7 +88,7 @@ def ring_flash_attention(query, key, value, axis="sep", mesh=None,
     Output sharding matches the input (seq-sharded over `axis`)."""
     mesh = mesh or get_mesh()
     if mesh is None or axis not in mesh.dim_names \
-            or mesh.get_dim_size(axis) <= 1 or _inside_manual_region():
+            or mesh.get_dim_size(axis) <= 1 or inside_manual_region():
         from ..pallas.flash_attention import flash_attention
         return flash_attention(query, key, value, causal=causal, scale=scale)
 
@@ -119,7 +101,7 @@ def ring_flash_attention(query, key, value, axis="sep", mesh=None,
     body = functools.partial(_ring_attention_local, axis=axis,
                              causal=causal, scale=sc)
     smapped = shard_map(body, mesh=jmesh, in_specs=(spec, spec, spec),
-                        out_specs=spec, check_rep=False)
+                        out_specs=spec, check_vma=False)
 
     return apply_op("ring_flash_attention",
                     lambda q, k, v: smapped(
@@ -164,7 +146,7 @@ def ulysses_attention(query, key, value, axis="sep", mesh=None, causal=True,
     num_heads % sep_degree == 0."""
     mesh = mesh or get_mesh()
     if mesh is None or axis not in mesh.dim_names \
-            or mesh.get_dim_size(axis) <= 1 or _inside_manual_region():
+            or mesh.get_dim_size(axis) <= 1 or inside_manual_region():
         from ..pallas.flash_attention import flash_attention
         return flash_attention(query, key, value, causal=causal, scale=scale)
     deg = mesh.get_dim_size(axis)
@@ -183,7 +165,7 @@ def ulysses_attention(query, key, value, axis="sep", mesh=None, causal=True,
     body = functools.partial(_ulysses_local, axis=axis, causal=causal,
                              scale=sc)
     smapped = shard_map(body, mesh=jmesh, in_specs=(spec, spec, spec),
-                        out_specs=spec, check_rep=False)
+                        out_specs=spec, check_vma=False)
 
     return apply_op("ulysses_attention",
                     lambda q, k, v: smapped(

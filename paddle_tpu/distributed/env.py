@@ -35,8 +35,7 @@ def init_parallel_env(coordinator_address=None, num_processes=None,
             # run before any backend touch, so callers may do it themselves)
             # — but ONLY when the distributed client really exists; a
             # too-late init with no client is a genuine failure.
-            from jax._src import distributed as _jd
-            if _jd.global_state.client is None:
+            if not jax.distributed.is_initialized():
                 raise RuntimeError(
                     "jax.distributed.initialize failed and no distributed "
                     "client exists — init_parallel_env must run before any "

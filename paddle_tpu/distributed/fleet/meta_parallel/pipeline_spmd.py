@@ -359,23 +359,15 @@ class SPMDPipeline:
                     _, ys = lax.scan(body, zero, jnp.arange(T))
                     return ys[None]  # [1, T, mb, ...]
 
-                if hasattr(jax, "shard_map"):       # jax >= 0.5 surface
-                    _shard_map = jax.shard_map
-                    sm_kwargs = dict(axis_names={"pp"}, check_vma=False)
-                else:                               # 0.4.x: experimental
-                    # full-manual over the mesh (0.4.x partial-auto
-                    # cannot host committed specs naming manual axes;
-                    # ZeRO-stacked pp × sep/mp combinations need the
-                    # jax >= 0.5 axis_names surface)
-                    from jax.experimental.shard_map import shard_map \
-                        as _shard_map
-                    sm_kwargs = dict(check_rep=False)
-                pipelined = _shard_map(
+                # manual over pp only: the other axes stay auto, so
+                # committed specs naming them pass through (ZeRO-stacked
+                # pp × sep/mp combinations)
+                pipelined = jax.shard_map(
                     tick_loop,
                     mesh=self._mesh.jax_mesh,
                     in_specs=([P("pp")] * len(stacked_arrays), P()),
                     out_specs=P("pp"),
-                    **sm_kwargs)
+                    axis_names={"pp"}, check_vma=False)
                 ys = pipelined(list(stacked_arrays), micros)  # [S, T, ...]
 
                 # collect each micro's exit tick from the last rank

@@ -82,11 +82,9 @@ class CoordKVStore:
 def coord_kv_store():
     """The coordination-service KV, or None outside a multi-controller
     job."""
-    try:
-        from jax._src import distributed as _jd
-        client = _jd.global_state.client
-    except Exception:
-        return None
+    # jax 0.9.0 has no public handle on the coordination-service client
+    from jax._src import distributed as _jd
+    client = _jd.global_state.client
     return CoordKVStore(client) if client is not None else None
 
 

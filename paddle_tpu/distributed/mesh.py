@@ -114,6 +114,28 @@ def set_mesh(mesh: ProcessMesh):
     _DEFAULT[0] = mesh
 
 
+def inside_manual_region() -> bool:
+    """True while tracing the body of a ``shard_map``: some mesh axis is
+    Manual there, the arrays are per-shard, and another manual
+    ``shard_map`` over the same axes cannot be nested."""
+    return bool(jax.sharding.get_abstract_mesh().manual_axes)
+
+
+def gspmd_mesh() -> ProcessMesh | None:
+    """The active mesh when the code being traced is (part of) ONE
+    program that GSPMD partitions over more than one device; None with
+    no mesh, a one-device mesh, or inside a ``shard_map`` body.
+
+    Mosaic (Pallas TPU) kernels cannot be partitioned automatically, so
+    every kernel gate asks this: under such a mesh a kernel either runs
+    inside its own ``shard_map`` (flash attention) or yields to its XLA
+    form (fused Adam, RMS norm, rope, paged decode)."""
+    mesh = get_mesh()
+    if mesh is None or mesh.jax_mesh.size == 1 or inside_manual_region():
+        return None
+    return mesh
+
+
 from contextlib import contextmanager
 
 

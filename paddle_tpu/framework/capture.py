@@ -31,7 +31,9 @@ The contract both rely on:
    did not see, host reads, and unexpected host-scalar providers all
    raise :class:`TraceEscape` so the caller can latch its byte-identical
    eager fallback instead of silently baking stale state into the
-   program as a constant.
+   program as a constant.  :data:`USER_TRACE_ERRORS` is the whole set
+   that may do so; any other failure of the trace or the compile
+   propagates to the caller.
 """
 from __future__ import annotations
 
@@ -63,6 +65,31 @@ class TraceEscape(Exception):
     lane permanently."""
 
     category = UserWarning
+
+
+#: What may latch a capture consumer's eager/uncompiled lane: a property
+#: of the USER's body — a typed :class:`TraceEscape`, or JAX refusing a
+#: host use of a traced value the framework's own hooks did not see
+#: (numpy conversion, ``int()``/``bool()``, a tracer leaked through a
+#: side channel).  Everything else raised while lowering or compiling
+#: (a Pallas ``NotImplementedError``/``ValueError``, ``XlaRuntimeError``,
+#: out of memory) is a defect of the framework or the device and
+#: propagates: the other lane would call the same kernel anyway, and a
+#: silent switch hides that the compiled program never ran.
+USER_TRACE_ERRORS = (
+    TraceEscape,
+    jax.errors.ConcretizationTypeError,    # incl. TracerBoolConversionError
+    jax.errors.TracerArrayConversionError,
+    jax.errors.TracerIntegerConversionError,
+    jax.errors.UnexpectedTracerError,
+)
+
+
+def describe_escape(e):
+    """The fallback reason a caught :data:`USER_TRACE_ERRORS` member
+    latches: a ``TraceEscape`` speaks for itself, a JAX error is named."""
+    return str(e) if isinstance(e, TraceEscape) \
+        else f"{type(e).__name__}: {e}"
 
 
 class Installed:

@@ -140,11 +140,12 @@ def fused_rotary_position_embedding(q, k=None, v=None, sin=None, cos=None,
         kk = ts[1] if k is not None else None
         vv = ts[2] if (v is not None and k is not None) else \
             (ts[1] if v is not None and k is None else None)
-        if cos2d is not None and _pf.rope_supported(qq.shape, d):
+        if cos2d is not None and _pf.rope_supported(
+                qq.shape, d, use_neox_rotary_style):
             c32 = cos2d.astype(jnp.float32)
             s32 = sin2d.astype(jnp.float32)
             outs = tuple(
-                _pf.rope_pallas(t, c32, s32, use_neox_rotary_style)
+                _pf.rope_pallas(t, c32, s32)
                 for t in (qq, kk, vv) if t is not None)
         else:
             outs = _apply_rope(qq, kk, vv, cos_a.astype(qq.dtype),
@@ -307,16 +308,16 @@ def paged_masked_multihead_attention(q, k, v, k_pool, v_pool, page_table,
     matmul directly), then runs the identical `_cache_attend` math.
     Returns (out, k_pool', v_pool', k_scale', v_scale') in this mode.
 
-    On TPU (or with ``PADDLE_TPU_PAGED_PALLAS=1`` under interpret
-    mode) the single-token decode read runs the Pallas kernel
+    On a TPU (and in Pallas interpret mode) the single-token decode
+    read runs the Pallas kernel
     (`pallas.flash_attention.paged_decode_attention`) that streams
     pages via a scalar-prefetched page table instead of materializing
     the gather (per-page scales ride their own scalar-prefetch-indexed
     BlockSpec); its online softmax is numerically (not bitwise)
-    equivalent, so the XLA gather path stays the default off-TPU.
+    equivalent to the XLA gather read, which serves everything else:
+    prefill chunks, CPUs, and programs partitioned over a mesh (the
+    kernel carries no ``shard_map``).
     """
-    import os as _os
-
     psz = int(page_size)
     quant = k_scale is not None
     s_new = q.shape[1] if hasattr(q, "shape") else 0
@@ -335,11 +336,8 @@ def paged_masked_multihead_attention(q, k, v, k_pool, v_pool, page_table,
             f"paged KV cache overflow: offset {off_concrete} + {s_new} "
             f"new tokens > page-table capacity {s_cap}")
 
-    env = _os.environ.get("PADDLE_TPU_PAGED_PALLAS", "")
     from ....pallas import flash_attention as _fa
-    use_kernel = (s_new == 1 and env != "0"
-                  and (_fa._on_tpu() or
-                       (env == "1" and _fa._interpret())))
+    use_kernel = s_new == 1 and _fa._unsharded_kernels_on()
 
     def fn(qa, ka, va, kp, vp, pt, off, *scales):
         from ....quantization import dequantize_kv, quantize_kv_rows

@@ -14,7 +14,8 @@ other counter:
   (last completed step)
 - ``<prefix>mfu``                gauge, analytic step FLOPs (from
   ``ops/flops.py``'s dispatch-funnel counter) / step time / peak
-  (``FLAGS_peak_flops``, else the device generation's spec number)
+  (``FLAGS_peak_flops``, else the device's row of the one peak table;
+  a CPU has no peak, so no MFU is published there)
 - ``device.memory.peak_bytes{device=i}`` high-watermark gauges sampled
   from ``jax.local_devices()[i].memory_stats()``; on backends that
   expose none (CPU) the fallback is the process RSS high-watermark in
@@ -61,8 +62,9 @@ class StepMetrics:
             prefix + "examples_per_sec", "throughput of the last step")
         self.tokens_per_sec = reg.gauge(
             prefix + "tokens_per_sec", "token throughput of the last step")
-        self.mfu = reg.gauge(
-            prefix + "mfu", "achieved / peak FLOPs of the last step")
+        # registered on the first step that has a peak to divide by: a
+        # CPU run exposes no MFU series at all, not a zero
+        self.mfu = None
         self.steps = reg.counter(prefix + "steps_total", "steps completed")
         # input-pipeline goodput (paddle_tpu.data.GoodputMeter): attached
         # by fit when the train loader is a data.Pipeline, so one
@@ -79,19 +81,24 @@ class StepMetrics:
         self.flops_per_step = flops if flops else None
 
     def peak_flops(self):
-        """``FLAGS_peak_flops`` wins; 0/unset derives from the device
-        generation's public spec sheet (profiler/timer.py)."""
+        """Peak FLOP/s of the devices the step runs on: the
+        ``peak_flops=`` argument or ``FLAGS_peak_flops`` when the caller
+        states one, else the device's row of the one peak table
+        (``cost_model.DEVICE_SPECS``) times the devices of the active
+        mesh — one when there is none, however many the host holds.
+        None on a CPU, which has no peak: no MFU is published there."""
         if self._peak:
             return float(self._peak)
         configured = float(_flag("FLAGS_peak_flops", 0.0) or 0.0)
         if configured > 0:
             return configured
-        from ..profiler.timer import device_peak_flops
-        try:
-            import jax
-            return device_peak_flops() * max(len(jax.local_devices()), 1)
-        except Exception:
+        from ..cost_model import device_peak_flops
+        from ..distributed.mesh import get_mesh
+        peak = device_peak_flops()
+        if peak is None:
             return None
+        mesh = get_mesh()
+        return peak * (mesh.jax_mesh.size if mesh is not None else 1)
 
     # ---- the per-step hot path ----
     def begin_step(self):
@@ -116,6 +123,10 @@ class StepMetrics:
         if self.flops_per_step:
             peak = self.peak_flops()
             if peak:
+                if self.mfu is None:
+                    self.mfu = self.registry.gauge(
+                        self.prefix + "mfu",
+                        "achieved / peak FLOPs of the last step")
                 self.mfu.set(
                     self.flops_per_step / max(dt, 1e-12) / peak)
         self._steps_seen += 1
@@ -154,7 +165,7 @@ class StepMetrics:
             "tokens_total": self.tokens_total.value,
             "examples_per_sec": self.examples_per_sec.value,
             "tokens_per_sec": self.tokens_per_sec.value,
-            "mfu": self.mfu.value if self.flops_per_step else None,
+            "mfu": None if self.mfu is None else self.mfu.value,
             "flops_per_step": self.flops_per_step,
             "peak_flops": self.peak_flops() if self.flops_per_step
             else None,

@@ -1,68 +1,20 @@
-"""Kernel autotune: block-size selection cache for Pallas kernels.
+"""Kernel autotune: block-size selection for Pallas kernels.
 
 Reference capability: runtime algorithm-selection cache
 (paddle/phi/kernels/autotune/cache.h, switch_autotune.h — conv algo and
 transpose tuning cached per shape key).  TPU-native realization: a
-per-(kernel, shape-key) cache of Pallas block sizes, filled either by an
-explicit timed sweep (`autotune()`) or on first use when
-``FLAGS_pallas_autotune`` is set.  The cache persists to disk so the cost
-is paid once per machine, mirroring the reference's serialized autotune
-cache.
+per-(kernel, shape-key) table of Pallas block sizes for this process,
+filled by an explicit timed sweep (`sweep()`).  Nothing is read from or
+written to a file outside the checkout: a kernel parameter comes from
+the kernel's own heuristics (``flash_attention._pick_blocks``), from a
+sweep this process ran, or from a table git tracks — so two runs of one
+commit compile the same programs.
 """
 from __future__ import annotations
 
-import json
-import os
 import time
 
 _CACHE: dict[str, dict[str, tuple]] = {}
-_LOADED = False
-
-
-def _cache_path():
-    return os.environ.get(
-        "PADDLE_TPU_AUTOTUNE_CACHE",
-        os.path.join(os.path.expanduser("~"), ".paddle_tpu_autotune.json"))
-
-
-def _load():
-    global _LOADED
-    if _LOADED:
-        return
-    _LOADED = True
-    try:
-        with open(_cache_path()) as f:
-            raw = json.load(f)
-        for op, entries in raw.items():
-            _CACHE.setdefault(op, {}).update(
-                {k: tuple(v) for k, v in entries.items()})
-    except (OSError, ValueError):
-        pass
-
-
-def _save():
-    """Merge-and-replace atomically: concurrent launched processes share
-    the cache file, so re-read before writing and os.replace the temp —
-    torn writes would silently drop every recorded config."""
-    try:
-        merged = {}
-        try:
-            with open(_cache_path()) as f:
-                disk = json.load(f)
-            if isinstance(disk, dict):
-                for op, entries in disk.items():
-                    merged.setdefault(op, {}).update(entries)
-        except (OSError, ValueError):
-            pass
-        for op, e in _CACHE.items():
-            merged.setdefault(op, {}).update(
-                {k: list(v) for k, v in e.items()})
-        tmp = _cache_path() + f".tmp.{os.getpid()}"
-        with open(tmp, "w") as f:
-            json.dump(merged, f)
-        os.replace(tmp, _cache_path())
-    except OSError:
-        pass
 
 
 def _key(shape_key):
@@ -70,51 +22,40 @@ def _key(shape_key):
 
 
 def lookup(op, shape_key):
-    """Cached config for (op, shape_key), or None."""
-    _load()
+    """Recorded config for (op, shape_key), or None."""
     return _CACHE.get(op, {}).get(_key(shape_key))
 
 
 def record(op, shape_key, config):
-    _load()
     _CACHE.setdefault(op, {})[_key(shape_key)] = tuple(config)
-    _save()
 
 
 def clear():
     _CACHE.clear()
-    try:
-        os.remove(_cache_path())
-    except OSError:
-        pass
 
 
 def sweep(op, shape_key, candidates, run, *, warmup=1, iters=3):
-    """Time `run(config)` for each candidate, cache and return the winner.
+    """Time `run(config)` for each candidate, record and return the winner.
 
     `run` must block until the device work is done (e.g. via
-    jax.block_until_ready).  Candidates that fail to compile/run are
-    skipped — the sweep never raises as long as one candidate works.
+    jax.block_until_ready).  A candidate that fails to compile or run
+    raises: a block shape the chip refuses is a finding, not a slow
+    candidate.
     """
-    _load()
     cached = lookup(op, shape_key)
     if cached is not None:
         return cached
     best, best_t = None, float("inf")
     for cfg in candidates:
-        try:
-            for _ in range(warmup):
-                run(cfg)
-            t0 = time.perf_counter()
-            for _ in range(iters):
-                run(cfg)
-            dt = (time.perf_counter() - t0) / iters
-        except Exception:
-            continue
+        for _ in range(warmup):
+            run(cfg)
+        t0 = time.perf_counter()
+        for _ in range(iters):
+            run(cfg)
+        dt = (time.perf_counter() - t0) / iters
         if dt < best_t:
             best, best_t = cfg, dt
     if best is None:
-        raise RuntimeError(
-            f"autotune sweep for {op}{shape_key}: no candidate ran")
+        raise ValueError(f"autotune sweep for {op}{shape_key}: no candidates")
     record(op, shape_key, best)
     return best

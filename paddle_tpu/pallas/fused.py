@@ -9,7 +9,7 @@ and the multi-tensor fused adam/adamw kernels
 TPU-native realization: row-blocked Pallas kernels with fp32 accumulation.
 RMS norm saves the per-row reciprocal-RMS as a residual so backward never
 re-reduces x², and accumulates the weight gradient across the sequential
-TPU grid in VMEM scratch (one kernel, no second pass).  Rope's backward is
+TPU grid in VMEM scratch (one kernel, no second pass).  Rope (neox style) has as its backward
 the forward kernel with negated sin (the rotation adjoint), so one kernel
 serves both directions.  The Adam update kernel streams (w, g, m1, m2)
 through VMEM row blocks and performs the EXACT elementwise fp32 sequence
@@ -29,7 +29,8 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from .flash_attention import _interpret, _on_tpu
+from .flash_attention import _interpret
+from .flash_attention import _unsharded_kernels_on as _kernels_on
 
 
 def _pick_block_rows(n_rows, n_cols, budget=1 << 21):
@@ -156,7 +157,7 @@ rms_norm_pallas.defvjp(_rms_vjp_fwd, _rms_vjp_bwd)
 
 
 def rms_norm_supported(x, w):
-    if not (_on_tpu() or _interpret()):
+    if not _kernels_on():
         return False
     if w is None or x.shape[-1] != w.shape[-1] or w.ndim != 1:
         return False
@@ -171,28 +172,16 @@ def rms_norm_supported(x, w):
 # Rope (rotary position embedding)
 # ------------------------------------------------------------------
 
-def _rope_kernel(t_ref, cos_ref, sin_ref, o_ref, *, neox):
+def _rope_kernel(t_ref, cos_ref, sin_ref, o_ref):
     t = t_ref[:].astype(jnp.float32)         # [block_s, H, D]
     cos = cos_ref[:].astype(jnp.float32)[:, None, :]   # [block_s, 1, D]
     sin = sin_ref[:].astype(jnp.float32)[:, None, :]
     d = t.shape[-1]
-    if neox:
-        t1 = t[..., :d // 2]
-        t2 = t[..., d // 2:]
-        rot = jnp.concatenate([-t2, t1], axis=-1)
-        o = t * cos + rot * sin
-    else:
-        # interleaved (GPT-J): pairs (0,1), (2,3), ...
-        tp = t.reshape(t.shape[:-1] + (d // 2, 2))
-        c = cos[..., 0::2]
-        s = sin[..., 0::2]
-        t1, t2 = tp[..., 0], tp[..., 1]
-        o = jnp.stack([t1 * c - t2 * s, t2 * c + t1 * s], axis=-1)
-        o = o.reshape(t.shape)
-    o_ref[:] = o.astype(o_ref.dtype)
+    rot = jnp.concatenate([-t[..., d // 2:], t[..., :d // 2]], axis=-1)
+    o_ref[:] = (t * cos + rot * sin).astype(o_ref.dtype)
 
 
-def _rope_call(t, cos, sin, neox):
+def _rope_call(t, cos, sin):
     """t: [B, S, H, D]; cos/sin: [S, D]."""
     from jax.experimental import pallas as pl
 
@@ -202,7 +191,7 @@ def _rope_call(t, cos, sin, neox):
         block_s //= 2
     grid = (b, s // block_s)
     return pl.pallas_call(
-        functools.partial(_rope_kernel, neox=neox),
+        _rope_kernel,
         grid=grid,
         in_specs=[pl.BlockSpec((None, block_s, h, d),
                                lambda i, j: (i, j, 0, 0)),
@@ -215,29 +204,34 @@ def _rope_call(t, cos, sin, neox):
     )(t, cos, sin)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
-def rope_pallas(t, cos, sin, neox):
-    """Rotary embedding, [B, S, H, D] with [S, D] tables."""
-    return _rope_call(t, cos, sin, neox)
+@jax.custom_vjp
+def rope_pallas(t, cos, sin):
+    """Rotary embedding, neox (rotate-half) style: [B, S, H, D] with
+    [S, D] tables."""
+    return _rope_call(t, cos, sin)
 
 
-def _rope_vjp_fwd(t, cos, sin, neox):
-    return _rope_call(t, cos, sin, neox), (cos, sin)
+def _rope_vjp_fwd(t, cos, sin):
+    return _rope_call(t, cos, sin), (cos, sin)
 
 
-def _rope_vjp_bwd(neox, res, g):
+def _rope_vjp_bwd(res, g):
     cos, sin = res
     # adjoint of the rotation = forward with sin negated; the sin/cos
     # tables are position constants, not parameters — zero cotangent
-    return (_rope_call(g, cos, -sin, neox),
+    return (_rope_call(g, cos, -sin),
             jnp.zeros_like(cos), jnp.zeros_like(sin))
 
 
 rope_pallas.defvjp(_rope_vjp_fwd, _rope_vjp_bwd)
 
 
-def rope_supported(t_shape, d):
-    if not (_on_tpu() or _interpret()):
+def rope_supported(t_shape, d, neox=True):
+    """The kernel serves the neox (rotate-half) style only: an
+    interleaved form's pair reshape lowers to a gather Mosaic refuses
+    ("Only 2D gather is supported"), so the XLA rope is the one
+    interleaved path."""
+    if not neox or not _kernels_on():
         return False
     return d % 2 == 0 and d <= 512 and t_shape[1] % 8 == 0
 
@@ -291,7 +285,7 @@ def adam_update_supported(w):
 def optimizer_kernels_enabled():
     from ..utils.flags import flag as _flag
     return bool(_flag("FLAGS_pallas_fused_optimizer", True)) and \
-        (_on_tpu() or _interpret())
+        _kernels_on()
 
 
 def adam_update_pallas(w, g, m1, m2, lr_s, bc1, bc2, *, b1, b2, eps, wd,

@@ -62,8 +62,8 @@ _ACTIVE = None
 def _use_pallas():
     if not flag("FLAGS_pallas_lora"):
         return False
-    from ..pallas.flash_attention import _interpret, _on_tpu
-    return _on_tpu() or _interpret()
+    from ..pallas.flash_attention import _unsharded_kernels_on
+    return _unsharded_kernels_on()
 
 
 def _pallas_delta(x, a_stack, b_stack, scale, idx):

@@ -36,7 +36,10 @@ decoding configured, layer hooks installed, and non-greedy sampling
 without a per-request ``SamplingParams.seed`` (the vectorized chain
 derives each slot's stream from ``fold_in(PRNGKey(seed), n_generated)``
 — without a seed the old path's global-RNG draws cannot be reproduced
-in-program).  See docs/SERVING.md "Compiled scheduler tick".
+in-program), and a model body one program cannot replay
+(``capture.USER_TRACE_ERRORS``).  A failure of lowering, compiling or
+running the tick is none of these: it propagates to the scheduler's
+restart wrapper.  See docs/SERVING.md "Compiled scheduler tick".
 """
 from __future__ import annotations
 
@@ -50,8 +53,9 @@ import jax.numpy as jnp
 from . import stats
 from ..core import state as _state
 from ..core.tensor import Tensor
-from ..framework.capture import (TRACE_LOCK, BindTracer, Installed,
-                                 TraceEscape, run_discovery)
+from ..framework.capture import (TRACE_LOCK, USER_TRACE_ERRORS, BindTracer,
+                                 Installed, TraceEscape, describe_escape,
+                                 run_discovery)
 from ..utils.flags import flag as _flag
 
 
@@ -175,6 +179,7 @@ class CompiledServingTick:
         self._warned = set()           # reason kinds already warned
         self._caps = []                # captured model tensors (params)
         self._jits = {}                # (mode, donating) -> jitted fn
+        self._sigs = {}                # (mode, donating) -> arg avals
         self._dev = None               # device state dict
         self._rep = {}                 # slot -> req at last rebuild
         self._mut_seen = -1            # engine mutation counter synced
@@ -358,14 +363,23 @@ class CompiledServingTick:
                                 alive, seen, out, limits, eos, temp,
                                 topk, topp, pen, keys, caps)
 
-        # the pools (the big buffers) are donated and replaced in place
-        # each tick.  The small token/seen state buffers are NOT — on
-        # this jaxlib, donating them alongside the persistent
-        # compilation cache (conftest arms it suite-wide) corrupts the
-        # CPU client's buffer bookkeeping and aborts the process; their
-        # per-tick copy is a few KB, noise next to the pool bytes.
-        donate = (0,) if donating else ()
+        # every buffer the tick replaces is donated: the pools and the
+        # last/counts/alive/seen/out scheduler state (``off`` is not —
+        # on a dirty tick it is the cache's own offset array)
+        donate = (0, 3, 4, 5, 6, 7) if donating else ()
         return jax.jit(fn, donate_argnums=donate)
+
+    def lowered_text(self, mode="greedy"):
+        """StableHLO text of a tick program that has run in ``mode`` —
+        what a reader checks to see which kernels are in it (Pallas
+        kernels appear as ``tpu_custom_call``).  None if none has."""
+        from ..core.state import no_grad
+        for key, sig in self._sigs.items():
+            if key[0] == mode:
+                # re-traces through the model, as the scheduler loop does
+                with TRACE_LOCK, no_grad():
+                    return self._jits[key].lower(*sig).as_text()
+        return None
 
     # ------------------------------------------------------------------
     # host <-> device state sync
@@ -467,13 +481,8 @@ class CompiledServingTick:
         if not self._built:
             try:
                 self._capture()
-            except TraceEscape as e:
-                self._note_fallback("capture", str(e), True)
-                return False
-            except Exception as e:  # noqa: BLE001 — any failure → eager
-                self._note_fallback(
-                    "capture", f"capture failed: "
-                    f"{type(e).__name__}: {e}", True)
+            except USER_TRACE_ERRORS as e:
+                self._note_fallback("capture", describe_escape(e), True)
                 return False
         if eng._mut != self._mut_seen or self._dev is None:
             self.flush_to_host()
@@ -508,8 +517,7 @@ class CompiledServingTick:
             for r in active.values()) else "mixed"
         donating = bool(_flag("FLAGS_jit_donate_buffers", True))
         key = (mode, donating)
-        first = key not in self._jits
-        if first:
+        if key not in self._jits:
             self._jits[key] = self._build_jit(mode, donating)
         jit = self._jits[key]
         d = self._dev
@@ -531,38 +539,30 @@ class CompiledServingTick:
                         pools += [lay["k_scale"]._data_,
                                   lay["v_scale"]._data_]
                 caps = tuple(t._data_ for t in self._caps)
+                args = (tuple(pools), pt, off, d["last"], d["counts"],
+                        d["alive"], d["seen"], d["out"], d["limits"],
+                        d["eos"], d["temp"], d["topk"], d["topp"],
+                        d["pen"], d["keys"], caps)
+                if key not in self._sigs:
+                    self._sigs[key] = jax.tree.map(
+                        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype),
+                        args)
                 (new_pools, new_off, new_last, new_counts, new_alive,
-                 new_seen, new_out, fin) = jit(
-                    tuple(pools), pt, off, d["last"], d["counts"],
-                    d["alive"], d["seen"], d["out"], d["limits"],
-                    d["eos"], d["temp"], d["topk"], d["topp"], d["pen"],
-                    d["keys"], caps)
+                 new_seen, new_out, fin) = jit(*args)
             fin_np = np.asarray(fin)    # the per-tick host sync point
-        except TraceEscape as e:
+        except USER_TRACE_ERRORS as e:
+            # the model body cannot be traced (host reads of raw array
+            # slots, data-dependent control flow) — raised during the
+            # trace, so before the pools were donated: latch the
+            # uncompiled scheduler permanently.  Any other failure
+            # (lowering, compile, device) propagates to the scheduler's
+            # restart wrapper, which fails the futures with the real
+            # error and rebuilds the cache: the uncompiled iteration
+            # would call the same kernels.
             self.flush_to_host()
             self._dev = None
-            self._note_fallback("trace", str(e), True)
+            self._note_fallback("trace", describe_escape(e), True)
             return False
-        except Exception as e:  # noqa: BLE001
-            burned = any(
-                getattr(a, "is_deleted", lambda: False)()
-                for lay in cache.layers for a in
-                (lay["k_pool"]._data_, lay["v_pool"]._data_))
-            if first and not burned:
-                # the model body cannot be traced (host reads of raw
-                # array slots, data-dependent control flow): latch the
-                # uncompiled scheduler permanently — serving never dies
-                # on the compiler
-                self.flush_to_host()
-                self._dev = None
-                self._note_fallback(
-                    "trace", f"tick trace/compile failed: "
-                    f"{type(e).__name__}: {e}", True)
-                return False
-            # a post-donation execution failure poisoned the pools —
-            # propagate so the scheduler's restart wrapper rebuilds the
-            # cache (the same crash semantics as any step failure)
-            raise
         # adopt the functionally-updated pools + offsets back into the
         # cache (device stays current; the host offset mirror advances
         # in lockstep so fallbacks/admission see the truth)

@@ -189,7 +189,7 @@ class Program:
         Program._prune_with_input used by save_inference_model).
         Returns a NEW built program computing only those outputs."""
         self._ensure_ir()
-        from jax._src.interpreters import partial_eval as pe
+        from jax.interpreters import partial_eval as pe
         n_out = len(self._jaxpr.jaxpr.outvars)
         used = [i in set(fetch_indices) for i in range(n_out)]
         new_jaxpr, used_consts, used_ins = pe.dce_jaxpr_consts(

@@ -5,8 +5,8 @@ from .flags import set_flags, get_flags  # noqa: F401
 def cache_stats():
     """Hit/miss/evict/bytes counters for the tiered executable cache
     (core/op_cache.py): ``tier1`` is the jitted eager-op dispatch LRU,
-    ``tier2`` the persistent XLA compilation cache behind
-    ``FLAGS_compile_cache_dir``.  See docs/CACHING.md."""
+    ``tier2`` the persistent XLA compilation cache.  See
+    docs/CACHING.md."""
     from ..core import op_cache
     return op_cache.cache_stats()
 

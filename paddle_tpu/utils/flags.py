@@ -30,11 +30,10 @@ _FLAGS: dict[str, Any] = {
     # tiered executable cache (core/op_cache.py).  Tier 1: jitted eager
     # op dispatch — repeated same-signature eager op calls replay one
     # cached XLA program instead of re-trace/re-dispatch; the LRU is
-    # bounded by FLAGS_eager_op_cache_size entries.  Tier 2: when
-    # FLAGS_compile_cache_dir names a directory, JAX's persistent
-    # compilation cache is enabled there, so re-runs skip XLA recompiles
-    # across processes (applies to to_static, static programs, sot
-    # segments, onnx modules, bench.py and tier-1 misses alike).
+    # bounded by FLAGS_eager_op_cache_size entries.  Tier 2 (JAX's
+    # persistent compilation cache) is always on and has no flag: the
+    # JAX_COMPILATION_CACHE_DIR environment variable places it, else it
+    # lives in <checkout>/.jax_cache (docs/CACHING.md).
     # hybrid dp×mp compiled train step (framework/train_step.py,
     # docs/TRAIN_STEP.md): a ProcessMesh with an mp axis > 1 compiles
     # the step as ONE GSPMD program over NamedSharding trees derived
@@ -59,7 +58,6 @@ _FLAGS: dict[str, Any] = {
     "FLAGS_serving_fused_sampling": True,
     "FLAGS_eager_op_cache": True,
     "FLAGS_eager_op_cache_size": 4096,
-    "FLAGS_compile_cache_dir": "",
     # fault-injection spec for robustness drills (utils/fault_injection.py;
     # grammar in docs/FAULT_TOLERANCE.md).  Empty = disabled: the save and
     # step paths then pay a single falsy check, nothing more.

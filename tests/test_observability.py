@@ -342,6 +342,35 @@ def test_step_metrics_throughput_and_mfu():
         "peak_bytes" in snap["memory"][key]
 
 
+def test_step_metrics_peak_is_per_device_of_the_step(monkeypatch):
+    """The peak comes from the one table by device_kind, times the
+    devices the step runs on (the active mesh; one without a mesh —
+    not every chip the host holds); an unlisted accelerator raises."""
+    import types
+
+    import jax
+    from paddle_tpu import cost_model
+    from paddle_tpu.distributed import mesh as mesh_mod
+
+    def fake(kind, platform="tpu"):
+        return [types.SimpleNamespace(platform=platform, device_kind=kind)]
+
+    sm = StepMetrics(prefix="pk.", registry=MetricsRegistry())
+    assert sm.peak_flops() is None                     # the CPU under test
+    monkeypatch.setattr(jax, "devices", lambda *a: fake("TPU v5 lite"))
+    assert cost_model.device_spec().name == "v5e"
+    assert cost_model.DEVICE_SPECS["v5e"].hbm_bandwidth == 819e9
+    assert sm.peak_flops() == 197e12                   # no mesh: one chip
+    monkeypatch.setattr(
+        mesh_mod, "get_mesh",
+        lambda: types.SimpleNamespace(
+            jax_mesh=types.SimpleNamespace(size=4)))
+    assert sm.peak_flops() == 4 * 197e12
+    monkeypatch.setattr(jax, "devices", lambda *a: fake("TPU v9 mega"))
+    with pytest.raises(KeyError, match="TPU v9 mega"):
+        sm.peak_flops()
+
+
 def test_step_metrics_peak_flops_flag():
     import paddle_tpu as paddle
     paddle.set_flags({"FLAGS_peak_flops": 5e11})
@@ -384,9 +413,11 @@ def test_hapi_fit_reports_step_metrics():
     assert snap["examples_per_sec"] > 0
     # float inputs: no token notion, but examples counted
     assert snap["examples_total"] - examples_before == 32
-    # linear layers have estimators → analytic flops → finite MFU
+    # linear layers have estimators → analytic flops; the CPU this runs
+    # on has no peak, so no MFU is derived from its timings
     assert snap["flops_per_step"] and snap["flops_per_step"] > 0
-    assert snap["mfu"] is not None and snap["mfu"] > 0
+    assert snap["mfu"] is None and snap["peak_flops"] is None
+    assert "train_mfu" not in model.step_metrics.registry.render_prometheus()
 
 
 # ---------------------------------------------------------------------------

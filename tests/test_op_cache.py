@@ -5,6 +5,8 @@ eviction bound, fallback-path parity (saved-tensor hooks, unhashable
 statics, per-call closure impls, flag off), gradient correctness through
 the cached jitted vjp, RNG-drawing op opt-out, and the tier-2 persistent
 compilation cache round trip."""
+import os
+
 import numpy as np
 import pytest
 
@@ -207,17 +209,19 @@ def test_eager_train_loss_parity_cache_on_off():
     np.testing.assert_allclose(on, off, rtol=1e-5, atol=1e-7)
 
 
-def test_tier2_persistent_compile_cache_round_trip(tmp_path):
+def test_tier2_persistent_compile_cache_round_trip():
     import jax
     import jax.numpy as jnp
 
-    prev_dir = jax.config.jax_compilation_cache_dir
-    d = str(tmp_path / "xla_cache")
-    paddle.set_flags({"FLAGS_compile_cache_dir": d})
+    # conftest armed the cache through the framework's resolver
+    d = op_cache.ensure_compile_cache()
+    assert d == jax.config.jax_compilation_cache_dir
+    assert os.path.isabs(d)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     try:
-        assert op_cache.ensure_compile_cache()
-        f = jax.jit(lambda a: (a * 3 + 1).sum())
-        f(jnp.ones((32, 32)))
+        const = float(np.random.default_rng().integers(1 << 30))
+        f = jax.jit(lambda a: (a * 3 + const).sum())   # a program no
+        f(jnp.ones((32, 32)))                          # earlier run cached
         st = cache_stats()["tier2"]
         assert st["enabled"] and st["dir"] == d
         assert st["entries"] > 0 and st["bytes"] > 0
@@ -225,17 +229,46 @@ def test_tier2_persistent_compile_cache_round_trip(tmp_path):
         # from the persistent cache (the cross-process re-run analog)
         jax.clear_caches()
         before = cache_stats()["tier2"]["hits"]
-        f2 = jax.jit(lambda a: (a * 3 + 1).sum())
+        f2 = jax.jit(lambda a: (a * 3 + const).sum())
         f2(jnp.ones((32, 32)))
         assert cache_stats()["tier2"]["hits"] > before
     finally:
-        paddle.set_flags({"FLAGS_compile_cache_dir": ""})
-        op_cache._T2_APPLIED = None
-        jax.config.update("jax_compilation_cache_dir", prev_dir)
         jax.config.update("jax_persistent_cache_min_compile_time_secs",
                           0.5)
-        try:     # re-point the live cache object at the restored dir
-            from jax._src import compilation_cache as _cc
-            _cc.reset_cache()
-        except Exception:
-            pass
+
+
+_PLACEMENT_PROBE = """
+import os, sys
+import jax, jax.numpy as jnp
+import paddle_tpu as paddle
+from paddle_tpu.core import op_cache
+d = op_cache.ensure_compile_cache()
+(paddle.to_tensor([1.0, 2.0]) * 3).numpy()      # an eager-op program
+jax.jit(lambda a: (a * 5 + 2).sum())(jnp.ones((8, 8)))
+print(d)
+print(len([f for f in os.listdir(d) if not f.endswith("-atime")]))
+"""
+
+
+def test_tier2_cache_placed_by_environment_only(tmp_path):
+    """JAX_COMPILATION_CACHE_DIR places the cache and the files appear
+    there and nowhere else; unset, the directory is the absolute
+    <checkout>/.jax_cache next to the package."""
+    import subprocess
+    import sys
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    default = os.path.join(root, ".jax_cache")
+    assert op_cache._DEFAULT_CACHE_DIR == default
+
+    placed = str(tmp_path / "placed")
+    before = set(os.listdir(default)) if os.path.isdir(default) else set()
+    env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=root,
+               JAX_COMPILATION_CACHE_DIR=placed)
+    out = subprocess.run([sys.executable, "-c", _PLACEMENT_PROBE], env=env,
+                         capture_output=True, text=True, timeout=300)
+    assert out.returncode == 0, out.stderr[-2000:]
+    got_dir, n_files = out.stdout.strip().splitlines()[-2:]
+    assert got_dir == placed and int(n_files) > 0
+    after = set(os.listdir(default)) if os.path.isdir(default) else set()
+    assert after == before          # nothing leaked into the default dir

@@ -2,6 +2,9 @@
 state machine, span capture, chrome export)."""
 import json
 import os
+import types
+
+import pytest
 
 import paddle_tpu as paddle
 from paddle_tpu import profiler
@@ -65,12 +68,16 @@ def test_benchmark_ips():
     assert "ips" in bm.step_info()
 
 
+_V5E = types.SimpleNamespace(platform="tpu", device_kind="TPU v5 lite")
+
+
 def test_mfu_calculator():
-    # 1 TFLOP step in 0.1s on a nominal-1TFLOPs cpu device = 10x? no:
-    # mfu = flops/time/peak; just sanity-check monotonicity + bounds
-    m1 = profiler.mfu(1e12, 1.0, n_devices=1)
-    m2 = profiler.mfu(1e12, 2.0, n_devices=1)
-    assert m1 > m2 > 0
+    # mfu = flops/time/peak, the peak from the device's row of the table
+    m1 = profiler.mfu(197e12, 1.0, n_devices=1, device=_V5E)
+    m2 = profiler.mfu(197e12, 2.0, n_devices=2, device=_V5E)
+    assert m1 == pytest.approx(1.0) and m2 == pytest.approx(0.25)
+    # the CPU these tests run on has no peak: no MFU from its timings
+    assert profiler.mfu(1e12, 1.0) is None
 
 
 def test_registry_flops_counter_mfu():
@@ -99,7 +106,7 @@ def test_registry_flops_counter_mfu():
     ratio = fc.train_step_flops / analytic_step
     assert 1 / 3 < ratio < 3, (ratio, fc.by_op, fc.uncounted)
     # registry-metadata MFU is finite and positive
-    val = profiler.mfu(fc.train_step_flops, step_time_s=0.5)
+    val = profiler.mfu(fc.train_step_flops, step_time_s=0.5, device=_V5E)
     assert 0 < val < 100
 
 

@@ -1,0 +1,327 @@
+"""Every kernel that turns itself on by platform compiles for the chip.
+
+No chip is needed: the installed libtpu describes a v5e 2x2 topology
+(``jax.experimental.topologies.get_topology_desc``) whose devices can be
+compiled against — real Mosaic, no run.  Each Pallas kernel is lowered
+and compiled for it at the shapes GPT-2 124M, GPT-3 1.3B widths and
+Llama use, alone and inside a program partitioned over a 2x2 mesh; a
+kernel that cannot be hosted somewhere must be deselected there by a
+rule in the code, which is asserted too.  The interpreter
+(``PADDLE_TPU_PALLAS_INTERPRET``) is off throughout.
+"""
+import math
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import NamedSharding, PartitionSpec as P
+from jax.sharding import SingleDeviceSharding
+
+from paddle_tpu.pallas import flash_attention as fa
+from paddle_tpu.pallas import fused
+
+BF16, F32, I8 = jnp.bfloat16, jnp.float32, jnp.int8
+
+
+@pytest.fixture(scope="module")
+def v5e():
+    """The four compile-only ``TPU v5 lite`` devices of a v5e 2x2."""
+    mp = pytest.MonkeyPatch()
+    mp.setenv("TPU_ACCELERATOR_TYPE", "v5litepod-4")
+    mp.setenv("TPU_WORKER_HOSTNAMES", "localhost")
+    try:
+        from jax.experimental import topologies
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — the one admitted skip
+        pytest.skip("jax.experimental.topologies.get_topology_desc cannot "
+                    f"describe a v5e topology here, so nothing can be "
+                    f"compiled for the chip: {type(e).__name__}: {e}")
+    finally:
+        mp.undo()
+    assert topo.devices[0].device_kind == "TPU v5 lite"
+    return list(topo.devices)
+
+
+@pytest.fixture(autouse=True)
+def _as_on_the_chip(monkeypatch):
+    """The gates decide as they do on a TPU; no interpreter."""
+    monkeypatch.delenv("PADDLE_TPU_PALLAS_INTERPRET", raising=False)
+    monkeypatch.setattr(fa, "_on_tpu", lambda: True)
+
+
+def _compile(fn, shapes, sharding):
+    """Lower + compile ``fn`` for the TPU; returns the StableHLO text."""
+    args = [None if s is None else
+            jax.ShapeDtypeStruct(s[0], s[1], sharding=sharding)
+            for s in shapes]
+    lowered = jax.jit(fn).lower(*args)
+    lowered.compile()
+    return lowered.as_text()
+
+
+def _n_kernels(text):
+    return text.count("tpu_custom_call")
+
+
+# ---------------------------------------------------------------- flash
+
+#           b  s     h   h_kv d    head_major  features
+_FLASH = {
+    "gpt2-124m": (2, 1024, 12, 12, 64, True, ()),
+    "gpt3-1.3b": (1, 2048, 16, 16, 128, True, ()),
+    "llama-gqa-segments": (1, 2048, 32, 8, 128, False, ("seg",)),
+    "d64-additive-mask": (1, 1024, 12, 12, 64, False, ("mask",)),
+    "d64-dropout": (1, 1024, 12, 12, 64, True, ("dropout",)),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_FLASH))
+def test_flash_fwd_bwd_compiles(v5e, case):
+    b, s, h, h_kv, d, head_major, feats = _FLASH[case]
+    dropout = 0.1 if "dropout" in feats else 0.0
+    bq, bk = fa._pick_blocks(s, d)
+    bqb, bkb = fa._pick_blocks(s, d, which="bwd")
+
+    def f(q, k, v, mask, qseg, kseg, seed):
+        def loss(q, k, v):
+            out = fa._flash_core(q, k, v, mask, qseg, kseg, seed, True,
+                                 1.0 / math.sqrt(d), dropout, bq, bk,
+                                 bqb, bkb, head_major)
+            return jnp.sum(out.astype(F32))
+        return jax.value_and_grad(loss, argnums=(0, 1, 2))(q, k, v)
+
+    def qkv(heads):
+        return ((b, heads, s, d) if head_major else (b, s, heads, d), BF16)
+
+    shapes = [qkv(h), qkv(h_kv), qkv(h_kv),
+              ((1, 1, s, s), F32) if "mask" in feats else None,
+              ((b, s, 1), jnp.int32) if "seg" in feats else None,
+              ((b, 1, s), jnp.int32) if "seg" in feats else None,
+              ((1, 1), jnp.uint32) if dropout else None]
+    text = _compile(f, shapes, SingleDeviceSharding(v5e[0]))
+    assert _n_kernels(text) == 3          # fwd, dK/dV, dQ
+
+
+def test_flash_every_autotune_candidate_compiles(v5e):
+    """``autotune_blocks`` sweeps these nine block pairs on the chip and
+    no longer skips one that fails: each must compile."""
+    s, d = 1024, 64
+    for bq in (128, 256, 512):
+        for bk in (128, 256, 512):
+            def f(q, k, v, bq=bq, bk=bk):
+                return fa._flash_core(q, k, v, None, None, None, None,
+                                      True, 0.125, 0.0, bq, bk, None,
+                                      None, False)
+            text = _compile(f, [((1, s, 2, d), BF16)] * 3,
+                            SingleDeviceSharding(v5e[0]))
+            assert _n_kernels(text) == 1, (bq, bk)
+
+
+# ------------------------------------------------- rms norm, rope, adam
+
+@pytest.mark.parametrize("rows,n", [(2048, 2048), (2048, 4096)])
+def test_rms_norm_fwd_bwd_compiles(v5e, rows, n):
+    assert fused.rms_norm_supported(
+        jax.ShapeDtypeStruct((rows, n), BF16),
+        jax.ShapeDtypeStruct((n,), BF16))
+
+    def f(x, w):
+        return jax.value_and_grad(
+            lambda x, w: jnp.sum(fused.rms_norm_pallas(x, w, 1e-6)
+                                 .astype(F32)), argnums=(0, 1))(x, w)
+
+    text = _compile(f, [((rows, n), BF16), ((n,), BF16)],
+                    SingleDeviceSharding(v5e[0]))
+    assert _n_kernels(text) == 2
+
+
+def test_rope_neox_compiles_and_interleaved_is_deselected(v5e):
+    shape, d = (1, 2048, 32, 128), 128
+    assert fused.rope_supported(shape, d, neox=True)
+    # no interleaved kernel compiles for the chip (its pair reshape is a
+    # gather Mosaic refuses): the XLA rope is that style's one path
+    assert not fused.rope_supported(shape, d, neox=False)
+
+    def f(t, cos, sin):
+        return jax.value_and_grad(
+            lambda t: jnp.sum(fused.rope_pallas(t, cos, sin)
+                              .astype(F32)))(t)
+
+    text = _compile(f, [(shape, BF16), ((2048, d), F32), ((2048, d), F32)],
+                    SingleDeviceSharding(v5e[0]))
+    assert _n_kernels(text) == 2
+
+
+@pytest.mark.parametrize("shape", [(50304, 768), (768, 3072),
+                                   (2048, 8192)])
+def test_fused_adam_compiles(v5e, shape):
+    assert fused.optimizer_kernels_enabled()
+    assert fused.adam_update_supported(jax.ShapeDtypeStruct(shape, F32))
+
+    def f(w, g, m1, m2, lr, bc1, bc2):
+        return fused.adam_update_pallas(
+            w, g, m1, m2, lr, bc1, bc2, b1=0.9, b2=0.999, eps=1e-8,
+            wd=0.01, decoupled=True)
+
+    text = _compile(f, [(shape, F32), (shape, BF16), (shape, F32),
+                        (shape, F32), ((), F32), ((), F32), ((), F32)],
+                    SingleDeviceSharding(v5e[0]))
+    assert _n_kernels(text) == 1
+
+
+# --------------------------------------------------------- paged decode
+
+#         slots h   h_kv d    page pages/row pool dtype
+_PAGED = {
+    "gpt2-f32": (4, 12, 12, 64, 16, 64, F32),
+    "gpt2-int8": (4, 12, 12, 64, 32, 32, I8),
+    "gpt3-1.3b-bf16": (4, 16, 16, 128, 16, 128, BF16),
+    "llama-gqa-int8": (4, 32, 8, 128, 32, 64, I8),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_PAGED))
+def test_paged_decode_compiles(v5e, case):
+    b, h, h_kv, d, psz, n, pool_dt = _PAGED[case]
+    pool = (1 + b * n, psz, h_kv, d)
+    shapes = [((b, h, d), F32 if pool_dt == F32 else BF16),
+              (pool, pool_dt), (pool, pool_dt),
+              ((b, n), jnp.int32), ((b,), jnp.int32)]
+    if pool_dt == I8:
+        shapes += [(pool[:2], F32), (pool[:2], F32)]
+
+        def f(q, k, v, pt, off, ks, vs):
+            return fa.paged_decode_attention(q, k, v, pt, off,
+                                             k_scale=ks, v_scale=vs)
+    else:
+        f = fa.paged_decode_attention
+    text = _compile(f, shapes, SingleDeviceSharding(v5e[0]))
+    assert _n_kernels(text) == 1
+
+
+def test_paged_decode_through_the_op_compiles(v5e):
+    """The serving decode step reaches the kernel through the framework
+    op (page write + kernel read) at the GPT-2 124M tick's shapes."""
+    from paddle_tpu.core.state import no_grad
+    from paddle_tpu.core.tensor import Tensor
+    from paddle_tpu.incubate.nn import functional as IF
+
+    b, h, d, psz, n = 4, 12, 64, 16, 64
+    pool = (1 + b * n, psz, h, d)
+
+    def f(q, k, v, kp, vp, pt, off):
+        with no_grad():
+            out, kp2, vp2 = IF.paged_masked_multihead_attention(
+                Tensor(q), Tensor(k), Tensor(v), Tensor(kp), Tensor(vp),
+                Tensor(pt), Tensor(off), psz)
+        return out._data_, kp2._data_, vp2._data_
+
+    text = _compile(f, [((b, 1, h, d), BF16)] * 3
+                    + [(pool, F32), (pool, F32), ((b, n), jnp.int32),
+                       ((b,), jnp.int32)], SingleDeviceSharding(v5e[0]))
+    assert _n_kernels(text) == 1
+
+
+# ----------------------------------------------------------- lora delta
+
+def test_lora_delta_compiles(v5e):
+    """``FLAGS_pallas_lora`` is off by default and off the main path;
+    its kernel lowers all the same (verdict recorded in PERF.md)."""
+    from paddle_tpu.serving.adapters import _pallas_delta
+
+    ns, din, rp, dout, pool = 4, 768, 8, 2304, 5
+    text = _compile(_pallas_delta,
+                    [((ns, 1, din), BF16), ((pool, din, rp), BF16),
+                     ((pool, rp, dout), BF16), ((pool,), F32),
+                     ((ns,), jnp.int32)], SingleDeviceSharding(v5e[0]))
+    assert _n_kernels(text) == 1
+
+
+# ------------------------------------------------- under a 2x2 dp×mp mesh
+
+@pytest.fixture
+def mesh2x2(v5e):
+    from paddle_tpu.distributed.mesh import ProcessMesh
+    mesh = ProcessMesh(np.array(v5e, dtype=object).reshape(2, 2),
+                       ["dp", "mp"])
+    with mesh:
+        yield mesh
+
+
+def test_flash_under_mesh_runs_in_shard_map(v5e, mesh2x2):
+    """One GSPMD program over dp2×mp2 (the hybrid lane of
+    ``CompiledTrainStep``): Mosaic kernels cannot be partitioned
+    automatically, so the public op wraps them in ``shard_map`` — batch
+    over dp, heads over mp — and the program compiles with flash on."""
+    from paddle_tpu.core.tensor import Tensor
+    from paddle_tpu.nn import functional as F
+
+    def f(q, k, v):
+        def loss(q, k, v):
+            out = F.scaled_dot_product_attention(
+                Tensor(q), Tensor(k), Tensor(v), is_causal=True)
+            return jnp.sum(out._data_.astype(F32))
+        return jax.value_and_grad(loss, argnums=(0, 1, 2))(q, k, v)
+
+    sharding = NamedSharding(mesh2x2.jax_mesh, P("dp", None, "mp", None))
+    text = _compile(f, [((8, 1024, 12, 64), BF16)] * 3, sharding)
+    assert _n_kernels(text) == 3 and "sdy.manual_computation" in text
+
+
+def test_bare_kernel_under_mesh_is_refused(v5e, mesh2x2):
+    """Why the rule exists: without ``shard_map`` Mosaic refuses the
+    very same call, replicated operands or not."""
+    def f(q, k, v):
+        return fa._flash_core(q, k, v, None, None, None, None, True,
+                              0.125, 0.0, 256, 512, None, None, False)
+
+    sharding = NamedSharding(mesh2x2.jax_mesh, P())
+    with pytest.raises(Exception, match="shard_map"):
+        _compile(f, [((8, 1024, 12, 64), BF16)] * 3, sharding)
+
+
+def _adamw_update():
+    """The optimizer's own fused update over one parameter, as the
+    compiled step's tail calls it."""
+    import paddle_tpu as paddle
+
+    opt = paddle.optimizer.AdamW(
+        1e-4, parameters=[paddle.Parameter(np.zeros((8, 8), np.float32))])
+
+    def f(p, g, m1, m2):
+        new_p, _ = opt._fused_update(
+            jnp.float32(1e-4), jnp.float32(1.0), [p], [g],
+            {"moment1": [m1], "moment2": [m2], "master": [None]},
+            lr_scales=(1.0,), wd_mask=(True,))
+        return new_p
+    return f
+
+
+def test_unsharded_kernels_yield_to_xla_under_mesh(v5e, mesh2x2):
+    """Adam, RMS norm, rope and paged decode carry no ``shard_map``:
+    under a multi-device mesh their gates say no (a static rule), and
+    the AdamW update of mp-sharded parameters compiles as plain XLA."""
+    assert not fused.optimizer_kernels_enabled()
+    assert not fused.rms_norm_supported(
+        jax.ShapeDtypeStruct((2048, 4096), BF16),
+        jax.ShapeDtypeStruct((4096,), BF16))
+    assert not fused.rope_supported((1, 2048, 32, 128), 128, neox=True)
+    assert not fa._unsharded_kernels_on()
+
+    sharding = NamedSharding(mesh2x2.jax_mesh, P(None, "mp"))
+    text = _compile(_adamw_update(), [((768, 3072), F32)] * 4, sharding)
+    assert _n_kernels(text) == 0
+
+
+def test_same_update_is_the_pallas_kernel_without_a_mesh(v5e):
+    text = _compile(_adamw_update(), [((768, 3072), F32)] * 4,
+                    SingleDeviceSharding(v5e[0]))
+    assert _n_kernels(text) == 1
+
+
+def test_interpreter_on_a_tpu_is_an_error(monkeypatch):
+    monkeypatch.setenv("PADDLE_TPU_PALLAS_INTERPRET", "1")
+    with pytest.raises(RuntimeError, match="PADDLE_TPU_PALLAS_INTERPRET"):
+        fa._interpret()

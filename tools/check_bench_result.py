@@ -214,7 +214,7 @@ _MFU_SWEEP_SCHEMA = {
     "platform": str,
 }
 _MFU_LAYOUT_KEYS = ("dp", "mp", "p50_ms", "tokens_per_sec", "compiled",
-                    "projected_ms", "projected_err", "anchor", "mfu")
+                    "projected_ms", "projected_err", "anchor")
 
 # acceptance floors (ISSUE 12): at equal world size the hybrid
 # dp×mp compiled step must beat the dp-only compiled step by >= 1.3x
