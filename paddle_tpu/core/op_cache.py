@@ -90,6 +90,18 @@ def _flush_hot_hits():
     if n:
         _T1_HOT_HITS[0] = 0
         _T1_STATS["hits"].inc(n)
+
+
+def dispatch_count():
+    """Eager ops the funnel has dispatched so far: tier-1 hits, misses
+    and bypasses, the hot-hit batch flushed first.  A caller reads it
+    before and after a stretch of eager code to count its launches at
+    no per-op cost."""
+    with _LOCK:
+        _flush_hot_hits()
+    return sum(_T1_STATS[k].value for k in ("hits", "misses", "bypasses"))
+
+
 _T1_BYTES = _metrics.gauge("cache.tier1.bytes",
                            "summed input-aval bytes of cached signatures")
 # op names permanently opted out: impls that draw framework RNG inside
@@ -102,6 +114,9 @@ _T2_STATS = {
     for k in ("hits", "misses")
 }
 _T2_DIR = None            # the resolved cache dir, once armed
+_COMPILE_MS = _metrics.histogram(
+    "jit.compile_ms", "wall time to build one program: an XLA compile "
+    "or a load from the persistent compile cache (ms)")
 
 
 def _freeze(v):
@@ -280,6 +295,14 @@ def _t2_listener(event, **kwargs):
         _T2_STATS["misses"].inc()
 
 
+def _compile_listener(event, duration_secs, **kwargs):
+    # jax times ``compile_or_get_cached`` as one backend-compile event,
+    # whether XLA compiled the program or the persistent cache gave it
+    # back: every program built or loaded, once, with its seconds
+    if event == "/jax/core/compile/backend_compile_duration":
+        _COMPILE_MS.observe(duration_secs * 1e3)
+
+
 #: where the cache lives when the environment does not place it: next
 #: to the package, absolute and the same in every process of a checkout
 #: (never a temp name, a pid or a time — a directory that moves never
@@ -320,6 +343,8 @@ def ensure_compile_cache():
         jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
         jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
         jax.monitoring.register_event_listener(_t2_listener)
+        jax.monitoring.register_event_duration_secs_listener(
+            _compile_listener)
         _T2_DIR = d
     return d
 

@@ -75,6 +75,7 @@ import jax.numpy as jnp
 
 from ..core import state as _state
 from ..core.tensor import Tensor
+from ..observability.tracing import span as _span
 from ..utils.flags import flag as _flag
 from .capture import (USER_TRACE_ERRORS, BindTracer, Installed,
                       TraceEscape, describe_escape, run_discovery)
@@ -228,30 +229,35 @@ class CompiledTrainStep:
             update = (self._micro + 1) >= self._accum
         self._calls += 1
         from ..utils import monitor as _monitor
-        if self._fallback_reason is not None or not self._eligible_now():
-            _monitor.incr("jit.compiled_step_fallback")
-            loss = self._run_eager(x, y, update)
-        elif self._calls == 1:
-            loss = self._run_eager(x, y, update)   # real warmup step
-            try:
-                self._discover(x, y)
-            except USER_TRACE_ERRORS as e:
-                self._latch_user_escape(e)
-        else:
-            try:
-                loss = self._run_compiled(x, y, update)
-                _monitor.incr("jit.compiled_step_hit")
-            except USER_TRACE_ERRORS as e:
-                # raised while tracing, so before any buffer was donated
-                self._latch_user_escape(e)
+        # host time of the call; the device runs on after it returns
+        with _span("train.step", step_num=self._calls):
+            if self._fallback_reason is not None \
+                    or not self._eligible_now():
+                _monitor.incr("jit.compiled_step_fallback")
                 loss = self._run_eager(x, y, update)
-            except Exception as e:
-                # a lowering, compile or device failure is not the
-                # user's and has no other lane: it propagates, with the
-                # state of the donated buffers said when they are gone
-                if self._donation_burned():
-                    raise RuntimeError(_DONATED_FAILURE_MSG) from e
-                raise
+            elif self._calls == 1:
+                loss = self._run_eager(x, y, update)   # real warmup step
+                try:
+                    self._discover(x, y)
+                except USER_TRACE_ERRORS as e:
+                    self._latch_user_escape(e)
+            else:
+                try:
+                    loss = self._run_compiled(x, y, update)
+                    _monitor.incr("jit.compiled_step_hit")
+                except USER_TRACE_ERRORS as e:
+                    # raised while tracing, so before any buffer was
+                    # donated
+                    self._latch_user_escape(e)
+                    loss = self._run_eager(x, y, update)
+                except Exception as e:
+                    # a lowering, compile or device failure is not the
+                    # user's and has no other lane: it propagates, with
+                    # the state of the donated buffers said when they
+                    # are gone
+                    if self._donation_burned():
+                        raise RuntimeError(_DONATED_FAILURE_MSG) from e
+                    raise
         self._micro = 0 if update else self._micro + 1
         return loss
 
@@ -669,9 +675,10 @@ class CompiledTrainStep:
             grads = [g._data_ for _, g in pairs]
 
         new_step = step_arr + 1.0
-        new_params, new_states = type(opt)._fused_update(
-            opt, lr, new_step, list(param_arrs), grads, states,
-            lr_scales=self._lr_scales, wd_mask=self._wd_mask)
+        with jax.named_scope("optimizer"):
+            new_params, new_states = type(opt)._fused_update(
+                opt, lr, new_step, list(param_arrs), grads, states,
+                lr_scales=self._lr_scales, wd_mask=self._wd_mask)
 
         # skip decision: the scaler's found-inf flag when one is
         # installed (bitwise-identical to the pre-sentinel program), or
@@ -720,8 +727,8 @@ class CompiledTrainStep:
         ensure_compile_cache()     # tier-2 persistent XLA compile cache
         mesh = self._mesh
 
-        def fn(x, y, params, grads, caps, states, step_arr, svec, lr,
-               key, hmark):
+        def train_step(x, y, params, grads, caps, states, step_arr, svec,
+                       lr, key, hmark):
             if self._shard_map:
                 from jax.sharding import PartitionSpec as P
 
@@ -769,7 +776,10 @@ class CompiledTrainStep:
             # chain is elementwise, so outputs land on the input
             # shardings and donation stays usable)
             kwargs["in_shardings"] = self._hybrid_shardings(args)
-        return jax.jit(fn, donate_argnums=donate, **kwargs)
+        # the program's name on the trace's ``XLA Modules`` line
+        train_step.__name__ = train_step.__qualname__ = \
+            "train_step" if update else "train_micro_step"
+        return jax.jit(train_step, donate_argnums=donate, **kwargs)
 
     def _gather_args(self, x, y):
         opt = self._opt
@@ -809,7 +819,8 @@ class CompiledTrainStep:
     def _run_compiled(self, x, y, update):
         from ..utils import monitor as _monitor
         opt = self._opt
-        args = self._gather_args(x, y)
+        with _span("train.step.gather"):
+            args = self._gather_args(x, y)
         if self._dp > 1 and (args[0].shape[0] % self._dp):
             # ragged tail batch cannot shard evenly: one-off eager step
             _monitor.incr("jit.compiled_step_ragged_fallback")
@@ -832,39 +843,41 @@ class CompiledTrainStep:
                 self._jit_full = jit
             else:
                 self._jit_micro = jit
-            _monitor.incr("jit.compiled_step_compile")
         if self._donating and self._aliased(args, update):
             _monitor.incr("jit.compiled_step_alias_fallback")
             return self._run_eager(x, y, update)
 
-        if update:
-            (loss, new_params, zeroed, new_states, new_step, new_svec,
-             mut_vals, health) = jit(*args)
-            self.last_health = health    # device [gnorm_sq, skipped]
-            for p, arr in zip(self._params, new_params):
-                p._data_ = arr
-            for name in self._state_names:
-                vals = opt._state[name]
-                for k, i in enumerate(self._idxs):
-                    nv = new_states[name][k]
-                    if nv is None:
-                        continue
-                    if vals[i] is None:
-                        vals[i] = Tensor(nv)
-                    else:
-                        vals[i]._data_ = nv
-            opt._step_tensor._data_ = new_step
-            opt._step_count += 1
-            if new_svec is not None:
-                self._scaler_vec = new_svec
-            for p, g in zip(self._params, zeroed):
-                p.grad._data_ = g
-        else:
-            loss, new_grads, mut_vals = jit(*args)
-            for p, g in zip(self._params, new_grads):
-                p.grad._data_ = g
-        for t, arr in zip(self._mut_caps, mut_vals):
-            t._data_ = arr
+        with _span("train.step.launch"):
+            out = jit(*args)
+        with _span("train.step.adopt"):
+            if update:
+                (loss, new_params, zeroed, new_states, new_step, new_svec,
+                 mut_vals, health) = out
+                self.last_health = health    # device [gnorm_sq, skipped]
+                for p, arr in zip(self._params, new_params):
+                    p._data_ = arr
+                for name in self._state_names:
+                    vals = opt._state[name]
+                    for k, i in enumerate(self._idxs):
+                        nv = new_states[name][k]
+                        if nv is None:
+                            continue
+                        if vals[i] is None:
+                            vals[i] = Tensor(nv)
+                        else:
+                            vals[i]._data_ = nv
+                opt._step_tensor._data_ = new_step
+                opt._step_count += 1
+                if new_svec is not None:
+                    self._scaler_vec = new_svec
+                for p, g in zip(self._params, zeroed):
+                    p.grad._data_ = g
+            else:
+                loss, new_grads, mut_vals = out
+                for p, g in zip(self._params, new_grads):
+                    p.grad._data_ = g
+            for t, arr in zip(self._mut_caps, mut_vals):
+                t._data_ = arr
         return Tensor(loss)
 
     def _aliased(self, args, update):

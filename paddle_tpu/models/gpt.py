@@ -13,6 +13,7 @@ import math
 from dataclasses import dataclass, field
 
 import numpy as np
+from jax import named_scope
 
 from ..nn import Layer, Linear, Embedding, LayerNorm, Dropout, LayerList
 from ..nn import functional as F
@@ -133,8 +134,12 @@ class GPTBlock(Layer):
         self.dropout = Dropout(config.dropout)
 
     def forward(self, x, cache=None):
-        x = x + self.dropout(self.attn(self.ln_1(x), cache=cache))
-        x = x + self.dropout(self.mlp(self.ln_2(x)))
+        # scopes name a compiled program's operations by layer kind in
+        # an xprof view (docs/OBSERVABILITY.md, "Names on the device")
+        with named_scope("attn"):
+            x = x + self.dropout(self.attn(self.ln_1(x), cache=cache))
+        with named_scope("mlp"):
+            x = x + self.dropout(self.mlp(self.ln_2(x)))
         return x
 
 
@@ -155,19 +160,20 @@ class GPTModel(Layer):
 
     def forward(self, input_ids, position_ids=None, caches=None):
         b, s = input_ids.shape
-        if position_ids is None:
-            position_ids = creation.arange(s, dtype="int32")
-            if caches is not None:
-                off = caches[0]["offset"]
-                if len(getattr(off, "shape", [])) == 1:
-                    # per-slot offsets (serving): [B, S] positions so each
-                    # row is embedded at its own age
-                    position_ids = MA.reshape(off, [b, 1]) + \
-                        MA.reshape(position_ids, [1, s])
-                else:
-                    position_ids = position_ids + off
-        x = self.wte(input_ids) + self.wpe(position_ids)
-        x = self.drop(x)
+        with named_scope("embed"):
+            if position_ids is None:
+                position_ids = creation.arange(s, dtype="int32")
+                if caches is not None:
+                    off = caches[0]["offset"]
+                    if len(getattr(off, "shape", [])) == 1:
+                        # per-slot offsets (serving): [B, S] positions so
+                        # each row is embedded at its own age
+                        position_ids = MA.reshape(off, [b, 1]) + \
+                            MA.reshape(position_ids, [1, s])
+                    else:
+                        position_ids = position_ids + off
+            x = self.wte(input_ids) + self.wpe(position_ids)
+            x = self.drop(x)
         for i, block in enumerate(self.h):
             if self.config.use_recompute and caches is None \
                     and not x.stop_gradient:
@@ -192,14 +198,16 @@ class GPTForCausalLM(Layer):
     def forward(self, input_ids, labels=None, position_ids=None,
                 caches=None):
         hidden = self.gpt(input_ids, position_ids, caches=caches)
-        if self.lm_head is not None:
-            logits = self.lm_head(hidden)
-        else:
-            logits = F.linear(hidden, self.gpt.wte.weight.T)
+        with named_scope("head"):
+            if self.lm_head is not None:
+                logits = self.lm_head(hidden)
+            else:
+                logits = F.linear(hidden, self.gpt.wte.weight.T)
         if labels is not None:
-            loss = F.cross_entropy(
-                MA.reshape(logits, [-1, self.config.vocab_size]),
-                MA.reshape(labels, [-1]))
+            with named_scope("loss"):
+                loss = F.cross_entropy(
+                    MA.reshape(logits, [-1, self.config.vocab_size]),
+                    MA.reshape(labels, [-1]))
             return logits, loss
         return logits
 

@@ -13,6 +13,8 @@ from __future__ import annotations
 import math
 from dataclasses import dataclass
 
+from jax import named_scope
+
 from ..nn import Layer, Linear, Embedding, RMSNorm, LayerList
 from ..nn import functional as F
 from ..nn.initializer import Normal, ParamAttr
@@ -159,8 +161,12 @@ class LlamaBlock(Layer):
         self.mlp = LlamaMLP(config)
 
     def forward(self, x, cache=None):
-        x = x + self.self_attn(self.input_layernorm(x), cache=cache)
-        x = x + self.mlp(self.post_attention_layernorm(x))
+        # scopes name a compiled program's operations by layer kind in
+        # an xprof view (docs/OBSERVABILITY.md, "Names on the device")
+        with named_scope("attn"):
+            x = x + self.self_attn(self.input_layernorm(x), cache=cache)
+        with named_scope("mlp"):
+            x = x + self.mlp(self.post_attention_layernorm(x))
         return x
 
 
@@ -177,7 +183,8 @@ class LlamaModel(Layer):
         self.norm = RMSNorm(config.hidden_size, epsilon=config.rms_norm_eps)
 
     def forward(self, input_ids, caches=None):
-        x = self.embed_tokens(input_ids)
+        with named_scope("embed"):
+            x = self.embed_tokens(input_ids)
         for i, blk in enumerate(self.layers):
             x = blk(x, cache=None if caches is None else caches[i])
         return self.norm(x)
@@ -196,14 +203,17 @@ class LlamaForCausalLM(Layer):
 
     def forward(self, input_ids, labels=None, caches=None):
         hidden = self.llama(input_ids, caches=caches)
-        if self.lm_head is not None:
-            logits = self.lm_head(hidden)
-        else:
-            logits = F.linear(hidden, self.llama.embed_tokens.weight.T)
+        with named_scope("head"):
+            if self.lm_head is not None:
+                logits = self.lm_head(hidden)
+            else:
+                logits = F.linear(hidden,
+                                  self.llama.embed_tokens.weight.T)
         if labels is not None:
-            loss = F.cross_entropy(
-                MA.reshape(logits, [-1, self.config.vocab_size]),
-                MA.reshape(labels, [-1]))
+            with named_scope("loss"):
+                loss = F.cross_entropy(
+                    MA.reshape(logits, [-1, self.config.vocab_size]),
+                    MA.reshape(labels, [-1]))
             return logits, loss
         return logits
 

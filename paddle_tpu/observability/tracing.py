@@ -38,9 +38,19 @@ context objects, spans, or I/O exist — every instrumented seam pays a
 single falsy flag check or ``is None`` compare, and serving output is
 byte-identical to this module never existing (the
 ``FLAGS_fault_inject`` / flight-recorder ``capacity <= 0`` precedent).
+
+Phases that start and end on one thread (the train step, the scheduler
+tick, a prefill chunk, admission) use :func:`span` instead: one context
+manager whose sinks are the profiler's own trace (a
+``jax.profiler.TraceAnnotation``, so the phase sits on the device
+trace's clock), the ``<name>_ms`` histogram, the flight recorder and a
+recording ``Profiler`` — all with tracing off — and, with
+``FLAGS_trace_dir`` set, a ``kind: "phase"`` record in a ring of its
+own beside the request spans, spooled and merged with them.
 """
 from __future__ import annotations
 
+import atexit
 import contextlib
 import hashlib
 import itertools
@@ -50,7 +60,10 @@ import threading
 import time
 from collections import deque
 
+from jax.profiler import StepTraceAnnotation, TraceAnnotation
+
 from ..utils.flags import flag as _flag
+from . import flight_recorder as _flight_recorder
 
 SCHEMA_VERSION = 1
 
@@ -63,6 +76,11 @@ _tls = threading.local()
 _ids = itertools.count(1)
 _buffer: deque = deque()          # completed span/decision records
 _spooled: list = []               # drained records awaiting/already on disk
+# phase records (:func:`span`) ride a ring and a spool list of their
+# own, same bounds: a busy scheduler writes several a tick and must not
+# evict a request's spans
+_phases: deque = deque()
+_phases_spooled: list = []
 _decided: dict = {}               # trace_id -> decision record (first wins)
 _proc_name: str | None = None
 _decisions_since_spool = 0
@@ -136,12 +154,14 @@ class Span:
     ``t0``/``t1`` (monotonic) give drift-free durations."""
 
     __slots__ = ("ctx", "name", "wall", "t0", "t1", "status", "winner",
-                 "attrs", "events", "_ended")
+                 "attrs", "events", "kind", "_ended")
 
-    def __init__(self, name, trace_id, parent_span_id, attrs):
+    def __init__(self, name, trace_id, parent_span_id, attrs,
+                 kind="span"):
         sid = f"{os.getpid():x}.{next(_ids):x}"
         self.ctx = TraceContext(trace_id, sid, parent_span_id)
         self.name = name
+        self.kind = kind
         self.wall = time.time()
         self.t0 = time.monotonic()
         self.t1 = None
@@ -177,7 +197,7 @@ class Span:
             self.winner = bool(winner)
         if attrs:
             self.attrs.update(attrs)
-        rec = {"kind": "span", "trace": self.ctx.trace_id,
+        rec = {"kind": self.kind, "trace": self.ctx.trace_id,
                "span": self.ctx.span_id,
                "parent": self.ctx.parent_span_id,
                "name": self.name, "proc": _proc(), "pid": os.getpid(),
@@ -189,18 +209,21 @@ class Span:
             rec["attrs"] = self.attrs
         if self.events:
             rec["events"] = self.events
-        _record(rec)
-        _incr("spans")
+        if self.kind == "phase":
+            _record(rec, _phases)
+        else:
+            _record(rec)
+            _incr("spans")
         return self
 
 
-def _record(rec):
+def _record(rec, ring=_buffer):
     cap = int(_flag("FLAGS_trace_buffer_cap", 4096) or 0)
     with _lock:
-        while cap > 0 and len(_buffer) >= cap:
-            _buffer.popleft()
+        while cap > 0 and len(ring) >= cap:
+            ring.popleft()
             _incr("spans_dropped")
-        _buffer.append(rec)
+        ring.append(rec)
 
 
 def start_span(name, parent=None, **attrs):
@@ -219,6 +242,102 @@ def start_span(name, parent=None, **attrs):
         return Span(name, parent.trace_id, parent.span_id, attrs)
     trace_id = f"{_proc()}-{os.getpid():x}-{next(_ids):x}"
     return Span(name, trace_id, None, attrs)
+
+
+# ---------------- phase spans: one primitive, one clock ----------------
+_monitor = None
+_profiler = None
+_exit_spool_armed = False
+
+
+def _late_imports():
+    # utils.monitor and profiler.profiler import this package
+    global _monitor, _profiler
+    from ..profiler import profiler as prof
+    from ..utils import monitor
+    _monitor, _profiler = monitor, prof
+
+
+class span:  # noqa: N801 - used as ``with span(...)``
+    """``with span(name, **attrs):`` — one phase on one thread.
+
+    Sinks, in the order they are touched:
+
+    1. a ``jax.profiler.TraceAnnotation(name)``
+       (``StepTraceAnnotation(name, step_num=...)`` when ``step_num`` is
+       given): one atomic check with no profiler session; with one, the
+       phase is an event on the xplane's host plane, on the device
+       trace's clock;
+    2. the histogram ``hist`` (default ``<name>_ms``) in the registry;
+    3. the flight recorder's ring, and the host buffer of a recording
+       ``Profiler`` (``cat`` is its event category);
+    4. only with ``FLAGS_trace_dir`` set: a ``kind: "phase"`` record in
+       the phase ring — name, ``wall``, ``t0``/``t1``, trace id, the
+       span id of ``parent`` (default: the phase open on this thread)
+       and ``attrs``.
+
+    ``attrs`` go to sinks 3 and 4; build costly ones (sorted request
+    ids) only ``if enabled()``.  After the block ``.ms`` holds the
+    duration."""
+
+    __slots__ = ("name", "hist", "cat", "attrs", "parent", "ms", "_ann",
+                 "_t0", "_rec")
+
+    def __init__(self, name, parent=None, *, hist=None, step_num=None,
+                 cat="UserDefined", **attrs):
+        self.name = name
+        self.hist = hist or name + "_ms"
+        self.cat = cat
+        self.attrs = attrs
+        self.parent = parent
+        self.ms = None
+        self._rec = None
+        self._ann = TraceAnnotation(name) if step_num is None else \
+            StepTraceAnnotation(name, step_num=step_num)
+
+    def __enter__(self):
+        self._ann.__enter__()
+        # both clocks next to the annotation's own reading, so that a
+        # phase record's ``wall`` is its xplane twin's start
+        wall, self._t0 = time.time(), time.monotonic()
+        if enabled():
+            global _exit_spool_armed
+            if not _exit_spool_armed:
+                # a training process has no shutdown to spool from
+                _exit_spool_armed = True
+                atexit.register(spool_now)
+            stack = getattr(_tls, "phases", None)
+            if stack is None:
+                stack = _tls.phases = []
+            parent = self.parent or (stack[-1] if stack else None)
+            rec = self._rec = Span(
+                self.name, f"{_proc()}-{os.getpid():x}-phases",
+                parent.ctx.span_id if parent is not None else None,
+                self.attrs, kind="phase")
+            rec.wall, rec.t0 = wall, self._t0
+            stack.append(rec)
+        return self
+
+    def __exit__(self, exc_type, exc, tb):
+        t1 = time.monotonic()
+        self._ann.__exit__(exc_type, exc, tb)
+        self.ms = (t1 - self._t0) * 1e3
+        if _monitor is None:
+            _late_imports()
+        _monitor.observe(self.hist, self.ms)
+        if _profiler._ACTIVE:
+            _profiler._HOST_BUFFER.add(
+                self.name, self._t0 * 1e6, self.ms * 1e3,
+                threading.get_ident() % 2 ** 31, self.cat,
+                args=self.attrs or None)
+        _flight_recorder.record("span", self.name,
+                                dur_ms=round(self.ms, 3), **self.attrs)
+        rec = self._rec
+        if rec is not None:
+            _tls.phases.remove(rec)
+            rec.end(status="ok" if exc_type is None
+                    else exc_type.__name__)
+        return False
 
 
 # ---------------- thread-bound context (rpc propagation) ----------------
@@ -331,14 +450,15 @@ def spool_now(trace_dir=None):
     if not enabled() and trace_dir is None:
         return None
     with _lock:
-        while _buffer:
-            _spooled.append(_buffer.popleft())
         cap = int(_flag("FLAGS_trace_buffer_cap", 4096) or 0)
         bound = max(cap * 8, 1024)
-        while len(_spooled) > bound:
-            _spooled.pop(0)
-            _incr("spans_dropped")
-        records = list(_spooled)
+        for ring, kept in ((_buffer, _spooled), (_phases, _phases_spooled)):
+            while ring:
+                kept.append(ring.popleft())
+            while len(kept) > bound:
+                kept.pop(0)
+                _incr("spans_dropped")
+        records = _spooled + _phases_spooled
     if not records:
         return None
     path = spool_path(trace_dir)
@@ -362,11 +482,12 @@ def reset():
     (tests; fresh campaigns).  On-disk spool files are untouched."""
     global _decisions_since_spool
     with _lock:
-        _buffer.clear()
-        _spooled.clear()
+        for kept in (_buffer, _spooled, _phases, _phases_spooled):
+            kept.clear()
         _decided.clear()
         _decisions_since_spool = 0
     _tls.ctx = None
+    _tls.phases = []
 
 
 def merge_spools(trace_dir=None):
@@ -376,7 +497,11 @@ def merge_spools(trace_dir=None):
 
         {"schema_version": 1,
          "traces": [{"trace_id", "sampled", "decision", "decision_count",
-                     "span_count", "spans": [...]}, ...]}
+                     "span_count", "spans": [...]}, ...],
+         "phases": [...]}
+
+    ``phases`` holds every process's :func:`span` records by start
+    time; they belong to no request's trace and are never sampled.
 
     Spans of explicitly dropped traces (decision keep=False) are
     elided (the span_count remains) — that IS the sampling.  Undecided
@@ -385,6 +510,7 @@ def merge_spools(trace_dir=None):
     d = str(trace_dir or _flag("FLAGS_trace_dir") or "")
     spans: dict = {}          # trace_id -> {span_id: record}
     decisions: dict = {}      # trace_id -> [records]
+    phases: dict = {}         # span_id -> record
     if d and os.path.isdir(d):
         for fn in sorted(os.listdir(d)):
             if not (fn.startswith("spool-") and fn.endswith(".jsonl")):
@@ -409,6 +535,8 @@ def merge_spools(trace_dir=None):
                     spans.setdefault(tid, {})[rec["span"]] = rec
                 elif rec.get("kind") == "decision":
                     decisions.setdefault(tid, []).append(rec)
+                elif rec.get("kind") == "phase" and rec.get("span"):
+                    phases[rec["span"]] = rec
     traces = []
     for tid in sorted(set(spans) | set(decisions)):
         ds = decisions.get(tid, [])
@@ -425,7 +553,10 @@ def merge_spools(trace_dir=None):
         traces.append(entry)
     return {"schema_version": SCHEMA_VERSION,
             "generator": "paddle_tpu.observability.tracing",
-            "traces": traces}
+            "traces": traces,
+            "phases": sorted(phases.values(),
+                             key=lambda r: (r.get("wall", 0.0),
+                                            r.get("span", "")))}
 
 
 def write_merged(merged, path):
@@ -448,7 +579,8 @@ def load_merged(path):
 # ---------------- chrome-trace export ----------------
 def chrome_events(merged):
     """Merged traces -> (chrome-trace events, proc_names): one "X"
-    duration event per span (wall-clock microseconds — the per-span
+    duration event per span and, on a second row of its process, per
+    phase (wall-clock microseconds — the per-span
     wall anchor aligns processes; durations come from the monotonic
     pair) plus "s"/"f" flow events for every parent->child edge that
     crosses a process, so Perfetto draws the request's hop arrows
@@ -500,6 +632,15 @@ def chrome_events(merged):
                                "ph": "f", "bp": "e", "id": fid,
                                "ts": rec.get("wall", 0.0) * 1e6,
                                "pid": row(rec), "tid": 1})
+    for rec in merged.get("phases", []) or []:
+        events.append({"name": rec["name"], "cat": "phase", "ph": "X",
+                       "ts": rec.get("wall", 0.0) * 1e6,
+                       "dur": max((rec.get("t1", 0.0)
+                                   - rec.get("t0", 0.0)) * 1e6, 1.0),
+                       "pid": row(rec), "tid": 2,
+                       "args": dict(rec.get("attrs") or {},
+                                    span_id=rec["span"],
+                                    parent=rec.get("parent"))})
     return events, proc_names
 
 

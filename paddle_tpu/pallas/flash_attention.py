@@ -391,6 +391,7 @@ def _pallas_flash_fwd(q, k, v, mask=None, qseg=None, kseg=None, seed=None,
                         pltpu.VMEM((block_q, 1), jnp.float32),
                         pltpu.VMEM((block_q, d), jnp.float32)],
         interpret=_interpret(),
+        name="flash_fwd",
     )(_to_bh(q, head_major), _to_bh(k, head_major),
       _to_bh(v, head_major), *feat_inputs)
     return _from_bh(out, b, h, head_major), lse.reshape(b, h, s, 1)
@@ -605,6 +606,7 @@ def _pallas_flash_bwd(q, k, v, out, lse, dout, mask=None, qseg=None,
         scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32),
                         pltpu.VMEM((block_k, d), jnp.float32)],
         interpret=_interpret(),
+        name="flash_bwd_dkv",
     )(q3, k3, v3, do3, lse3, delta, *feat_inputs_q)
 
     # ---- dQ: grid (b*h, num_q, num_kv)
@@ -633,6 +635,7 @@ def _pallas_flash_bwd(q, k, v, out, lse, dout, mask=None, qseg=None,
         out_shape=jax.ShapeDtypeStruct((b * h, s, d), q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
         interpret=_interpret(),
+        name="flash_bwd_dq",
     )(q3, k3, v3, do3, lse3, delta, *feat_inputs)
     return (_from_bh(dq, b, h, head_major),
             _from_bh(dk, b, h_kv, head_major),
@@ -882,6 +885,7 @@ def paged_decode_attention(q, k_pool, v_pool, page_table, offsets,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, n_rep, h_kv, d), out_dtype),
         interpret=_interpret(),
+        name="paged_decode",
     )(page_table.astype(jnp.int32), offsets.astype(jnp.int32),
       *operands)
     return out.transpose(0, 2, 1, 3).reshape(b, h, d).astype(q.dtype)

@@ -100,6 +100,7 @@ def _rms_pallas_fwd(x2d, w, eps, block_rows):
         out_shape=[jax.ShapeDtypeStruct((rows, n), x2d.dtype),
                    jax.ShapeDtypeStruct((rows, 1), jnp.float32)],
         interpret=_interpret(),
+        name="rms_norm_fwd",
     )(x2d, w.reshape(1, n))
     return y, r
 
@@ -123,6 +124,7 @@ def _rms_pallas_bwd(x2d, w, r, g2d, block_rows):
                    jax.ShapeDtypeStruct((1, n), jnp.float32)],
         scratch_shapes=[pltpu.VMEM((1, n), jnp.float32)],
         interpret=_interpret(),
+        name="rms_norm_bwd",
     )(x2d, w.reshape(1, n), r, g2d)
     return dx, dw.reshape(w.shape)
 
@@ -201,6 +203,7 @@ def _rope_call(t, cos, sin):
                                lambda i, j: (i, j, 0, 0)),
         out_shape=jax.ShapeDtypeStruct(t.shape, t.dtype),
         interpret=_interpret(),
+        name="rope",
     )(t, cos, sin)
 
 
@@ -320,6 +323,7 @@ def adam_update_pallas(w, g, m1, m2, lr_s, bc1, bc2, *, b1, b2, eps, wd,
         out_specs=[row_spec, row_spec, row_spec],
         out_shape=[jax.ShapeDtypeStruct((rows, _ADAM_LANES), jnp.float32)] * 3,
         interpret=_interpret(),
+        name="adamw_update",
     )(scal, w2, g2, m1_2, m2_2)
     return (out[0].reshape(shape), out[1].reshape(shape),
             out[2].reshape(shape))

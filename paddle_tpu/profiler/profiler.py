@@ -8,8 +8,10 @@ spans; cuda_tracer.cc records CUPTI GPU activity).
 
 TPU-native realization: two planes, mirroring the reference's host/device
 split —
-- host plane: `RecordEvent` spans recorded in-process (this module) and
-  exported as Chrome trace JSON (chrome://tracing / Perfetto-loadable);
+- host plane: `RecordEvent` spans and the framework's own phases
+  (`observability.tracing.span`) recorded in-process and exported as
+  Chrome trace JSON (chrome://tracing / Perfetto-loadable); the same
+  spans are `TraceAnnotation`s in the device plane's xplane;
 - device plane: `jax.profiler` xplane capture (TensorBoard/xprof-loadable),
   started/stopped with the same scheduler — XLA's profiler is the CUPTI
   analog on TPU.
@@ -134,36 +136,33 @@ class RecordEvent:
     """User-scope span (reference: profiler/utils.py RecordEvent over C++
     event_tracing.h).  Usable as context manager or begin()/end().
 
-    ``args`` lands in the chrome-trace event's ``args`` field (e.g. the
-    serving engine threads its ``request_id`` here so a trace span can
-    be joined against the request's metrics).  Finished spans also feed
-    the observability flight recorder — a bounded ring that survives
-    crashes — whether or not a profiler is attached."""
+    A thin wrapper over ``observability.tracing.span``, so a finished
+    span lands where the framework's own phases do: in the xplane of a
+    running ``jax.profiler`` session, in the ``<name>_ms`` histogram,
+    in the observability flight recorder — a bounded ring that
+    survives crashes — whether or not a profiler is attached, and in a
+    recording ``Profiler``'s host buffer.  ``args`` lands in the
+    chrome-trace event's ``args`` field (e.g. a ``request_id`` so a
+    trace span can be joined against the request's metrics)."""
 
     def __init__(self, name, event_type="UserDefined", args=None):
         self.name = name
         self.event_type = event_type
         self.args = args
-        self._t0 = None
+        self._span = None
 
     def begin(self):
-        self._t0 = time.perf_counter_ns()
+        from ..observability.tracing import span
+        self._span = span(self.name, cat=self.event_type,
+                          **(self.args or {}))
+        self._span.__enter__()
         return self
 
     def end(self):
-        if self._t0 is None:
+        if self._span is None:
             return
-        t1 = time.perf_counter_ns()
-        if _ACTIVE:
-            _HOST_BUFFER.add(self.name, self._t0 / 1e3,
-                             (t1 - self._t0) / 1e3,
-                             threading.get_ident() % 2 ** 31,
-                             self.event_type, args=self.args)
-        from ..observability import flight_recorder as _fr
-        _fr.record("span", self.name,
-                   dur_ms=round((t1 - self._t0) / 1e6, 3),
-                   **(self.args or {}))
-        self._t0 = None
+        span, self._span = self._span, None
+        span.__exit__(None, None, None)
 
     __enter__ = begin
 

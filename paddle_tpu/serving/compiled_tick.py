@@ -52,6 +52,8 @@ import jax.numpy as jnp
 
 from . import stats
 from ..core import state as _state
+from ..observability import tracing
+from ..observability.tracing import span
 from ..core.tensor import Tensor
 from ..framework.capture import (TRACE_LOCK, USER_TRACE_ERRORS, BindTracer,
                                  Installed, TraceEscape, describe_escape,
@@ -357,17 +359,21 @@ class CompiledServingTick:
         from ..core.op_cache import ensure_compile_cache
         ensure_compile_cache()      # tier-2 persistent XLA compile cache
 
-        def fn(pools, pt, off, last, counts, alive, seen, out, limits,
-               eos, temp, topk, topp, pen, keys, caps):
+        def serving_tick(pools, pt, off, last, counts, alive, seen, out,
+                         limits, eos, temp, topk, topp, pen, keys, caps):
             return self._traced(mode, pools, pt, off, last, counts,
                                 alive, seen, out, limits, eos, temp,
                                 topk, topp, pen, keys, caps)
+
+        # the program's name on the trace's ``XLA Modules`` line
+        serving_tick.__name__ = serving_tick.__qualname__ = \
+            "serving_tick_" + mode
 
         # every buffer the tick replaces is donated: the pools and the
         # last/counts/alive/seen/out scheduler state (``off`` is not —
         # on a dirty tick it is the cache's own offset array)
         donate = (0, 3, 4, 5, 6, 7) if donating else ()
-        return jax.jit(fn, donate_argnums=donate)
+        return jax.jit(serving_tick, donate_argnums=donate)
 
     def lowered_text(self, mode="greedy"):
         """StableHLO text of a tick program that has run in ``mode`` —
@@ -489,18 +495,21 @@ class CompiledServingTick:
             self._rebuild()
         return self._run()
 
-    def _run(self):
+    def _build_args(self, active):
+        """Host work before the launch: page growth, the lazy flush of
+        the page table, the program for this mode, its arguments."""
         eng = self.eng
         cache = eng.cache
-        t0 = time.monotonic()
-        active = dict(eng._active)
-        n_active = len(active)
-        eng._max_active = max(eng._max_active, n_active)
+        eng._max_active = max(eng._max_active, len(active))
         stats.set_value("max_active_slots", eng._max_active)
         # page-by-page growth exactly like the uncompiled step: the
         # admission reservation guarantees the host-side pop succeeds
         for slot in active:
             cache.ensure_capacity(slot, int(cache.offsets[slot]))
+        # pages held against pages promised, summed over ticks
+        stats.incr("kv.page_ticks_in_use", cache.pages_in_use)
+        stats.incr("kv.page_ticks_reserved",
+                   cache.usable_pages - cache.available_pages)
         # page table / offsets: host mutations (admission, release,
         # growth) flow through the cache's own lazy flush; steady-state
         # ticks ride the previous program's device outputs
@@ -519,37 +528,56 @@ class CompiledServingTick:
         key = (mode, donating)
         if key not in self._jits:
             self._jits[key] = self._build_jit(mode, donating)
-        jit = self._jits[key]
         d = self._dev
-        from ..profiler import RecordEvent
-        rids = sorted(r.id for r in active.values())
+        pools = []
+        for lay in cache.layers:
+            pools += [lay["k_pool"]._data_, lay["v_pool"]._data_]
+            if quant:
+                pools += [lay["k_scale"]._data_, lay["v_scale"]._data_]
+        caps = tuple(t._data_ for t in self._caps)
+        args = (tuple(pools), pt, off, d["last"], d["counts"],
+                d["alive"], d["seen"], d["out"], d["limits"],
+                d["eos"], d["temp"], d["topk"], d["topp"],
+                d["pen"], d["keys"], caps)
+        if key not in self._sigs:
+            self._sigs[key] = jax.tree.map(
+                lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), args)
+        return self._jits[key], args
+
+    def _run(self):
+        eng = self.eng
+        attrs = {"request_ids": sorted(r.id for r in
+                                       eng._active.values()),
+                 "compiled_tick": True} if tracing.enabled() else {}
+        with span("serving.tick", hist="serving.decode_ms", **attrs) as tick:
+            sync_ms = self._run_phases()
+        if sync_ms is None:
+            return False
+        # the tick's time on the host: all of it but the wait for the
+        # device's ``fin``
+        stats.observe("tick.host_ms", tick.ms - sync_ms)
+        return True
+
+    def _run_phases(self):
+        """The tick's four phases; returns the milliseconds spent
+        waiting for the device, or None when the trace fell back."""
+        eng = self.eng
+        cache = eng.cache
+        active = dict(eng._active)
+        n_active = len(active)
         try:
             # TRACE_LOCK covers reading the (possibly shared) parameter
             # slots AND the program call: while ANOTHER engine's tick
             # traces, those slots hold tracer arrays — gathering them
             # here would bake a leaked tracer into this engine's call
-            with TRACE_LOCK, \
-                    RecordEvent("serving::decode",
-                                args={"request_ids": rids,
-                                      "compiled_tick": True}):
-                pools = []
-                for lay in cache.layers:
-                    pools += [lay["k_pool"]._data_, lay["v_pool"]._data_]
-                    if quant:
-                        pools += [lay["k_scale"]._data_,
-                                  lay["v_scale"]._data_]
-                caps = tuple(t._data_ for t in self._caps)
-                args = (tuple(pools), pt, off, d["last"], d["counts"],
-                        d["alive"], d["seen"], d["out"], d["limits"],
-                        d["eos"], d["temp"], d["topk"], d["topp"],
-                        d["pen"], d["keys"], caps)
-                if key not in self._sigs:
-                    self._sigs[key] = jax.tree.map(
-                        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype),
-                        args)
-                (new_pools, new_off, new_last, new_counts, new_alive,
-                 new_seen, new_out, fin) = jit(*args)
-            fin_np = np.asarray(fin)    # the per-tick host sync point
+            with TRACE_LOCK:
+                with span("serving.tick.build"):
+                    jit, args = self._build_args(active)
+                with span("serving.tick.launch"):
+                    (new_pools, new_off, new_last, new_counts, new_alive,
+                     new_seen, new_out, fin) = jit(*args)
+            with span("serving.tick.sync") as sync:
+                fin_np = np.asarray(fin)    # the per-tick host sync point
         except USER_TRACE_ERRORS as e:
             # the model body cannot be traced (host reads of raw array
             # slots, data-dependent control flow) — raised during the
@@ -562,50 +590,52 @@ class CompiledServingTick:
             self.flush_to_host()
             self._dev = None
             self._note_fallback("trace", describe_escape(e), True)
-            return False
-        # adopt the functionally-updated pools + offsets back into the
-        # cache (device stays current; the host offset mirror advances
-        # in lockstep so fallbacks/admission see the truth)
-        offsets_np = cache.offsets.copy()
-        offsets_np[list(active)] += 1
-        cache.absorb_tick(new_pools, new_off, offsets_np)
-        d.update(off=new_off, last=new_last, counts=new_counts,
-                 alive=new_alive, seen=new_seen, out=new_out)
-        self._h_counts[list(active)] += 1
-        self._ahead = True
+            return None
+        with span("serving.tick.deliver"):
+            # adopt the functionally-updated pools + offsets back into
+            # the cache (device stays current; the host offset mirror
+            # advances in lockstep so fallbacks/admission see the truth)
+            offsets_np = cache.offsets.copy()
+            offsets_np[list(active)] += 1
+            cache.absorb_tick(new_pools, new_off, offsets_np)
+            self._dev.update(off=new_off, last=new_last,
+                             counts=new_counts, alive=new_alive,
+                             seen=new_seen, out=new_out)
+            self._h_counts[list(active)] += 1
+            self._ahead = True
 
-        wall_ms = (time.monotonic() - t0) * 1e3
-        stats.observe("decode_ms", wall_ms)
-        stats.incr("decode_steps")
-        stats.incr("tick.compiled_hits")
-        stats.incr("slot_steps", cache.num_slots)
-        stats.incr("slot_steps_active", n_active)
-        stats.incr("tokens_generated", n_active)
+            stats.incr("decode_steps")
+            stats.incr("tick.compiled_hits")
+            stats.incr("slot_steps", cache.num_slots)
+            stats.incr("slot_steps_active", n_active)
+            stats.incr("tokens_generated", n_active)
 
-        now = time.monotonic()
-        evict = eng.scfg.deadline_policy == "evict"
-        out_np = None
-        for slot, req in active.items():
-            if evict and req.deadline is not None and now > req.deadline:
-                # same per-token deadline granularity (and precedence
-                # over eos/length) as the uncompiled _append_token
-                from .api import DeadlineExceededError
-                self.flush_to_host()
-                eng._fail(req, DeadlineExceededError(
-                    f"request {req.id} exceeded its deadline after "
-                    f"{len(req.tokens)} token(s)"))
-                stats.incr("requests_evicted_deadline")
+            now = time.monotonic()
+            evict = eng.scfg.deadline_policy == "evict"
+            out_np = None
+            for slot, req in active.items():
+                if evict and req.deadline is not None \
+                        and now > req.deadline:
+                    # same per-token deadline granularity (and
+                    # precedence over eos/length) as the uncompiled
+                    # _append_token
+                    from .api import DeadlineExceededError
+                    self.flush_to_host()
+                    eng._fail(req, DeadlineExceededError(
+                        f"request {req.id} exceeded its deadline after "
+                        f"{len(req.tokens)} token(s)"))
+                    stats.incr("requests_evicted_deadline")
+                    eng._release(req)
+                    continue
+                code = int(fin_np[slot])
+                if code == 0:
+                    continue
+                if out_np is None:
+                    out_np = np.asarray(new_out)
+                count = int(self._h_counts[slot])
+                req.tokens = [int(t) for t in out_np[slot, :count]]
+                req.last_token = req.tokens[-1]
+                eng._complete(req, "eos" if code == 1 else "length", now)
                 eng._release(req)
-                continue
-            code = int(fin_np[slot])
-            if code == 0:
-                continue
-            if out_np is None:
-                out_np = np.asarray(new_out)
-            count = int(self._h_counts[slot])
-            req.tokens = [int(t) for t in out_np[slot, :count]]
-            req.last_token = req.tokens[-1]
-            eng._complete(req, "eos" if code == 1 else "length", now)
-            eng._release(req)
-        stats.set_value("active_slots", len(eng._active))
-        return True
+            stats.set_value("active_slots", len(eng._active))
+        return sync.ms

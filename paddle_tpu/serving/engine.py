@@ -33,6 +33,7 @@ import numpy as np
 
 from . import stats
 from ..observability import tracing
+from ..observability.tracing import span
 from ..utils import fault_injection as _fi
 from .api import (DeadlineExceededError, EngineShutdownError,
                   QueueFullError, RequestCancelledError, RequestOutput,
@@ -252,6 +253,7 @@ class Engine:
             self._max_active = 0
             self._pool_pub = None
             self._pool_iters = 0
+            self._queue_mark = (0, time.monotonic())
             self._running = True
             self._draining = False
             self._restarts = 0
@@ -768,20 +770,10 @@ class Engine:
                 with self._work:
                     if not self._running:
                         break
-                    self._process_migration_results_locked()
-                    self._process_cancels_locked()
-                    self._expire_queued_locked()
-                    admits = []
-                    while self._queue and self.cache.free_slots:
-                        if self._paged:
-                            slot = self._try_admit_paged(self._queue[0])
-                            if slot is None:
-                                break       # page backpressure: FIFO
-                            admits.append((self._queue.popleft(), slot))
-                        else:
-                            slot = self.cache.allocate()
-                            admits.append((self._queue.popleft(), slot))
-                    stats.set_value("queue_depth", len(self._queue))
+                    # no span for an empty queue: idle passes land here
+                    with span("serving.admit") if self._queue \
+                            else contextlib.nullcontext():
+                        admits = self._admit_locked()
                     if not admits and not self._active \
                             and not self._prefilling:
                         self._iter_deadline = None
@@ -789,45 +781,80 @@ class Engine:
                         continue
                 if budget > 0:
                     self._iter_deadline = time.monotonic() + budget
-                t_tick = time.monotonic()
-                if _fi.active("engine_slow") is not None:
-                    # gray-failure drill: a per-iteration stall on this
-                    # replica — heartbeats stay healthy, every request
-                    # hashed here just gets slower (docs/RESILIENCE.md)
-                    _fi.check_rpc("engine_slow", self.fault_name or "")
-                if self._paged and self._draining and \
-                        self._drain_migrate and self.migrator is not None:
-                    # preemption recovery: stream the still-decoding
-                    # slots' pages to survivors instead of racing the
-                    # drain deadline token by token
-                    self._migrate_out_active()
-                if self._paged:
-                    for req, slot in admits:
-                        if req.resume is not None:
-                            self._activate_resumed(req, slot)
-                        else:
-                            self._start_prefill(req, slot)
-                    # ONE batched chunk call covers every prefilling
-                    # request, then the decode step runs: long prompts
-                    # advance without ever blocking in-flight streams
-                    # for more than a chunk
-                    if self._prefilling:
-                        self._prefill_round()
-                else:
-                    for req, slot in admits:
-                        self._prefill(req, slot)
-                if self._active:
-                    if self._can_speculate():
-                        self._spec_step()
-                    elif self._tick is not None and self._tick.step():
-                        pass        # ONE compiled program ran the tick
-                    else:
-                        self._decode_step()
-                if self._paged:
-                    self._publish_pool_stats()
-                stats.observe("tick_ms",
-                              (time.monotonic() - t_tick) * 1e3)
+                # one iteration with work to do: ``serving.admit`` lies
+                # before it, as the admission block always lay before
+                # ``tick_ms``'s clock, so an idle wait is in neither
+                with span("serving.iteration", hist="serving.tick_ms"):
+                    self._iterate(admits)
                 self._iter_deadline = None
+
+    def _admit_locked(self):
+        """The locked block of one iteration: cancels, expiry, admission
+        of queued requests into free slots; returns [(request, slot)].
+        ``serving.queue.request_ms`` integrates the depth of the queue
+        each iteration left behind over the time to the next one, idle
+        waits included."""
+        self._process_migration_results_locked()
+        self._process_cancels_locked()
+        self._expire_queued_locked()
+        admits = []
+        while self._queue and self.cache.free_slots:
+            if self._paged:
+                slot = self._try_admit_paged(self._queue[0])
+                if slot is None:
+                    break       # page backpressure: FIFO
+                admits.append((self._queue.popleft(), slot))
+            else:
+                slot = self.cache.allocate()
+                admits.append((self._queue.popleft(), slot))
+        now = time.monotonic()
+        for req, _ in admits:
+            stats.observe("queue_wait_ms", (now - req.submit_t) * 1e3)
+        left, since = self._queue_mark
+        if left:
+            stats.incr("queue.request_ms", left * (now - since) * 1e3)
+        self._queue_mark = (len(self._queue), now)
+        stats.set_value("queue_depth", len(self._queue))
+        return admits
+
+    def _iterate(self, admits):
+        if _fi.active("engine_slow") is not None:
+            # gray-failure drill: a per-iteration stall on this
+            # replica — heartbeats stay healthy, every request
+            # hashed here just gets slower (docs/RESILIENCE.md)
+            _fi.check_rpc("engine_slow", self.fault_name or "")
+        if self._paged and self._draining and \
+                self._drain_migrate and self.migrator is not None:
+            # preemption recovery: stream the still-decoding
+            # slots' pages to survivors instead of racing the
+            # drain deadline token by token
+            self._migrate_out_active()
+        if self._paged:
+            for req, slot in admits:
+                if req.resume is not None:
+                    self._activate_resumed(req, slot)
+                else:
+                    self._start_prefill(req, slot)
+            # ONE batched chunk call covers every prefilling
+            # request, then the decode step runs: long prompts
+            # advance without ever blocking in-flight streams
+            # for more than a chunk
+            if self._prefilling:
+                with span("serving.prefill_round"):
+                    self._prefill_round()
+        else:
+            for req, slot in admits:
+                self._prefill(req, slot)
+        if self._active:
+            if self._can_speculate():
+                self._spec_step()
+            elif self._tick is not None and self._tick.step():
+                pass        # ONE compiled program ran the tick
+            else:
+                self._decode_step()
+        if self._paged:
+            with span("serving.publish"):
+                self._publish_pool_stats()
 
     def _stall_monitor(self):
         """Scheduler-iteration watchdog (armed by step_timeout_s > 0):
@@ -880,7 +907,6 @@ class Engine:
         """Batch-1 prompt pass into the slot's rows + first token."""
         from ..core.tensor import Tensor
         from ..models.generation import init_kv_caches
-        from ..profiler import RecordEvent
         from ..framework.capture import TRACE_LOCK
         tr = req.trace
         if tr is not None:
@@ -889,9 +915,7 @@ class Engine:
             tr.prefill = tracing.start_span(
                 "engine.prefill", parent=tr.root, slot=slot,
                 prompt_tokens=int(req.prompt.size))
-        t0 = time.monotonic()
-        with RecordEvent("serving::prefill",
-                         args={"request_id": req.id}):
+        with span("serving.prefill", request_id=req.id):
             caches = init_kv_caches(
                 self.cfg.num_layers, 1, self.max_len, self._kv_heads,
                 self.cfg.head_dim, dtype=self.scfg.cache_dtype)
@@ -904,10 +928,8 @@ class Engine:
                 seen[req.prompt] = True
                 req.seen = seen
             tok = self._sample_row(logits[:, -1, :], req)
-        now = time.monotonic()
-        req.ttft_ms = (now - req.submit_t) * 1e3
+        req.ttft_ms = (time.monotonic() - req.submit_t) * 1e3
         stats.observe("ttft_ms", req.ttft_ms)
-        stats.observe("prefill_ms", (now - t0) * 1e3)
         stats.incr("prefill_steps")
         req.slot = slot
         self._active[slot] = req
@@ -1056,8 +1078,6 @@ class Engine:
         K/V (same tokens, same cache contents), and pad positions past
         the prompt scatter into unassigned table entries, i.e. the
         scratch page, which no causal mask ever exposes."""
-        from ..core.tensor import Tensor
-        from ..profiler import RecordEvent
         now = time.monotonic()
         if self.scfg.deadline_policy == "evict":
             for req in list(self._prefilling):
@@ -1163,19 +1183,21 @@ class Engine:
         `model` against `cache` for `reqs` at per-request progress
         `offs`; returns (logits, starts)."""
         from ..core.tensor import Tensor
-        from ..profiler import RecordEvent
+        from ..core.op_cache import dispatch_count
+        from ..framework.capture import TRACE_LOCK
         chunk = self._chunk
         cap = cache.capacity
         tokens = np.zeros((cache.num_slots, chunk), np.int32)
         starts = []
+        useful = 0
         for row, (req, off) in enumerate(zip(reqs, offs)):
             start = min(off, cap - chunk)
             seg = req.prompt[start:min(start + chunk, req.prompt.size)]
             tokens[row, :seg.size] = seg
             new_real = min(start + chunk, req.prompt.size) - off
+            useful += new_real
             cache.ensure_capacity(req.slot, off + new_real - 1)
             starts.append(start)
-        from ..framework.capture import TRACE_LOCK
         # chunked prefill batches by CALL ROW, not scheduler slot: the
         # adapter index for this call is row-ordered (scratch rows ride
         # the identity slot 0).  Draft-model calls are never adapted.
@@ -1186,17 +1208,24 @@ class Engine:
                 rows[row] = req.adapter_slot
             lora = self.adapter_pool.activate(
                 self.adapter_pool.row_tensor(rows))
-        t0 = time.monotonic()
-        with RecordEvent("serving::prefill",
-                         args={"request_ids": [r.id for r in reqs]}):
-            views = cache.prefill_view([r.slot for r in reqs], starts)
-            with TRACE_LOCK, lora:  # a shared model may be mid-capture
+        attrs = {"request_ids": [r.id for r in reqs]} \
+            if tracing.enabled() else {}
+        launches0 = dispatch_count()
+        with span("serving.prefill_chunk", **attrs) as sp:
+            with span("serving.prefill.view"):
+                views = cache.prefill_view([r.slot for r in reqs], starts)
+            with span("serving.prefill.model"), TRACE_LOCK, lora:
+                # a shared model may be mid-capture
                 logits = model(Tensor(tokens), caches=views)
-            cache.absorb_view(views)
-        dt_ms = (time.monotonic() - t0) * 1e3
-        stats.observe("prefill_chunk_ms", dt_ms)
-        stats.observe("prefill_ms", dt_ms)
+            with span("serving.prefill.absorb"):
+                cache.absorb_view(views)
+        stats.observe("prefill_ms", sp.ms)
         stats.incr("prefill_chunks", len(reqs))
+        # what the round computed against what it was for, and how many
+        # programs the eager funnel launched for it
+        stats.incr("prefill.tokens_computed", cache.num_slots * chunk)
+        stats.incr("prefill.tokens_useful", useful)
+        stats.incr("prefill.launches", dispatch_count() - launches0)
         return logits, starts
 
     # ---------------- live KV-page migration (disaggregation) ----------------
@@ -1509,7 +1538,6 @@ class Engine:
         tokens were accepted."""
         from ..core.tensor import Tensor
         from ..framework.capture import TRACE_LOCK
-        from ..profiler import RecordEvent
         from ..tensor_ops import search as S
         K = self._spec_k
         ns = self.cache.num_slots
@@ -1517,16 +1545,15 @@ class Engine:
         n_active = len(active)
         self._max_active = max(self._max_active, n_active)
         stats.set_value("max_active_slots", self._max_active)
-        rids = sorted(r.id for r in active.values())
+        attrs = {"request_ids": sorted(r.id for r in active.values())} \
+            if tracing.enabled() else {}
         tgt_off = {s: int(self.cache.offsets[s]) for s in active}
         d_off0 = {s: int(self.draft_cache.offsets[s]) for s in active}
 
         # --- draft: K proposer steps on the mirror cache ---
-        t0 = time.monotonic()
         prev_out = {s: 0 for s in active}
         draft_out = {s: [] for s in active}
-        with RecordEvent("serving::spec_draft",
-                         args={"request_ids": rids}):
+        with span("serving.spec_draft", **attrs):
             for j in range(K):
                 tok_in = np.zeros((ns, 1), np.int32)
                 for s, req in active.items():
@@ -1544,57 +1571,52 @@ class Engine:
                 for s in active:
                     prev_out[s] = int(toks[s])
                     draft_out[s].append(int(toks[s]))
-        stats.observe("spec_draft_ms", (time.monotonic() - t0) * 1e3)
 
         # --- verify: one batched K+1 target call ---
-        t0 = time.monotonic()
-        tok_in = np.zeros((ns, K + 1), np.int32)
-        caps = {}
-        proposed = 0
-        for s, req in active.items():
-            # a lagging draft (bonus token / fallback steps) yields
-            # fewer usable proposals this window; the tail positions
-            # are padding that the accept cap below always rejects
-            lag = tgt_off[s] - d_off0[s]
-            cap = max(0, K - lag)
-            caps[s] = cap
-            tok_in[s, 0] = req.last_token
-            for i in range(1, K + 1):
-                tok_in[s, i] = draft_out[s][lag + i - 1] \
-                    if i <= cap else req.last_token
-            proposed += cap
-            self.cache.ensure_capacity(s, tgt_off[s] + K)
-        with RecordEvent("serving::spec_verify",
-                         args={"request_ids": rids}):
+        with span("serving.spec_verify", **attrs):
+            tok_in = np.zeros((ns, K + 1), np.int32)
+            caps = {}
+            proposed = 0
+            for s, req in active.items():
+                # a lagging draft (bonus token / fallback steps) yields
+                # fewer usable proposals this window; the tail positions
+                # are padding that the accept cap below always rejects
+                lag = tgt_off[s] - d_off0[s]
+                cap = max(0, K - lag)
+                caps[s] = cap
+                tok_in[s, 0] = req.last_token
+                for i in range(1, K + 1):
+                    tok_in[s, i] = draft_out[s][lag + i - 1] \
+                        if i <= cap else req.last_token
+                proposed += cap
+                self.cache.ensure_capacity(s, tgt_off[s] + K)
             with TRACE_LOCK:    # shared model may be mid-capture
                 logits = self.model(Tensor(tok_in),
                                     caches=self.cache.layer_caches())
             t = np.asarray(S.argmax(logits, axis=-1)._data_)  # [ns, K+1]
-        stats.observe("spec_verify_ms", (time.monotonic() - t0) * 1e3)
 
         # --- accept mask + rollback ---
-        t0 = time.monotonic()
-        accepted = 0
-        for s, req in active.items():
-            a = 0
-            while a < caps[s] and tok_in[s, a + 1] == t[s, a]:
-                a += 1
-            accepted += a
-            for i in range(a + 1):
-                self._append_token(req, int(t[s, i]))
-                if req.slot is None:    # eos/length/deadline mid-window
-                    break               # truncates the rest of it
-            if req.slot is None:
-                continue                # _release returned the pages
-            new_off = tgt_off[s] + a + 1
-            self.cache.set_offset(s, new_off)
-            self.cache.rollback(s, new_off)
-            # the draft cache is valid through the accepted prefix it
-            # wrote itself (never past what IT cached this window)
-            d_new = min(d_off0[s] + K, new_off)
-            self.draft_cache.set_offset(s, d_new)
-            self.draft_cache.rollback(s, d_new)
-        stats.observe("spec_rollback_ms", (time.monotonic() - t0) * 1e3)
+        with span("serving.spec_rollback"):
+            accepted = 0
+            for s, req in active.items():
+                a = 0
+                while a < caps[s] and tok_in[s, a + 1] == t[s, a]:
+                    a += 1
+                accepted += a
+                for i in range(a + 1):
+                    self._append_token(req, int(t[s, i]))
+                    if req.slot is None:    # eos/length/deadline
+                        break               # mid-window truncates the rest
+                if req.slot is None:
+                    continue                # _release returned the pages
+                new_off = tgt_off[s] + a + 1
+                self.cache.set_offset(s, new_off)
+                self.cache.rollback(s, new_off)
+                # the draft cache is valid through the accepted prefix
+                # it wrote itself (never past what IT cached this window)
+                d_new = min(d_off0[s] + K, new_off)
+                self.draft_cache.set_offset(s, d_new)
+                self.draft_cache.rollback(s, d_new)
         stats.incr("spec_windows")
         stats.incr("spec_proposed_tokens", proposed)
         stats.incr("spec_accepted_tokens", accepted)
@@ -1605,14 +1627,15 @@ class Engine:
     def _decode_step(self):
         """One batched step over ALL slots: the continuous batch."""
         from ..core.tensor import Tensor
-        from ..profiler import RecordEvent
+        from ..framework.capture import TRACE_LOCK
         from ..tensor_ops import search as S
-        t0 = time.monotonic()
-        n_active = len(self._active)
-        self._max_active = max(self._max_active, n_active)
-        stats.set_value("max_active_slots", self._max_active)
-        rids = sorted(r.id for r in self._active.values())
-        with RecordEvent("serving::decode", args={"request_ids": rids}):
+        attrs = {"request_ids": sorted(r.id for r in
+                                       self._active.values())} \
+            if tracing.enabled() else {}
+        with span("serving.decode", **attrs):
+            n_active = len(self._active)
+            self._max_active = max(self._max_active, n_active)
+            stats.set_value("max_active_slots", self._max_active)
             if self._paged:
                 # page-by-page growth: assign a fresh page only when a
                 # row's write position crosses a page boundary (the
@@ -1623,7 +1646,6 @@ class Engine:
             tok_in = np.zeros((self.cache.num_slots, 1), np.int32)
             for slot, req in self._active.items():
                 tok_in[slot, 0] = req.last_token
-            from ..framework.capture import TRACE_LOCK
             with TRACE_LOCK, self._lora_ctx():
                 logits = self.model(Tensor(tok_in),
                                     caches=self.cache.layer_caches())
@@ -1645,7 +1667,6 @@ class Engine:
                 tok = int(toks[slot]) if toks is not None else \
                     self._sample_row(last[slot:slot + 1, :], req)
                 self._append_token(req, tok)
-        stats.observe("decode_ms", (time.monotonic() - t0) * 1e3)
         stats.incr("decode_steps")
         stats.incr("slot_steps", self.cache.num_slots)
         stats.incr("slot_steps_active", n_active)

@@ -22,9 +22,9 @@ def incr(name, value=1):
 
 def request_observe(name, request_id, value, help=""):  # noqa: A002
     """Per-request labeled series ``serving.<name>{request_id=...}`` —
-    the same monotonically increasing id the engine puts in its
-    ``serving::prefill``/``serving::decode`` span args, so one request's
-    trace spans and metrics join on it.  Cardinality is bounded TWICE:
+    the same monotonically increasing id the engine puts in the
+    ``request_ids`` of its ``serving.prefill_chunk``/``serving.tick``
+    phase spans, so one request's trace spans and metrics join on it.  Cardinality is bounded TWICE:
     ``reset_serving_stats()`` clears the families at engine start, and
     within one engine run the family is LRU-rotated to at most
     ``FLAGS_serving_request_label_cap`` children (the oldest request's
@@ -106,6 +106,24 @@ def declare_tick_stats():
                       "uncompiled fallback")
     _registry.histogram(PREFIX + "tick_ms",
                         "wall time of one scheduler iteration (ms)")
+    _registry.histogram(PREFIX + "tick.host_ms",
+                        "host time of one compiled tick: the tick less "
+                        "its wait for the device (ms)")
+    _registry.histogram(PREFIX + "queue_wait_ms",
+                        "submit to admission, per admitted request (ms)")
+    for name, text in (
+            ("queue.request_ms", "queue depth integrated over time "
+                                 "(request-milliseconds)"),
+            ("kv.page_ticks_in_use", "KV pages held, summed over ticks"),
+            ("kv.page_ticks_reserved", "KV pages held or promised to "
+                                       "admitted requests, summed over "
+                                       "ticks"),
+            ("prefill.tokens_computed", "token positions the prefill "
+                                        "chunk calls computed"),
+            ("prefill.tokens_useful", "of those, new prompt tokens"),
+            ("prefill.launches", "eager-op dispatches inside prefill "
+                                 "chunk calls")):
+        _registry.counter(PREFIX + name, text)
 
 
 def declare_migration_stats():

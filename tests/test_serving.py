@@ -285,8 +285,9 @@ def test_serving_with_llama_gqa():
 
 
 def test_profiler_captures_serving_spans(model):
-    """serving::prefill / serving::decode spans land in profiler traces
-    (the scheduler thread is instrumented like any op dispatch)."""
+    """serving.prefill_chunk / serving.tick spans land in profiler
+    traces (the scheduler thread is instrumented like any op
+    dispatch)."""
     from paddle_tpu.profiler import Profiler, ProfilerTarget
     (p,) = _prompts([5])
     prof = Profiler(targets=[ProfilerTarget.CPU], timer_only=True)
@@ -297,5 +298,5 @@ def test_profiler_captures_serving_spans(model):
     finally:
         prof.stop()
     names = {e["name"] for e in prof.events}
-    assert "serving::prefill" in names
-    assert "serving::decode" in names
+    assert "serving.prefill_chunk" in names
+    assert "serving.tick" in names
