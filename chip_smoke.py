@@ -249,10 +249,14 @@ def serve_phase(model, cfg, sizes, dry_run):
     snap = serving_stats()
     say(f"serve: tick_compiled_hits={snap['tick_compiled_hits']} "
         f"tick_fallbacks={snap['tick_fallbacks']} "
+        f"prefill_compiled_hits={snap['prefill_compiled_hits']} "
+        f"prefill_fallbacks={snap['prefill_fallbacks']} "
         f"scheduler_restarts={snap['scheduler_restarts']} "
         f"max_active_slots={snap.get('max_active_slots')}")
     assert snap["tick_compiled_hits"] > 0
     assert snap["tick_fallbacks"] == 0
+    assert snap["prefill_compiled_hits"] > 0
+    assert snap["prefill_fallbacks"] == 0
     assert snap["scheduler_restarts"] == 0
     assert tick_text is not None, "no greedy tick program ran"
     pallas_calls(tick_text, "serve tick", dry_run)

@@ -29,6 +29,15 @@ device-resident scheduler state:
   (and deadline eviction — a wall-clock decision) are the only times
   token buffers cross to the host.
 
+The prefill chunk call is a member of the same program family (ISSUE
+27): ``serving_prefill_r<rows>``, ONE donated program per call over the
+same pools and the same capture list, with as many rows as the smallest
+power-of-two bucket (capped at ``num_slots``) that holds the requests
+that are prefilling.  Every member is compiled from shapes alone before
+the first chunk call returns, so traffic meets no bucket cold; the eager
+``Engine._prefill_chunk_eager`` stays as its reference and as the lane
+for what one program cannot host.
+
 Fallbacks latch the uncompiled scheduler byte-identically and warn once
 with the typed :class:`TickFallbackWarning`: flag off
 (``FLAGS_compiled_tick``), slot (non-paged) cache layout, speculative
@@ -45,6 +54,7 @@ from __future__ import annotations
 
 import time
 import warnings
+from concurrent.futures import ThreadPoolExecutor
 
 import numpy as np
 import jax
@@ -167,8 +177,9 @@ def request_key(sp):
 # ---------------------------------------------------------------------------
 
 class CompiledServingTick:
-    """Owns the device-resident scheduler state and the per-mode jitted
-    tick programs for one :class:`~paddle_tpu.serving.engine.Engine`.
+    """Owns the device-resident scheduler state and the compiled program
+    family — the per-mode jitted tick programs and the per-bucket prefill
+    members — for one :class:`~paddle_tpu.serving.engine.Engine`.
 
     ``step()`` runs one compiled tick and returns True, or returns False
     after latching/flushing so the engine's uncompiled iteration (the
@@ -182,6 +193,7 @@ class CompiledServingTick:
         self._caps = []                # captured model tensors (params)
         self._jits = {}                # (mode, donating) -> jitted fn
         self._sigs = {}                # (mode, donating) -> arg avals
+        self._prefill = {}             # (rows, donating) -> compiled member
         self._dev = None               # device state dict
         self._rep = {}                 # slot -> req at last rebuild
         self._mut_seen = -1            # engine mutation counter synced
@@ -200,8 +212,9 @@ class CompiledServingTick:
     # eligibility / fallback accounting
     # ------------------------------------------------------------------
 
-    def _note_fallback(self, kind, reason, permanent=False):
-        stats.incr("tick.fallbacks")
+    def _note_fallback(self, kind, reason, permanent=False,
+                       counter="tick.fallbacks"):
+        stats.incr(counter)
         if permanent:
             self._disabled = reason
         if kind not in self._warned:
@@ -222,9 +235,10 @@ class CompiledServingTick:
                     "(draft_model + speculation_k > 0)", True)
         return None
 
-    def _blocker(self):
-        """(kind, reason, permanent) for the current scheduler state, or
-        None when this tick can run compiled."""
+    def _model_blocker(self):
+        """(kind, reason, permanent) for what stops the model call from
+        running as one program — the lattice the tick and the prefill
+        member share — or None."""
         eng = self.eng
         blk = self._static_blocker()
         if blk is not None:
@@ -237,6 +251,15 @@ class CompiledServingTick:
         for layer in self._sublayers or ():
             if layer._forward_pre_hooks or layer._forward_post_hooks:
                 return ("hooks", "layer forward hooks installed", False)
+        return None
+
+    def _blocker(self):
+        """(kind, reason, permanent) for the current scheduler state, or
+        None when this tick can run compiled."""
+        eng = self.eng
+        blk = self._model_blocker()
+        if blk is not None:
+            return blk
         for req in eng._active.values():
             if not sampling_hostable(req.sampling):
                 return ("sampling", "non-greedy sampling without a "
@@ -257,12 +280,9 @@ class CompiledServingTick:
         eng = self.eng
         cache = eng.cache
         views = [dict(lay) for lay in cache.layer_caches()]
-        tok = Tensor(np.zeros((cache.num_slots, 1), np.int32))
-        exclude = {id(tok)}
-        for view in views:
-            for v in view.values():
-                if isinstance(v, Tensor):
-                    exclude.add(id(v))
+        tok = jnp.zeros((cache.num_slots, 1), jnp.int32)
+        exclude = {id(v) for view in views for v in view.values()
+                   if isinstance(v, Tensor)}
         with TRACE_LOCK:
             # discovery runs under the SAME adapter activation as the
             # live tick, so the pool's A/B stacks, scales, and per-slot
@@ -270,10 +290,14 @@ class CompiledServingTick:
             # re-gathered captures — hot-loads and admission re-points
             # flow into the compiled program with no retrace, and the
             # identity slot 0 keeps base-only batches on this one program
-            def _fwd():
+            def _body(tok_arr):
                 with eng._lora_ctx():
-                    return eng.model(tok, caches=views)
-            disc = run_discovery(_fwd)
+                    return eng.model(Tensor(tok_arr), caches=views)._data_
+
+            # on shapes alone: the pass is after the forward's READS, and
+            # run eagerly it builds (or loads) and runs a program per op
+            # for them; every member of the family traces the same body
+            disc = run_discovery(lambda: jax.eval_shape(_body, tok))
         if disc.uses_rng:
             raise TraceEscape(
                 "model forward draws framework RNG (dropout in eval?) — "
@@ -287,48 +311,40 @@ class CompiledServingTick:
     # the traced tick body (phase 2)
     # ------------------------------------------------------------------
 
-    def _traced(self, mode, pools, pt, off, last, counts, alive, seen,
-                out, limits, eos, temp, topk, topp, pen, keys, caps):
+    def _replay_model(self, tokens, pools, pt, off, caps, lora_idx=None):
+        """The captured model call, replayed while ``jax.jit`` traces a
+        member of the family: ``tokens`` [rows, s] against the flat
+        ``pools`` through page table ``pt`` [rows, pages_per_slot] at
+        write offsets ``off`` [rows].  ``lora_idx`` is the call's own
+        adapter index (the prefill's row-ordered one); None activates
+        the pool's persistent per-slot vector.  Returns the [rows, s, V]
+        logits and the functionally-updated pools, flat."""
         eng = self.eng
         cache = eng.cache
-        quant = cache.quant_dtype is not None
         tracer = BindTracer(rng_key=None)
         _state.STATE.tracer = tracer
         try:
             with Installed(list(zip(self._caps, caps))):
-                # dead/prefilling rows feed token 0 exactly like the
-                # uncompiled step's zero-filled tok_in; their scratch
-                # writes are causally masked (and prefill re-writes its
-                # positions next chunk) either way
-                tok_in = jnp.where(alive, last,
-                                   jnp.zeros_like(last))[:, None]
-                pt_t, off_t = Tensor(pt), Tensor(off)
-                views = []
-                i = 0
-                for _ in range(len(cache.layers)):
-                    view = {"k_pool": Tensor(pools[i]),
-                            "v_pool": Tensor(pools[i + 1]),
-                            "page_table": pt_t, "offset": off_t,
-                            "page_size": cache.page_size}
-                    i += 2
-                    if quant:
-                        view["k_scale"] = Tensor(pools[i])
-                        view["v_scale"] = Tensor(pools[i + 1])
-                        i += 2
-                    views.append(view)
-                with eng._lora_ctx():
-                    logits_t = eng.model(Tensor(tok_in), caches=views)
-                logits = logits_t._data_[:, -1, :]
-                new_pools = []
-                for view in views:
-                    new_pools += [view["k_pool"]._data_,
-                                  view["v_pool"]._data_]
-                    if quant:
-                        new_pools += [view["k_scale"]._data_,
-                                      view["v_scale"]._data_]
+                views = cache.views_over(pools, pt, off)
+                idx = None if lora_idx is None else Tensor(lora_idx)
+                with eng._lora_ctx(idx):
+                    logits_t = eng.model(Tensor(tokens), caches=views)
+                logits = logits_t._data_
+                new_pools = cache.flat_pools(views)
         finally:
             _state.STATE.tracer = None
             tracer.rollback_mutations()
+        return logits, new_pools
+
+    def _traced(self, mode, pools, pt, off, last, counts, alive, seen,
+                out, limits, eos, temp, topk, topp, pen, keys, caps):
+        # dead/prefilling rows feed token 0 exactly like the uncompiled
+        # step's zero-filled tok_in; their scratch writes are causally
+        # masked (and prefill re-writes its positions next chunk) either
+        # way
+        tok_in = jnp.where(alive, last, jnp.zeros_like(last))[:, None]
+        logits, new_pools = self._replay_model(tok_in, pools, pt, off, caps)
+        logits = logits[:, -1, :]
 
         ns = logits.shape[0]
         if mode == "greedy":
@@ -352,7 +368,7 @@ class CompiledServingTick:
         new_alive = alive & (fin == 0)
         new_last = jnp.where(alive, tok, last)
         new_off = off + alive.astype(off.dtype)
-        return (tuple(new_pools), new_off, new_last, new_counts,
+        return (new_pools, new_off, new_last, new_counts,
                 new_alive, new_seen, new_out, fin)
 
     def _build_jit(self, mode, donating):
@@ -386,6 +402,127 @@ class CompiledServingTick:
                 with TRACE_LOCK, no_grad():
                     return self._jits[key].lower(*sig).as_text()
         return None
+
+    # ------------------------------------------------------------------
+    # the prefill member: one donated program per chunk call
+    # ------------------------------------------------------------------
+
+    def prefill_buckets(self):
+        """Row counts the family has a prefill member for: the powers
+        of two below ``num_slots``, and ``num_slots``."""
+        ns = self.eng.cache.num_slots
+        rows, out = 1, []
+        while rows < ns:
+            out.append(rows)
+            rows *= 2
+        return out + [ns]
+
+    def _build_prefill_jit(self, rows, donating):
+        def serving_prefill(pools, pt, off, tokens, last, lora_idx, caps):
+            logits, new_pools = self._replay_model(tokens, pools, pt, off,
+                                                   caps, lora_idx)
+            # each row's logits at its last real position: all the host
+            # ever reads of a chunk
+            picked = jnp.take_along_axis(
+                logits, last[:, None, None], axis=1)[:, 0]
+            return new_pools, picked
+
+        # never ``serving_tick``: readers of the device trace tell ticks
+        # from the other programs by that
+        serving_prefill.__name__ = serving_prefill.__qualname__ = \
+            f"serving_prefill_r{rows}"
+        return jax.jit(serving_prefill,
+                       donate_argnums=(0,) if donating else ())
+
+    def _compile_prefill_family(self, donating):
+        """Every member, compiled (or loaded from the tier-2 cache) from
+        shapes alone — nothing runs, so no bucket is met cold in
+        traffic, whatever row counts the admission loop has batched so
+        far."""
+        from ..core.op_cache import ensure_compile_cache
+        ensure_compile_cache()
+        eng = self.eng
+        cache = eng.cache
+        chunk = eng._chunk
+        lowered = {}
+        for rows in self.prefill_buckets():
+            jit = self._build_prefill_jit(rows, donating)
+            lora = None if eng.adapter_pool is None \
+                else np.zeros(rows, np.int32)
+            with TRACE_LOCK:
+                pools, caps = self._donated_and_captured()
+                args = (pools,
+                        np.zeros((rows, cache.pages_per_slot), np.int32),
+                        np.zeros(rows, np.int32),
+                        np.zeros((rows, chunk), np.int32),
+                        np.zeros(rows, np.int32), lora, caps)
+                lowered[rows] = jit.lower(*args)
+            key = (f"prefill_r{rows}", donating)
+            self._jits[key] = jit
+            self._sigs[key] = jax.tree.map(
+                lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), args)
+        # tracing is the interpreter's and serial; XLA's compiles (and
+        # the cache's loads) are not, and a family has several
+        with ThreadPoolExecutor(len(lowered)) as pool:
+            built = {rows: pool.submit(low.compile)
+                     for rows, low in lowered.items()}
+            for rows, fut in built.items():
+                self._prefill[rows, donating] = fut.result()
+
+    def prefill_member(self, n):
+        """(rows, program) of the compiled member that hosts a chunk call
+        of ``n`` prefilling requests — the smallest bucket that holds
+        them — or None when the call has to take the eager lane (counted
+        under ``serving.prefill.fallbacks``, warned once a reason).  The
+        first call runs the family's one discovery pass, if the tick has
+        not yet, and compiles every member."""
+        if not _flag("FLAGS_compiled_tick", True):
+            return None
+        if self._disabled is not None:
+            stats.incr("prefill.fallbacks")
+            return None
+        blk = self._model_blocker()
+        if blk is not None:
+            self._note_fallback(*blk, counter="prefill.fallbacks")
+            return None
+        donating = bool(_flag("FLAGS_jit_donate_buffers", True))
+        buckets = self.prefill_buckets()
+        try:
+            if not self._built:
+                self._capture()
+            if (buckets[0], donating) not in self._prefill:
+                self._compile_prefill_family(donating)
+        except USER_TRACE_ERRORS as e:
+            # raised while tracing, so before anything was donated
+            self._note_fallback("trace", describe_escape(e), True,
+                                counter="prefill.fallbacks")
+            return None
+        rows = next(r for r in buckets if r >= n)
+        return rows, self._prefill[rows, donating]
+
+    def run_prefill(self, member, slots, starts, tokens, last, lora_rows):
+        """One chunk call as ONE program over the donated pools: the
+        ``prefill_member``'s [rows, chunk] ``tokens`` for ``slots`` at
+        write offsets ``starts``; returns the [rows, V] logits at each
+        row's ``last`` position.  The cache has adopted the new pools on
+        return."""
+        cache = self.eng.cache
+        rows, program = member
+        with span("serving.prefill.view"):
+            table, off = cache.prefill_table(slots, starts, rows)
+        with span("serving.prefill.model"):
+            # TRACE_LOCK as in the tick: parameter slots may hold another
+            # engine's tracers while it traces
+            with TRACE_LOCK:
+                pools, caps = self._donated_and_captured()
+                new_pools, picked = program(pools, table, off, tokens,
+                                            last, lora_rows, caps)
+            # the call's device time belongs to the call's span
+            picked.block_until_ready()
+        with span("serving.prefill.absorb"):
+            cache.absorb_pools(new_pools)
+        stats.incr("prefill.compiled_hits")
+        return Tensor(picked)
 
     # ------------------------------------------------------------------
     # host <-> device state sync
@@ -520,7 +657,6 @@ class CompiledServingTick:
         else:
             pt = cache.layers[0]["page_table"]._data_
             off = self._dev["off"]
-        quant = cache.quant_dtype is not None
         mode = "greedy" if all(
             r.sampling.greedy and not r.sampling.uses_penalty
             for r in active.values()) else "mixed"
@@ -529,13 +665,8 @@ class CompiledServingTick:
         if key not in self._jits:
             self._jits[key] = self._build_jit(mode, donating)
         d = self._dev
-        pools = []
-        for lay in cache.layers:
-            pools += [lay["k_pool"]._data_, lay["v_pool"]._data_]
-            if quant:
-                pools += [lay["k_scale"]._data_, lay["v_scale"]._data_]
-        caps = tuple(t._data_ for t in self._caps)
-        args = (tuple(pools), pt, off, d["last"], d["counts"],
+        pools, caps = self._donated_and_captured()
+        args = (pools, pt, off, d["last"], d["counts"],
                 d["alive"], d["seen"], d["out"], d["limits"],
                 d["eos"], d["temp"], d["topk"], d["topp"],
                 d["pen"], d["keys"], caps)
@@ -543,6 +674,13 @@ class CompiledServingTick:
             self._sigs[key] = jax.tree.map(
                 lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), args)
         return self._jits[key], args
+
+    def _donated_and_captured(self):
+        """What every member of the family takes beside its own rows:
+        the cache's pools and the captured tensors' current arrays.
+        Read under ``TRACE_LOCK``."""
+        return (self.eng.cache.flat_pools(),
+                tuple(t._data_ for t in self._caps))
 
     def _run(self):
         eng = self.eng

@@ -1071,13 +1071,17 @@ class Engine:
         runs — long prompts no longer starve in-flight streams, and a
         burst of admissions costs one call, not one per request.
 
-        Static shapes: every round is the same [num_slots, C] program
-        (surplus rows ride the scratch page like free decode slots).
-        A final short chunk is left-shifted to start at ``min(offset,
-        capacity - C)`` — re-fed positions recompute bitwise-identical
-        K/V (same tokens, same cache contents), and pad positions past
-        the prompt scatter into unassigned table entries, i.e. the
-        scratch page, which no causal mask ever exposes."""
+        Static shapes: a round's model call is one of a few [rows, C]
+        programs, rows the smallest power-of-two bucket (capped at
+        num_slots) that holds the prefilling requests, all compiled
+        before the first call returns; surplus rows of a bucket ride the
+        scratch page like free decode slots.  (The eager lane computes
+        all num_slots rows op by op.)  A final short chunk is
+        left-shifted to start at ``min(offset, capacity - C)`` — re-fed
+        positions recompute bitwise-identical K/V (same tokens, same
+        cache contents), and pad positions past the prompt scatter into
+        unassigned table entries, i.e. the scratch page, which no causal
+        mask ever exposes."""
         now = time.monotonic()
         if self.scfg.deadline_policy == "evict":
             for req in list(self._prefilling):
@@ -1117,7 +1121,7 @@ class Engine:
                     seen[req.prompt] = True
                     req.seen = seen
                 req.first_tok = self._sample_row(
-                    logits[row:row + 1, plen - 1 - start, :], req)
+                    logits[row:row + 1, :], req)
                 req.ttft_ms = (time.monotonic() - req.submit_t) * 1e3
                 stats.observe("ttft_ms", req.ttft_ms)
                 stats.incr("prefill_steps")
@@ -1179,54 +1183,86 @@ class Engine:
         stats.set_value("active_slots", len(self._active))
 
     def _prefill_chunk_call(self, model, cache, reqs, offs):
-        """One batched `[num_slots, chunk]` prefill-chunk call of
-        `model` against `cache` for `reqs` at per-request progress
-        `offs`; returns (logits, starts)."""
-        from ..core.tensor import Tensor
-        from ..core.op_cache import dispatch_count
-        from ..framework.capture import TRACE_LOCK
+        """One batched prefill-chunk call of `model` against `cache` for
+        `reqs` at per-request progress `offs`; returns (logits [rows, V]
+        at each row's last real position of the chunk, starts).
+
+        The target model's call is ONE donated program of the compiled
+        tick's family, with as many rows as the smallest bucket that
+        holds `reqs` (``CompiledServingTick.prefill_member``).  What one
+        program cannot host — the tick's own blockers, and the draft
+        model — runs the eager `[num_slots, chunk]` call op by op, the
+        lane the compiled one is tested against."""
         chunk = self._chunk
         cap = cache.capacity
-        tokens = np.zeros((cache.num_slots, chunk), np.int32)
+        member = None
+        if model is self.model and self._tick is not None:
+            member = self._tick.prefill_member(len(reqs))
+        rows = member[0] if member is not None else cache.num_slots
+        tokens = np.zeros((rows, chunk), np.int32)
+        last = np.zeros(rows, np.int32)
+        # chunked prefill batches by CALL ROW, not scheduler slot: the
+        # adapter index for this call is row-ordered (scratch rows ride
+        # the identity slot 0).  Draft-model calls are never adapted.
+        lora_rows = np.zeros(rows, np.int32) \
+            if self.adapter_pool is not None and model is self.model \
+            else None
         starts = []
         useful = 0
         for row, (req, off) in enumerate(zip(reqs, offs)):
             start = min(off, cap - chunk)
-            seg = req.prompt[start:min(start + chunk, req.prompt.size)]
-            tokens[row, :seg.size] = seg
-            new_real = min(start + chunk, req.prompt.size) - off
-            useful += new_real
-            cache.ensure_capacity(req.slot, off + new_real - 1)
+            end = min(start + chunk, req.prompt.size)
+            tokens[row, :end - start] = req.prompt[start:end]
+            last[row] = end - 1 - start
+            useful += end - off
+            cache.ensure_capacity(req.slot, end - 1)
             starts.append(start)
-        # chunked prefill batches by CALL ROW, not scheduler slot: the
-        # adapter index for this call is row-ordered (scratch rows ride
-        # the identity slot 0).  Draft-model calls are never adapted.
-        lora = contextlib.nullcontext()
-        if self.adapter_pool is not None and model is self.model:
-            rows = np.zeros(cache.num_slots, np.int32)
-            for row, req in enumerate(reqs):
-                rows[row] = req.adapter_slot
-            lora = self.adapter_pool.activate(
-                self.adapter_pool.row_tensor(rows))
+            if lora_rows is not None:
+                lora_rows[row] = req.adapter_slot
+        slots = [r.slot for r in reqs]
         attrs = {"request_ids": [r.id for r in reqs]} \
             if tracing.enabled() else {}
-        launches0 = dispatch_count()
         with span("serving.prefill_chunk", **attrs) as sp:
-            with span("serving.prefill.view"):
-                views = cache.prefill_view([r.slot for r in reqs], starts)
-            with span("serving.prefill.model"), TRACE_LOCK, lora:
-                # a shared model may be mid-capture
-                logits = model(Tensor(tokens), caches=views)
-            with span("serving.prefill.absorb"):
-                cache.absorb_view(views)
+            if member is not None:
+                logits = self._tick.run_prefill(member, slots, starts,
+                                                tokens, last, lora_rows)
+                launches = 1
+            else:
+                logits, launches = self._prefill_chunk_eager(
+                    model, cache, slots, starts, tokens, last, lora_rows)
         stats.observe("prefill_ms", sp.ms)
         stats.incr("prefill_chunks", len(reqs))
-        # what the round computed against what it was for, and how many
-        # programs the eager funnel launched for it
-        stats.incr("prefill.tokens_computed", cache.num_slots * chunk)
+        # what the call computed against what it was for, and how many
+        # programs it launched
+        stats.incr("prefill.tokens_computed", rows * chunk)
         stats.incr("prefill.tokens_useful", useful)
-        stats.incr("prefill.launches", dispatch_count() - launches0)
+        stats.incr("prefill.launches", launches)
         return logits, starts
+
+    def _prefill_chunk_eager(self, model, cache, slots, starts, tokens,
+                             last, lora_rows):
+        """The eager lane of a chunk call: `model` over all `num_slots`
+        rows through the op funnel; returns ([num_slots, V] logits at
+        each row's `last` position, the programs the funnel launched)."""
+        import jax.numpy as jnp
+        from ..core.tensor import Tensor
+        from ..core.op_cache import dispatch_count
+        from ..framework.capture import TRACE_LOCK
+        lora = contextlib.nullcontext() if lora_rows is None else \
+            self.adapter_pool.activate(
+                self.adapter_pool.row_tensor(lora_rows))
+        launches0 = dispatch_count()
+        with span("serving.prefill.view"):
+            views = cache.prefill_view(slots, starts)
+        with span("serving.prefill.model"), TRACE_LOCK, lora:
+            # a shared model may be mid-capture
+            logits = model(Tensor(tokens), caches=views)
+        with span("serving.prefill.absorb"):
+            cache.absorb_view(views)
+        launches = dispatch_count() - launches0
+        picked = jnp.take_along_axis(logits._data_, last[:, None, None],
+                                     axis=1)[:, 0]
+        return Tensor(picked), launches
 
     # ---------------- live KV-page migration (disaggregation) ----------------
     def _migrate_ready(self, req, tok):

@@ -44,8 +44,8 @@ from ..core.tensor import Tensor
 class PagedKVCache:
     """Block-granular KV storage behind the same scheduler-facing
     surface as `SlotKVCache` (allocate/release/advance/layer_caches)
-    plus the page machinery (`ensure_capacity`, `prefill_view`,
-    `make_shared`, `reclaim`).
+    plus the page machinery (`ensure_capacity`, `prefill_table`,
+    `prefill_view`, `make_shared`, `reclaim`).
 
     Host-side bookkeeping is plain numpy; device uploads are batched:
     mutations only mark the cache dirty, and `layer_caches()` uploads
@@ -352,52 +352,73 @@ class PagedKVCache:
         self._flush()
         return self.layers
 
-    def prefill_view(self, slots, starts):
-        """Per-layer cache dicts for one BATCHED prefill-chunk call:
-        always [num_slots] rows (static shape — one compiled prefill
-        program total), row i carrying `slots[i]`'s page-table row at
-        write offset `starts[i]`; surplus rows point at the scratch
-        page, so their pad writes vanish like any free slot's.  Pool
-        updates made by the model call are pulled back with
-        `absorb_view`."""
-        table = np.zeros_like(self.table)
-        off = np.zeros(self.num_slots, np.int32)
+    def prefill_table(self, slots, starts, rows):
+        """Host arrays for one batched prefill-chunk call of ``rows``
+        rows: row i carries ``slots[i]``'s page-table row at write offset
+        ``starts[i]``; surplus rows point at the scratch page, so their
+        pad writes vanish like any free slot's.  Returns (table [rows,
+        pages_per_slot], offsets [rows])."""
+        table = np.zeros((rows, self.pages_per_slot), np.int32)
+        off = np.zeros(rows, np.int32)
         for row, (slot, start) in enumerate(zip(slots, starts)):
             table[row] = self.table[slot]
             off[row] = start
-        pt = Tensor(jnp.asarray(table))
-        offt = Tensor(jnp.asarray(off))
+        return table, off
+
+    def views_over(self, pools_flat, page_table, offset):
+        """Per-layer cache dicts over ``pools_flat`` (the pools, and the
+        per-page scales of a quantized cache, flat per layer in
+        ``flat_pools`` order) behind one page table and offset vector."""
+        pt, off = Tensor(page_table), Tensor(offset)
+        quant = self.quant_dtype is not None
         views = []
-        for lay in self.layers:
-            view = {"k_pool": lay["k_pool"], "v_pool": lay["v_pool"],
-                    "page_table": pt, "offset": offt,
+        i = 0
+        for _ in self.layers:
+            view = {"k_pool": Tensor(pools_flat[i]),
+                    "v_pool": Tensor(pools_flat[i + 1]),
+                    "page_table": pt, "offset": off,
                     "page_size": self.page_size}
-            if self.quant_dtype is not None:
-                view["k_scale"] = lay["k_scale"]
-                view["v_scale"] = lay["v_scale"]
+            i += 2
+            if quant:
+                view["k_scale"] = Tensor(pools_flat[i])
+                view["v_scale"] = Tensor(pools_flat[i + 1])
+                i += 2
             views.append(view)
         return views
+
+    def flat_pools(self, views=None):
+        """The device arrays a model call updates, flat per layer (k, v,
+        then the scales of a quantized cache): of the cache itself, or
+        of ``views`` after a call."""
+        quant = self.quant_dtype is not None
+        flat = []
+        for lay in self.layers if views is None else views:
+            flat += [lay["k_pool"]._data_, lay["v_pool"]._data_]
+            if quant:
+                flat += [lay["k_scale"]._data_, lay["v_scale"]._data_]
+        return tuple(flat)
+
+    def prefill_view(self, slots, starts):
+        """Per-layer cache dicts for one EAGER batched prefill-chunk
+        call — the reference lane of the compiled prefill member
+        (serving/compiled_tick.py), which takes ``prefill_table`` at its
+        own row count instead: always [num_slots] rows over the cache's
+        own pools.  Pool updates made by the model call are pulled back
+        with `absorb_view`; until then the old and the new pools are
+        both alive."""
+        table, off = self.prefill_table(slots, starts, self.num_slots)
+        return self.views_over(self.flat_pools(), jnp.asarray(table),
+                               jnp.asarray(off))
 
     def absorb_view(self, views):
         """Adopt the functionally-updated pools (and per-page scales)
         from a `prefill_view` model call back into the shared dicts."""
-        for lay, view in zip(self.layers, views):
-            lay["k_pool"] = view["k_pool"]
-            lay["v_pool"] = view["v_pool"]
-            if self.quant_dtype is not None:
-                lay["k_scale"] = view["k_scale"]
-                lay["v_scale"] = view["v_scale"]
+        self.absorb_pools(self.flat_pools(views))
 
-    def absorb_tick(self, pools_flat, new_offsets, offsets_np=None):
-        """Adopt one compiled scheduler tick's functionally-updated
-        device state (serving/compiled_tick.py): the donated-through
-        pools (+ per-page scales, flat per layer in ``layer_caches``
-        order), the in-program-advanced offsets device array, and —
-        when given — the host offset mirror that advanced in lockstep.
-        The dirty flag is NOT set: device and host agree after this
-        call, so a later ``layer_caches()`` must not re-upload stale
-        Tensors over the tick's outputs."""
-        off_t = Tensor(new_offsets)
+    def absorb_pools(self, pools_flat):
+        """Adopt functionally-updated pools (``flat_pools`` order) — what
+        a compiled prefill chunk hands back after the old ones were
+        donated to it.  Offsets and page table are the host's to set."""
         quant = self.quant_dtype is not None
         i = 0
         for lay in self.layers:
@@ -408,6 +429,18 @@ class PagedKVCache:
                 lay["k_scale"] = Tensor(pools_flat[i])
                 lay["v_scale"] = Tensor(pools_flat[i + 1])
                 i += 2
+
+    def absorb_tick(self, pools_flat, new_offsets, offsets_np=None):
+        """Adopt one compiled scheduler tick's functionally-updated
+        device state (serving/compiled_tick.py): the donated-through
+        pools (``flat_pools`` order), the in-program-advanced offsets
+        device array, and — when given — the host offset mirror that
+        advanced in lockstep.  The dirty flag is NOT set: device and
+        host agree after this call, so a later ``layer_caches()`` must
+        not re-upload stale Tensors over the tick's outputs."""
+        self.absorb_pools(pools_flat)
+        off_t = Tensor(new_offsets)
+        for lay in self.layers:
             lay["offset"] = off_t
         if offsets_np is not None:
             self.offsets[:] = offsets_np

@@ -121,8 +121,15 @@ def declare_tick_stats():
             ("prefill.tokens_computed", "token positions the prefill "
                                         "chunk calls computed"),
             ("prefill.tokens_useful", "of those, new prompt tokens"),
-            ("prefill.launches", "eager-op dispatches inside prefill "
-                                 "chunk calls")):
+            ("prefill.launches", "programs launched inside prefill "
+                                 "chunk calls: one a compiled call, "
+                                 "every eager-op dispatch of an eager "
+                                 "one"),
+            ("prefill.compiled_hits", "prefill chunk calls run as ONE "
+                                      "compiled program"),
+            ("prefill.fallbacks", "prefill chunk calls that took the "
+                                  "eager lane because one program could "
+                                  "not host them")):
         _registry.counter(PREFIX + name, text)
 
 
@@ -292,7 +299,9 @@ def serving_stats():
     ``tick_fallbacks`` counting iterations the ONE-program compiled
     tick executed vs iterations that latched the uncompiled scheduler
     (flag off mid-run, slot layout, speculation, unhostable sampling,
-    hooks); all three ride the Prometheus exposition
+    hooks), and ``prefill_compiled_hits`` / ``prefill_fallbacks`` the
+    same for prefill chunk calls (the draft model's eager calls are in
+    neither); all ride the Prometheus exposition
     (``serving_tick_ms`` histogram, ``serving_tick_compiled_hits`` /
     ``serving_tick_fallbacks`` counters, gated by
     tools/check_telemetry.py --serving-tick).
@@ -390,6 +399,8 @@ def serving_stats():
         "tick_ms_avg": avg("tick_ms"),
         "tick_compiled_hits": g("tick.compiled_hits"),
         "tick_fallbacks": g("tick.fallbacks"),
+        "prefill_compiled_hits": g("prefill.compiled_hits"),
+        "prefill_fallbacks": g("prefill.fallbacks"),
         "kv_pages_in_use": g("kv_pages_in_use"),
         "kv_pages_free": g("kv_pages_free"),
         "kv_pages_peak": g("kv_pages_peak"),

@@ -279,13 +279,16 @@ def test_tick_host_time_is_the_tick_less_its_sync(serve_run):
 def test_prefill_token_counters_are_exact_on_a_two_request_plan(serve_run):
     _, reg, _ = serve_run
     chunks = reg["serving.prefill_chunk_ms.count"]
-    # both admitted together or one after the other: 2 or 3 chunk calls
-    # of [4 slots, 16 tokens]; the prompts' 5 + 21 tokens are all that
+    # both admitted together (a call of 2 rows, then one of 1) or one
+    # after the other (three calls of 1 row): 3 rows of 16 tokens either
+    # way, one program a call; the prompts' 5 + 21 tokens are all that
     # was new
     assert chunks in (2, 3)
-    assert reg["serving.prefill.tokens_computed"] == chunks * 4 * 16
+    assert reg["serving.prefill.tokens_computed"] == 3 * 16
     assert reg["serving.prefill.tokens_useful"] == 5 + 21
-    assert reg["serving.prefill.launches"] >= chunks
+    assert reg["serving.prefill.launches"] == chunks
+    assert reg["serving.prefill.compiled_hits"] == chunks
+    assert reg["serving.prefill.fallbacks"] == 0
     assert reg["serving.prefill_ms.count"] == chunks
 
 
@@ -298,7 +301,8 @@ def test_page_ticks_in_use_never_pass_reserved(serve_run):
 def test_tick_programs_carry_their_names(serve_run):
     _, _, tick = serve_run
     assert {j.__name__ for j in tick._jits.values()} == \
-        {"serving_tick_greedy"}
+        {"serving_tick_greedy", "serving_prefill_r1", "serving_prefill_r2",
+         "serving_prefill_r4"}
     assert tick._build_jit("mixed", False).__name__ == "serving_tick_mixed"
 
 
