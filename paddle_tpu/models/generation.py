@@ -36,6 +36,17 @@ def init_kv_caches(num_layers, batch, max_len, num_heads, head_dim,
     return caches
 
 
+def recurrent_layer_states(cfg, dtype="float32"):
+    """What ``cfg.layer_states(dtype)`` says each layer keeps per
+    sequence (None: keys and values; else ``{name: (shape, dtype)}`` of
+    a fixed-size recurrent state), or None when the config has no such
+    method or every layer keeps keys and values."""
+    states = getattr(cfg, "layer_states", None)
+    states = None if states is None else states(dtype)
+    return states if states and any(s is not None for s in states) \
+        else None
+
+
 def _advance(caches, n):
     off = caches[0]["offset"] + n
     for c in caches:
@@ -179,11 +190,17 @@ def generate(model, input_ids, max_new_tokens=32, temperature=0.0,
                     break
             return ids
 
-        # GQA caches hold num_kv_heads rows; MMHA groups Q heads natively
-        kv_heads = getattr(cfg, "num_kv_heads", cfg.num_heads)
-        caches = init_kv_caches(
-            cfg.num_layers, b, max_len, kv_heads, cfg.head_dim,
-            dtype="float32")
+        if hasattr(model, "init_caches"):
+            # a model whose layers do not all keep keys and values
+            # builds its own per-layer caches
+            caches = model.init_caches(b, max_len)
+        else:
+            # GQA caches hold num_kv_heads rows; MMHA groups Q heads
+            # natively
+            kv_heads = getattr(cfg, "num_kv_heads", cfg.num_heads)
+            caches = init_kv_caches(
+                cfg.num_layers, b, max_len, kv_heads, cfg.head_dim,
+                dtype="float32")
         tracker = _EosTracker(b, eos_token_id)
         logits = model(input_ids, caches=caches)      # prefill
         _advance(caches, s)
@@ -243,6 +260,12 @@ def speculative_generate(model, draft_model, input_ids,
                         temperature=0.0, eos_token_id=eos_token_id)
     cfg = model.config
     dcfg = draft_model.config
+    for which, c in (("model", cfg), ("draft_model", dcfg)):
+        if recurrent_layer_states(c) is not None:
+            raise NotImplementedError(
+                f"speculative_generate: {which} has layers that keep a "
+                "recurrent state, and a rejected tail cannot be rewound "
+                "out of a recurrence by moving an offset")
     b, s = input_ids.shape
     max_len = min(cfg.max_seq_len, s + max_new_tokens)
     n_new = max_len - s

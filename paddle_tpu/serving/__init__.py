@@ -18,7 +18,8 @@ from .adapters import AdapterPool  # noqa: F401
 from .api import (  # noqa: F401
     AdapterConfigError, DeadlineExceededError, EngineShutdownError,
     NoReplicaError, PageMigrationError, QueueFullError,
-    RequestCancelledError, RequestOutput, SamplingParams,
+    RecurrentStateError, RequestCancelledError, RequestOutput,
+    SamplingParams,
     SchedulerStallError, ServingConfig, ServingError,
     UnknownAdapterError,
 )
@@ -40,7 +41,7 @@ __all__ = [
     "SlotKVCache", "PagedKVCache", "PrefixTree", "ServingError",
     "QueueFullError", "DeadlineExceededError", "EngineShutdownError",
     "SchedulerStallError", "NoReplicaError", "PageMigrationError",
-    "RequestCancelledError",
+    "RequestCancelledError", "RecurrentStateError",
     "AdapterConfigError", "UnknownAdapterError", "AdapterPool",
     "serving_stats", "reset_serving_stats", "reset_router_stats",
     "ServingRouter", "RouterConfig", "HashRing", "ServingFleet",

@@ -85,6 +85,17 @@ class PageMigrationError(ServingError):
     like a dead target: it falls back to decoding locally."""
 
 
+class RecurrentStateError(ServingError, ValueError):
+    """The configuration asks for a mechanism that cannot yet carry the
+    fixed-size recurrent state some of the model's layers keep per
+    sequence (what ``model.config.layer_states()`` reports): prefix
+    sharing, speculative rollback, the slot cache layout, and page
+    export / migration all move or rewind keys and values by page or by
+    offset, and a recurrence has neither.  Raised at ``Engine``
+    construction (and by the page export / adopt calls themselves) and
+    names the mechanism — never a wrong answer mid-decode."""
+
+
 @dataclass(frozen=True)
 class SamplingParams:
     """Per-request decoding knobs — the same semantics (and HF processor
@@ -342,6 +353,11 @@ class RequestOutput:
     #: replica that decoded the tail of this request (fleet only): the
     #: submit target unless KV-page migration resumed it elsewhere
     decoded_by: str | None = None
+    #: the slot whose pages and state rows held the request (None where
+    #: another replica decoded it).  Its recurrent state rows stay as the
+    #: request's last tick left them until the slot's next admission
+    #: (`PagedKVCache.read_state`).
+    slot: int | None = None
 
     @property
     def ids(self):

@@ -311,21 +311,26 @@ class CompiledServingTick:
     # the traced tick body (phase 2)
     # ------------------------------------------------------------------
 
-    def _replay_model(self, tokens, pools, pt, off, caps, lora_idx=None):
+    def _replay_model(self, tokens, pools, pt, off, caps, lora_idx=None,
+                      state_rows=None, valid_len=None):
         """The captured model call, replayed while ``jax.jit`` traces a
         member of the family: ``tokens`` [rows, s] against the flat
         ``pools`` through page table ``pt`` [rows, pages_per_slot] at
         write offsets ``off`` [rows].  ``lora_idx`` is the call's own
         adapter index (the prefill's row-ordered one); None activates
-        the pool's persistent per-slot vector.  Returns the [rows, s, V]
-        logits and the functionally-updated pools, flat."""
+        the pool's persistent per-slot vector.  ``state_rows`` and
+        ``valid_len`` are for the layers that keep a recurrent state:
+        each row's state row (None: row i is slot i) and how many of its
+        positions are real.  Returns the [rows, s, V] logits and the
+        functionally-updated pools, flat."""
         eng = self.eng
         cache = eng.cache
         tracer = BindTracer(rng_key=None)
         _state.STATE.tracer = tracer
         try:
             with Installed(list(zip(self._caps, caps))):
-                views = cache.views_over(pools, pt, off)
+                views = cache.views_over(pools, pt, off, state_rows,
+                                         valid_len)
                 idx = None if lora_idx is None else Tensor(lora_idx)
                 with eng._lora_ctx(idx):
                     logits_t = eng.model(Tensor(tokens), caches=views)
@@ -343,7 +348,13 @@ class CompiledServingTick:
         # masked (and prefill re-writes its positions next chunk) either
         # way
         tok_in = jnp.where(alive, last, jnp.zeros_like(last))[:, None]
-        logits, new_pools = self._replay_model(tok_in, pools, pt, off, caps)
+        # a recurrent state has no mask to hide a write behind: only the
+        # rows that decode may move theirs (a row mid-prefill keeps what
+        # its chunks have built)
+        valid = alive.astype(jnp.int32) if self.eng.cache.has_state \
+            else None
+        logits, new_pools = self._replay_model(tok_in, pools, pt, off, caps,
+                                               valid_len=valid)
         logits = logits[:, -1, :]
 
         ns = logits.shape[0]
@@ -418,13 +429,23 @@ class CompiledServingTick:
         return out + [ns]
 
     def _build_prefill_jit(self, rows, donating):
-        def serving_prefill(pools, pt, off, tokens, last, lora_idx, caps):
-            logits, new_pools = self._replay_model(tokens, pools, pt, off,
-                                                   caps, lora_idx)
+        has_state = self.eng.cache.has_state
+        num_slots = self.eng.cache.num_slots
+
+        def serving_prefill(pools, pt, off, tokens, last, lora_idx,
+                            state_rows, caps):
+            # the pad positions after a row's last real one must leave a
+            # recurrent state as it was
+            logits, new_pools = self._replay_model(
+                tokens, pools, pt, off, caps, lora_idx, state_rows,
+                last + 1 if has_state else None)
             # each row's logits at its last real position: all the host
-            # ever reads of a chunk
+            # ever reads of a chunk — padded to [num_slots, V], the eager
+            # lane's shape, so that the host's row slices are the same
+            # few programs whatever bucket ran
             picked = jnp.take_along_axis(
                 logits, last[:, None, None], axis=1)[:, 0]
+            picked = jnp.pad(picked, ((0, num_slots - rows), (0, 0)))
             return new_pools, picked
 
         # never ``serving_tick``: readers of the device trace tell ticks
@@ -455,7 +476,9 @@ class CompiledServingTick:
                         np.zeros((rows, cache.pages_per_slot), np.int32),
                         np.zeros(rows, np.int32),
                         np.zeros((rows, chunk), np.int32),
-                        np.zeros(rows, np.int32), lora, caps)
+                        np.zeros(rows, np.int32), lora,
+                        cache.state_rows((), rows) if cache.has_state
+                        else None, caps)
                 lowered[rows] = jit.lower(*args)
             key = (f"prefill_r{rows}", donating)
             self._jits[key] = jit
@@ -503,20 +526,23 @@ class CompiledServingTick:
     def run_prefill(self, member, slots, starts, tokens, last, lora_rows):
         """One chunk call as ONE program over the donated pools: the
         ``prefill_member``'s [rows, chunk] ``tokens`` for ``slots`` at
-        write offsets ``starts``; returns the [rows, V] logits at each
-        row's ``last`` position.  The cache has adopted the new pools on
-        return."""
+        write offsets ``starts``; returns the logits at each row's
+        ``last`` position, [num_slots, V] with the rows first.  The
+        cache has adopted the new pools on return."""
         cache = self.eng.cache
         rows, program = member
         with span("serving.prefill.view"):
             table, off = cache.prefill_table(slots, starts, rows)
+            state_rows = cache.state_rows(slots, rows) \
+                if cache.has_state else None
         with span("serving.prefill.model"):
             # TRACE_LOCK as in the tick: parameter slots may hold another
             # engine's tracers while it traces
             with TRACE_LOCK:
                 pools, caps = self._donated_and_captured()
                 new_pools, picked = program(pools, table, off, tokens,
-                                            last, lora_rows, caps)
+                                            last, lora_rows, state_rows,
+                                            caps)
             # the call's device time belongs to the call's span
             picked.block_until_ready()
         with span("serving.prefill.absorb"):
@@ -598,7 +624,6 @@ class CompiledServingTick:
             "topk": jnp.asarray(topk), "topp": jnp.asarray(topp),
             "pen": jnp.asarray(pen), "keys": jnp.asarray(keys),
             "seen": jnp.asarray(seen), "out": jnp.asarray(out),
-            "off": None,
         }
         self._h_counts = counts.copy()
         self._rep = dict(eng._active)
@@ -650,13 +675,7 @@ class CompiledServingTick:
         # page table / offsets: host mutations (admission, release,
         # growth) flow through the cache's own lazy flush; steady-state
         # ticks ride the previous program's device outputs
-        if cache._dirty or self._dev["off"] is None:
-            lay0 = cache.layer_caches()[0]
-            pt = lay0["page_table"]._data_
-            off = lay0["offset"]._data_
-        else:
-            pt = cache.layers[0]["page_table"]._data_
-            off = self._dev["off"]
+        pt, off = cache.table_arrays()
         mode = "greedy" if all(
             r.sampling.greedy and not r.sampling.uses_penalty
             for r in active.values()) else "mixed"
@@ -736,9 +755,8 @@ class CompiledServingTick:
             offsets_np = cache.offsets.copy()
             offsets_np[list(active)] += 1
             cache.absorb_tick(new_pools, new_off, offsets_np)
-            self._dev.update(off=new_off, last=new_last,
-                             counts=new_counts, alive=new_alive,
-                             seen=new_seen, out=new_out)
+            self._dev.update(last=new_last, counts=new_counts,
+                             alive=new_alive, seen=new_seen, out=new_out)
             self._h_counts[list(active)] += 1
             self._ahead = True
 
@@ -747,6 +765,11 @@ class CompiledServingTick:
             stats.incr("slot_steps", cache.num_slots)
             stats.incr("slot_steps_active", n_active)
             stats.incr("tokens_generated", n_active)
+            if cache.has_state:
+                # state rows the tick moved for a request, of all it
+                # passed through the update
+                stats.incr("state.row_ticks_live", n_active)
+                stats.incr("state.row_ticks_total", cache.num_slots)
 
             now = time.monotonic()
             evict = eng.scfg.deadline_policy == "evict"

@@ -36,9 +36,11 @@ from ..observability import tracing
 from ..observability.tracing import span
 from ..utils import fault_injection as _fi
 from .api import (DeadlineExceededError, EngineShutdownError,
-                  QueueFullError, RequestCancelledError, RequestOutput,
-                  SamplingParams, SchedulerStallError, ServingConfig)
+                  QueueFullError, RecurrentStateError,
+                  RequestCancelledError, RequestOutput, SamplingParams,
+                  SchedulerStallError, ServingConfig)
 from .kv_slots import SlotKVCache
+from ..models.generation import recurrent_layer_states
 
 
 class _Request:
@@ -154,6 +156,13 @@ class Engine:
                     f"draft_model vocab {dcfg.vocab_size} != target "
                     f"vocab {self.cfg.vocab_size}")
         self.draft_cache = None
+        # which layers keep a fixed-size recurrent state per sequence
+        # instead of keys and values: the model's config says
+        self._layer_states = None
+        if hasattr(self.cfg, "layer_states"):
+            self._layer_states = recurrent_layer_states(
+                self.cfg, next(iter(model.parameters()))._data_.dtype)
+        self._refuse_for_recurrent_state()
         self._pages_peak = 0
         self._queue: deque[_Request] = deque()
         self._active: dict[int, _Request] = {}
@@ -206,7 +215,7 @@ class Engine:
         # ack) -> result payload` (phase 2: block for the remote decode
         # with no local resources held).  None = this engine never
         # migrates (the pre-disaggregation engine, byte-for-byte)
-        self.migrator = None
+        self._migrator = None
         self.migration_awaiter = None
         self._migrating_out: dict[int, _Request] = {}
         self._migration_results: deque = deque()
@@ -235,6 +244,52 @@ class Engine:
                 self.scfg.adapter_rank_pool, self.scfg.num_slots)
             for aid, source in (self.scfg.adapters or {}).items():
                 self.adapter_pool.register(aid, source)
+
+    def _refuse_for_recurrent_state(self):
+        """Typed refusals, at construction, of what cannot carry a
+        recurrent state yet (docs/SERVING.md "Recurrent state beside
+        pages")."""
+        draft = self.scfg.draft_model
+        if draft is not None and \
+                recurrent_layer_states(draft.config) is not None:
+            raise RecurrentStateError(
+                "draft_model has layers that keep a recurrent state: "
+                "the draft cache holds keys and values only")
+        if self._layer_states is None:
+            return
+        n = sum(s is not None for s in self._layer_states)
+        why = f"{n} of the model's layers keep a recurrent state"
+        if self.scfg.kv_layout != "paged":
+            raise RecurrentStateError(
+                f"kv_layout={self.scfg.kv_layout!r}: {why}, which only "
+                "the paged cache manager holds (kv_layout='paged')")
+        if self.scfg.enable_prefix_cache:
+            raise RecurrentStateError(
+                f"enable_prefix_cache=True: {why}, and a shared prefix "
+                "would need the state as it stood at the prefix's end, "
+                "which no page holds; pass enable_prefix_cache=False")
+        if self._spec_k > 0:
+            raise RecurrentStateError(
+                f"speculation_k={self._spec_k}: {why}, and a rejected "
+                "tail cannot be rolled back out of a recurrence by "
+                "moving an offset")
+        if self.scfg.role != "mixed":
+            raise RecurrentStateError(
+                f"role={self.scfg.role!r}: {why}, and page export / "
+                "migration carries keys and values only")
+
+    @property
+    def migrator(self):
+        return self._migrator
+
+    @migrator.setter
+    def migrator(self, fn):
+        if fn is not None and self._layer_states is not None:
+            raise RecurrentStateError(
+                "migrator: page export / migration carries keys and "
+                "values only, and the model's layers keep a recurrent "
+                "state")
+        self._migrator = fn
 
     # ---------------- lifecycle ----------------
     def start(self):
@@ -277,13 +332,22 @@ class Engine:
             # +speculation_k positions of headroom: a verify window may
             # write K tokens past the last real position before the
             # accept-mask rollback rewinds them
+            slot_len = self.max_len + self._spec_k
+            if self._layer_states is not None:
+                # a last chunk that would overrun the slot is shifted
+                # left and re-feeds tokens, which pages forgive and a
+                # recurrence does not: whole chunks always fit (the
+                # extra table entries stay on the scratch page)
+                chunk = min(self.scfg.prefill_chunk_tokens, slot_len)
+                slot_len = -(-slot_len // chunk) * chunk
             cache = PagedKVCache(
-                self.cfg.num_layers, self.scfg.num_slots,
-                self.max_len + self._spec_k,
+                self.cfg.num_layers, self.scfg.num_slots, slot_len,
                 self._kv_heads, self.cfg.head_dim,
                 page_size=self._page_size,
                 num_pages=self.scfg.kv_pool_pages,
-                dtype=self.scfg.cache_dtype)
+                dtype=self.scfg.cache_dtype,
+                layer_states=self._layer_states)
+            stats.set_value("state.bytes", cache.state_bytes)
             self.prefix_tree = PrefixTree(self._page_size) \
                 if self.scfg.enable_prefix_cache else None
             # one compiled prefill program: every chunk is this wide
@@ -550,6 +614,10 @@ class Engine:
         if not self._paged:
             raise PageMigrationError(
                 "page adoption requires kv_layout='paged'")
+        if self._layer_states is not None:
+            raise RecurrentStateError(
+                "submit_resume: migrated pages carry keys and values "
+                "only, and the model's layers keep a recurrent state")
         prompt = np.asarray(
             prompt_ids._data_ if hasattr(prompt_ids, "_data_")
             else prompt_ids).astype(np.int32).reshape(-1)
@@ -1060,6 +1128,8 @@ class Engine:
             if req.adapter_id is not None:
                 stats.adapter_observe(req.adapter_id)
         self.cache.set_offset(slot, req.shared_len)
+        # the slot's previous tenant left its recurrent state behind
+        self.cache.reset_state(slot)
         if self._spec:
             req.draft_prefill_pos = 0
             self.draft_cache.set_offset(slot, 0)
@@ -1211,6 +1281,10 @@ class Engine:
         useful = 0
         for row, (req, off) in enumerate(zip(reqs, offs)):
             start = min(off, cap - chunk)
+            if start != off and cache.has_state:  # pragma: no cover
+                raise RuntimeError(
+                    f"prefill chunk at {off} shifted to {start}: tokens "
+                    "would be fed to a recurrent state twice")
             end = min(start + chunk, req.prompt.size)
             tokens[row, :end - start] = req.prompt[start:end]
             last[row] = end - 1 - start
@@ -1253,7 +1327,7 @@ class Engine:
                 self.adapter_pool.row_tensor(lora_rows))
         launches0 = dispatch_count()
         with span("serving.prefill.view"):
-            views = cache.prefill_view(slots, starts)
+            views = cache.prefill_view(slots, starts, last + 1)
         with span("serving.prefill.model"), TRACE_LOCK, lora:
             # a shared model may be mid-capture
             logits = model(Tensor(tokens), caches=views)
@@ -1682,9 +1756,10 @@ class Engine:
             tok_in = np.zeros((self.cache.num_slots, 1), np.int32)
             for slot, req in self._active.items():
                 tok_in[slot, 0] = req.last_token
+            caches = self.cache.layer_caches(self._active) \
+                if self._paged else self.cache.layer_caches()
             with TRACE_LOCK, self._lora_ctx():
-                logits = self.model(Tensor(tok_in),
-                                    caches=self.cache.layer_caches())
+                logits = self.model(Tensor(tok_in), caches=caches)
             self.cache.advance(self._active.keys())
             last = logits[:, -1, :]                  # [num_slots, V]
             all_greedy = all(
@@ -1821,7 +1896,7 @@ class Engine:
             request_id=req.id, prompt_ids=req.prompt,
             output_ids=np.asarray(req.tokens, np.int32),
             finish_reason=reason, ttft_ms=req.ttft_ms,
-            latency_ms=(now - req.submit_t) * 1e3)
+            latency_ms=(now - req.submit_t) * 1e3, slot=req.slot)
         with self._lock:
             self._pending.pop(req.id, None)
         try:

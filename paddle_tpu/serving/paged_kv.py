@@ -30,15 +30,28 @@ min(prompt+max_new, max_len)/page_size)`` minus shared), and
 `available_pages` subtracts outstanding reservations — so admission
 backpressure happens up front and `ensure_capacity` can never fail
 mid-decode.
+
+**Recurrent state beside pages.**  A layer that keeps a fixed-size state
+per sequence instead of keys and values (``layer_states``: what the
+model's config says of each layer) gets one state ROW per slot and one
+scratch row, in arrays no page table indexes: the slot is all the
+reservation it needs.  ``reset_state`` zeroes a slot's rows in place at
+admission; a batch row finds its state row through ``state_rows`` (the
+slot, or the scratch row for the surplus rows of a prefill call) and
+says through ``valid_len`` how many of its positions are real.
 """
 from __future__ import annotations
 
 import itertools
 
 import numpy as np
+import jax
 import jax.numpy as jnp
 
+from . import stats
 from ..core.tensor import Tensor
+from ..observability.tracing import span
+from ..utils.flags import flag as _flag
 
 
 class PagedKVCache:
@@ -54,7 +67,8 @@ class PagedKVCache:
     """
 
     def __init__(self, num_layers, num_slots, max_len, num_kv_heads,
-                 head_dim, page_size=16, num_pages=None, dtype="float32"):
+                 head_dim, page_size=16, num_pages=None, dtype="float32",
+                 layer_states=None):
         self.num_slots = int(num_slots)
         self.page_size = int(page_size)
         self.max_len = int(max_len)
@@ -84,14 +98,35 @@ class PagedKVCache:
         self.quant_dtype = dtype if quant else None
         store_dtype = quant[0] if quant else dtype
         pool_shape = [total, self.page_size, num_kv_heads, head_dim]
+        layer_states = list(layer_states or [None] * num_layers)
+        if len(layer_states) != num_layers:
+            raise ValueError(f"{len(layer_states)} layer_states for "
+                             f"{num_layers} layers")
+        #: the state row that surplus rows of a prefill call read and
+        #: write (a slot's row is its index)
+        self.scratch_row = self.num_slots
         self.layers = []
-        for _ in range(num_layers):
+        #: per layer, the names of the device arrays a model call updates
+        #: (``flat_pools`` order)
+        self._keys = []
+        for state in layer_states:
+            if state is not None:
+                # a row a slot, no page table: the recurrent layer's
+                # fixed-size state
+                lay = {name: Tensor(jnp.zeros(
+                    (self.num_slots + 1,) + tuple(shape), dtype=dt))
+                    for name, (shape, dt) in state.items()}
+                self._keys.append(tuple(state))
+                lay.update(state_rows=None, valid_len=None)
+                self.layers.append(lay)
+                continue
             lay = {"k_pool": Tensor(jnp.zeros(pool_shape,
                                               dtype=store_dtype)),
                    "v_pool": Tensor(jnp.zeros(pool_shape,
                                               dtype=store_dtype)),
                    "page_table": None, "offset": None,
                    "page_size": self.page_size}
+            keys = ("k_pool", "v_pool")
             if quant:
                 # one float32 scale per cached token position, stored
                 # page-major alongside the pools: a write only ever
@@ -101,8 +136,70 @@ class PagedKVCache:
                                                  jnp.float32))
                 lay["v_scale"] = Tensor(jnp.ones([total, self.page_size],
                                                  jnp.float32))
+                keys += ("k_scale", "v_scale")
+            self._keys.append(keys)
             self.layers.append(lay)
+        self._paged = tuple(i for i, st in enumerate(layer_states)
+                            if st is None)
+        self._stateful = tuple(i for i, st in enumerate(layer_states)
+                               if st is not None)
+        self._reset_jits = {}       # donating or not -> the reset program
         self._flush()
+
+    # ---------------- recurrent state ----------------
+    @property
+    def has_state(self):
+        """Whether any layer keeps a per-slot recurrent state."""
+        return bool(self._stateful)
+
+    @property
+    def state_bytes(self):
+        return sum(self.layers[i][k]._data_.nbytes
+                   for i in self._stateful for k in self._keys[i])
+
+    def _state_arrays(self):
+        return tuple(self.layers[i][k]._data_
+                     for i in self._stateful for k in self._keys[i])
+
+    def reset_state(self, slot):
+        """Zero ``slot``'s row of every state array, in place: ONE
+        program over the donated arrays writes the rows and nothing
+        else (an ``.at[slot].set(0)`` outside a donating program would
+        copy every array).  Admission calls it; the tick and the prefill
+        member never see a previous tenant's state."""
+        if not self._stateful:
+            return
+        donating = bool(_flag("FLAGS_jit_donate_buffers", True))
+        if donating not in self._reset_jits:
+            def state_reset(arrays, row):
+                return tuple(jax.lax.dynamic_update_slice(
+                    a, jnp.zeros((1,) + a.shape[1:], a.dtype),
+                    (row,) + (0,) * (a.ndim - 1)) for a in arrays)
+
+            self._reset_jits[donating] = jax.jit(
+                state_reset, donate_argnums=(0,) if donating else ())
+        with span("serving.state.reset"):
+            it = iter(self._reset_jits[donating](self._state_arrays(),
+                                                 np.int32(slot)))
+            for i in self._stateful:
+                for k in self._keys[i]:
+                    self.layers[i][k] = Tensor(next(it))
+        stats.incr("state.resets")
+
+    def read_state(self, slot):
+        """``{layer: {name: array}}``: a host copy of ``slot``'s row of
+        every state array.  The row is as the last call that named the
+        slot left it — a released slot's stays until ``reset_state`` —
+        so a snapshot, or a check against a reference, reads it here."""
+        return {i: {k: np.asarray(self.layers[i][k]._data_[slot])
+                    for k in self._keys[i]} for i in self._stateful}
+
+    def state_rows(self, slots, rows):
+        """int32 [rows]: the state row of each row of a prefill call —
+        ``slots[i]`` for row i, the scratch row for the surplus rows."""
+        out = np.full(rows, self.scratch_row, np.int32)
+        out[:len(slots)] = slots
+        return out
 
     # ---------------- pool accounting ----------------
     @property
@@ -258,6 +355,7 @@ class PagedKVCache:
         from .api import PageMigrationError
         k_pages = np.asarray(k_pages)
         v_pages = np.asarray(v_pages)
+        self._refuse_state("adopt_pages")
         pool = np.asarray(self.layers[0]["k_pool"]._data_)
         want = (len(self.layers),) + pool.shape[1:]
         if k_pages.ndim != 5 or k_pages.shape[0] != want[0] or \
@@ -327,6 +425,7 @@ class PagedKVCache:
         offset has written into (shared tree pages included — the COPY
         migrates; tree ownership stays here), scales None for float
         pools."""
+        self._refuse_state("export_pages")
         off = int(self.offsets[slot])
         n = max(1, -(-off // self.page_size))
         ids = [int(p) for p in self.table[slot, :n]]
@@ -344,13 +443,34 @@ class PagedKVCache:
         return off, k, v, np.ascontiguousarray(np.stack(kss)), \
             np.ascontiguousarray(np.stack(vss))
 
+    def _refuse_state(self, what):
+        if self._stateful:
+            from .api import RecurrentStateError
+            raise RecurrentStateError(
+                f"{what}: {len(self._stateful)} layers keep a recurrent "
+                "state per slot, which pages do not carry")
+
     # ---------------- device views ----------------
-    def layer_caches(self):
+    def layer_caches(self, live=None):
         """Per-layer cache dicts for the batched decode step.  Flushes
         the (single, shared) offsets + page-table device arrays if any
-        host-side mutation happened since the last call."""
+        host-side mutation happened since the last call.  ``live`` (the
+        slots that decode this step) is what the recurrent layers'
+        ``valid_len`` is made of: every other row's state stays as it
+        was."""
         self._flush()
+        if self._stateful:
+            valid = np.zeros(self.num_slots, np.int32)
+            valid[list(live or ())] = 1
+            valid = Tensor(jnp.asarray(valid))
+            for i in self._stateful:
+                self.layers[i].update(state_rows=None, valid_len=valid)
         return self.layers
+
+    def table_arrays(self):
+        """(page table, offsets) as the device holds them, flushed."""
+        self._flush()
+        return self._pt._data_, self._off._data_
 
     def prefill_table(self, slots, starts, rows):
         """Host arrays for one batched prefill-chunk call of ``rows``
@@ -365,40 +485,39 @@ class PagedKVCache:
             off[row] = start
         return table, off
 
-    def views_over(self, pools_flat, page_table, offset):
-        """Per-layer cache dicts over ``pools_flat`` (the pools, and the
-        per-page scales of a quantized cache, flat per layer in
-        ``flat_pools`` order) behind one page table and offset vector."""
+    def views_over(self, pools_flat, page_table, offset, state_rows=None,
+                   valid_len=None):
+        """Per-layer cache dicts over ``pools_flat`` (each layer's device
+        arrays, flat in ``flat_pools`` order): the paged layers behind
+        one page table and offset vector, the recurrent layers behind
+        ``state_rows`` (None: row i is slot i) and ``valid_len`` (None:
+        every position is real)."""
         pt, off = Tensor(page_table), Tensor(offset)
-        quant = self.quant_dtype is not None
+        rows = None if state_rows is None else Tensor(state_rows)
+        valid = None if valid_len is None else Tensor(valid_len)
         views = []
-        i = 0
-        for _ in self.layers:
-            view = {"k_pool": Tensor(pools_flat[i]),
-                    "v_pool": Tensor(pools_flat[i + 1]),
-                    "page_table": pt, "offset": off,
-                    "page_size": self.page_size}
-            i += 2
-            if quant:
-                view["k_scale"] = Tensor(pools_flat[i])
-                view["v_scale"] = Tensor(pools_flat[i + 1])
-                i += 2
+        it = iter(pools_flat)
+        for keys in self._keys:
+            view = {k: Tensor(next(it)) for k in keys}
+            if "k_pool" in view:
+                view.update(page_table=pt, offset=off,
+                            page_size=self.page_size)
+            else:
+                view.update(state_rows=rows, valid_len=valid)
             views.append(view)
         return views
 
     def flat_pools(self, views=None):
         """The device arrays a model call updates, flat per layer (k, v,
-        then the scales of a quantized cache): of the cache itself, or
-        of ``views`` after a call."""
-        quant = self.quant_dtype is not None
-        flat = []
-        for lay in self.layers if views is None else views:
-            flat += [lay["k_pool"]._data_, lay["v_pool"]._data_]
-            if quant:
-                flat += [lay["k_scale"]._data_, lay["v_scale"]._data_]
-        return tuple(flat)
+        then the scales of a quantized cache; a recurrent layer's state
+        arrays): of the cache itself, or of ``views`` after a call."""
+        return tuple(lay[k]._data_
+                     for lay, keys in zip(
+                         self.layers if views is None else views,
+                         self._keys)
+                     for k in keys)
 
-    def prefill_view(self, slots, starts):
+    def prefill_view(self, slots, starts, valid=None):
         """Per-layer cache dicts for one EAGER batched prefill-chunk
         call — the reference lane of the compiled prefill member
         (serving/compiled_tick.py), which takes ``prefill_table`` at its
@@ -407,8 +526,13 @@ class PagedKVCache:
         with `absorb_view`; until then the old and the new pools are
         both alive."""
         table, off = self.prefill_table(slots, starts, self.num_slots)
+        if not self._stateful:
+            rows = valid = None
+        else:
+            rows = jnp.asarray(self.state_rows(slots, self.num_slots))
+            valid = jnp.asarray(valid)
         return self.views_over(self.flat_pools(), jnp.asarray(table),
-                               jnp.asarray(off))
+                               jnp.asarray(off), rows, valid)
 
     def absorb_view(self, views):
         """Adopt the functionally-updated pools (and per-page scales)
@@ -419,16 +543,10 @@ class PagedKVCache:
         """Adopt functionally-updated pools (``flat_pools`` order) — what
         a compiled prefill chunk hands back after the old ones were
         donated to it.  Offsets and page table are the host's to set."""
-        quant = self.quant_dtype is not None
-        i = 0
-        for lay in self.layers:
-            lay["k_pool"] = Tensor(pools_flat[i])
-            lay["v_pool"] = Tensor(pools_flat[i + 1])
-            i += 2
-            if quant:
-                lay["k_scale"] = Tensor(pools_flat[i])
-                lay["v_scale"] = Tensor(pools_flat[i + 1])
-                i += 2
+        it = iter(pools_flat)
+        for lay, keys in zip(self.layers, self._keys):
+            for k in keys:
+                lay[k] = Tensor(next(it))
 
     def absorb_tick(self, pools_flat, new_offsets, offsets_np=None):
         """Adopt one compiled scheduler tick's functionally-updated
@@ -439,20 +557,20 @@ class PagedKVCache:
         host agree after this call, so a later ``layer_caches()`` must
         not re-upload stale Tensors over the tick's outputs."""
         self.absorb_pools(pools_flat)
-        off_t = Tensor(new_offsets)
-        for lay in self.layers:
-            lay["offset"] = off_t
+        self._off = off_t = Tensor(new_offsets)
+        for i in self._paged:
+            self.layers[i]["offset"] = off_t
         if offsets_np is not None:
             self.offsets[:] = offsets_np
 
     def _flush(self):
         if not self._dirty:
             return
-        off = Tensor(jnp.asarray(self.offsets))
-        pt = Tensor(jnp.asarray(self.table))
-        for lay in self.layers:
-            lay["offset"] = off
-            lay["page_table"] = pt
+        self._off = off = Tensor(jnp.asarray(self.offsets))
+        self._pt = pt = Tensor(jnp.asarray(self.table))
+        for i in self._paged:
+            self.layers[i]["offset"] = off
+            self.layers[i]["page_table"] = pt
         self._dirty = False
 
 

@@ -131,6 +131,20 @@ def declare_tick_stats():
                                   "eager lane because one program could "
                                   "not host them")):
         _registry.counter(PREFIX + name, text)
+    # recurrent state beside pages (zero for a model without such layers)
+    for name, text in (
+            ("state.resets", "slots whose recurrent state an admission "
+                             "reset"),
+            ("state.row_ticks_live", "state rows a compiled tick moved "
+                                     "for a decoding request"),
+            ("state.row_ticks_total", "state rows a compiled tick passed "
+                                      "through the update")):
+        _registry.counter(PREFIX + name, text)
+    _registry.histogram(PREFIX + "state.reset_ms",
+                        "one admission's reset of its slot's recurrent "
+                        "state (ms)")
+    _registry.gauge(PREFIX + "state.bytes",
+                    "bytes of the per-slot recurrent state arrays")
 
 
 def declare_migration_stats():
@@ -317,6 +331,14 @@ def serving_stats():
     sequences (the paged pool admits more of them than
     ``pool_bytes / max_seq_len`` stripes would).
 
+    Recurrent-state quantities (a model whose layers keep a per-slot
+    state beside the pages, zero otherwise): ``state_bytes`` (the state
+    arrays' size), ``state_resets`` and ``state_reset_ms_avg`` (an
+    admission's in-place reset of its slot's rows), and
+    ``state_rows_live_share`` — of the state rows the compiled ticks
+    passed through the update, the share that belonged to a decoding
+    request.
+
     Speculative-decoding quantities (``speculation_k > 0``, zero
     otherwise): ``spec_windows`` (draft→verify→rollback iterations),
     ``spec_proposed_tokens``/``spec_accepted_tokens`` and the derived
@@ -401,6 +423,12 @@ def serving_stats():
         "tick_fallbacks": g("tick.fallbacks"),
         "prefill_compiled_hits": g("prefill.compiled_hits"),
         "prefill_fallbacks": g("prefill.fallbacks"),
+        "state_bytes": g("state.bytes"),
+        "state_resets": g("state.resets"),
+        "state_reset_ms_avg": avg("state.reset_ms"),
+        "state_rows_live_share": (g("state.row_ticks_live")
+                                  / g("state.row_ticks_total"))
+        if g("state.row_ticks_total") else None,
         "kv_pages_in_use": g("kv_pages_in_use"),
         "kv_pages_free": g("kv_pages_free"),
         "kv_pages_peak": g("kv_pages_peak"),

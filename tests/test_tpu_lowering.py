@@ -224,6 +224,30 @@ def test_paged_decode_through_the_op_compiles(v5e):
     assert _n_kernels(text) == 1
 
 
+# ----------------------------------------------------------- ssm update
+
+#         rows slots+1 heads head state groups
+_SSM = {
+    "granite-4.0-h-micro-64-slots": (64, 65, 64, 64, 128, 1),
+    "prefill-bucket-of-2": (2, 65, 64, 64, 128, 1),
+    "two-groups": (8, 9, 16, 64, 128, 2),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_SSM))
+def test_ssm_update_compiles(v5e, case):
+    """The decode step's in-place state update at Granite 4.0-H Micro's
+    widths: one kernel, the state aliased in and out."""
+    from paddle_tpu.pallas import ssm
+    b, r, h, p, n, g = _SSM[case]
+    text = _compile(ssm.ssm_step, [
+        ((r, h, p, n), F32), ((b,), jnp.int32), ((b, h, p), F32),
+        ((b, h), F32), ((h,), F32), ((b, g, n), F32), ((b, g, n), F32)],
+        SingleDeviceSharding(v5e[0]))
+    assert _n_kernels(text) == 1
+    assert "ssm_update" in text
+
+
 # ----------------------------------------------------------- lora delta
 
 def test_lora_delta_compiles(v5e):
