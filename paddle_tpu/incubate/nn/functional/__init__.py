@@ -310,13 +310,20 @@ def paged_masked_multihead_attention(q, k, v, k_pool, v_pool, page_table,
 
     On a TPU (and in Pallas interpret mode) the single-token decode
     read runs the Pallas kernel
-    (`pallas.flash_attention.paged_decode_attention`) that streams
-    pages via a scalar-prefetched page table instead of materializing
-    the gather (per-page scales ride their own scalar-prefetch-indexed
-    BlockSpec); its online softmax is numerically (not bitwise)
-    equivalent to the XLA gather read, which serves everything else:
-    prefill chunks, CPUs, and programs partitioned over a mesh (the
-    kernel carries no ``shard_map``).
+    (`pallas.flash_attention.paged_decode_attention`): each row streams
+    its LIVE pages alone, several a step, from the pools in HBM through
+    the scalar-prefetched page table, and the query heads that share a
+    kv head are the rows of one MXU product — its time follows the
+    contexts, not the slots' capacity.  Its online softmax is
+    numerically (not bitwise) equivalent to the XLA gather read, which
+    serves everything else: prefill chunks, CPUs, programs partitioned
+    over a mesh (the kernel carries no ``shard_map``), and pools whose
+    pages the kernel cannot view as whole 128-lane rows
+    (`paged_decode_pages_per_step` answers 0: a rule on ``page_size``,
+    kv heads, head size).  Which lane a single-token read took is
+    decided when the op is traced and counted there, once a trace:
+    ``pallas.paged_decode.kernel`` / ``pallas.paged_decode.xla_lane``
+    (`serving_stats()` shows both).
     """
     psz = int(page_size)
     quant = k_scale is not None
@@ -337,7 +344,8 @@ def paged_masked_multihead_attention(q, k, v, k_pool, v_pool, page_table,
             f"new tokens > page-table capacity {s_cap}")
 
     from ....pallas import flash_attention as _fa
-    use_kernel = s_new == 1 and _fa._unsharded_kernels_on()
+    from ....utils import monitor as _monitor
+    kernels_on = s_new == 1 and _fa._unsharded_kernels_on()
 
     def fn(qa, ka, va, kp, vp, pt, off, *scales):
         from ....quantization import dequantize_kv, quantize_kv_rows
@@ -359,6 +367,11 @@ def paged_masked_multihead_attention(q, k, v, k_pool, v_pool, page_table,
         else:
             kp = kp.at[page_ids, in_page].set(ka.astype(kp.dtype))
             vp = vp.at[page_ids, in_page].set(va.astype(vp.dtype))
+        use_kernel = kernels_on and _fa.paged_decode_pages_per_step(
+            psz, kp.shape[2], d, kp.dtype.itemsize) > 0
+        if s_new == 1:
+            _monitor.incr("pallas.paged_decode.kernel" if use_kernel
+                          else "pallas.paged_decode.xla_lane")
         if use_kernel:
             out = _fa.paged_decode_attention(
                 qa[:, 0], kp, vp, pt.astype(jnp.int32), off,

@@ -754,67 +754,170 @@ def autotune_blocks(s, d, dtype=jnp.bfloat16, batch=1, heads=1):
 # serving engine's paged KV cache (serving/paged_kv.py)
 # ------------------------------------------------------------------
 
-def _paged_decode_kernel(pt_ref, off_ref, q_ref, k_ref, v_ref, *rest,
-                         scale, page_size, n_rep, quant):
-    """One (batch, page) step of a single-token decode, all kv heads at
-    once.
+_PAGED_STEP_BYTES = 512 * 1024
 
-    The page axis is innermost: scratch (m, l, acc) carries the online
-    softmax across a row's pages.  Which physical page this step reads
-    was decided by the BlockSpec index map from the scalar-prefetched
-    page table — the kernel body only sees the already-gathered
-    [page_size, H_kv, D] block.  Pages past the row's offset are skipped
-    (their fetch is clamped to the last live page, so Mosaic dedupes the
-    DMA).  Scores and the weighted sum are broadcast-multiply + reduce
-    with the kv-head axis kept on the sublanes throughout ([.., H_kv, 1]
-    statistics against [.., H_kv, D] values): one query token per row
-    makes the step HBM-bound, and no relayout of the pool is needed.
-    Quantized pools (int8/fp8) arrive with per-page [page_size, 1, 1]
-    scale blocks fetched through the same index map."""
+
+def paged_decode_pages_per_step(page_size, h_kv, d, itemsize):
+    """How many pages one step of the paged decode kernel streams, or 0
+    where the kernel cannot host the pool and the XLA gather lane reads
+    it.  A rule on what the call can see, nothing else.
+
+    The kernel reads a page as a lane-dense matrix ``[rows, 128]``: a
+    row holds ``128 // d`` kv heads of one token side by side.  So ``d``
+    divides the 128 lanes, the kv heads fill whole rows, and a page is
+    whole sublane tiles (8 rows, in every pool type on the chip) — or
+    the answer is 0.  A step moves about ``_PAGED_STEP_BYTES`` of K and
+    as much of V, in whole pages, at least one, a power of two of them.
+    """
+    heads_a_row = 128 // d if d and 128 % d == 0 else 0
+    if not heads_a_row or h_kv % heads_a_row \
+            or (page_size * h_kv // heads_a_row) % 8:
+        return 0
+    g = max(1, _PAGED_STEP_BYTES // (page_size * h_kv * d * itemsize))
+    return 1 << (g.bit_length() - 1)
+
+
+def _mxu_f32(lhs, rhs, rhs_contracts):
+    """``lhs [m, k]`` times ``rhs`` (contracting its dim ``rhs_contracts``)
+    accumulated in float32 with no bit of an operand dropped, whatever
+    the process's default matmul precision.  Equal 16-bit types
+    multiply exactly in one MXU pass; a float32 ``rhs`` takes the
+    full-precision passes; a float32 ``lhs`` against a bfloat16 ``rhs``
+    is the sum of its three bfloat16 terms, the rows of ONE pass."""
+    def dot(a, precision):
+        return jax.lax.dot_general(
+            a, rhs, (((1,), (rhs_contracts,)), ((), ())),
+            precision=precision, preferred_element_type=jnp.float32)
+    if rhs.dtype == jnp.float32:
+        return dot(lhs.astype(jnp.float32), jax.lax.Precision.HIGHEST)
+    if lhs.dtype == rhs.dtype:
+        return dot(lhs, jax.lax.Precision.DEFAULT)
+    terms, rest = [], lhs.astype(jnp.float32)
+    for _ in range(3):
+        terms.append(rest.astype(rhs.dtype))
+        rest = rest - terms[-1].astype(jnp.float32)
+    out, m = dot(jnp.concatenate(terms, axis=0),
+                 jax.lax.Precision.DEFAULT), lhs.shape[0]
+    return out[:m] + out[m:2 * m] + out[2 * m:]
+
+
+def _paged_decode_kernel(pt_ref, off_ref, q_ref, k_hbm, v_hbm, *rest,
+                         scale, page_size, group, token_rows,
+                         q_heads_a_row, quant):
+    """One row of a single-token decode: the row's LIVE pages, ``group``
+    of them a step, every kv head at once.
+
+    The pools stay in HBM, a page a lane-dense matrix ``[page_size *
+    token_rows, 128]``.  A step's pages are copied into one of two VMEM
+    buffers by the kernel's own DMAs, one a page, addressed through the
+    scalar-prefetched page table; while a step computes, the next one's
+    copies are in flight — the row's next group or, after its last, the
+    first group of the next row.  The loop runs ``ceil(live / group)``
+    times with ``live = offset // page_size + 1``: a page past the row's
+    offset costs neither a step nor a copy, an empty slot (offset 0)
+    streams one page.
+
+    A step's keys are one matrix ``[c, 128]``, ``c = group * page_size *
+    token_rows`` rows ordered (token, row of the token), so the whole
+    query group rides the MXU: scores ``[h, c] = q [h, 128] · kᵀ`` for
+    every query head against every row, of which a mask keeps the row
+    that holds the head's own kv head (row ``h // q_heads_a_row`` of the
+    token; the caller put the head's query on that kv head's lanes,
+    zeros beside it) and the positions up to the offset.  Probabilities
+    of the rest are exactly 0, so ``p [h, c] · v [c, 128]`` holds the
+    row's output on the same lanes.  Scores and the online softmax's
+    statistics are float32, probabilities take the pool's type, the sum
+    is float32 (``_mxu_f32``).  Quantized pools: a token's scale
+    multiplies its columns of the scores (K) and of the probabilities
+    (V); ``ks_ref`` / ``vs_ref`` hold the row's scales already laid out
+    a column each."""
     from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
 
     if quant:
-        ks_ref, vs_ref, o_ref, m_scr, l_scr, acc_scr = rest
+        ks_ref, vs_ref, o_ref, kbuf, vbuf, sem, slot_ref = rest
     else:
-        o_ref, m_scr, l_scr, acc_scr = rest
+        o_ref, kbuf, vbuf, sem, slot_ref = rest
     b = pl.program_id(0)
-    j = pl.program_id(1)
-    num_pages = pl.num_programs(1)
+    rows = pl.num_programs(0)
+    h, lanes = q_ref.shape
+    tokens = group * page_size
+    page_rows = page_size * token_rows
+    cols = group * page_rows
 
-    @pl.when(j == 0)
-    def _init():
-        m_scr[:] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[:] = jnp.zeros_like(l_scr)
-        acc_scr[:] = jnp.zeros_like(acc_scr)
+    def live_pages(row):
+        return off_ref[row] // page_size + 1
+
+    def wide(x):
+        """int8 / fp8 values as bfloat16, which holds each of them."""
+        return x.astype(jnp.bfloat16) if x.dtype.itemsize == 1 else x
+
+    def copies(row, g, slot, act):
+        """``act`` on the page copies of group ``g`` of ``row``."""
+        first = g * group
+
+        def one(j, carry):
+            page = pt_ref[row, first + j]
+            at = pl.ds(pl.multiple_of(j * page_rows, page_rows), page_rows)
+            act(pltpu.make_async_copy(k_hbm.at[page], kbuf.at[slot, at],
+                                      sem.at[0, slot]))
+            act(pltpu.make_async_copy(v_hbm.at[page], vbuf.at[slot, at],
+                                      sem.at[1, slot]))
+            return carry
+        jax.lax.fori_loop(0, jnp.minimum(live_pages(row) - first, group),
+                          one, 0)
+
+    @pl.when(b == 0)
+    def _first_row():
+        # whatever a buffer holds past a step's live pages meets a
+        # probability of exactly 0: it has to be finite
+        kbuf[...] = jnp.zeros_like(kbuf)
+        vbuf[...] = jnp.zeros_like(vbuf)
+        slot_ref[0] = 0
+        copies(0, 0, 0, lambda c: c.start())
 
     off = off_ref[b]
-    live = j * page_size <= off
+    n_groups = (live_pages(b) + group - 1) // group
+    slot0 = slot_ref[0]
+    q = q_ref[...]
+    col = jax.lax.broadcasted_iota(jnp.int32, (h, cols), 1)
+    own = (col % token_rows) == jax.lax.broadcasted_iota(
+        jnp.int32, (h, cols), 0) // q_heads_a_row
+    tok = col // token_rows
 
-    @pl.when(live)
-    def _compute():
-        kf = k_ref[:].astype(jnp.float32)       # [page_size, h_kv, d]
-        vf = v_ref[:].astype(jnp.float32)
+    def step(g, carry):
+        m_prev, l_prev, acc = carry
+        slot = (slot0 + g) % 2
+        last = g == n_groups - 1
+        nxt_row = jnp.where(last, b + 1, b)
+
+        @pl.when(nxt_row < rows)
+        def _prefetch():
+            copies(jnp.minimum(nxt_row, rows - 1),
+                   jnp.where(last, 0, g + 1), 1 - slot,
+                   lambda c: c.start())
+
+        copies(b, g, slot, lambda c: c.wait())
+        s = _mxu_f32(q, wide(kbuf[slot]), 1) * scale
         if quant:
-            kf = kf * ks_ref[:]                 # [page_size, 1, 1] scales
-            vf = vf * vs_ref[:]
-        k_pos = j * page_size + jax.lax.broadcasted_iota(
-            jnp.int32, (page_size, kf.shape[1], 1), 0)
-        for r in range(n_rep):                  # q heads sharing a kv head
-            qf = q_ref[r].astype(jnp.float32)   # [h_kv, d]
-            s = jnp.sum(kf * qf[None], axis=-1, keepdims=True) * scale
-            s = jnp.where(k_pos <= off, s, NEG_INF)
-            m_prev = m_scr[r]                   # [h_kv, 1]
-            m_new = jnp.maximum(m_prev, jnp.max(s, axis=0))
-            p = jnp.exp(s - m_new[None])        # [page_size, h_kv, 1]
-            alpha = jnp.exp(m_prev - m_new)
-            m_scr[r] = m_new
-            l_scr[r] = alpha * l_scr[r] + jnp.sum(p, axis=0)
-            acc_scr[r] = alpha * acc_scr[r] + jnp.sum(p * vf, axis=0)
+            at = pl.ds(pl.multiple_of(g * cols, cols), cols)
+            s = s * ks_ref[:, at]
+        s = jnp.where(own & (g * tokens + tok <= off), s, NEG_INF)
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        alpha = jnp.exp(m_prev - m_new)
+        l_new = alpha * l_prev + jnp.sum(p, axis=-1, keepdims=True)
+        v = wide(vbuf[slot])
+        p = p * vs_ref[:, at] if quant else p.astype(v.dtype)
+        return m_new, l_new, alpha * acc + _mxu_f32(p, v, 0)
 
-    @pl.when(j == num_pages - 1)
-    def _finalize():
-        l = jnp.maximum(l_scr[:], 1e-30)  # noqa: E741
-        o_ref[:] = (acc_scr[:] / l).astype(o_ref.dtype)
+    _, l, acc = jax.lax.fori_loop(
+        0, n_groups, step,
+        (jnp.full((h, 1), NEG_INF, jnp.float32),
+         jnp.zeros((h, 1), jnp.float32),
+         jnp.zeros((h, lanes), jnp.float32)))
+    slot_ref[0] = (slot0 + n_groups) % 2
+    o_ref[...] = (acc / l).astype(o_ref.dtype)
 
 
 def paged_decode_attention(q, k_pool, v_pool, page_table, offsets,
@@ -826,69 +929,110 @@ def paged_decode_attention(q, k_pool, v_pool, page_table, offsets,
     physical page map; offsets: int32 [B] — row b attends positions
     <= offsets[b] (its freshly written token included).  With
     ``k_scale``/``v_scale`` ([P, page_size] float32) the pools hold
-    int8/fp8 values; each page's scale block streams in through the
-    same scalar-prefetched index map and the dequant multiply happens
-    on the block in VMEM — K/V cross HBM at the quantized width.
+    int8/fp8 values, cross HBM at that width, and are dequantized by
+    their scales inside the kernel.
 
-    The page table and offsets ride ``PrefetchScalarGridSpec`` scalar
-    prefetch, so the K/V BlockSpec index maps dereference them to pick
-    each grid step's physical page — the paged gather never
-    materializes a contiguous [B, N*page_size] cache copy the way the
-    XLA gather read does.  A block is one whole page, (page_size, H_kv,
-    D): its trailing two dims equal the pool's, which is the only shape
-    Mosaic accepts for head_dim 64 (a per-head block would squeeze the
-    second-to-last dim).  GQA: Q is regrouped [B, n_rep, H_kv, D] and
-    the kernel loops over the n_rep query heads each kv head serves.
+    The grid is over rows; the pools are never blocked (``pl.ANY``: they
+    stay in HBM) and never gathered into a contiguous copy.  Each row
+    streams its live pages alone, ``paged_decode_pages_per_step`` of
+    them a step, by double-buffered DMAs through the scalar-prefetched
+    page table (``_paged_decode_kernel``): the work follows the
+    contexts, not the slots' capacity.  The kernel sees a pool as
+    ``[P, rows, 128]`` (the same bytes where D is 128; one relayout by
+    XLA where D is less, as a pool of such a head size needed before)
+    and a query head on the lanes of its kv head.  The caller asks
+    ``paged_decode_pages_per_step`` first; a pool it answers 0 for is
+    read by the XLA gather lane.
     """
+    d = q.shape[-1]
+    psz, h_kv = k_pool.shape[1:3]
+    group = paged_decode_pages_per_step(psz, h_kv, d,
+                                        k_pool.dtype.itemsize)
+    while group > page_table.shape[1]:
+        group //= 2
+    if not group:
+        raise ValueError(
+            f"paged decode kernel: pages of {h_kv} kv heads of {d} are "
+            "not whole 128-lane rows and 8-row tiles; the caller reads "
+            "such a pool by the XLA gather lane")
+    return _paged_decode_call(
+        q, k_pool, v_pool, page_table, offsets, k_scale, v_scale,
+        scale=float(scale) if scale is not None else 1.0 / math.sqrt(d),
+        group=group, interpret=_interpret())
+
+
+@functools.partial(jax.jit, static_argnames=("scale", "group", "interpret"))
+def _paged_decode_call(q, k_pool, v_pool, page_table, offsets, k_scale,
+                       v_scale, *, scale, group, interpret):
+    """``paged_decode_attention`` at a fixed step size.  A program of its
+    own inside the caller's: the layers of a model trace and lower ONE
+    kernel between them (16 of them cost Mistral's tick 4 s of set-up
+    when each call site traced its own)."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     b, h, d = q.shape
-    psz, h_kv = k_pool.shape[1], k_pool.shape[2]
+    n_pool, psz, h_kv, _ = k_pool.shape
     n_pages = page_table.shape[1]
-    n_rep = h // h_kv
-    sc = scale if scale is not None else 1.0 / math.sqrt(d)
-    qg = q.reshape(b, h_kv, n_rep, d).transpose(0, 2, 1, 3)
     quant = k_scale is not None
+    page_table = page_table.astype(jnp.int32)
+    n_rep, heads_a_row = h // h_kv, 128 // d
+    token_rows = h_kv // heads_a_row
 
-    def q_index(bi, j, pt, off):
-        return (bi, 0, 0, 0)
+    # a query head on the lanes of its kv head, zeros beside it; heads
+    # padded to whole tiles of the matmul's left side
+    lane_seg = (jnp.arange(h) // n_rep) % heads_a_row
+    seg = jax.nn.one_hot(lane_seg, heads_a_row, dtype=q.dtype)
+    h_pad = -(-h // 16) * 16
+    qk = (q[:, :, None, :] * seg[None, :, :, None]).reshape(b, h, 128)
+    qk = jnp.pad(qk, ((0, 0), (0, h_pad - h), (0, 0)))
 
-    def page_index(bi, j, pt, off):
-        # dead pages (past the row's offset) clamp to the last live
-        # page so the skipped steps re-fetch a block already resident
-        j_live = jnp.minimum(j, off[bi] // psz)
-        return (pt[bi, j_live], 0, 0, 0)
-
-    q_spec = pl.BlockSpec((None, n_rep, h_kv, d), q_index)
-    kv_spec = pl.BlockSpec((None, psz, h_kv, d), page_index)
-    in_specs = [q_spec, kv_spec, kv_spec]
-    operands = [qg, k_pool, v_pool]
+    row_spec = pl.BlockSpec((None, h_pad, 128),
+                            lambda bi, pt, off: (bi, 0, 0))
+    hbm_spec = pl.BlockSpec(memory_space=pl.ANY)
+    in_specs = [row_spec, hbm_spec, hbm_spec]
+    page_rows = psz * token_rows
+    operands = [qk, k_pool.reshape(n_pool, page_rows, 128),
+                v_pool.reshape(n_pool, page_rows, 128)]
     if quant:
-        sc_spec = pl.BlockSpec((None, psz, 1, 1), page_index)
+        # a row's scales, a column of the kernel's score matrix each
+        # ((token, row of the token) order), padded to whole steps: a
+        # gather of 4 bytes a token beside the pools' h_kv * d
+        width = -(-n_pages // group) * group * page_rows
+
+        def columns(scales):
+            c = jnp.repeat(scales[page_table].reshape(b, -1), token_rows,
+                           axis=1)
+            return jnp.pad(c, ((0, 0), (0, width - c.shape[1])))[:, None]
+        sc_spec = pl.BlockSpec((None, 1, width),
+                               lambda bi, pt, off: (bi, 0, 0))
         in_specs += [sc_spec, sc_spec]
-        operands += [k_scale.reshape(k_scale.shape[0], psz, 1, 1),
-                     v_scale.reshape(v_scale.shape[0], psz, 1, 1)]
-    kernel = functools.partial(_paged_decode_kernel, scale=sc,
-                               page_size=psz, n_rep=n_rep, quant=quant)
+        operands += [columns(k_scale), columns(v_scale)]
+    kernel = functools.partial(
+        _paged_decode_kernel, scale=scale, page_size=psz, group=group,
+        token_rows=token_rows, q_heads_a_row=n_rep * heads_a_row,
+        quant=quant)
+    buf = pltpu.VMEM((2, group * page_rows, 128), k_pool.dtype)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(b, n_pages),
+        grid=(b,),
         in_specs=in_specs,
-        out_specs=q_spec,
-        scratch_shapes=[pltpu.VMEM((n_rep, h_kv, 1), jnp.float32),
-                        pltpu.VMEM((n_rep, h_kv, 1), jnp.float32),
-                        pltpu.VMEM((n_rep, h_kv, d), jnp.float32)])
-    out_dtype = q.dtype if not quant else jnp.float32
+        out_specs=row_spec,
+        scratch_shapes=[buf, buf, pltpu.SemaphoreType.DMA((2, 2)),
+                        pltpu.SMEM((1,), jnp.int32)])
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, n_rep, h_kv, d), out_dtype),
-        interpret=_interpret(),
+        out_shape=jax.ShapeDtypeStruct((b, h_pad, 128), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=pltpu.InterpretParams() if interpret else False,
         name="paged_decode",
-    )(page_table.astype(jnp.int32), offsets.astype(jnp.int32),
-      *operands)
-    return out.transpose(0, 2, 1, 3).reshape(b, h, d).astype(q.dtype)
+    )(page_table, offsets.astype(jnp.int32), *operands)
+    # a head's output lies on its kv head's lanes
+    out = out[:, :h].reshape(b, h, heads_a_row, d)
+    return jnp.take_along_axis(
+        out, lane_seg[None, :, None, None], axis=2)[:, :, 0]
 
 
 def _supports_pallas(q, k, v, attn_mask, segment_ids):

@@ -348,6 +348,8 @@ class Engine:
                 dtype=self.scfg.cache_dtype,
                 layer_states=self._layer_states)
             stats.set_value("state.bytes", cache.state_bytes)
+            stats.set_value("kv.pages_spanned",
+                            cache.num_slots * cache.pages_per_slot)
             self.prefix_tree = PrefixTree(self._page_size) \
                 if self.scfg.enable_prefix_cache else None
             # one compiled prefill program: every chunk is this wide

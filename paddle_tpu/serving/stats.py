@@ -145,6 +145,9 @@ def declare_tick_stats():
                         "state (ms)")
     _registry.gauge(PREFIX + "state.bytes",
                     "bytes of the per-slot recurrent state arrays")
+    _registry.gauge(PREFIX + "kv.pages_spanned",
+                    "page-table entries of the decode batch: slots x "
+                    "pages a slot")
 
 
 def declare_migration_stats():
@@ -330,6 +333,14 @@ def serving_stats():
     ``max_active_slots`` — the high-water mark of concurrent decoding
     sequences (the paged pool admits more of them than
     ``pool_bytes / max_seq_len`` stripes would).
+    ``kv_pages_streamed_per_tick`` (pages held, a mean over the
+    compiled ticks: what the paged decode kernel reads) stands beside
+    ``kv_pages_spanned_per_tick`` (slots x pages a slot: what a walk
+    over every table entry would pay), and
+    ``paged_decode_kernel_traces`` / ``paged_decode_xla_lane_traces``
+    count the traces of a single-token paged read that chose the
+    Pallas kernel or the XLA gather lane (process-wide, not reset at
+    engine start: a program is traced once and run many times).
 
     Recurrent-state quantities (a model whose layers keep a per-slot
     state beside the pages, zero otherwise): ``state_bytes`` (the state
@@ -429,6 +440,14 @@ def serving_stats():
         "state_rows_live_share": (g("state.row_ticks_live")
                                   / g("state.row_ticks_total"))
         if g("state.row_ticks_total") else None,
+        "kv_pages_streamed_per_tick": (g("kv.page_ticks_in_use")
+                                       / g("tick.compiled_hits"))
+        if g("tick.compiled_hits") else None,
+        "kv_pages_spanned_per_tick": g("kv.pages_spanned"),
+        "paged_decode_kernel_traces": s.get(
+            "pallas.paged_decode.kernel", 0),
+        "paged_decode_xla_lane_traces": s.get(
+            "pallas.paged_decode.xla_lane", 0),
         "kv_pages_in_use": g("kv_pages_in_use"),
         "kv_pages_free": g("kv_pages_free"),
         "kv_pages_peak": g("kv_pages_peak"),

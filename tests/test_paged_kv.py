@@ -1,8 +1,6 @@
 """Paged KV cache serving (serving/paged_kv.py): page-pool bookkeeping,
 pool-exhaustion backpressure, prefix-tree refcounts/eviction, chunked
 prefill equivalence, and the paged attention op/kernel."""
-import os
-
 import numpy as np
 import pytest
 
@@ -343,100 +341,164 @@ def test_paged_op_bitwise_matches_dense_op():
         np.testing.assert_array_equal(_np(vp)[pg, offs[b] % psz], v[b, 0])
 
 
-def test_paged_pallas_kernel_matches_gather_path():
-    """The Pallas paged-decode kernel (scalar-prefetched page table)
-    agrees with the XLA gather path in interpreter mode."""
-    prev = os.environ.get("PADDLE_TPU_PALLAS_INTERPRET")
-    os.environ["PADDLE_TPU_PALLAS_INTERPRET"] = "1"
-    try:
-        import jax.numpy as jnp
-        from paddle_tpu.pallas.flash_attention import \
-            paged_decode_attention
-        rng = np.random.default_rng(0)
-        B, H, Hkv, D, psz, N = 3, 8, 2, 16, 8, 4
-        P = 1 + B * N
-        k_pool = rng.normal(size=(P, psz, Hkv, D)).astype(np.float32)
-        v_pool = rng.normal(size=(P, psz, Hkv, D)).astype(np.float32)
-        q = rng.normal(size=(B, H, D)).astype(np.float32)
-        pt = rng.permutation(np.arange(1, P)).reshape(B, N) \
-            .astype(np.int32)
-        off = np.array([5, 17, 30], np.int32)
-        out = np.asarray(paged_decode_attention(
-            jnp.asarray(q), jnp.asarray(k_pool), jnp.asarray(v_pool),
-            jnp.asarray(pt), jnp.asarray(off)))
-        kf = k_pool[pt].reshape(B, N * psz, Hkv, D)
-        vf = v_pool[pt].reshape(B, N * psz, Hkv, D)
-        rep = H // Hkv
-        qg = q.reshape(B, Hkv, rep, D)
-        ref = np.zeros((B, Hkv, rep, D), np.float32)
-        for b in range(B):
-            for h in range(Hkv):
-                for r in range(rep):
-                    s = (kf[b, :, h] @ qg[b, h, r]) / np.sqrt(D)
-                    s[np.arange(N * psz) > off[b]] = -np.inf
-                    p = np.exp(s - s.max())
-                    p /= p.sum()
-                    ref[b, h, r] = p @ vf[b, :, h]
-        np.testing.assert_allclose(out, ref.reshape(B, H, D),
-                                   rtol=1e-5, atol=1e-5)
-    finally:
-        if prev is None:
-            os.environ.pop("PADDLE_TPU_PALLAS_INTERPRET", None)
-        else:
-            os.environ["PADDLE_TPU_PALLAS_INTERPRET"] = prev
+def _gather_reference(q, kf, vf, off, scale):
+    """Attention of one query token a row over the gathered view
+    ``[B, S, Hkv, D]``, positions <= off, in float64."""
+    B, H, D = q.shape
+    Hkv = kf.shape[2]
+    rep = H // Hkv
+    ref = np.zeros((B, H, D))
+    for b in range(B):
+        for h in range(H):
+            s = (kf[b, :, h // rep].astype(np.float64)
+                 @ q[b, h].astype(np.float64)) * scale
+            s[np.arange(kf.shape[1]) > off[b]] = -np.inf
+            p = np.exp(s - s.max())
+            ref[b, h] = (p / p.sum()) @ vf[b, :, h // rep].astype(np.float64)
+    return ref
 
 
-def test_paged_pallas_kernel_int8_scales_match_dequant():
-    """The quantized-pool Pallas kernel (per-page scale blocks riding
-    the scalar-prefetch index map) agrees with an explicit
-    dequantize-then-attend reference in interpreter mode."""
-    prev = os.environ.get("PADDLE_TPU_PALLAS_INTERPRET")
-    os.environ["PADDLE_TPU_PALLAS_INTERPRET"] = "1"
-    try:
-        import jax.numpy as jnp
-        from paddle_tpu.pallas.flash_attention import \
-            paged_decode_attention
-        rng = np.random.default_rng(1)
-        B, H, Hkv, D, psz, N = 2, 4, 2, 16, 8, 3
-        P = 1 + B * N
-        k_pool = rng.integers(-127, 128, (P, psz, Hkv, D)) \
-            .astype(np.int8)
-        v_pool = rng.integers(-127, 128, (P, psz, Hkv, D)) \
-            .astype(np.int8)
+#  B  H  Hkv D  page pages offsets   pool  empty rows  scale  pages a step
+_PAGED_KERNEL_CASES = {
+    "f32-gqa": (3, 16, 8, 16, 8, 6, [5, 17, 30], "float32", (), None, 0),
+    "int8-scales": (2, 16, 8, 16, 8, 3, [6, 19], "int8", (), None, 0),
+    "offset-0": (2, 16, 8, 16, 8, 6, [0, 9], "float32", (), None, 2),
+    "fills-the-slot": (2, 16, 8, 16, 8, 6, [47, 3], "float32", (), None, 2),
+    "empty-row-beside-live": (4, 16, 8, 16, 8, 6, [21, 0, 40, 0],
+                              "float32", (1, 3), None, 2),
+    "ends-mid-group": (2, 16, 8, 16, 8, 6, [20, 36], "float32", (), None,
+                       2),
+    "ends-on-a-groups-last-page": (2, 16, 8, 16, 8, 6, [31, 15], "float32",
+                                   (), None, 2),
+    "n_rep-1": (2, 8, 8, 16, 8, 6, [11, 44], "float32", (), None, 2),
+    "d64-explicit-scale": (2, 8, 2, 64, 8, 6, [13, 38], "float32", (),
+                           1.0 / 64, 2),
+    "bf16-pool": (2, 32, 8, 128, 16, 4, [37, 63], "bfloat16", (), None, 2),
+    "int8-two-groups": (2, 16, 8, 16, 8, 6, [6, 41], "int8", (), None, 2),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_PAGED_KERNEL_CASES))
+def test_paged_pallas_kernel_matches_gather_path(case, monkeypatch):
+    """The Pallas paged-decode kernel — its own page DMAs through the
+    scalar-prefetched table, run by the TPU interpreter
+    (``pltpu.InterpretParams``: scratch starts as NaN) — agrees with a
+    gather of the row's pages and a plain softmax."""
+    import jax.numpy as jnp
+    from paddle_tpu.pallas import flash_attention as fa
+    B, H, Hkv, D, psz, N, off, pool, empty, scale, group = \
+        _PAGED_KERNEL_CASES[case]
+    monkeypatch.setenv("PADDLE_TPU_PALLAS_INTERPRET", "1")
+    if group:       # several steps a row at these small shapes
+        item = {"float32": 4, "bfloat16": 2, "int8": 1}[pool]
+        monkeypatch.setattr(fa, "_PAGED_STEP_BYTES",
+                            group * psz * Hkv * D * item)
+        assert fa.paged_decode_pages_per_step(psz, Hkv, D, item) == group
+    rng = np.random.default_rng(sorted(_PAGED_KERNEL_CASES).index(case))
+    P = 1 + B * N
+    shape = (P, psz, Hkv, D)
+    pt = rng.permutation(np.arange(1, P)).reshape(B, N).astype(np.int32)
+    off = np.array(off, np.int32)
+    for b in empty:                      # a free slot: scratch page 0
+        pt[b], off[b] = 0, 0
+    q = rng.normal(size=(B, H, D)).astype(np.float32)
+    scales = {}
+    if pool == "int8":
+        k_pool = rng.integers(-127, 128, shape).astype(np.int8)
+        v_pool = rng.integers(-127, 128, shape).astype(np.int8)
         k_scale = rng.uniform(0.005, 0.03, (P, psz)).astype(np.float32)
         v_scale = rng.uniform(0.005, 0.03, (P, psz)).astype(np.float32)
-        q = rng.normal(size=(B, H, D)).astype(np.float32)
-        pt = rng.permutation(np.arange(1, P)).reshape(B, N) \
-            .astype(np.int32)
-        off = np.array([6, 19], np.int32)
-        out = np.asarray(paged_decode_attention(
-            jnp.asarray(q), jnp.asarray(k_pool), jnp.asarray(v_pool),
-            jnp.asarray(pt), jnp.asarray(off),
-            k_scale=jnp.asarray(k_scale), v_scale=jnp.asarray(v_scale)))
-        kf = (k_pool.astype(np.float32)
-              * k_scale[:, :, None, None])[pt].reshape(B, N * psz,
-                                                       Hkv, D)
-        vf = (v_pool.astype(np.float32)
-              * v_scale[:, :, None, None])[pt].reshape(B, N * psz,
-                                                       Hkv, D)
-        rep = H // Hkv
-        qg = q.reshape(B, Hkv, rep, D)
-        ref = np.zeros((B, Hkv, rep, D), np.float32)
-        for b in range(B):
-            for h in range(Hkv):
-                for r in range(rep):
-                    s = (kf[b, :, h] @ qg[b, h, r]) / np.sqrt(D)
-                    s[np.arange(N * psz) > off[b]] = -np.inf
-                    p = np.exp(s - s.max())
-                    p /= p.sum()
-                    ref[b, h, r] = p @ vf[b, :, h]
-        np.testing.assert_allclose(out, ref.reshape(B, H, D),
-                                   rtol=1e-5, atol=1e-5)
-    finally:
-        if prev is None:
-            os.environ.pop("PADDLE_TPU_PALLAS_INTERPRET", None)
-        else:
-            os.environ["PADDLE_TPU_PALLAS_INTERPRET"] = prev
+        scales = dict(k_scale=jnp.asarray(k_scale),
+                      v_scale=jnp.asarray(v_scale))
+        kd = k_pool.astype(np.float32) * k_scale[:, :, None, None]
+        vd = v_pool.astype(np.float32) * v_scale[:, :, None, None]
+        kj, vj, qj, tol = jnp.asarray(k_pool), jnp.asarray(v_pool), \
+            jnp.asarray(q), 1e-5
+    else:
+        dt = jnp.dtype(pool)
+        kj = jnp.asarray(rng.normal(size=shape), dt)
+        vj = jnp.asarray(rng.normal(size=shape), dt)
+        qj = jnp.asarray(q, dt)
+        kd, vd = np.asarray(kj.astype(jnp.float32)), \
+            np.asarray(vj.astype(jnp.float32))
+        q = np.asarray(qj.astype(jnp.float32))
+        # bfloat16: the probabilities take the pool's type for P.V
+        tol = 1e-5 if pool == "float32" else 2e-2
+    out = fa.paged_decode_attention(
+        qj, kj, vj, jnp.asarray(pt), jnp.asarray(off), scale=scale,
+        **scales)
+    assert out.shape == (B, H, D) and out.dtype == qj.dtype
+    ref = _gather_reference(
+        q, kd[pt].reshape(B, N * psz, Hkv, D),
+        vd[pt].reshape(B, N * psz, Hkv, D), off,
+        scale if scale is not None else 1.0 / np.sqrt(D))
+    np.testing.assert_allclose(np.asarray(out.astype(jnp.float32)), ref,
+                               rtol=tol, atol=tol)
+
+
+@pytest.mark.parametrize("page_size,h_kv,d,itemsize,pages", [
+    (16, 8, 128, 2, 16),       # mistral-7b: 32 KB pages, 512 KB a step
+    (16, 8, 64, 2, 32),        # granite-4.0-h-micro: two heads a row
+    (16, 12, 64, 4, 8),        # gpt-2 124m, float32 pages
+    (32, 12, 64, 1, 16),       # gpt-2 124m, int8 pages
+    (16, 16, 128, 2, 8),       # gpt-3 1.3b
+    (8, 2, 16, 4, 0),          # 2 kv heads of 16 do not fill a row
+    (16, 8, 256, 2, 0),        # a head wider than the lanes
+    (4, 8, 128, 2, 64),        # small pages: more of them a step
+    (2, 2, 64, 4, 0),          # a page of 2 rows is no whole tile
+])
+def test_paged_decode_pages_per_step_is_a_rule_on_shapes(
+        page_size, h_kv, d, itemsize, pages):
+    from paddle_tpu.pallas.flash_attention import \
+        paged_decode_pages_per_step
+    assert paged_decode_pages_per_step(page_size, h_kv, d,
+                                       itemsize) == pages
+
+
+@pytest.mark.parametrize("h_kv,lane", [(8, "kernel"), (2, "xla_lane")])
+def test_paged_decode_lane_is_counted_where_it_is_traced(
+        h_kv, lane, monkeypatch):
+    """A single-token paged read counts, once a trace, which lane the
+    shape rule gave it; a prefill chunk (the XLA lane by definition)
+    counts nothing; ``serving_stats()`` shows both counters."""
+    import jax
+    from paddle_tpu.core.tensor import Tensor
+    from paddle_tpu.incubate.nn import functional as IF
+    from paddle_tpu.utils import monitor
+    monkeypatch.setenv("PADDLE_TPU_PALLAS_INTERPRET", "1")
+    B, D, psz, N = 2, 16, 8, 3
+    P = 1 + B * N
+    rng = np.random.default_rng(3)
+    pool = rng.normal(size=(P, psz, h_kv, D)).astype(np.float32)
+    table = np.arange(1, P).reshape(B, N).astype(np.int32)
+    offs = np.array([4, 13], np.int32)
+
+    def read(s_new):
+        x = rng.normal(size=(B, s_new, h_kv, D)).astype(np.float32)
+
+        def f(x, kp, vp, pt, off):
+            out, _, _ = IF.paged_masked_multihead_attention(
+                Tensor(x), Tensor(x), Tensor(x), Tensor(kp), Tensor(vp),
+                Tensor(pt), Tensor(off), psz)
+            return out._data_
+        return jax.jit(f)(x, pool, pool, table, offs)
+
+    def counts():
+        s = monitor.all_stats()
+        return {k: s.get("pallas.paged_decode." + k, 0)
+                for k in ("kernel", "xla_lane")}
+
+    before = counts()
+    read(4)
+    assert counts() == before
+    out = read(1)
+    after = counts()
+    other = "xla_lane" if lane == "kernel" else "kernel"
+    assert after[lane] == before[lane] + 1 and after[other] == before[other]
+    assert np.isfinite(np.asarray(out)).all()
+    stats = serving_stats()
+    assert stats["paged_decode_kernel_traces"] == after["kernel"]
+    assert stats["paged_decode_xla_lane_traces"] == after["xla_lane"]
 
 
 def test_paged_metrics_reach_prometheus(model):

@@ -51,14 +51,15 @@ def _as_on_the_chip(monkeypatch):
     monkeypatch.setattr(fa, "_on_tpu", lambda: True)
 
 
-def _compile(fn, shapes, sharding):
-    """Lower + compile ``fn`` for the TPU; returns the StableHLO text."""
+def _compile(fn, shapes, sharding, compiled=False):
+    """Lower + compile ``fn`` for the TPU; returns the StableHLO text,
+    or with ``compiled`` the compiled program's."""
     args = [None if s is None else
             jax.ShapeDtypeStruct(s[0], s[1], sharding=sharding)
             for s in shapes]
     lowered = jax.jit(fn).lower(*args)
-    lowered.compile()
-    return lowered.as_text()
+    program = lowered.compile()
+    return program.as_text() if compiled else lowered.as_text()
 
 
 def _n_kernels(text):
@@ -179,17 +180,30 @@ _PAGED = {
     "gpt2-int8": (4, 12, 12, 64, 32, 32, I8),
     "gpt3-1.3b-bf16": (4, 16, 16, 128, 16, 128, BF16),
     "llama-gqa-int8": (4, 32, 8, 128, 32, 64, I8),
+    "llama-gqa-fp8": (4, 32, 8, 128, 32, 64, jnp.float8_e4m3fn),
+    # the benchmark's two serving cells, as their ticks call the kernel
+    "mistral-7b-d16": (32, 32, 8, 128, 16, 72, BF16),
+    "granite-4.0-h-micro": (64, 32, 8, 64, 16, 72, BF16),
 }
 
 
 @pytest.mark.parametrize("case", sorted(_PAGED))
 def test_paged_decode_compiles(v5e, case):
+    """One kernel, and in the compiled program what the benchmark's
+    patterns of it need (``chipbench/kernels/paged_decode_attention*``):
+    a ``tpu_custom_call`` named ``paged_decode`` with ONE array result,
+    whose first two operands are the int32 page table ``[slots, pages]``
+    and the int32 offsets ``[slots]``."""
+    import re
     b, h, h_kv, d, psz, n, pool_dt = _PAGED[case]
     pool = (1 + b * n, psz, h_kv, d)
+    quant = jnp.dtype(pool_dt).itemsize == 1
+    assert fa.paged_decode_pages_per_step(
+        psz, h_kv, d, jnp.dtype(pool_dt).itemsize) > 0
     shapes = [((b, h, d), F32 if pool_dt == F32 else BF16),
               (pool, pool_dt), (pool, pool_dt),
               ((b, n), jnp.int32), ((b,), jnp.int32)]
-    if pool_dt == I8:
+    if quant:
         shapes += [(pool[:2], F32), (pool[:2], F32)]
 
         def f(q, k, v, pt, off, ks, vs):
@@ -197,19 +211,30 @@ def test_paged_decode_compiles(v5e, case):
                                              k_scale=ks, v_scale=vs)
     else:
         f = fa.paged_decode_attention
-    text = _compile(f, shapes, SingleDeviceSharding(v5e[0]))
-    assert _n_kernels(text) == 1
+    text = _compile(f, shapes, SingleDeviceSharding(v5e[0]), compiled=True)
+    calls = [line.strip() for line in text.splitlines()
+             if "tpu_custom_call" in line]
+    assert len(calls) == 1, calls
+    # as chipbench/kernels/paged_decode_attention_hybrid/*.json
+    assert re.match(r"^(ROOT )?%paged_decode[\w.]* = ", calls[0]), calls[0]
+    # one array result, not a tuple
+    assert re.match(r"^(ROOT )?%[\w.-]+ = \w+\[[\d,]+\]\S* custom-call\(",
+                    calls[0]), calls[0]
+    # as chipbench/kernels/paged_decode_attention/pallas_paged.json: the
+    # first two operands, in the constraint list the call carries
+    assert re.search(
+        r"operand_layout_constraints=\{s32\[%d,%d\]\S*, s32\[%d\]" % (b, n, b),
+        calls[0]), calls[0]
 
 
-def test_paged_decode_through_the_op_compiles(v5e):
-    """The serving decode step reaches the kernel through the framework
-    op (page write + kernel read) at the GPT-2 124M tick's shapes."""
+def _kernels_through_the_op(device, b, h, h_kv, d, psz, n, pool_dt):
+    """Kernels in the framework op's single-token step (page write +
+    read) over a pool of these shapes."""
     from paddle_tpu.core.state import no_grad
     from paddle_tpu.core.tensor import Tensor
     from paddle_tpu.incubate.nn import functional as IF
 
-    b, h, d, psz, n = 4, 12, 64, 16, 64
-    pool = (1 + b * n, psz, h, d)
+    pool = (1 + b * n, psz, h_kv, d)
 
     def f(q, k, v, kp, vp, pt, off):
         with no_grad():
@@ -218,10 +243,32 @@ def test_paged_decode_through_the_op_compiles(v5e):
                 Tensor(pt), Tensor(off), psz)
         return out._data_, kp2._data_, vp2._data_
 
-    text = _compile(f, [((b, 1, h, d), BF16)] * 3
-                    + [(pool, F32), (pool, F32), ((b, n), jnp.int32),
-                       ((b,), jnp.int32)], SingleDeviceSharding(v5e[0]))
-    assert _n_kernels(text) == 1
+    return _n_kernels(_compile(
+        f, [((b, 1, h, d), BF16), ((b, 1, h_kv, d), BF16),
+            ((b, 1, h_kv, d), BF16), (pool, pool_dt), (pool, pool_dt),
+            ((b, n), jnp.int32), ((b,), jnp.int32)],
+        SingleDeviceSharding(device)))
+
+
+def test_paged_decode_unhostable_pool_is_deselected_by_the_rule(v5e):
+    """Pages the kernel cannot view as whole 128-lane rows (2 kv heads
+    of 16) go to the XLA gather lane by the shape rule: the op compiles
+    with no kernel in it, and the kernel itself refuses the pool."""
+    b, h, h_kv, d, psz, n = 4, 4, 2, 16, 8, 16
+    pool = (1 + b * n, psz, h_kv, d)
+    assert fa.paged_decode_pages_per_step(psz, h_kv, d, 2) == 0
+    assert _kernels_through_the_op(v5e[0], b, h, h_kv, d, psz, n, BF16) == 0
+    with pytest.raises(ValueError, match="XLA gather lane"):
+        fa.paged_decode_attention(
+            jnp.zeros((b, h, d), BF16), jnp.zeros(pool, BF16),
+            jnp.zeros(pool, BF16), jnp.zeros((b, n), jnp.int32),
+            jnp.zeros((b,), jnp.int32))
+
+
+def test_paged_decode_through_the_op_compiles(v5e):
+    """The serving decode step reaches the kernel through the framework
+    op (page write + kernel read) at the GPT-2 124M tick's shapes."""
+    assert _kernels_through_the_op(v5e[0], 4, 12, 12, 64, 16, 64, F32) == 1
 
 
 # ----------------------------------------------------------- ssm update
