@@ -4,7 +4,7 @@ One process drives the main path once through the entry points a user
 calls, at the full width and depth of GPT-2 124M (random weights from a
 seed), and checks what comes out by the repo's own means:
 
-1. train  — ``CompiledTrainStep`` as ``bench.py`` builds it: seq 1024,
+1. train  — ``CompiledTrainStep`` as a training script builds it: seq 1024,
    batch 8, bf16 O2, flash attention, AdamW; warm-up at batch 1, then
    compiled full-batch steps on a fixed batch.
 2. serve  — ``serving.Engine(model, ServingConfig()).start()`` with the
@@ -72,7 +72,7 @@ class Sizes:
         self.seq = self.model["max_seq_len"]
         self.batch = 4 if dry_run else 8
         self.steps = 6
-        self.amp = not dry_run          # bf16 O2, as bench.py on a TPU
+        self.amp = not dry_run          # bf16 O2 on a TPU
         self.prompt_lens = [8, 21, 33, 40, 64, 90, 100, 12] if dry_run \
             else [32, 57, 100, 128, 200, 333, 512, 64]
         self.new_tokens = [8, 12, 16, 9, 10, 16, 8, 12] if dry_run \
@@ -167,8 +167,8 @@ def train_model(model, cfg, sizes, warm_rows, dry_run, what):
     cstep = CompiledTrainStep(forward, opt, network=model,
                               eager_step=eager_step)
     t0 = time.perf_counter()
-    # warm-up as bench.py: the eager + discovery call and the first
-    # compiled call on a small batch, then the full batch re-traces
+    # warm-up: the eager + discovery call and the first compiled call
+    # on a small batch, then the full batch re-traces
     for _ in range(2):
         loss = cstep(xw, yw, update=True)
     jax.block_until_ready(loss._data_)

@@ -344,15 +344,17 @@ def _connect(to):
     def _dial():
         return Client((info.ip, info.port), authkey=_state["authkey"])
 
+    # EOFError: the peer accepted and died before the handshake ended
+    # (a replica killed while it was being dialled) — nothing was
+    # delivered, so it is a connect failure like the other two
+    dead = (ConnectionRefusedError, ConnectionResetError, EOFError)
     try:
         # decorrelated jitter: a fleet of dispatch threads mass-
         # reconnecting after a store blip spreads over the whole backoff
         # window instead of thundering-herding this replica in waves
-        return retry_call(_dial, tries=3,
-                          retry_on=(ConnectionRefusedError,
-                                    ConnectionResetError),
+        return retry_call(_dial, tries=3, retry_on=dead,
                           base=0.05, max_delay=0.5, decorrelated=True)
-    except (ConnectionRefusedError, ConnectionResetError) as e:
+    except dead as e:
         raise ConnectionError(
             f"rpc to worker {to!r} at {info.ip}:{info.port}: connect "
             f"failed after retries ({e})") from e

@@ -4,10 +4,9 @@ The single-shot entry points (`models.generation.generate`,
 `inference.Predictor.run`) decode one fixed batch to completion.  This
 package turns the compile-once decode step into a multi-tenant server:
 a paged KV cache with shared-prefix reuse and chunked prefill
-(`paged_kv`, the default) or fixed per-slot stripes (`kv_slots`), a
-background scheduler with Orca-style continuous batching (`engine`),
-admission control with bounded queueing and per-request deadlines
-(`api`), serving metrics through `utils.monitor` (`stats`), and —
+(`paged_kv`), a background scheduler with Orca-style continuous
+batching (`engine`), admission control with bounded queueing and
+per-request deadlines (`api`), serving metrics through `utils.monitor` (`stats`), and —
 scaling past one process — replicated engines behind a drain-aware,
 session-affine router that loses zero requests when a replica dies
 (`router`, `fleet`).  See docs/SERVING.md.
@@ -28,7 +27,6 @@ from .compiled_tick import (  # noqa: F401
 )
 from .engine import Engine  # noqa: F401
 from .fleet import ReplicaConfig, ReplicaServer, ServingFleet  # noqa: F401
-from .kv_slots import SlotKVCache  # noqa: F401
 from .paged_kv import PagedKVCache, PrefixTree  # noqa: F401
 from .router import HashRing, RouterConfig, ServingRouter  # noqa: F401
 from .stats import (  # noqa: F401
@@ -38,7 +36,7 @@ from .stats import (  # noqa: F401
 __all__ = [
     "Engine", "ServingConfig", "SamplingParams", "RequestOutput",
     "CompiledServingTick", "TickFallbackWarning",
-    "SlotKVCache", "PagedKVCache", "PrefixTree", "ServingError",
+    "PagedKVCache", "PrefixTree", "ServingError",
     "QueueFullError", "DeadlineExceededError", "EngineShutdownError",
     "SchedulerStallError", "NoReplicaError", "PageMigrationError",
     "RequestCancelledError", "RecurrentStateError",

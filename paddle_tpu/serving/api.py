@@ -89,8 +89,8 @@ class RecurrentStateError(ServingError, ValueError):
     """The configuration asks for a mechanism that cannot yet carry the
     fixed-size recurrent state some of the model's layers keep per
     sequence (what ``model.config.layer_states()`` reports): prefix
-    sharing, speculative rollback, the slot cache layout, and page
-    export / migration all move or rewind keys and values by page or by
+    sharing, speculative rollback, and page export / migration all
+    move or rewind keys and values by page or by
     offset, and a recurrence has neither.  Raised at ``Engine``
     construction (and by the page export / adopt calls themselves) and
     names the mechanism — never a wrong answer mid-decode."""
@@ -161,8 +161,7 @@ class ServingConfig:
                              and a dequant-fused read; each quantized
                              page packs 2x page_size tokens in half the
                              baseline page's bytes, so the pages-in-use
-                             gauge at equal token load ~halves (paged
-                             layout only)
+                             gauge at equal token load ~halves
     idle_wait_s              scheduler sleep when no work is queued
     drain_grace_s            `drain()` deadline when none is passed: how
                              long in-flight slots may run on before the
@@ -175,26 +174,21 @@ class ServingConfig:
     max_scheduler_restarts   bounded retries for the scheduler loop
                              after a crash or stall before the engine
                              gives up and stops accepting work
-    kv_layout                "paged" (default): block-granular KV pages
-                             with lazy per-page growth, shared-prefix
-                             reuse and chunked prefill
-                             (serving/paged_kv.py); "slots": the PR 3
-                             fixed [num_slots, max_seq_len] stripes
-    page_size                tokens per KV page (paged layout); pick a
-                             divisor of max_seq_len
-    kv_pool_pages            physical pages in the pool (paged layout);
-                             None → num_slots * ceil(max_seq_len /
-                             page_size), i.e. the same bytes the slot
-                             layout preallocates
+    page_size                tokens per KV page (serving/paged_kv.py);
+                             pick a divisor of max_seq_len
+    kv_pool_pages            physical pages in the pool; None →
+                             num_slots * ceil(max_seq_len / page_size),
+                             i.e. every slot can reach max_seq_len at
+                             once
     enable_prefix_cache      keep released prompt pages in a refcounted
                              prefix tree so requests sharing a system
                              prompt reuse its KV instead of recomputing
-                             prefill (paged layout only)
+                             prefill
     prefill_chunk_tokens     prompts prefill this many tokens per
                              scheduler iteration, interleaved with
                              decode steps, so a long prompt cannot
-                             starve in-flight streams (paged layout;
-                             one compiled prefill program total)
+                             starve in-flight streams (one compiled
+                             prefill program a row bucket)
     draft_model              small proposer model for speculative
                              decoding (same tokenizer/vocab as the
                              target; its config.max_seq_len must cover
@@ -204,9 +198,9 @@ class ServingConfig:
                              scheduler iteration; the target model
                              verifies all K+1 positions in ONE batched
                              call and an accept-mask rollback rewinds
-                             the rejected tail (paged layout only;
-                             0 = off — the decode loop is bitwise the
-                             plain one).  Speculation engages when
+                             the rejected tail (0 = off — the decode
+                             loop is bitwise the plain one).
+                             Speculation engages when
                              every active request is greedy without
                              repetition penalty; mixed batches fall
                              back to the plain step for that iteration
@@ -230,8 +224,7 @@ class ServingConfig:
                              per-projection A/B stacks of
                              max_adapters+1 slots (slot 0 = the exact
                              identity base requests ride) and enables
-                             submit(..., adapter_id=...); requires
-                             kv_layout="paged"
+                             submit(..., adapter_id=...)
     adapter_rank_pool        fixed rank budget every pool slot is padded
                              to; registering an adapter with rank >
                              adapter_rank_pool raises AdapterConfigError
@@ -257,7 +250,6 @@ class ServingConfig:
     drain_grace_s: float = 30.0
     step_timeout_s: float = 0.0
     max_scheduler_restarts: int = 2
-    kv_layout: str = "paged"
     page_size: int = 16
     kv_pool_pages: int | None = None
     enable_prefix_cache: bool = True
@@ -280,9 +272,6 @@ class ServingConfig:
         if self.max_queue < 1:
             raise ValueError(f"max_queue must be >= 1, got "
                              f"{self.max_queue}")
-        if self.kv_layout not in ("paged", "slots"):
-            raise ValueError("kv_layout must be 'paged' or 'slots', "
-                             f"got {self.kv_layout!r}")
         if self.page_size < 1:
             raise ValueError(f"page_size must be >= 1, got "
                              f"{self.page_size}")
@@ -306,33 +295,20 @@ class ServingConfig:
             raise ValueError(f"max_scheduler_restarts must be >= 0, "
                              f"got {self.max_scheduler_restarts}")
         from ..quantization import kv_quant_params
-        if kv_quant_params(self.cache_dtype) is not None and \
-                self.kv_layout != "paged":
-            raise ValueError(
-                f"cache_dtype={self.cache_dtype!r} (quantized KV with "
-                "per-page scales) requires kv_layout='paged'")
+        kv_quant_params(self.cache_dtype)   # an fp8 this jax lacks raises
         if self.speculation_k < 0:
             raise ValueError(f"speculation_k must be >= 0, got "
                              f"{self.speculation_k}")
-        if self.speculation_k > 0:
-            if self.draft_model is None:
-                raise ValueError(
-                    "speculation_k > 0 needs a draft_model to propose "
-                    "tokens; pass ServingConfig(draft_model=...)")
-            if self.kv_layout != "paged":
-                raise ValueError(
-                    "speculative decoding requires kv_layout='paged' "
-                    "(accept-mask rollback is a page-table/offset move)")
+        if self.speculation_k > 0 and self.draft_model is None:
+            raise ValueError(
+                "speculation_k > 0 needs a draft_model to propose "
+                "tokens; pass ServingConfig(draft_model=...)")
         if self.max_adapters < 0:
             raise ValueError(f"max_adapters must be >= 0, got "
                              f"{self.max_adapters}")
         if self.adapter_rank_pool < 1:
             raise ValueError(f"adapter_rank_pool must be >= 1, got "
                              f"{self.adapter_rank_pool}")
-        if self.max_adapters > 0 and self.kv_layout != "paged":
-            raise ValueError(
-                "max_adapters > 0 (multi-tenant LoRA serving) requires "
-                "kv_layout='paged'")
         if self.adapters and self.max_adapters == 0:
             raise ValueError(
                 "ServingConfig.adapters given but max_adapters == 0 — "

@@ -40,8 +40,8 @@ for what one program cannot host.
 
 Fallbacks latch the uncompiled scheduler byte-identically and warn once
 with the typed :class:`TickFallbackWarning`: flag off
-(``FLAGS_compiled_tick``), slot (non-paged) cache layout, speculative
-decoding configured, layer hooks installed, and non-greedy sampling
+(``FLAGS_compiled_tick``), speculative decoding configured, a
+framework tracer active, layer hooks installed, and non-greedy sampling
 without a per-request ``SamplingParams.seed`` (the vectorized chain
 derives each slot's stream from ``fold_in(PRNGKey(seed), n_generated)``
 — without a seed the old path's global-RNG draws cannot be reproduced
@@ -200,10 +200,10 @@ class CompiledServingTick:
         self._h_counts = None          # host mirror of generated counts
         self._ahead = False            # device tokens not yet on host
         self._sublayers = None
-        # static blockers (cache layout, speculation) are known at
-        # construction: warn right away — an all-greedy speculative
-        # engine never even consults the tick (the spec step runs), so
-        # an iteration-time warning would stay silent forever
+        # the static blocker (speculation) is known at construction:
+        # warn right away — an all-greedy speculative engine never even
+        # consults the tick (the spec step runs), so an iteration-time
+        # warning would stay silent forever
         blk = self._static_blocker()
         if blk is not None:
             self._note_fallback(*blk)
@@ -226,11 +226,7 @@ class CompiledServingTick:
     def _static_blocker(self):
         """(kind, reason, permanent) for configuration the tick can
         never host, known at engine start; None otherwise."""
-        eng = self.eng
-        if not eng._paged:
-            return ("layout", "kv_layout='slots' — the compiled tick "
-                    "runs on the paged cache", True)
-        if eng._spec:
+        if self.eng._spec:
             return ("spec", "speculative decoding configured "
                     "(draft_model + speculation_k > 0)", True)
         return None

@@ -7,10 +7,10 @@ every decode step is the SAME static-shape compiled program (PR 1 caches
 the executable), throughput is purely a matter of keeping that program
 FED.  A background scheduler thread:
 
-1. admits queued requests into free KV slots (batch-1 prefill, sampled
+1. admits queued requests into free KV slots (chunked prefill, sampled
    first token → time-to-first-token),
 2. runs ONE batched decode step per iteration over all `num_slots` slots
-   — per-slot offsets (serving/kv_slots.py) let sequences of different
+   — per-slot offsets (serving/paged_kv.py) let sequences of different
    ages share the step, and a finished/evicted slot is refilled on the
    next iteration without draining the batch,
 3. applies per-request sampling params (the processor chain factored out
@@ -39,7 +39,6 @@ from .api import (DeadlineExceededError, EngineShutdownError,
                   QueueFullError, RecurrentStateError,
                   RequestCancelledError, RequestOutput, SamplingParams,
                   SchedulerStallError, ServingConfig)
-from .kv_slots import SlotKVCache
 from ..models.generation import recurrent_layer_states
 
 
@@ -66,7 +65,7 @@ class _Request:
         self.seen = None            # [V] bool, only under rep penalty
         self.last_token = 0
         self.slot = None
-        self.prefill_pos = 0        # next prompt token to prefill (paged)
+        self.prefill_pos = 0        # next prompt token to prefill
         self.shared_len = 0         # prompt tokens reused from the tree
         self.prefix_nodes = []      # tree nodes this request references
         self.draft_prefill_pos = 0  # draft-model prefill progress (spec)
@@ -138,8 +137,7 @@ class Engine:
         # and the pool's byte budget stretches (docs/SERVING.md)
         self._page_size = self.scfg.page_size * (2 if self._quant else 1)
         self._spec_k = int(self.scfg.speculation_k)
-        self._spec = bool(self.scfg.kv_layout == "paged"
-                          and self._spec_k > 0
+        self._spec = bool(self._spec_k > 0
                           and self.scfg.draft_model is not None)
         if self._spec:
             draft = self.scfg.draft_model
@@ -168,7 +166,6 @@ class Engine:
         self._active: dict[int, _Request] = {}
         # requests holding a slot whose prompt is mid-(chunked-)prefill
         self._prefilling: deque[_Request] = deque()
-        self._paged = self.scfg.kv_layout == "paged"
         self.prefix_tree = None
         self._max_active = 0
         # EVERY unresolved request, from submit() until its future
@@ -259,10 +256,6 @@ class Engine:
             return
         n = sum(s is not None for s in self._layer_states)
         why = f"{n} of the model's layers keep a recurrent state"
-        if self.scfg.kv_layout != "paged":
-            raise RecurrentStateError(
-                f"kv_layout={self.scfg.kv_layout!r}: {why}, which only "
-                "the paged cache manager holds (kv_layout='paged')")
         if self.scfg.enable_prefix_cache:
             raise RecurrentStateError(
                 f"enable_prefix_cache=True: {why}, and a shared prefix "
@@ -327,53 +320,48 @@ class Engine:
     def _new_cache(self):
         """Fresh KV storage (and prefix tree, and the draft model's
         mirror cache when speculating) for a (re)started loop."""
-        if self._paged:
-            from .paged_kv import PagedKVCache, PrefixTree
-            # +speculation_k positions of headroom: a verify window may
-            # write K tokens past the last real position before the
-            # accept-mask rollback rewinds them
-            slot_len = self.max_len + self._spec_k
-            if self._layer_states is not None:
-                # a last chunk that would overrun the slot is shifted
-                # left and re-feeds tokens, which pages forgive and a
-                # recurrence does not: whole chunks always fit (the
-                # extra table entries stay on the scratch page)
-                chunk = min(self.scfg.prefill_chunk_tokens, slot_len)
-                slot_len = -(-slot_len // chunk) * chunk
-            cache = PagedKVCache(
-                self.cfg.num_layers, self.scfg.num_slots, slot_len,
-                self._kv_heads, self.cfg.head_dim,
-                page_size=self._page_size,
-                num_pages=self.scfg.kv_pool_pages,
-                dtype=self.scfg.cache_dtype,
-                layer_states=self._layer_states)
-            stats.set_value("state.bytes", cache.state_bytes)
-            stats.set_value("kv.pages_spanned",
-                            cache.num_slots * cache.pages_per_slot)
-            self.prefix_tree = PrefixTree(self._page_size) \
-                if self.scfg.enable_prefix_cache else None
-            # one compiled prefill program: every chunk is this wide
-            self._chunk = min(self.scfg.prefill_chunk_tokens,
-                              cache.capacity)
-            self._prefilling.clear()
-            self._pages_peak = 0
-            if self._spec:
-                dcfg = self.scfg.draft_model.config
-                # full preallocation for the small draft model: prefix
-                # pages are never shared into the draft cache (the
-                # draft prefills the whole prompt itself), so its pool
-                # must never be the admission bottleneck
-                self.draft_cache = PagedKVCache(
-                    dcfg.num_layers, self.scfg.num_slots,
-                    self.max_len + self._spec_k,
-                    getattr(dcfg, "num_kv_heads", dcfg.num_heads),
-                    dcfg.head_dim, page_size=self._page_size,
-                    num_pages=None, dtype=self.scfg.cache_dtype)
-            return cache
-        return SlotKVCache(
-            self.cfg.num_layers, self.scfg.num_slots, self.max_len,
+        from .paged_kv import PagedKVCache, PrefixTree
+        # +speculation_k positions of headroom: a verify window may
+        # write K tokens past the last real position before the
+        # accept-mask rollback rewinds them
+        slot_len = self.max_len + self._spec_k
+        if self._layer_states is not None:
+            # a last chunk that would overrun the slot is shifted
+            # left and re-feeds tokens, which pages forgive and a
+            # recurrence does not: whole chunks always fit (the
+            # extra table entries stay on the scratch page)
+            chunk = min(self.scfg.prefill_chunk_tokens, slot_len)
+            slot_len = -(-slot_len // chunk) * chunk
+        cache = PagedKVCache(
+            self.cfg.num_layers, self.scfg.num_slots, slot_len,
             self._kv_heads, self.cfg.head_dim,
-            dtype=self.scfg.cache_dtype)
+            page_size=self._page_size,
+            num_pages=self.scfg.kv_pool_pages,
+            dtype=self.scfg.cache_dtype,
+            layer_states=self._layer_states)
+        stats.set_value("state.bytes", cache.state_bytes)
+        stats.set_value("kv.pages_spanned",
+                        cache.num_slots * cache.pages_per_slot)
+        self.prefix_tree = PrefixTree(self._page_size) \
+            if self.scfg.enable_prefix_cache else None
+        # one compiled prefill program: every chunk is this wide
+        self._chunk = min(self.scfg.prefill_chunk_tokens,
+                          cache.capacity)
+        self._prefilling.clear()
+        self._pages_peak = 0
+        if self._spec:
+            dcfg = self.scfg.draft_model.config
+            # full preallocation for the small draft model: prefix
+            # pages are never shared into the draft cache (the
+            # draft prefills the whole prompt itself), so its pool
+            # must never be the admission bottleneck
+            self.draft_cache = PagedKVCache(
+                dcfg.num_layers, self.scfg.num_slots,
+                self.max_len + self._spec_k,
+                getattr(dcfg, "num_kv_heads", dcfg.num_heads),
+                dcfg.head_dim, page_size=self._page_size,
+                num_pages=None, dtype=self.scfg.cache_dtype)
+        return cache
 
     def _make_tick(self):
         """A fresh compiled-tick driver for a (re)started loop, or None
@@ -439,7 +427,7 @@ class Engine:
                 return
             already = self._draining
             self._drain_migrate = bool(migrate) and \
-                self.migrator is not None and self._paged
+                self.migrator is not None
             self._draining = True
             queued = list(self._queue)
             self._queue.clear()
@@ -493,7 +481,7 @@ class Engine:
         and `ValueError` for prompts the slot cache cannot hold.
 
         ``handoff`` (disaggregation): a migration target descriptor the
-        hosting replica's `migrator` understands.  When set on a paged
+        hosting replica's `migrator` understands.  When set on an
         engine with a migrator installed, the request's KV pages are
         streamed to that replica once its prompt is hot and decoding
         resumes there; on any migration failure the request falls back
@@ -519,20 +507,19 @@ class Engine:
         if max_new < 1:
             raise ValueError(f"max_new_tokens must be >= 1, got "
                              f"{max_new}")
-        if self._paged:
-            # infeasible requests are rejected up front: admission
-            # backpressure only helps when the pool could EVER fit it
-            psz = self._page_size
-            pool = self.scfg.kv_pool_pages or \
-                self.scfg.num_slots * \
-                (-(-(self.max_len + self._spec_k) // psz))
-            need = -(-(min(prompt.size + max_new, self.max_len)
-                       + self._spec_k) // psz)
-            if need > pool:
-                raise ValueError(
-                    f"request needs {need} KV pages (prompt "
-                    f"{prompt.size} + max_new {max_new}) but the pool "
-                    f"holds {pool}; raise ServingConfig.kv_pool_pages")
+        # infeasible requests are rejected up front: admission
+        # backpressure only helps when the pool could EVER fit it
+        psz = self._page_size
+        pool = self.scfg.kv_pool_pages or \
+            self.scfg.num_slots * \
+            (-(-(self.max_len + self._spec_k) // psz))
+        need = -(-(min(prompt.size + max_new, self.max_len)
+                   + self._spec_k) // psz)
+        if need > pool:
+            raise ValueError(
+                f"request needs {need} KV pages (prompt "
+                f"{prompt.size} + max_new {max_new}) but the pool "
+                f"holds {pool}; raise ServingConfig.kv_pool_pages")
         if adapter_id is not None:
             known = self.adapter_pool.known_ids() \
                 if self.adapter_pool is not None else []
@@ -552,7 +539,7 @@ class Engine:
                        eos_token_id, deadline)
         if adapter_id is not None:
             req.adapter_id = str(adapter_id)
-        if handoff is not None and self._paged:
+        if handoff is not None:
             req.handoff = handoff
         if tracing.enabled():
             # a routed request arrives on an rpc handler thread with the
@@ -613,9 +600,6 @@ class Engine:
         recomputed.  Raises `PageMigrationError` for payloads this
         engine's pool can never hold."""
         from .api import PageMigrationError
-        if not self._paged:
-            raise PageMigrationError(
-                "page adoption requires kv_layout='paged'")
         if self._layer_states is not None:
             raise RecurrentStateError(
                 "submit_resume: migrated pages carry keys and values "
@@ -829,7 +813,7 @@ class Engine:
             self._fail_all(EngineShutdownError("engine shut down"))
             stats.set_value("active_slots", 0)
             stats.set_value("queue_depth", 0)
-            if self._paged and self.cache is not None:
+            if self.cache is not None:
                 self._publish_pool_stats(force=True)
 
     def _loop_once(self):
@@ -869,14 +853,10 @@ class Engine:
         self._expire_queued_locked()
         admits = []
         while self._queue and self.cache.free_slots:
-            if self._paged:
-                slot = self._try_admit_paged(self._queue[0])
-                if slot is None:
-                    break       # page backpressure: FIFO
-                admits.append((self._queue.popleft(), slot))
-            else:
-                slot = self.cache.allocate()
-                admits.append((self._queue.popleft(), slot))
+            slot = self._try_admit(self._queue[0])
+            if slot is None:
+                break       # page backpressure: FIFO
+            admits.append((self._queue.popleft(), slot))
         now = time.monotonic()
         for req, _ in admits:
             stats.observe("queue_wait_ms", (now - req.submit_t) * 1e3)
@@ -893,28 +873,23 @@ class Engine:
             # replica — heartbeats stay healthy, every request
             # hashed here just gets slower (docs/RESILIENCE.md)
             _fi.check_rpc("engine_slow", self.fault_name or "")
-        if self._paged and self._draining and \
+        if self._draining and \
                 self._drain_migrate and self.migrator is not None:
             # preemption recovery: stream the still-decoding
             # slots' pages to survivors instead of racing the
             # drain deadline token by token
             self._migrate_out_active()
-        if self._paged:
-            for req, slot in admits:
-                if req.resume is not None:
-                    self._activate_resumed(req, slot)
-                else:
-                    self._start_prefill(req, slot)
-            # ONE batched chunk call covers every prefilling
-            # request, then the decode step runs: long prompts
-            # advance without ever blocking in-flight streams
-            # for more than a chunk
-            if self._prefilling:
-                with span("serving.prefill_round"):
-                    self._prefill_round()
-        else:
-            for req, slot in admits:
-                self._prefill(req, slot)
+        for req, slot in admits:
+            if req.resume is not None:
+                self._activate_resumed(req, slot)
+            else:
+                self._start_prefill(req, slot)
+        # ONE batched chunk call covers every prefilling request, then
+        # the decode step runs: long prompts advance without ever
+        # blocking in-flight streams for more than a chunk
+        if self._prefilling:
+            with span("serving.prefill_round"):
+                self._prefill_round()
         if self._active:
             if self._can_speculate():
                 self._spec_step()
@@ -922,9 +897,8 @@ class Engine:
                 pass        # ONE compiled program ran the tick
             else:
                 self._decode_step()
-        if self._paged:
-            with span("serving.publish"):
-                self._publish_pool_stats()
+        with span("serving.publish"):
+            self._publish_pool_stats()
 
     def _stall_monitor(self):
         """Scheduler-iteration watchdog (armed by step_timeout_s > 0):
@@ -973,47 +947,7 @@ class Engine:
                 keep.append(req)
         self._queue = keep
 
-    def _prefill(self, req, slot):
-        """Batch-1 prompt pass into the slot's rows + first token."""
-        from ..core.tensor import Tensor
-        from ..models.generation import init_kv_caches
-        from ..framework.capture import TRACE_LOCK
-        tr = req.trace
-        if tr is not None:
-            if tr.queue is not None:
-                tr.queue.end(slot=slot)
-            tr.prefill = tracing.start_span(
-                "engine.prefill", parent=tr.root, slot=slot,
-                prompt_tokens=int(req.prompt.size))
-        with span("serving.prefill", request_id=req.id):
-            caches = init_kv_caches(
-                self.cfg.num_layers, 1, self.max_len, self._kv_heads,
-                self.cfg.head_dim, dtype=self.scfg.cache_dtype)
-            with TRACE_LOCK:    # a shared model may be mid-capture
-                logits = self.model(Tensor(req.prompt[None, :]),
-                                    caches=caches)
-            self.cache.write_prefill(slot, caches, req.prompt.size)
-            if req.sampling.uses_penalty:
-                seen = np.zeros(self.cfg.vocab_size, bool)
-                seen[req.prompt] = True
-                req.seen = seen
-            tok = self._sample_row(logits[:, -1, :], req)
-        req.ttft_ms = (time.monotonic() - req.submit_t) * 1e3
-        stats.observe("ttft_ms", req.ttft_ms)
-        stats.incr("prefill_steps")
-        req.slot = slot
-        self._active[slot] = req
-        if tr is not None:
-            tr.prefill.event("first_token",
-                             ttft_ms=round(req.ttft_ms, 3))
-            tr.prefill.end()
-            tr.decode = tracing.start_span(
-                "engine.decode", parent=tr.root, slot=slot)
-        self._append_token(req, tok)
-        stats.set_value("active_slots", len(self._active))
-
-    # ---------------- paged scheduler (kv_layout="paged") ----------------
-    def _try_admit_paged(self, req):
+    def _try_admit(self, req):
         """Reserve a slot + worst-case page budget for `req` (called
         under the lock).  Matches the prompt against the prefix tree
         first — shared pages shrink the reservation — and evicts LRU
@@ -1748,18 +1682,16 @@ class Engine:
             n_active = len(self._active)
             self._max_active = max(self._max_active, n_active)
             stats.set_value("max_active_slots", self._max_active)
-            if self._paged:
-                # page-by-page growth: assign a fresh page only when a
-                # row's write position crosses a page boundary (the
-                # admission reservation guarantees the page exists)
-                for slot in self._active:
-                    self.cache.ensure_capacity(
-                        slot, int(self.cache.offsets[slot]))
+            # page-by-page growth: assign a fresh page only when a
+            # row's write position crosses a page boundary (the
+            # admission reservation guarantees the page exists)
+            for slot in self._active:
+                self.cache.ensure_capacity(
+                    slot, int(self.cache.offsets[slot]))
             tok_in = np.zeros((self.cache.num_slots, 1), np.int32)
             for slot, req in self._active.items():
                 tok_in[slot, 0] = req.last_token
-            caches = self.cache.layer_caches(self._active) \
-                if self._paged else self.cache.layer_caches()
+            caches = self.cache.layer_caches(self._active)
             with TRACE_LOCK, self._lora_ctx():
                 logits = self.model(Tensor(tok_in), caches=caches)
             self.cache.advance(self._active.keys())
@@ -1945,20 +1877,16 @@ class Engine:
         if req.slot is None:
             return
         self._mut += 1          # slot membership changed: tick rebuilds
-        in_active = req.slot in self._active and \
-            self._active[req.slot] is req
-        if in_active:
+        if self._active.get(req.slot) is req:
             del self._active[req.slot]
-        if in_active or self._paged:
-            # paged requests hold pages from admission on (prefill
-            # included); slot-layout requests only own a slot once
-            # active
-            self.cache.release(req.slot)
-            if self._spec and self.draft_cache is not None:
-                self.draft_cache.release(req.slot)
-            if req.prefix_nodes and self.prefix_tree is not None:
-                self.prefix_tree.release(req.prefix_nodes)
-                req.prefix_nodes = []
+        # a request holds its slot and pages from admission on (prefill
+        # included), not only once it is active
+        self.cache.release(req.slot)
+        if self._spec and self.draft_cache is not None:
+            self.draft_cache.release(req.slot)
+        if req.prefix_nodes and self.prefix_tree is not None:
+            self.prefix_tree.release(req.prefix_nodes)
+            req.prefix_nodes = []
         if self.adapter_pool is not None:
             self.adapter_pool.clear_row(req.slot)
             if req.adapter_id is not None:

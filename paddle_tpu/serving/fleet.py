@@ -20,7 +20,7 @@ relaunch (PAPER.md layers 5/9).  TPU-native realization:
   membership `TCPStore`, spawns N replicas, waits for them to warm into
   the ring, fronts them with a `ServingRouter`, and supports chaos
   (SIGKILL), graceful scale-down (SIGTERM → drain) and scale-up
-  (`add_replica`).  `benchmarks/serving_fleet_bench.py` drives it.
+  (`add_replica`).  `tests/test_fleet.py` drives it.
 
 Replica lifecycle states gossiped in the `fleet.{name}` record:
 ``warming`` (model building / warmup compile) → ``ready`` (routable) →

@@ -1,8 +1,8 @@
 """Paged KV cache + prefix tree for the serving engine.
 
-`SlotKVCache` reserves a full ``max_seq_len`` stripe per slot up front —
-a request generating 40 tokens from a 10-token prompt squats the same
-HBM as one that fills the slot.  This module brings the PagedAttention
+A full ``max_seq_len`` stripe reserved per slot up front makes a
+request generating 40 tokens from a 10-token prompt squat the same HBM
+as one that fills the slot.  This module brings the PagedAttention
 (vLLM) / RadixAttention (SGLang) memory model to the TPU's static-shape
 regime:
 
@@ -14,8 +14,7 @@ regime:
 - **Scratch page 0** is never allocated.  Free slots (and table entries
   not yet grown into) point at it, so the static-shape batch's dummy
   writes land in scratch and the per-row causal mask keeps every live
-  row blind to it — the paged analog of SlotKVCache's "free slots ride
-  the batch harmlessly".
+  row blind to it: free slots ride the batch harmlessly.
 - **Prefix tree** (`PrefixTree`): refcounted, page-granular radix tree
   over prompt tokens.  Requests that share a system prompt attach the
   shared pages to their page table instead of recomputing prefill;
@@ -55,15 +54,14 @@ from ..utils.flags import flag as _flag
 
 
 class PagedKVCache:
-    """Block-granular KV storage behind the same scheduler-facing
-    surface as `SlotKVCache` (allocate/release/advance/layer_caches)
-    plus the page machinery (`ensure_capacity`, `prefill_table`,
-    `prefill_view`, `make_shared`, `reclaim`).
+    """Block-granular KV storage: the scheduler-facing surface
+    (allocate/release/advance/layer_caches) plus the page machinery
+    (`ensure_capacity`, `prefill_table`, `prefill_view`, `make_shared`,
+    `reclaim`).
 
     Host-side bookkeeping is plain numpy; device uploads are batched:
     mutations only mark the cache dirty, and `layer_caches()` uploads
-    the offsets + page table ONCE per scheduler iteration (the same
-    lazy-flush contract as `SlotKVCache`).
+    the offsets + page table ONCE per scheduler iteration.
     """
 
     def __init__(self, num_layers, num_slots, max_len, num_kv_heads,

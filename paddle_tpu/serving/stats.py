@@ -315,15 +315,15 @@ def serving_stats():
     decode, whichever lane ran it) — plus ``tick_compiled_hits`` /
     ``tick_fallbacks`` counting iterations the ONE-program compiled
     tick executed vs iterations that latched the uncompiled scheduler
-    (flag off mid-run, slot layout, speculation, unhostable sampling,
-    hooks), and ``prefill_compiled_hits`` / ``prefill_fallbacks`` the
-    same for prefill chunk calls (the draft model's eager calls are in
+    (flag off mid-run, speculation, unhostable sampling, hooks), and
+    ``prefill_compiled_hits`` / ``prefill_fallbacks`` the same for
+    prefill chunk calls (the draft model's eager calls are in
     neither); all ride the Prometheus exposition
     (``serving_tick_ms`` histogram, ``serving_tick_compiled_hits`` /
     ``serving_tick_fallbacks`` counters, gated by
     tools/check_telemetry.py --serving-tick).
 
-    Paged-cache quantities (kv_layout="paged", zero otherwise):
+    KV-pool quantities:
     ``kv_pages_in_use``/``kv_pages_free`` pool gauges plus the
     ``kv_pages_peak`` high-water mark (the int8-KV capacity gate reads
     it: at equal token load a quantized pool's peak ~halves),
@@ -331,8 +331,8 @@ def serving_stats():
     ``prefix_cache_hit_tokens`` tree counters, ``prefill_chunks`` and
     ``prefill_chunk_ms_avg`` chunked-prefill cadence, and
     ``max_active_slots`` — the high-water mark of concurrent decoding
-    sequences (the paged pool admits more of them than
-    ``pool_bytes / max_seq_len`` stripes would).
+    sequences (the pool admits more of them than it has
+    ``max_seq_len``-long stretches of pages).
     ``kv_pages_streamed_per_tick`` (pages held, a mean over the
     compiled ticks: what the paged decode kernel reads) stands beside
     ``kv_pages_spanned_per_tick`` (slots x pages a slot: what a walk

@@ -1,7 +1,7 @@
 """Runtime flag system (reference: paddle/phi/core/flags.cc — ~100
 PHI_DEFINE_EXPORTED_* flags surfaced via paddle.set_flags).  TPU-native: a
-typed registry seeded from environment variables; consumed by debugging
-hooks (nan/inf checks), allocator-style knobs map onto XLA options."""
+typed registry seeded from environment variables; every flag here has a
+reader (docs/KNOBS.md)."""
 from __future__ import annotations
 
 import os
@@ -11,15 +11,6 @@ from typing import Any
 _FLAGS: dict[str, Any] = {
     "FLAGS_check_nan_inf": False,
     "FLAGS_check_nan_inf_level": 0,
-    "FLAGS_cudnn_deterministic": False,
-    "FLAGS_embedding_deterministic": 0,
-    "FLAGS_use_autotune": True,
-    "FLAGS_allocator_strategy": "auto_growth",
-    "FLAGS_eager_delete_tensor_gb": 0.0,
-    "FLAGS_log_level": 0,
-    "FLAGS_profile": False,
-    "FLAGS_amp_dtype": "bfloat16",
-    "FLAGS_matmul_precision": "default",  # maps to jax.default_matmul_precision
     # donate mutated captures (params/opt state) in compiled train steps so
     # XLA updates them in place; disable if user code holds raw jax arrays
     # of parameters across steps, or Tensors that SHARE a parameter's

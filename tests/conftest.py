@@ -8,6 +8,7 @@ import os
 # replicas) inherits it.  XLA_FLAGS is read at CPU client creation, so
 # setting it here works.
 import jax  # noqa: E402
+import pytest  # noqa: E402
 
 jax.config.update("jax_platforms", "cpu")
 os.environ["JAX_PLATFORMS"] = "cpu"
@@ -127,8 +128,20 @@ _SLOW_TESTS = {
 }
 
 
+@pytest.fixture(scope="session")
+def api_spec():
+    """The public surface the repository freezes, as
+    {module name: {name: {"kind": ...}}}: tools/api_spec.json, the spec
+    tests/test_api_gate.py enforces.  The ``*_parity`` tests take their
+    expected names from it."""
+    import json
+    path = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "tools", "api_spec.json")
+    with open(path) as f:
+        return json.load(f)
+
+
 def pytest_collection_modifyitems(config, items):
-    import pytest
     if os.environ.get("PADDLE_TPU_RUN_SLOW"):
         return
     skip = pytest.mark.skip(

@@ -98,6 +98,7 @@ def test_mixed_workload_bit_equality(model, tick_flag):
     assert got[1].finish_reason == "eos"
     assert got[1].output_ids.size < 8         # refilled mid-flight
     assert snap_c["tick_compiled_hits"] > 0
+    assert snap_c["tick_fallbacks"] == 0      # every request hostable
     assert snap_u["tick_compiled_hits"] == 0
     np.testing.assert_array_equal(got[0].output_ids,
                                   _ref_greedy(model, pa, 8))
@@ -143,23 +144,11 @@ def test_unseeded_sampling_typed_warn_once_fallback(model, tick_flag):
     assert all(o.output_ids.size == 6 for o in outs)
 
 
-def test_slots_layout_and_speculation_fall_back_typed(model, tick_flag):
-    """kv_layout='slots' and speculation-on both latch the uncompiled
-    scheduler with the typed warning; speculation_k=0 with a draft
-    model configured does NOT (the tick runs)."""
+def test_speculation_falls_back_typed(model, tick_flag):
+    """Speculation-on latches the uncompiled scheduler with the typed
+    warning; speculation_k=0 with a draft model configured does NOT
+    (the tick runs)."""
     (p,) = _prompts([5])
-    with warnings.catch_warnings(record=True) as w:
-        warnings.simplefilter("always")
-        outs, snap, _ = _serve(
-            model, [(p, 4, None, None)],
-            cfg=ServingConfig(num_slots=1, kv_layout="slots"),
-            compiled=True)
-    assert any(issubclass(x.category, TickFallbackWarning) and
-               "slots" in str(x.message) for x in w)
-    assert snap["tick_compiled_hits"] == 0
-    np.testing.assert_array_equal(outs[0].output_ids,
-                                  _ref_greedy(model, p, 4))
-
     with warnings.catch_warnings(record=True) as w:
         warnings.simplefilter("always")
         outs, snap, _ = _serve(

@@ -178,14 +178,20 @@ def _fit_losses(ckpt_dir, resume=None, num_iters=None, save_mid=False):
     return losses
 
 
-def test_fit_resumes_mid_epoch_bit_exact(tmp_path):
-    flags.set_flags({"FLAGS_compiled_train_step": 0})
+@pytest.mark.parametrize("compiled", [0, 1], ids=["eager", "compiled"])
+def test_fit_resumes_mid_epoch_bit_exact(tmp_path, compiled):
+    flags.set_flags({"FLAGS_compiled_train_step": compiled})
     try:
         ref = _fit_losses(tmp_path / "ref")
         head = _fit_losses(tmp_path / "ck", num_iters=5, save_mid=True)
         tail = _fit_losses(tmp_path / "ck", resume=True)
-        assert len(head) == 5
-        assert head + tail == ref      # float equality == bitwise here
+        assert len(head) == 5 and len(head + tail) == len(ref)
+        if compiled:
+            # the compiled step reassociates reductions (docs/DATA.md)
+            np.testing.assert_allclose(head + tail, ref, rtol=0,
+                                       atol=5e-6)
+        else:
+            assert head + tail == ref  # float equality == bitwise here
     finally:
         flags.set_flags({"FLAGS_compiled_train_step": 1})
 

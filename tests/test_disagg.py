@@ -3,8 +3,7 @@ page export/adopt round-trips (fp32 + int8, attention bit-equal), the
 rpc raw-bytes fast path, engine-level handoff/resume/fallback, prefix
 -tree copy semantics across replicas, the role-aware router, and the
 drain-time migration + role-flip rejoin protocol.  Thread-mode replicas
-keep these fast; the process-mode perf gate lives in
-benchmarks/serving_fleet_bench.py --workload disagg."""
+keep these fast."""
 import pickle
 import threading
 import time
@@ -515,17 +514,30 @@ def test_drain_migrates_active_requests_to_survivor(model):
 def test_role_flip_rejoins_with_bumped_generation(model):
     """A replica leaves as prefill and rejoins as decode under the same
     name: the store generation bumps, the router admits the rejoin
-    (anti-flap), and the gossiped role flips."""
+    (anti-flap), and the gossiped role flips.  Requests in flight across
+    the flip are neither lost nor doubled: every stream completes
+    bit-equal to sequential greedy."""
     specs = {"rep-f": ServingConfig(num_slots=2, role="prefill"),
              "rep-g": ServingConfig(num_slots=2, role="decode")}
     with _RoleFleet(model, specs) as f:
         rep = f.reps["rep-f"]
         gen0 = rep.gen
+        inflight = _prompts([5, 7, 6, 8], seed=12)
+        futs = [f.router.submit(p, max_new_tokens=12,
+                                session_id=f"flip{i}")
+                for i, p in enumerate(inflight)]
         rep.drain(deadline_s=30.0)
         deadline = time.monotonic() + 15
         while "rep-f" in f.router.ring.members:
             assert time.monotonic() < deadline
             time.sleep(0.05)
+        # settled before the name comes back: a transport error of the
+        # old generation is charged to whichever generation the router
+        # sees at that moment
+        for p, fut in zip(inflight, futs):
+            np.testing.assert_array_equal(
+                fut.result(timeout=300).output_ids,
+                _ref_greedy(model, p, 12))
         flipped = ReplicaServer(
             "rep-f", model, TCPStore("127.0.0.1", f.master.port),
             ServingConfig(num_slots=2, role="decode"),

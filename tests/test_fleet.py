@@ -3,8 +3,9 @@ consistent-hash routing, membership + anti-flap reap, load shedding,
 failover with idempotent resubmission, drain awareness, and the rpc /
 store / engine hardening underneath it.  Thread-mode replicas (several
 `ReplicaServer`s in one process, each with its own rpc listener) keep
-these fast; the process-mode chaos drill lives in
-benchmarks/serving_fleet_bench.py and the CI fleet lane."""
+these fast; one process-mode drill (`ServingFleet`: SIGKILL mid-load,
+SIGTERM drain) closes the file."""
+import signal
 import threading
 import time
 
@@ -20,7 +21,8 @@ from paddle_tpu.serving import (Engine, EngineShutdownError, HashRing,
                                 QueueFullError, ReplicaConfig,
                                 ReplicaServer, RouterConfig,
                                 SamplingParams, ServingConfig,
-                                ServingRouter, serving_stats)
+                                ServingFleet, ServingRouter,
+                                serving_stats)
 from paddle_tpu.utils.flags import set_flags
 
 
@@ -28,14 +30,19 @@ def _np(t):
     return np.asarray(t._data_)
 
 
-@pytest.fixture(scope="module")
-def model():
+def _make_model():
+    """Top-level, so that a spawned replica process can unpickle it."""
     paddle.seed(0)
     m = GPTForCausalLM(gpt_config(
         "gpt2-124m", num_layers=2, hidden_size=64, num_heads=4,
         vocab_size=256, max_seq_len=64))
     m.eval()
     return m
+
+
+@pytest.fixture(scope="module")
+def model():
+    return _make_model()
 
 
 def _prompts(lens, seed=0, vocab=256):
@@ -363,6 +370,27 @@ def test_rpc_shutdown_idempotent_and_connect_retry():
         rpc.forget_worker(name="ghost")
     with pytest.raises(ValueError, match="unknown worker"):
         rpc.rpc_sync("ghost", sorted, args=([],))
+    # a peer that accepts and dies before the handshake ends (a replica
+    # killed while it was being dialled) is the same connect failure,
+    # not an EOFError the router would take for an application error
+    s = socket.socket()
+    s.bind(("127.0.0.1", 0))
+    s.listen()
+
+    def accept_and_die():
+        for _ in range(3):                   # one per connect retry
+            s.accept()[0].close()
+
+    dier = threading.Thread(target=accept_and_die, daemon=True)
+    dier.start()
+    rpc.connect_worker("dying", "127.0.0.1", s.getsockname()[1])
+    try:
+        with pytest.raises(ConnectionError, match="dying"):
+            rpc.rpc_sync("dying", sorted, args=([3, 1],))
+    finally:
+        rpc.forget_worker(name="dying")
+        dier.join(5)
+        s.close()
 
 
 def test_rpc_server_close_releases_port():
@@ -473,3 +501,43 @@ def test_router_submit_validation(model):
                             sampling=SamplingParams(temperature=-1))
     with pytest.raises(EngineShutdownError):
         f.router.submit(_prompts([4])[0])
+
+
+# ------------------------------------------------------- process mode
+def test_process_fleet_sigkill_midload_then_sigterm_drain(model):
+    """Replica PROCESSES behind the router: SIGKILL one under load and
+    every request still resolves once, bit-equal to sequential greedy
+    (none lost, no token doubled); a request of a session the victim
+    owned fails over; SIGTERM makes the survivor drain and exit 0; no
+    process outlives the fleet."""
+    prompts = _prompts([6, 9, 4, 11, 7, 5], seed=13)
+    fleet = ServingFleet(
+        _make_model, num_replicas=2,
+        serving_config=ServingConfig(num_slots=2, max_queue=16),
+        replica_config=ReplicaConfig(drain_deadline_s=20.0, **_FAST),
+        router_config=RouterConfig(heartbeat_ttl_s=1.2,
+                                   poll_interval_s=0.1),
+        warmup_prompt=_prompts([4], seed=1)[0])
+    with fleet:
+        procs = dict(fleet._procs)
+        victim, survivor = sorted(procs)
+        key = next(f"s{i}" for i in range(1000)
+                   if fleet.router.ring.lookup(f"s{i}") == victim)
+        failovers = fleet.stats()["router_failovers"]
+        futs = [fleet.submit(p, max_new_tokens=24, session_id=i)
+                for i, p in enumerate(prompts)]
+        fleet.kill_replica(victim, sig=signal.SIGKILL)
+        futs.append(fleet.submit(prompts[0], max_new_tokens=24,
+                                 session_id=key))
+        for p, fut in zip(prompts + prompts[:1], futs):
+            np.testing.assert_array_equal(
+                fut.result(timeout=300).output_ids,
+                _ref_greedy(model, p, 24))
+        procs[victim].join(30)
+        assert procs[victim].exitcode == -signal.SIGKILL
+        assert fleet.stats()["router_failovers"] > failovers
+        fleet.drain_replica(survivor)               # SIGTERM
+        procs[survivor].join(60)
+        assert procs[survivor].exitcode == 0
+    assert [n for n, p in procs.items() if p.is_alive()] == []
+

@@ -356,11 +356,10 @@ def _draft():
 
 @pytest.mark.parametrize("kwargs, names", [
     (dict(enable_prefix_cache=True), "enable_prefix_cache"),
-    (dict(kv_layout="slots"), "kv_layout"),
     (dict(speculation_k=2, draft_model="llama"), "speculation_k"),
     (dict(role="prefill"), "role"),
     (dict(role="decode"), "role"),
-], ids=["prefix", "slots", "speculation", "prefill-role", "decode-role"])
+], ids=["prefix", "speculation", "prefill-role", "decode-role"])
 def test_refused_at_construction(tiny, kwargs, names):
     kwargs = dict(kwargs)
     if kwargs.get("draft_model") == "llama":

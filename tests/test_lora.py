@@ -468,9 +468,6 @@ def test_adapter_config_errors(model, specs):
     with pytest.raises(ValueError, match="adapters"):
         ServingConfig(num_slots=2,
                       adapters={"t0": specs["t0"]}).validate()
-    with pytest.raises(ValueError, match="paged"):
-        ServingConfig(num_slots=2, kv_layout="slots",
-                      max_adapters=1).validate()
 
 
 def test_adapter_telemetry_keys_and_exposition(model, specs):

@@ -1,7 +1,6 @@
-"""nn/functional/optimizer/io long-tail surface (reference __all__ parity
-+ OpTest-style numerics; conv transposes verified vs torch elsewhere)."""
-import re
-
+"""nn/functional/optimizer/io long-tail surface (parity with the frozen
+surface in tools/api_spec.json + OpTest-style numerics; conv transposes
+verified vs torch elsewhere)."""
 import numpy as np
 import pytest
 
@@ -14,22 +13,14 @@ def T(a):
     return paddle.to_tensor(np.asarray(a))
 
 
-def _ref_all(path):
-    s = open(path).read()
-    return set(re.findall(r"'([^']+)'",
-                          re.search(r"__all__ = \[(.*?)\]", s, re.S).group(1)))
-
-
-def test_subpackage_all_parity():
-    for mod, path in [
-            (paddle.nn, "/root/reference/python/paddle/nn/__init__.py"),
-            (paddle.nn.functional,
-             "/root/reference/python/paddle/nn/functional/__init__.py"),
-            (paddle.optimizer,
-             "/root/reference/python/paddle/optimizer/__init__.py"),
-            (paddle.io, "/root/reference/python/paddle/io/__init__.py")]:
-        missing = sorted(s for s in _ref_all(path) if not hasattr(mod, s))
-        assert missing == [], f"{path}: {missing}"
+def test_subpackage_all_parity(api_spec):
+    for mod, name in [(paddle.nn, "nn"),
+                      (paddle.nn.functional, "nn.functional"),
+                      (paddle.optimizer, "optimizer"),
+                      (paddle.io, "io")]:
+        missing = sorted(s for s in api_spec[f"paddle_tpu.{name}"]
+                         if not hasattr(mod, s))
+        assert missing == [], f"{name}: {missing}"
 
 
 def test_ctc_loss_matches_torch():

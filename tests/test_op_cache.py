@@ -244,7 +244,9 @@ import paddle_tpu as paddle
 from paddle_tpu.core import op_cache
 d = op_cache.ensure_compile_cache()
 (paddle.to_tensor([1.0, 2.0]) * 3).numpy()      # an eager-op program
-jax.jit(lambda a: (a * 5 + 2).sum())(jnp.ones((8, 8)))
+def placement_probe(a):
+    return (a * 5 + 2).sum()
+jax.jit(placement_probe)(jnp.ones((8, 8)))
 print(d)
 print(len([f for f in os.listdir(d) if not f.endswith("-atime")]))
 """
@@ -262,7 +264,14 @@ def test_tier2_cache_placed_by_environment_only(tmp_path):
     assert op_cache._DEFAULT_CACHE_DIR == default
 
     placed = str(tmp_path / "placed")
-    before = set(os.listdir(default)) if os.path.isdir(default) else set()
+
+    def probes(d):
+        # the probe's own program: other test workers write theirs into
+        # the default directory all the while
+        return {f for f in (os.listdir(d) if os.path.isdir(d) else ())
+                if f.startswith("jit_placement_probe-")}
+
+    before = probes(default)
     env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=root,
                JAX_COMPILATION_CACHE_DIR=placed)
     out = subprocess.run([sys.executable, "-c", _PLACEMENT_PROBE], env=env,
@@ -270,5 +279,5 @@ def test_tier2_cache_placed_by_environment_only(tmp_path):
     assert out.returncode == 0, out.stderr[-2000:]
     got_dir, n_files = out.stdout.strip().splitlines()[-2:]
     assert got_dir == placed and int(n_files) > 0
-    after = set(os.listdir(default)) if os.path.isdir(default) else set()
-    assert after == before          # nothing leaked into the default dir
+    assert probes(placed)
+    assert probes(default) == before    # nothing leaked into the default dir

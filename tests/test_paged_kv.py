@@ -47,9 +47,10 @@ def test_paged_cache_bookkeeping():
                          num_pages=6)
     assert cache.usable_pages == 6 and cache.pages_in_use == 0
     assert cache.capacity == 64 and cache.pages_per_slot == 4
+    assert cache.free_slots == 2
     # reservation counts against availability before any page moves
     slot = cache.allocate(3)
-    assert slot is not None
+    assert slot is not None and cache.free_slots == 1
     assert cache.pages_in_use == 0 and cache.available_pages == 3
     # growth assigns pages lazily, one per boundary crossing
     cache.ensure_capacity(slot, 0)
@@ -70,7 +71,7 @@ def test_paged_cache_bookkeeping():
     with pytest.raises(ValueError, match="already free"):
         cache.release(slot)
     cache.release(other)
-    assert cache.available_pages == 6
+    assert cache.available_pages == 6 and cache.free_slots == 2
     # offsets/page table ride ONE shared device array across layers
     s2 = cache.allocate(1)
     cache.set_offset(s2, 5)
@@ -279,9 +280,9 @@ def test_long_prompt_does_not_starve_inflight_decode(model):
 
 
 def test_paged_admits_more_sequences_than_preallocation(model):
-    """The acceptance bound: with the SAME pool bytes the slot layout
-    spends on 2 × max_seq_len stripes, the paged engine runs 4
-    sequences concurrently."""
+    """The acceptance bound: a pool of the bytes that 2 full
+    max_seq_len stretches take runs 4 sequences concurrently, because
+    pages are claimed as sequences grow."""
     pages_per_slot = 128 // 16
     cfg = ServingConfig(num_slots=4, page_size=16,
                         kv_pool_pages=2 * pages_per_slot,   # 2 stripes

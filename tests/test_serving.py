@@ -10,7 +10,7 @@ import paddle_tpu as paddle
 from paddle_tpu.models import GPTForCausalLM, gpt_config
 from paddle_tpu.serving import (
     DeadlineExceededError, Engine, EngineShutdownError, QueueFullError,
-    SamplingParams, ServingConfig, SlotKVCache, serving_stats,
+    SamplingParams, ServingConfig, serving_stats,
 )
 
 
@@ -191,28 +191,6 @@ def test_submit_validation_and_capacity(model):
         Engine(model, ServingConfig(num_slots=0))
     with pytest.raises(ValueError, match="deadline_policy"):
         Engine(model, ServingConfig(deadline_policy="nope"))
-
-
-def test_slot_kv_cache_bookkeeping():
-    cache = SlotKVCache(num_layers=2, num_slots=3, max_len=8,
-                        num_kv_heads=2, head_dim=4)
-    assert cache.free_slots == 3
-    s0, s1 = cache.allocate(), cache.allocate()
-    assert {s0, s1} == {0, 1} and cache.free_slots == 1
-    cache.release(s0)
-    with pytest.raises(ValueError, match="already free"):
-        cache.release(s0)
-    assert cache.free_slots == 2
-    assert cache.allocate() in (s0, 2)
-    with pytest.raises(ValueError, match="capacity"):
-        cache.write_prefill(s1, [], 9)
-    # offsets propagate to every layer as one shared [num_slots] tensor
-    cache.offsets[s1] = 5
-    cache.advance([s1])
-    offs = _np(cache.layer_caches()[0]["offset"])
-    assert offs[s1] == 6
-    assert cache.layer_caches()[0]["offset"] is \
-        cache.layer_caches()[1]["offset"]
 
 
 def test_monitor_thread_safety():

@@ -235,7 +235,7 @@ def serve_run(model, tmp_path_factory):
     4-slot paged engine under a jax.profiler trace: (host event names,
     registry delta, the engine's compiled tick)."""
     d = str(tmp_path_factory.mktemp("serve_trace"))
-    cfg = ServingConfig(num_slots=4, kv_layout="paged", page_size=4,
+    cfg = ServingConfig(num_slots=4, page_size=4,
                         prefill_chunk_tokens=16)
     with Engine(model, cfg) as eng:         # start() resets serving.*
         before = monitor.all_stats()
@@ -307,7 +307,7 @@ def test_tick_programs_carry_their_names(serve_run):
 
 
 def test_queue_request_ms_is_zero_until_a_request_waits(model):
-    cfg = ServingConfig(num_slots=1, kv_layout="paged", page_size=4,
+    cfg = ServingConfig(num_slots=1, page_size=4,
                         prefill_chunk_tokens=16)
     (p,) = _prompts([5])
     with Engine(model, cfg) as eng:
@@ -329,7 +329,7 @@ def test_queue_request_ms_is_zero_until_a_request_waits(model):
 
 def test_serving_phase_spans_carry_request_ids_when_armed(model,
                                                           trace_dir):
-    cfg = ServingConfig(num_slots=2, kv_layout="paged", page_size=4,
+    cfg = ServingConfig(num_slots=2, page_size=4,
                         prefill_chunk_tokens=16)
     (p,) = _prompts([5])
     with Engine(model, cfg) as eng:

@@ -178,9 +178,6 @@ def test_mixed_sampling_falls_back_to_plain_step(model, agreeing_draft):
 def test_spec_config_validation(model, agreeing_draft):
     with pytest.raises(ValueError, match="draft_model"):
         ServingConfig(speculation_k=2).validate()
-    with pytest.raises(ValueError, match="paged"):
-        ServingConfig(speculation_k=2, draft_model=agreeing_draft,
-                      kv_layout="slots").validate()
     with pytest.raises(ValueError, match="max_seq_len"):
         Engine(model, ServingConfig(
             speculation_k=2,
@@ -376,11 +373,6 @@ def test_int8_engine_pages_halve_at_equal_load(model):
     assert peaks["int8"] * 2 == peaks["float32"], peaks
     for o in outs["int8"]:
         assert o.output_ids.size == 48
-
-
-def test_int8_requires_paged_layout():
-    with pytest.raises(ValueError, match="paged"):
-        ServingConfig(cache_dtype="int8", kv_layout="slots").validate()
 
 
 def test_int8_spec_engine_combined(model, agreeing_draft):

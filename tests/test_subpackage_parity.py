@@ -1,6 +1,7 @@
-"""Subpackage __all__ parity vs the reference + functional smoke of the
-static/sparse/fft compat surface."""
-import re
+"""Subpackage parity with the frozen public surface
+(tools/api_spec.json) + functional smoke of the static/sparse/fft compat
+surface."""
+import importlib
 
 import numpy as np
 import pytest
@@ -8,24 +9,17 @@ import pytest
 import paddle_tpu as paddle
 
 
-def _ref_all(path):
-    s = open(path).read()
-    return set(re.findall(r"'([^']+)'",
-                          re.search(r"__all__ = \[(.*?)\]", s, re.S).group(1)))
+def _assert_parity(api_spec, names):
+    for name in names:
+        mod = importlib.import_module(f"paddle_tpu.{name}")
+        missing = sorted(s for s in api_spec[f"paddle_tpu.{name}"]
+                         if not hasattr(mod, s))
+        assert missing == [], f"{name}: {missing}"
 
 
-def test_all_subpackages_parity():
-    R = "/root/reference/python/paddle"
-    for mod, path in [
-            (paddle.static, f"{R}/static/__init__.py"),
-            (paddle.static.nn, f"{R}/static/nn/__init__.py"),
-            (paddle.amp, f"{R}/amp/__init__.py"),
-            (paddle.vision, f"{R}/vision/__init__.py"),
-            (paddle.fft, f"{R}/fft.py"),
-            (paddle.sparse, f"{R}/sparse/__init__.py"),
-            (paddle.distribution, f"{R}/distribution/__init__.py")]:
-        missing = sorted(s for s in _ref_all(path) if not hasattr(mod, s))
-        assert missing == [], f"{path}: {missing}"
+def test_all_subpackages_parity(api_spec):
+    _assert_parity(api_spec, ["static", "static.nn", "amp", "vision",
+                              "fft", "sparse", "distribution"])
 
 
 def test_sparse_ops():
@@ -137,21 +131,9 @@ def test_vision_image_backend():
     assert paddle.amp.is_bfloat16_supported()
 
 
-def test_remaining_namespaces_parity():
-    import importlib
-    R = "/root/reference/python/paddle"
-    for name, path in [("incubate", f"{R}/incubate/__init__.py"),
-                       ("text", f"{R}/text/__init__.py"),
-                       ("device", f"{R}/device/__init__.py"),
-                       ("profiler", f"{R}/profiler/__init__.py"),
-                       ("jit", f"{R}/jit/__init__.py"),
-                       ("utils", f"{R}/utils/__init__.py"),
-                       ("autograd", f"{R}/autograd/__init__.py"),
-                       ("hub", f"{R}/hub.py")]:
-        refs = _ref_all(path)
-        mod = importlib.import_module(f"paddle_tpu.{name}")
-        missing = sorted(s for s in refs if not hasattr(mod, s))
-        assert missing == [], f"{name}: {missing}"
+def test_remaining_namespaces_parity(api_spec):
+    _assert_parity(api_spec, ["incubate", "text", "device", "jit",
+                              "autograd", "hub"])
 
 
 def test_viterbi_matches_bruteforce():
@@ -196,19 +178,11 @@ def test_hub_local_repo(tmp_path):
     assert paddle.hub.load(str(tmp_path), "toy", scale=3) == ("model", 3)
 
 
-def test_deep_namespaces_parity():
-    import importlib
-    R = "/root/reference/python/paddle"
-    for name in ["vision.datasets", "incubate.nn", "incubate.nn.functional",
-                 "incubate.optimizer", "metric", "nn.initializer",
-                 "nn.utils"]:
-        refs = _ref_all(f"{R}/{name.replace('.', '/')}/__init__.py")
-        mod = importlib.import_module(f"paddle_tpu.{name}")
-        missing = sorted(s for s in refs if not hasattr(mod, s))
-        assert missing == [], f"{name}: {missing}"
-    refs = _ref_all(f"{R}/linalg.py")
-    missing = sorted(s for s in refs if not hasattr(paddle.linalg, s))
-    assert missing == [], f"linalg: {missing}"
+def test_deep_namespaces_parity(api_spec):
+    _assert_parity(api_spec, [
+        "vision.datasets", "incubate.nn", "incubate.nn.functional",
+        "incubate.optimizer", "metric", "nn.initializer", "nn.utils",
+        "linalg"])
 
 
 def test_fused_layers_forward_and_train():
