@@ -252,9 +252,14 @@ def serve_phase(model, cfg, sizes, dry_run):
         f"prefill_compiled_hits={snap['prefill_compiled_hits']} "
         f"prefill_fallbacks={snap['prefill_fallbacks']} "
         f"scheduler_restarts={snap['scheduler_restarts']} "
-        f"max_active_slots={snap.get('max_active_slots')}")
+        f"max_active_slots={snap.get('max_active_slots')} "
+        f"tick_overlap_share={snap['tick_overlap_share']:.3f} "
+        f"tick_drains={snap['tick_drains']}")
     assert snap["tick_compiled_hits"] > 0
     assert snap["tick_fallbacks"] == 0
+    # ticks launched over an unread one: their inputs were donated
+    # futures, which only a device with real donation puts to the test
+    assert snap["tick_overlap_share"] > 0
     assert snap["prefill_compiled_hits"] > 0
     assert snap["prefill_fallbacks"] == 0
     assert snap["scheduler_restarts"] == 0

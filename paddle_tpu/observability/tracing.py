@@ -184,14 +184,15 @@ class Span:
         self.attrs.update(attrs)
         return self
 
-    def end(self, status="ok", winner=None, **attrs):
+    def end(self, status="ok", winner=None, *, t1=None, **attrs):
         """Close the span and push its record into the process ring.
         Idempotent: a second end is ignored (the first outcome wins —
-        the same discipline as first-answer-wins futures)."""
+        the same discipline as first-answer-wins futures).  ``t1`` is
+        the closing reading where the caller already took one."""
         if self._ended:
             return self
         self._ended = True
-        self.t1 = time.monotonic()
+        self.t1 = time.monotonic() if t1 is None else t1
         self.status = status
         if winner is not None:
             self.winner = bool(winner)
@@ -335,8 +336,9 @@ class span:  # noqa: N801 - used as ``with span(...)``
         rec = self._rec
         if rec is not None:
             _tls.phases.remove(rec)
+            # the record closes on the reading the histogram took
             rec.end(status="ok" if exc_type is None
-                    else exc_type.__name__)
+                    else exc_type.__name__, t1=t1)
         return False
 
 

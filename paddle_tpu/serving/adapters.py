@@ -369,7 +369,9 @@ class AdapterPool:
     # ---------------- per-row index plumbing ----------------
     def set_row(self, row, pool_slot):
         self._idx_np[row] = int(pool_slot)
-        self.idx._data_ = jnp.asarray(self._idx_np)
+        # a host copy: a tick in flight may still read the vector it was
+        # given, which on the CPU is a view of the array it came from
+        self.idx._data_ = jnp.asarray(self._idx_np.copy())
 
     def clear_row(self, row):
         self.set_row(row, 0)

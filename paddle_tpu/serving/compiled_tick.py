@@ -25,9 +25,14 @@ device-resident scheduler state:
   the offset advance; the batched-argmax fast path compiles its own
   leaner variant so an all-greedy batch stays bitwise the old argmax;
 - **host boundary** — per tick the host reads back ONE small
-  ``[num_slots]`` finish-code vector.  Request admission and completion
-  (and deadline eviction — a wall-clock decision) are the only times
-  token buffers cross to the host.
+  ``[num_slots]`` finish-code vector, and reads it one tick late: tick
+  n+1 is launched from tick n's outputs as they stand (device futures)
+  BEFORE tick n's codes are read, so the host's per-tick work runs under
+  the next program instead of between two.  At most one tick is in
+  flight unread; whatever reads or rewrites what it holds collects it
+  first (``drain``).  Request admission and completion (and deadline
+  eviction — a wall-clock decision) are the only times token buffers
+  cross to the host.
 
 The prefill chunk call is a member of the same program family (ISSUE
 27): ``serving_prefill_r<rows>``, ONE donated program per call over the
@@ -176,14 +181,28 @@ def request_key(sp):
 # the compiled tick
 # ---------------------------------------------------------------------------
 
+class _Launched:
+    """One tick the device has been given and the host has not read."""
+    __slots__ = ("fin", "active", "counts", "overlapped", "host_ms")
+
+    def __init__(self, fin, active, counts, overlapped):
+        self.fin = fin                  # [num_slots] finish codes, a future
+        self.active = active            # slot -> request the host ran live
+        self.counts = counts            # generated counts after this tick
+        self.overlapped = overlapped    # launched over an unread tick
+        self.host_ms = 0.0              # build + launch; deliver is added
+
+
 class CompiledServingTick:
     """Owns the device-resident scheduler state and the compiled program
     family — the per-mode jitted tick programs and the per-bucket prefill
     members — for one :class:`~paddle_tpu.serving.engine.Engine`.
 
-    ``step()`` runs one compiled tick and returns True, or returns False
-    after latching/flushing so the engine's uncompiled iteration (the
-    byte-identical fallback) runs instead."""
+    ``step()`` launches one compiled tick and returns True, or returns
+    False after latching/flushing so the engine's uncompiled iteration
+    (the byte-identical fallback) runs instead.  The tick it launched
+    stays in flight, unread, until the next ``step()`` has launched its
+    successor or a ``drain()`` collects it."""
 
     def __init__(self, engine):
         self.eng = engine
@@ -198,7 +217,9 @@ class CompiledServingTick:
         self._rep = {}                 # slot -> req at last rebuild
         self._mut_seen = -1            # engine mutation counter synced
         self._h_counts = None          # host mirror of generated counts
+        self._h_limits = None          # host copy of the token limits
         self._ahead = False            # device tokens not yet on host
+        self._pending = None           # the _Launched tick not yet read
         self._sublayers = None
         # the static blocker (speculation) is known at construction:
         # warn right away — an all-greedy speculative engine never even
@@ -554,7 +575,9 @@ class CompiledServingTick:
         """Materialize device-side token progress back into the request
         objects (the step the uncompiled lane needs before it can take
         over mid-request).  Token/seen/last bookkeeping only — stats
-        were already counted per tick."""
+        were already counted per tick.  Collects the tick in flight
+        first: its tokens are part of that progress."""
+        self.drain()
         if not self._ahead or self._dev is None:
             return
         self._ahead = False
@@ -622,6 +645,7 @@ class CompiledServingTick:
             "seen": jnp.asarray(seen), "out": jnp.asarray(out),
         }
         self._h_counts = counts.copy()
+        self._h_limits = limits
         self._rep = dict(eng._active)
         self._mut_seen = eng._mut
 
@@ -649,9 +673,23 @@ class CompiledServingTick:
                 self._note_fallback("capture", describe_escape(e), True)
                 return False
         if eng._mut != self._mut_seen or self._dev is None:
+            # a mutation of request/slot state: the tick in flight is
+            # collected (inside the flush) before the state is re-made
             self.flush_to_host()
+            if not eng._active:
+                return True             # what it delivered finished them all
             self._rebuild()
         return self._run()
+
+    def drain(self):
+        """Collect the tick in flight, if there is one: what anything
+        that reads or rewrites what it holds does first — a mutation's
+        flush and rebuild, a blocker's hand-over to the eager lane, a
+        migration export, an engine with nothing left to launch, the
+        scheduler's clean stop."""
+        if self._pending is not None:
+            stats.incr("tick.drains")
+            self._collect()
 
     def _build_args(self, active):
         """Host work before the launch: page growth, the lazy flush of
@@ -698,101 +736,155 @@ class CompiledServingTick:
                 tuple(t._data_ for t in self._caps))
 
     def _run(self):
+        """Launch the next tick, THEN read the one before it: the wait
+        for ``fin`` and the deliveries it decides run under the program
+        just launched."""
         eng = self.eng
-        attrs = {"request_ids": sorted(r.id for r in
-                                       eng._active.values()),
+        # a row at its token limit ended in the tick before this one, and
+        # the host knows without ``fin``: it is left out of the launch
+        # (growth, mirrors).  An eos is not known: its row rides the next
+        # tick dead on the device — the program masks it, as it masks an
+        # empty slot — and costs its own reservation at most one page
+        # until its delivery releases it.
+        live = {slot: req for slot, req in eng._active.items()
+                if self._h_counts[slot] < self._h_limits[slot]}
+        if not live:
+            self.drain()        # the tick in flight finishes every row
+            return True
+        attrs = {"request_ids": sorted(r.id for r in live.values()),
                  "compiled_tick": True} if tracing.enabled() else {}
         with span("serving.tick", hist="serving.decode_ms", **attrs) as tick:
-            sync_ms = self._run_phases()
-        if sync_ms is None:
-            return False
-        # the tick's time on the host: all of it but the wait for the
-        # device's ``fin``
-        stats.observe("tick.host_ms", tick.ms - sync_ms)
+            try:
+                nxt = self._launch(live)
+            except USER_TRACE_ERRORS as e:
+                # the model body cannot be traced (host reads of raw array
+                # slots, data-dependent control flow) — raised during the
+                # trace, so before the pools were donated: latch the
+                # uncompiled scheduler permanently.  Any other failure
+                # (lowering, compile, device) propagates to the scheduler's
+                # restart wrapper, which fails the futures with the real
+                # error and rebuilds the cache: the uncompiled iteration
+                # would call the same kernels.
+                self.flush_to_host()
+                self._dev = None
+                self._note_fallback("trace", describe_escape(e), True)
+                return False
+            collect_ms = self._collect()
+            self._pending = nxt
+        # the tick's time on the host: its launching call less the
+        # collection of the tick before, plus (at its own collection) its
+        # deliveries — all of it but the wait for the device's ``fin``
+        nxt.host_ms = tick.ms - collect_ms
         return True
 
-    def _run_phases(self):
-        """The tick's four phases; returns the milliseconds spent
-        waiting for the device, or None when the trace fell back."""
+    def _launch(self, live):
+        """Build and launch one tick over the ``live`` rows from the last
+        one's outputs as they stand, and settle on the host what the
+        next launch needs settled: the cache adopts the new pools and
+        offsets (the old ones are donated and gone) and the host mirrors
+        advance in lockstep, so page growth, admission and a fallback
+        see the truth without reading the device."""
+        cache = self.eng.cache
+        # TRACE_LOCK covers reading the (possibly shared) parameter
+        # slots AND the program call: while ANOTHER engine's tick
+        # traces, those slots hold tracer arrays — gathering them
+        # here would bake a leaked tracer into this engine's call
+        with TRACE_LOCK:
+            with span("serving.tick.build"):
+                jit, args = self._build_args(live)
+            with span("serving.tick.launch"):
+                (new_pools, new_off, new_last, new_counts, new_alive,
+                 new_seen, new_out, fin) = jit(*args)
+                rows = list(live)
+                # a row that ends by eos in the tick in flight keeps its
+                # device offset while this mirror moves on: a dirty flush
+                # then uploads an offset one too far for a row that is
+                # dead on the device and released at its delivery
+                offsets_np = cache.offsets.copy()
+                offsets_np[rows] += 1
+                cache.absorb_tick(new_pools, new_off, offsets_np)
+                self._dev.update(last=new_last, counts=new_counts,
+                                 alive=new_alive, seen=new_seen,
+                                 out=new_out)
+                self._h_counts[rows] += 1
+                self._ahead = True
+        return _Launched(fin, live, self._h_counts.copy(),
+                         self._pending is not None)
+
+    def _collect(self):
+        """Read the finish codes of the tick in flight — the one blocking
+        read of a steady tick; it returns when that tick ends, while its
+        successor runs — and deliver it.  Returns the milliseconds this
+        took, the wait included; 0.0 with nothing in flight."""
+        tick, self._pending = self._pending, None
+        if tick is None:
+            return 0.0
+        # a row whose request has left since the launch — ended by eos a
+        # tick earlier, cancelled — ran dead or for nobody: no token of
+        # this tick is counted or delivered for it
+        rows = {slot: req for slot, req in tick.active.items()
+                if self.eng._active.get(slot) is req}
+        with span("serving.tick.sync") as sync:
+            fin_np = np.asarray(tick.fin)
+            ending = self._ending(rows, fin_np)
+            # a row that ends hands its tokens over.  This tick's own
+            # ``out`` was donated to its successor: the newest holds the
+            # same tokens (a dead row's row is left as it was, a live
+            # row's grows past its count), and reading it waits for the
+            # tick in flight — the refill's drain would, a moment later
+            out_np = np.asarray(self._dev["out"]) if ending else None
+        with span("serving.tick.deliver") as deliver:
+            self._deliver(tick, rows, ending, out_np)
+        stats.observe("tick.host_ms", tick.host_ms + deliver.ms)
+        return sync.ms + deliver.ms
+
+    def _ending(self, rows, fin_np):
+        """{slot: reason} of the ``rows`` this tick was the last of: "eos"
+        or "length" by its finish code, None for a deadline the clock
+        has passed — same per-token granularity (and precedence over
+        eos/length) as the uncompiled ``_append_token``."""
+        now = time.monotonic()
+        evict = self.eng.scfg.deadline_policy == "evict"
+        ending = {}
+        for slot, req in rows.items():
+            if evict and req.deadline is not None and now > req.deadline:
+                ending[slot] = None
+            elif fin_np[slot]:
+                ending[slot] = "eos" if fin_np[slot] == 1 else "length"
+        return ending
+
+    def _deliver(self, tick, rows, ending, out_np):
+        """One collected tick's accounting and deliveries: counters,
+        deadline eviction, completions, releases."""
         eng = self.eng
         cache = eng.cache
-        active = dict(eng._active)
-        n_active = len(active)
-        try:
-            # TRACE_LOCK covers reading the (possibly shared) parameter
-            # slots AND the program call: while ANOTHER engine's tick
-            # traces, those slots hold tracer arrays — gathering them
-            # here would bake a leaked tracer into this engine's call
-            with TRACE_LOCK:
-                with span("serving.tick.build"):
-                    jit, args = self._build_args(active)
-                with span("serving.tick.launch"):
-                    (new_pools, new_off, new_last, new_counts, new_alive,
-                     new_seen, new_out, fin) = jit(*args)
-            with span("serving.tick.sync") as sync:
-                fin_np = np.asarray(fin)    # the per-tick host sync point
-        except USER_TRACE_ERRORS as e:
-            # the model body cannot be traced (host reads of raw array
-            # slots, data-dependent control flow) — raised during the
-            # trace, so before the pools were donated: latch the
-            # uncompiled scheduler permanently.  Any other failure
-            # (lowering, compile, device) propagates to the scheduler's
-            # restart wrapper, which fails the futures with the real
-            # error and rebuilds the cache: the uncompiled iteration
-            # would call the same kernels.
-            self.flush_to_host()
-            self._dev = None
-            self._note_fallback("trace", describe_escape(e), True)
-            return None
-        with span("serving.tick.deliver"):
-            # adopt the functionally-updated pools + offsets back into
-            # the cache (device stays current; the host offset mirror
-            # advances in lockstep so fallbacks/admission see the truth)
-            offsets_np = cache.offsets.copy()
-            offsets_np[list(active)] += 1
-            cache.absorb_tick(new_pools, new_off, offsets_np)
-            self._dev.update(last=new_last, counts=new_counts,
-                             alive=new_alive, seen=new_seen, out=new_out)
-            self._h_counts[list(active)] += 1
-            self._ahead = True
+        n_live = len(rows)
+        stats.incr("decode_steps")
+        stats.incr("tick.compiled_hits")
+        if tick.overlapped:
+            stats.incr("tick.overlapped")
+        stats.incr("slot_steps", cache.num_slots)
+        stats.incr("slot_steps_active", n_live)
+        stats.incr("tokens_generated", n_live)
+        if cache.has_state:
+            # state rows the tick moved for a request, of all it
+            # passed through the update
+            stats.incr("state.row_ticks_live", n_live)
+            stats.incr("state.row_ticks_total", cache.num_slots)
 
-            stats.incr("decode_steps")
-            stats.incr("tick.compiled_hits")
-            stats.incr("slot_steps", cache.num_slots)
-            stats.incr("slot_steps_active", n_active)
-            stats.incr("tokens_generated", n_active)
-            if cache.has_state:
-                # state rows the tick moved for a request, of all it
-                # passed through the update
-                stats.incr("state.row_ticks_live", n_active)
-                stats.incr("state.row_ticks_total", cache.num_slots)
-
-            now = time.monotonic()
-            evict = eng.scfg.deadline_policy == "evict"
-            out_np = None
-            for slot, req in active.items():
-                if evict and req.deadline is not None \
-                        and now > req.deadline:
-                    # same per-token deadline granularity (and
-                    # precedence over eos/length) as the uncompiled
-                    # _append_token
-                    from .api import DeadlineExceededError
-                    self.flush_to_host()
-                    eng._fail(req, DeadlineExceededError(
-                        f"request {req.id} exceeded its deadline after "
-                        f"{len(req.tokens)} token(s)"))
-                    stats.incr("requests_evicted_deadline")
-                    eng._release(req)
-                    continue
-                code = int(fin_np[slot])
-                if code == 0:
-                    continue
-                if out_np is None:
-                    out_np = np.asarray(new_out)
-                count = int(self._h_counts[slot])
-                req.tokens = [int(t) for t in out_np[slot, :count]]
-                req.last_token = req.tokens[-1]
-                eng._complete(req, "eos" if code == 1 else "length", now)
-                eng._release(req)
-            stats.set_value("active_slots", len(eng._active))
-        return sync.ms
+        now = time.monotonic()
+        for slot, reason in ending.items():
+            req = rows[slot]
+            count = int(tick.counts[slot])
+            req.tokens = [int(t) for t in out_np[slot, :count]]
+            req.last_token = req.tokens[-1]
+            if reason is None:
+                from .api import DeadlineExceededError
+                eng._fail(req, DeadlineExceededError(
+                    f"request {req.id} exceeded its deadline after "
+                    f"{count} token(s)"))
+                stats.incr("requests_evicted_deadline")
+            else:
+                eng._complete(req, reason, now)
+            eng._release(req)
+        stats.set_value("active_slots", len(eng._active))

@@ -841,6 +841,9 @@ class Engine:
                 with span("serving.iteration", hist="serving.tick_ms"):
                     self._iterate(admits)
                 self._iter_deadline = None
+            if self._tick is not None:
+                # a clean stop delivers what the tick in flight finished
+                self._tick.drain()
 
     def _admit_locked(self):
         """The locked block of one iteration: cancels, expiry, admission
@@ -894,8 +897,8 @@ class Engine:
             if self._can_speculate():
                 self._spec_step()
             elif self._tick is not None and self._tick.step():
-                pass        # ONE compiled program ran the tick
-            else:
+                pass        # ONE compiled program runs the tick
+            elif self._active:  # (the tick's drain may have ended them all)
                 self._decode_step()
         with span("serving.publish"):
             self._publish_pool_stats()
@@ -1492,8 +1495,9 @@ class Engine:
         tokens ride along), so a drain costs one page transfer instead
         of re-running the prompt elsewhere."""
         if self._tick is not None:
-            # the compiled tick keeps token buffers device-resident;
-            # the export ships req.tokens, so sync the host mirror first
+            # the compiled tick keeps token buffers device-resident and
+            # one tick in flight; the export ships req.tokens, so collect
+            # it and sync the host mirror first
             self._tick.flush_to_host()
         now = time.monotonic()
         for slot, req in list(self._active.items()):

@@ -551,9 +551,12 @@ class PagedKVCache:
         device state (serving/compiled_tick.py): the donated-through
         pools (``flat_pools`` order), the in-program-advanced offsets
         device array, and — when given — the host offset mirror that
-        advanced in lockstep.  The dirty flag is NOT set: device and
-        host agree after this call, so a later ``layer_caches()`` must
-        not re-upload stale Tensors over the tick's outputs."""
+        advanced in lockstep.  The dirty flag is NOT set: a later
+        ``layer_caches()`` must not re-upload stale Tensors over the
+        tick's outputs.  Device and host agree after this call for every
+        row the device still runs; for a row it has already finished by
+        eos, which rides this tick dead, the mirror is one step ahead
+        until that row's delivery releases it."""
         self.absorb_pools(pools_flat)
         self._off = off_t = Tensor(new_offsets)
         for i in self._paged:
@@ -564,8 +567,13 @@ class PagedKVCache:
     def _flush(self):
         if not self._dirty:
             return
-        self._off = off = Tensor(jnp.asarray(self.offsets))
-        self._pt = pt = Tensor(jnp.asarray(self.table))
+        # host copies, never views: the compiled tick runs one program
+        # ahead of the host, and ``jnp.asarray`` of an aligned host array
+        # is a zero-copy view on the CPU (``jnp.array`` copies it on the
+        # device, later) — the mirrors' next in-place write would move
+        # the arguments of a tick still in flight
+        self._off = off = Tensor(jnp.asarray(self.offsets.copy()))
+        self._pt = pt = Tensor(jnp.asarray(self.table.copy()))
         for i in self._paged:
             self.layers[i]["offset"] = off
             self.layers[i]["page_table"] = pt

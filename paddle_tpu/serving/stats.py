@@ -104,6 +104,13 @@ def declare_tick_stats():
     _registry.counter(PREFIX + "tick.fallbacks",
                       "scheduler iterations that latched the "
                       "uncompiled fallback")
+    _registry.counter(PREFIX + "tick.overlapped",
+                      "compiled ticks launched while the one before was "
+                      "still unread")
+    _registry.counter(PREFIX + "tick.drains",
+                      "collections of the tick in flight with no launch "
+                      "over them: a mutation, a blocker, nothing left to "
+                      "launch")
     _registry.histogram(PREFIX + "tick_ms",
                         "wall time of one scheduler iteration (ms)")
     _registry.histogram(PREFIX + "tick.host_ms",
@@ -315,7 +322,10 @@ def serving_stats():
     decode, whichever lane ran it) — plus ``tick_compiled_hits`` /
     ``tick_fallbacks`` counting iterations the ONE-program compiled
     tick executed vs iterations that latched the uncompiled scheduler
-    (flag off mid-run, speculation, unhostable sampling, hooks), and
+    (flag off mid-run, speculation, unhostable sampling, hooks),
+    ``tick_overlapped`` (ticks launched while the one before was still
+    unread), ``tick_drains`` (collections of the tick in flight that no
+    launch covered) and ``tick_overlap_share`` = overlapped / hits, and
     ``prefill_compiled_hits`` / ``prefill_fallbacks`` the same for
     prefill chunk calls (the draft model's eager calls are in
     neither); all ride the Prometheus exposition
@@ -432,6 +442,11 @@ def serving_stats():
         "tick_ms_avg": avg("tick_ms"),
         "tick_compiled_hits": g("tick.compiled_hits"),
         "tick_fallbacks": g("tick.fallbacks"),
+        "tick_overlapped": g("tick.overlapped"),
+        "tick_drains": g("tick.drains"),
+        "tick_overlap_share": (g("tick.overlapped")
+                               / g("tick.compiled_hits"))
+        if g("tick.compiled_hits") else None,
         "prefill_compiled_hits": g("prefill.compiled_hits"),
         "prefill_fallbacks": g("prefill.fallbacks"),
         "state_bytes": g("state.bytes"),

@@ -401,3 +401,138 @@ def test_fused_sampling_flag_off_keeps_per_row_path(model, tick_flag):
     c, d = run(7), run(8)
     assert not np.array_equal(c, d)
     assert not np.array_equal(a, c)
+
+
+# ---------------------------------------------------------------------------
+# one tick in flight (ISSUE 31): tick n+1 is launched before tick n's
+# finish codes are read
+# ---------------------------------------------------------------------------
+
+def _sampled(seed):
+    return SamplingParams(temperature=1.0, seed=seed)
+
+
+def _fresh_at(stream, lo):
+    """(k, token) of the first position >= ``lo`` whose token has not
+    occurred before it in ``stream``: as an eos id it ends the request
+    exactly there."""
+    for k in range(lo, stream.size):
+        if stream[k] not in stream[:k]:
+            return k, int(stream[k])
+    raise AssertionError(f"no fresh token from {lo} on in {stream}")
+
+
+def _serve_together(model, subs, compiled, num_slots=2):
+    """``subs`` submitted under the engine's lock, so that one admission
+    round takes the first ``num_slots`` of them together; returns
+    ([RequestOutput], stats after the stop, the stopped engine, the pool
+    as it was at the start)."""
+    saved = _flags._FLAGS["FLAGS_compiled_tick"]
+    _flags._FLAGS["FLAGS_compiled_tick"] = compiled
+    try:
+        eng = Engine(model, ServingConfig(
+            num_slots=num_slots, max_queue=len(subs) + 1,
+            enable_prefix_cache=False)).start()
+        try:
+            start = (eng.cache.free_page_count, eng.cache.free_slots,
+                     eng.cache.offsets.copy())
+            with eng._work:
+                futs = [eng.submit(p, max_new_tokens=mn, sampling=sp,
+                                   eos_token_id=eos)
+                        for p, mn, sp, eos in subs]
+            outs = [f.result(timeout=300) for f in futs]
+        finally:
+            eng.shutdown()          # a clean stop reads the tick in flight
+        return outs, serving_stats(), eng, start
+    finally:
+        _flags._FLAGS["FLAGS_compiled_tick"] = saved
+
+
+def test_eos_mid_stream_while_other_rows_decode_on(model, tick_flag):
+    """A request that ends by eos while its neighbour decodes on: the
+    tick after its last was launched before the host knew, and ran its
+    row dead.  Tokens, finish reason and token count are the eager
+    lane's; no token of the dead tick is delivered or counted."""
+    pa, pb = _prompts([6, 9], seed=31)
+    free, _, _, _ = _serve_together(
+        model, [(pa, 24, _sampled(5), None)], compiled=False)
+    k, eos = _fresh_at(free[0].output_ids, 4)
+    subs = [(pa, 24, _sampled(5), eos), (pb, 24, _sampled(6), None)]
+    ref, snap_u, _, _ = _serve_together(model, subs, compiled=False)
+    got, snap_c, eng, _ = _serve_together(model, subs, compiled=True)
+    for r, g in zip(ref, got):
+        np.testing.assert_array_equal(r.output_ids, g.output_ids)
+        assert r.finish_reason == g.finish_reason
+    assert got[0].finish_reason == "eos"
+    assert got[0].output_ids.size == k + 1 < 24 == got[1].output_ids.size
+    total = sum(o.output_ids.size for o in got)
+    assert snap_c["tokens_generated"] == snap_u["tokens_generated"] == total
+    assert snap_c["tick_fallbacks"] == 0 and snap_c["tick_overlapped"] > 0
+    assert eng._tick._pending is None
+
+
+def test_length_and_eos_in_one_tick_then_refills_leave_the_pool_whole(
+        model, tick_flag):
+    """A length finish (known to the host before the launch: its row is
+    left out of growth) and an eos finish (not known: its row rides one
+    tick dead, its mirrors one step ahead) in the SAME tick, the slots
+    refilled until five requests are done: every output is the eager
+    lane's, and pages, slots and the host offset mirror are back where
+    they started."""
+    pa, pb, pc, pd, pe = _prompts([5, 9, 7, 6, 4], seed=32)
+    free, _, _, _ = _serve_together(
+        model, [(pb, 24, _sampled(3), None), (pd, 24, _sampled(4), None)],
+        compiled=False)
+    kb, eos_b = _fresh_at(free[0].output_ids, 3)
+    _, eos_d = _fresh_at(free[1].output_ids, 6)
+    subs = [(pa, kb + 1, None, None),           # length, in b's eos tick
+            (pb, 24, _sampled(3), eos_b),
+            (pc, 9, None, None),
+            (pd, 24, _sampled(4), eos_d),
+            (pe, 5, _sampled(8), None)]
+    ref, _, _, _ = _serve_together(model, subs, compiled=False)
+    got, snap, eng, start = _serve_together(model, subs, compiled=True)
+    for r, g in zip(ref, got):
+        np.testing.assert_array_equal(r.output_ids, g.output_ids)
+        assert r.finish_reason == g.finish_reason
+    assert [g.finish_reason for g in got] == \
+        ["length", "eos", "length", "eos", "length"]
+    assert got[0].output_ids.size == got[1].output_ids.size == kb + 1
+    assert snap["tick_fallbacks"] == 0
+    free_pages, free_slots, offsets = start
+    assert eng.cache.free_page_count == free_pages == eng.cache.usable_pages
+    assert eng.cache.available_pages == free_pages   # no reservation left
+    assert eng.cache.free_slots == free_slots == 2
+    np.testing.assert_array_equal(eng.cache.offsets, offsets)
+    assert eng._tick._pending is None
+
+
+def test_overlap_and_drain_counters(model, tick_flag):
+    """Three long answers one after the other through ONE slot, two
+    ended by eos and one by length: every tick but a request's first is
+    launched over an unread one, and each request's end — the one
+    mutation of its life that meets a tick in flight — collects that
+    tick with no launch over it, once."""
+    (p,) = _prompts([6], seed=33)
+    free, _, _, _ = _serve_together(
+        model, [(p, 40, _sampled(9), None)], compiled=False, num_slots=1)
+    k, eos = _fresh_at(free[0].output_ids, 30)
+    tick_flag["FLAGS_compiled_tick"] = True
+    eng = Engine(model, ServingConfig(num_slots=1)).start()
+    try:
+        outs = [eng.generate(p, max_new_tokens=40, sampling=_sampled(9),
+                             eos_token_id=e, timeout=300)
+                for e in (eos, None, eos)]
+    finally:
+        eng.shutdown()
+    snap = serving_stats()
+    assert [o.finish_reason for o in outs] == ["eos", "length", "eos"]
+    assert [o.output_ids.size for o in outs] == [k + 1, 40, k + 1]
+    # a request of T tokens: T - 1 live ticks, the first launched over
+    # nothing; an eos costs one more tick, run dead over the unread last
+    assert snap["tick_compiled_hits"] == 2 * (k + 1) + 39
+    assert snap["tick_overlapped"] == 2 * k + 38
+    assert snap["tick_drains"] == 3
+    assert snap["tick_overlap_share"] >= 0.8
+    assert snap["tick_fallbacks"] == 0
+    assert snap["tokens_generated"] == sum(o.output_ids.size for o in outs)

@@ -285,6 +285,46 @@ def test_finished_request_leaves_its_state_rows_readable(tiny, tick_flag,
                     rtol=2e-4, atol=2e-5)
 
 
+def test_eos_row_keeps_its_state_through_the_dead_tick(tiny, tick_flag):
+    """The compiled tick runs one ahead of the host (ISSUE 31): the
+    tick after a row's eos is launched before the host has read it, and
+    runs the row dead.  The row's recurrent state is then what its last
+    fed token left — the eager lane's, which never ran that tick —
+    however long the neighbour decodes on."""
+    model = tiny[0]
+    short, long_one = _prompts([13, 37], seed=12)
+    sp = SamplingParams(temperature=1.0, seed=21)
+
+    def run(compiled, eos):
+        tick_flag["FLAGS_compiled_tick"] = compiled
+        with Engine(model, _cfg(num_slots=2)) as eng:
+            with eng._work:
+                futs = [eng.submit(short, max_new_tokens=24, sampling=sp,
+                                   eos_token_id=eos),
+                        eng.submit(long_one, max_new_tokens=24)]
+            outs = [f.result(timeout=300) for f in futs]
+            return outs, eng.cache.read_state(outs[0].slot)
+
+    (free, _), _ = run(False, None)
+    stream = free.output_ids
+    k = next(k for k in range(5, 20) if stream[k] not in stream[:k])
+    (e_short, e_long), e_state = run(False, int(stream[k]))
+    (c_short, c_long), c_state = run(True, int(stream[k]))
+    assert c_short.finish_reason == e_short.finish_reason == "eos"
+    np.testing.assert_array_equal(c_short.output_ids, stream[:k + 1])
+    np.testing.assert_array_equal(e_short.output_ids, stream[:k + 1])
+    np.testing.assert_array_equal(c_long.output_ids, e_long.output_ids)
+    assert c_long.output_ids.size == 24 > k + 5
+    assert sorted(c_state) == sorted(e_state) and c_state
+    for i, arrays in e_state.items():
+        for name, want in arrays.items():
+            # the lanes' programs round apart in the last bits; one fed
+            # token more would shift the whole conv window
+            np.testing.assert_allclose(c_state[i][name], want, rtol=2e-4,
+                                       atol=2e-5,
+                                       err_msg=f"layer {i} {name}")
+
+
 # (e), (f) -------------------------------------------------------------
 def _ssm_inputs(batch, rows_total, seed=0, heads=8, p=16, n=16, g=1):
     ks = jax.random.split(jax.random.PRNGKey(seed), 6)

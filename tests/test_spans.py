@@ -264,14 +264,44 @@ def test_serving_phases_reach_the_profiler_trace(serve_run):
     assert reg["serving.tick.fallbacks"] == 0
 
 
-def test_tick_host_time_is_the_tick_less_its_sync(serve_run):
-    _, reg, _ = serve_run
+def test_tick_host_time_is_the_tick_less_its_sync(model, trace_dir):
+    cfg = ServingConfig(num_slots=4, page_size=4,
+                        prefill_chunk_tokens=16)
+    with Engine(model, cfg) as eng:         # start() resets serving.*
+        eng.submit(_prompts([5])[0], max_new_tokens=2).result(timeout=300)
+        tracing.reset()                     # the programs are compiled
+        before = monitor.all_stats()
+        futs = [eng.submit(p, max_new_tokens=n)
+                for p, n in zip(_prompts([5, 21]), (24, 12))]
+        for f in futs:
+            f.result(timeout=300)
+        reg = _delta(before, monitor.all_stats())
     n = reg["serving.tick.host_ms.count"]
     assert n == reg["serving.decode_ms.count"] == \
         reg["serving.tick.sync_ms.count"] > 0
-    assert reg["serving.tick.host_ms.sum"] <= reg["serving.decode_ms.sum"]
-    assert reg["serving.tick.host_ms.sum"] == pytest.approx(
-        reg["serving.decode_ms.sum"] - reg["serving.tick.sync_ms.sum"])
+    by_name = {}
+    for ph in tracing.merge_spools(trace_dir)["phases"]:
+        by_name.setdefault(ph["name"], []).append(ph)
+    # a tick's host time is its launching call (its ``serving.tick``)
+    # less the collection of the tick before it, which that call holds
+    # -- the wait for the device and that tick's deliveries -- plus its
+    # own deliveries, wherever it is collected.  The phase records say
+    # which collections ran under a launch and which were drains.
+    tick_ids = {t["span"] for t in by_name["serving.tick"]}
+    held = {k: sum((p["t1"] - p["t0"]) * 1e3
+                   for p in by_name[f"serving.tick.{k}"]
+                   if p["parent"] in tick_ids)
+            for k in ("sync", "deliver")}
+    host, decode, sync, deliver = (
+        reg[f"serving.{k}_ms.sum"] for k in
+        ("tick.host", "decode", "tick.sync", "tick.deliver"))
+    assert host == pytest.approx(
+        decode - held["sync"] - held["deliver"] + deliver, rel=1e-6)
+    # and the identity has teeth: most ticks were read under a launch,
+    # and a host time that kept their waits would be off by them
+    assert reg["serving.tick.overlapped"] >= 0.8 * n
+    assert 0 < held["sync"] <= sync
+    assert held["sync"] > 1e3 * 1e-6 * host
     # the old histograms keep their names: an iteration holds its tick
     assert reg["serving.tick_ms.sum"] >= reg["serving.decode_ms.sum"]
 
@@ -343,9 +373,14 @@ def test_serving_phase_spans_carry_request_ids_when_armed(model,
     assert by_name["serving.tick"][0]["attrs"]["request_ids"] == [rid]
     assert by_name["serving.prefill_chunk"][0]["attrs"]["request_ids"] \
         == [rid]
+    # two ticks for three tokens: the first is read under the second's
+    # launch (its ``serving.tick``), the second is drained, with nothing
+    # left to launch, straight under its iteration
     tick_ids = {t["span"] for t in by_name["serving.tick"]}
-    assert all(s["parent"] in tick_ids
-               for s in by_name["serving.tick.sync"])
+    iter_ids = {t["span"] for t in by_name["serving.iteration"]}
+    parents = [s["parent"] for s in by_name["serving.tick.sync"]]
+    assert len(parents) == len(tick_ids) == 2
+    assert parents[0] in tick_ids and parents[1] in iter_ids
     # the request's own trace is whole and alone
     (tr,) = merged["traces"]
     assert tr["decision_count"] == 1
