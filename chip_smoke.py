@@ -12,7 +12,12 @@ seed), and checks what comes out by the repo's own means:
    together so slots refill mid-flight, one checked against
    ``model.generate()`` on the same chip; then the Pallas decode kernel
    against the XLA gather read at the tick's shapes, f32 and int8 pages.
-3. hybrid — with >= 4 devices: ``ParallelGPTForCausalLM`` at the same
+3. sparse — a small ``CohereMoeForCausalLM`` (window and full attention
+   layers with page tables of their own, routed and shared experts of
+   which 4 of 16 are held) through the same engine, at shapes the
+   kernels host: which lane the windowed paged read and the grouped
+   expert product took is printed, and on a TPU has to be the kernel's.
+4. hybrid — with >= 4 devices: ``ParallelGPTForCausalLM`` at the same
    widths under ``fleet.init`` (mp 2, dp the rest) through the same
    ``CompiledTrainStep``, losses against phase 1.
 
@@ -341,6 +346,73 @@ def paged_kernel_phase(cfg, sizes):
         assert err <= PAGED_KERNEL_ATOL
 
 
+def sparse_phase(dry_run):
+    """The sparse-expert family through the compiled tick and the prefill
+    member; a lane that silently fell to XLA on the chip shows here."""
+    import paddle_tpu as paddle
+    from paddle_tpu.models.cohere_moe import (CohereMoeConfig,
+                                              CohereMoeForCausalLM)
+    from paddle_tpu.serving import Engine, ServingConfig
+    from paddle_tpu.serving.stats import serving_stats
+    from paddle_tpu.utils import monitor
+
+    paddle.seed(SEED)
+    # 8 kv heads of 128 in pages of 16, hidden and expert width 256: the
+    # paged kernel and expert_gmm both host these
+    cfg = CohereMoeConfig(
+        vocab_size=512, hidden_size=256, num_layers=4, num_heads=16,
+        num_kv_heads=8, head_dim=128, sliding_window=64,
+        intermediate_size=256, num_experts_published=16,
+        num_experts_per_tok=4, num_shared_experts=2, held_experts=(4, 4),
+        max_seq_len=256, initializer_range=0.05)
+    model = CohereMoeForCausalLM(cfg)
+    model.eval()
+    rng = np.random.default_rng(SEED + 2)
+    lens, new = [100, 150, 40, 90], [40, 24, 48, 32]
+    prompts = [rng.integers(0, cfg.vocab_size, (n,)).astype(np.int32)
+               for n in lens]
+    before = monitor.all_stats()
+    with warnings.catch_warnings(record=True) as caught:
+        warnings.simplefilter("always")
+        eng = Engine(model, ServingConfig(
+            num_slots=4, page_size=16, prefill_chunk_tokens=32,
+            enable_prefix_cache=False)).start()
+        try:
+            futs = [eng.submit(p, max_new_tokens=n)
+                    for p, n in zip(prompts, new)]
+            outs = [f.result(timeout=900) for f in futs]
+        finally:
+            eng.shutdown()
+    no_fallback_warnings(caught, "sparse")
+    assert [len(o.output_ids) for o in outs] == new
+    snap, after = serving_stats(), monitor.all_stats()
+    lanes = {name: after.get(name, 0) - before.get(name, 0)
+             for name in ("pallas.paged_decode.kernel",
+                          "pallas.paged_decode.xla_lane",
+                          "pallas.expert_gmm.kernel",
+                          "pallas.expert_gmm.xla_lane")}
+    say(f"sparse: tick_compiled_hits={snap['tick_compiled_hits']} "
+        f"tick_fallbacks={snap['tick_fallbacks']} "
+        f"prefill_fallbacks={snap['prefill_fallbacks']} "
+        f"tick_overlap_share={snap['tick_overlap_share']:.3f} "
+        f"expert_pairs_per_token={snap['expert_pairs_per_token']:.3f} "
+        f"window_pages_held_share={snap['window_pages_held_share']:.3f} "
+        f"paged_decode_kernel_traces={snap['paged_decode_kernel_traces']} "
+        f"paged_decode_xla_lane_traces="
+        f"{snap['paged_decode_xla_lane_traces']}")
+    say("sparse: traces in this phase " + " ".join(
+        f"{k}={v}" for k, v in lanes.items()))
+    assert snap["tick_compiled_hits"] > 0 and snap["tick_fallbacks"] == 0
+    assert snap["prefill_compiled_hits"] > 0
+    assert snap["prefill_fallbacks"] == 0
+    assert snap["tick_overlap_share"] > 0
+    assert 0 < snap["window_pages_held_share"] < 1
+    if not dry_run:
+        assert lanes["pallas.paged_decode.kernel"] > 0, lanes
+        assert lanes["pallas.expert_gmm.kernel"] > 0, lanes
+        assert lanes["pallas.expert_gmm.xla_lane"] == 0, lanes
+
+
 def hybrid_phase(cfg, sizes, one_chip_losses, dry_run):
     import jax
     import paddle_tpu.distributed as dist
@@ -418,6 +490,7 @@ def main():
     paged_kernel_phase(cfg, sizes)
     del model
     gc.collect()
+    sparse_phase(args.dry_run)
     if jax.device_count() >= 4:
         hybrid_phase(cfg, sizes, losses, args.dry_run)
     else:

@@ -9,6 +9,8 @@ fuses into neighbors; flash attention uses the Pallas kernel).
 """
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 
@@ -280,10 +282,95 @@ def _cache_attend(qa, ck, cv, off, scale):
     return out.astype(qa.dtype)
 
 
+#: keys one step of the blocked chunk read gathers and scores at once
+_CHUNK_KEY_BLOCK = 512
+#: a read whose float32 scores over the table's whole capacity are no
+#: larger than this is one block and no loop
+_ONE_BLOCK_SCORE_BYTES = 32 << 20
+
+
+@functools.partial(jax.jit, static_argnames=("psz", "scale", "window"))
+def _paged_block_attend(qa, kp, vp, pt, off, ks=None, vs=None, *, psz,
+                        scale, window):
+    """Attention of ``qa`` [B, S, Hq, D] at positions ``off .. off + S -
+    1`` over each row's LIVE pages — and, with ``window``, over its
+    in-window pages alone — in blocks of ``_CHUNK_KEY_BLOCK`` keys:
+    a block's pages are gathered through the page table, scored in the
+    operands' type with float32 accumulation, and folded into a float32
+    online softmax, so nothing of size ``[Hq, S, capacity]`` ever
+    exists.  The loop runs as many blocks as the longest row needs; a
+    read small enough over the whole table (``_ONE_BLOCK_SCORE_BYTES``:
+    a single-token read, a short chunk of a few rows over a short slot)
+    is one block without a loop.
+    With ``window`` the table is a ring (logical page ``p`` at entry
+    ``p % N``; the same entry where the table spans the slot).  A
+    ``jax.jit`` of its own inside the caller's program: the layers of a
+    model trace it once between them, not once each."""
+    import math as _math
+    from ....quantization import dequantize_kv
+    b, s, h_q, d = qa.shape
+    h_kv, n_tab = kp.shape[2], pt.shape[1]
+    rep = h_q // h_kv
+    sc = scale if scale is not None else 1.0 / _math.sqrt(d)
+    one_block = b * h_q * s * n_tab * psz * 4 <= _ONE_BLOCK_SCORE_BYTES
+    kb = n_tab if one_block else max(1, min(n_tab, _CHUNK_KEY_BLOCK // psz))
+    blk = kb * psz
+    quant = ks is not None
+    kdt = jnp.float32 if quant else kp.dtype
+    cdt = jnp.promote_types(qa.dtype, kdt)
+    qg = qa.astype(cdt).reshape(b, s, h_kv, rep, d)
+    q_pos = off[:, None] + jnp.arange(s, dtype=jnp.int32)[None, :]  # [b,s]
+    first_tok = jnp.zeros_like(off) if window is None else \
+        jnp.maximum(off - (window - 1), 0)
+    first_page = first_tok // psz
+    last_page = (off + (s - 1)) // psz
+    n_blocks = jnp.max(-(-(last_page - first_page + 1) // kb))
+    pt = pt.astype(jnp.int32)
+
+    def gather(pool, scales, phys):
+        if quant:
+            return dequantize_kv(pool[phys], scales[phys])
+        return pool[phys]
+
+    def body(j, carry):
+        m_prev, l_prev, acc = carry
+        lp = first_page[:, None] + j * kb + \
+            jnp.arange(kb, dtype=jnp.int32)[None, :]             # [b, kb]
+        idx = lp % n_tab if window is not None else \
+            jnp.minimum(lp, n_tab - 1)
+        phys = jnp.take_along_axis(pt, idx, axis=1)
+        kblk = gather(kp, ks, phys).reshape(b, blk, h_kv, d)
+        vblk = gather(vp, vs, phys).reshape(b, blk, h_kv, d)
+        k_pos = (lp[:, :, None] * psz
+                 + jnp.arange(psz, dtype=jnp.int32)).reshape(b, blk)
+        sco = jnp.einsum("bqhrd,bkhd->bhrqk", qg, kblk.astype(cdt),
+                         preferred_element_type=jnp.float32) * sc
+        mask = k_pos[:, None, :] <= q_pos[:, :, None]            # [b,s,blk]
+        if window is not None:
+            mask &= k_pos[:, None, :] > q_pos[:, :, None] - window
+        sco = jnp.where(mask[:, None, None], sco, -1e30)
+        m_new = jnp.maximum(m_prev, jnp.max(sco, axis=-1, keepdims=True))
+        p = jnp.where(mask[:, None, None], jnp.exp(sco - m_new), 0.0)
+        alpha = jnp.exp(m_prev - m_new)
+        l_new = alpha * l_prev + jnp.sum(p, axis=-1, keepdims=True)
+        pv = jnp.einsum("bhrqk,bkhd->bhrqd", p.astype(vblk.dtype), vblk,
+                        preferred_element_type=jnp.float32)
+        return m_new, l_new, alpha * acc + pv
+
+    init = (jnp.full((b, h_kv, rep, s, 1), -1e30, jnp.float32),
+            jnp.zeros((b, h_kv, rep, s, 1), jnp.float32),
+            jnp.zeros((b, h_kv, rep, s, d), jnp.float32))
+    _, l, acc = body(0, init) if one_block else \
+        jax.lax.fori_loop(0, n_blocks, body, init)
+    out = acc / jnp.maximum(l, 1e-30)
+    return jnp.transpose(out, (0, 3, 1, 2, 4)).reshape(b, s, h_q, d) \
+        .astype(qa.dtype)
+
+
 def paged_masked_multihead_attention(q, k, v, k_pool, v_pool, page_table,
                                      offset, page_size, scale=None,
                                      k_scale=None, v_scale=None,
-                                     name=None):
+                                     window=None, name=None):
     """Decode/chunked-prefill attention against a PAGED KV cache
     (serving/paged_kv.py — the vLLM PagedAttention layout kept
     static-shape for TPU).
@@ -294,10 +381,11 @@ def paged_masked_multihead_attention(q, k, v, k_pool, v_pool, page_table,
     offset: int32 [B] tokens already cached per row.  Writes the new
     K/V through the page table at offset..offset+S per row (rows whose
     table entries are 0 scatter into the reserved scratch page — how
-    free/ungrown slots ride the static batch harmlessly), gathers each
-    row's logical [N*page_size] cache view, and attends causally with
-    exactly `masked_multihead_attention`'s math — so paged and dense
-    caches holding the same values produce bit-identical outputs.
+    free/ungrown slots ride the static batch harmlessly), then reads
+    each row's live pages in blocks of keys and attends causally with
+    `masked_multihead_attention`'s math in an online softmax
+    (`_paged_block_attend`) — paged and dense caches holding the same
+    values agree to float32 rounding.
 
     Quantized KV storage: when ``k_scale``/``v_scale`` ([P, page_size]
     float32 per-page scale arrays) are passed, the pools hold int8 (or
@@ -305,7 +393,7 @@ def paged_masked_multihead_attention(q, k, v, k_pool, v_pool, page_table,
     with its own scale (`paddle_tpu.quantization.quantize_kv_rows`) and
     scatters value + scale through the same page table; the read
     dequantizes fused into the gather (scale × int8 feeds the attention
-    matmul directly), then runs the identical `_cache_attend` math.
+    matmul directly).
     Returns (out, k_pool', v_pool', k_scale', v_scale') in this mode.
 
     On a TPU (and in Pallas interpret mode) the single-token decode
@@ -315,15 +403,27 @@ def paged_masked_multihead_attention(q, k, v, k_pool, v_pool, page_table,
     the scalar-prefetched page table, and the query heads that share a
     kv head are the rows of one MXU product — its time follows the
     contexts, not the slots' capacity.  Its online softmax is
-    numerically (not bitwise) equivalent to the XLA gather read, which
-    serves everything else: prefill chunks, CPUs, programs partitioned
-    over a mesh (the kernel carries no ``shard_map``), and pools whose
-    pages the kernel cannot view as whole 128-lane rows
+    numerically (not bitwise) equivalent to the XLA blocked read, which
+    serves single-token reads everywhere else: CPUs, programs
+    partitioned over a mesh (the kernel carries no ``shard_map``), and
+    pools whose pages the kernel cannot view as whole 128-lane rows
     (`paged_decode_pages_per_step` answers 0: a rule on ``page_size``,
     kv heads, head size).  Which lane a single-token read took is
     decided when the op is traced and counted there, once a trace:
     ``pallas.paged_decode.kernel`` / ``pallas.paged_decode.xla_lane``
     (`serving_stats()` shows both).
+
+    Every other read — a prefill chunk (S > 1), a single token where
+    the kernel does not run — is `_paged_block_attend`: the new keys are
+    written first, then a float32 online softmax runs over as many
+    blocks as the longest row has, never over a long slot's capacity.
+
+    ``window`` (a layer that sees only its latest ``window`` positions:
+    key ``t`` is visible to query ``s`` iff ``s - window < t <= s``)
+    bounds every read from below as well: the kernel starts at the row's
+    first in-window page, the XLA lanes mask by position.  The page
+    table is then a ring (logical page ``p`` at entry ``p % N``, N the
+    table's width; see serving/paged_kv.py).
     """
     psz = int(page_size)
     quant = k_scale is not None
@@ -338,7 +438,8 @@ def paged_masked_multihead_attention(q, k, v, k_pool, v_pool, page_table,
             off_concrete = _np.asarray(raw)
     except Exception:
         pass   # traced offset: caller owns the bound
-    if off_concrete is not None and (off_concrete + s_new > s_cap).any():
+    if window is None and off_concrete is not None \
+            and (off_concrete + s_new > s_cap).any():
         raise ValueError(
             f"paged KV cache overflow: offset {off_concrete} + {s_new} "
             f"new tokens > page-table capacity {s_cap}")
@@ -348,12 +449,12 @@ def paged_masked_multihead_attention(q, k, v, k_pool, v_pool, page_table,
     kernels_on = s_new == 1 and _fa._unsharded_kernels_on()
 
     def fn(qa, ka, va, kp, vp, pt, off, *scales):
-        from ....quantization import dequantize_kv, quantize_kv_rows
+        from ....quantization import quantize_kv_rows
         b, s, h_q, d = qa.shape
         off = off.astype(jnp.int32)
         pos = off[:, None] + jnp.arange(s, dtype=jnp.int32)[None, :]
-        page_ids = jnp.take_along_axis(pt.astype(jnp.int32),
-                                       pos // psz, axis=1)
+        entry = pos // psz if window is None else (pos // psz) % n_pages
+        page_ids = jnp.take_along_axis(pt.astype(jnp.int32), entry, axis=1)
         in_page = pos % psz
         if quant:
             ks, vs = scales
@@ -367,8 +468,9 @@ def paged_masked_multihead_attention(q, k, v, k_pool, v_pool, page_table,
         else:
             kp = kp.at[page_ids, in_page].set(ka.astype(kp.dtype))
             vp = vp.at[page_ids, in_page].set(va.astype(vp.dtype))
-        use_kernel = kernels_on and _fa.paged_decode_pages_per_step(
-            psz, kp.shape[2], d, kp.dtype.itemsize) > 0
+        use_kernel = kernels_on and not (quant and window is not None) \
+            and _fa.paged_decode_pages_per_step(
+                psz, kp.shape[2], d, kp.dtype.itemsize) > 0
         if s_new == 1:
             _monitor.incr("pallas.paged_decode.kernel" if use_kernel
                           else "pallas.paged_decode.xla_lane")
@@ -377,18 +479,12 @@ def paged_masked_multihead_attention(q, k, v, k_pool, v_pool, page_table,
                 qa[:, 0], kp, vp, pt.astype(jnp.int32), off,
                 scale=scale,
                 k_scale=ks if quant else None,
-                v_scale=vs if quant else None)[:, None]
+                v_scale=vs if quant else None, window=window)[:, None]
         else:
-            h_kv = kp.shape[2]
-            if quant:
-                kf = dequantize_kv(kp[pt], ks[pt]) \
-                    .reshape(b, n_pages * psz, h_kv, d)
-                vf = dequantize_kv(vp[pt], vs[pt]) \
-                    .reshape(b, n_pages * psz, h_kv, d)
-            else:
-                kf = kp[pt].reshape(b, n_pages * psz, h_kv, d)
-                vf = vp[pt].reshape(b, n_pages * psz, h_kv, d)
-            out = _cache_attend(qa, kf, vf, off, scale)
+            out = _paged_block_attend(
+                qa, kp, vp, pt, off, *((ks, vs) if quant else ()), psz=psz,
+                scale=None if scale is None else float(scale),
+                window=window)
         if quant:
             return out, kp, vp, ks, vs
         return out, kp, vp
@@ -411,13 +507,13 @@ def paged_cache_attention(q, k, v, cache, scale=None):
             q, k, v, cache["k_pool"], cache["v_pool"],
             cache["page_table"], cache["offset"], cache["page_size"],
             scale=scale, k_scale=cache["k_scale"],
-            v_scale=cache["v_scale"])
+            v_scale=cache["v_scale"], window=cache.get("window"))
         cache["k_scale"], cache["v_scale"] = ks, vs
     else:
         out, kp, vp = paged_masked_multihead_attention(
             q, k, v, cache["k_pool"], cache["v_pool"],
             cache["page_table"], cache["offset"], cache["page_size"],
-            scale=scale)
+            scale=scale, window=cache.get("window"))
     cache["k_pool"], cache["v_pool"] = kp, vp
     return out
 

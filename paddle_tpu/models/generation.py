@@ -190,6 +190,14 @@ def generate(model, input_ids, max_new_tokens=32, temperature=0.0,
                     break
             return ids
 
+        windows = getattr(cfg, "layer_windows", None)
+        if windows is not None and any(w is not None for w in windows()):
+            raise NotImplementedError(
+                f"generate(use_cache=True) for {type(model).__name__}: "
+                "its sliding_attention layers see a window of positions, "
+                "which the dense caches built here do not have; serve it "
+                "through serving.Engine (page tables by layer kind) or "
+                "pass use_cache=False")
         if hasattr(model, "init_caches"):
             # a model whose layers do not all keep keys and values
             # builds its own per-layer caches

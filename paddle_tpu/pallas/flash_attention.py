@@ -803,7 +803,7 @@ def _mxu_f32(lhs, rhs, rhs_contracts):
 
 def _paged_decode_kernel(pt_ref, off_ref, q_ref, k_hbm, v_hbm, *rest,
                          scale, page_size, group, token_rows,
-                         q_heads_a_row, quant):
+                         q_heads_a_row, quant, window=None):
     """One row of a single-token decode: the row's LIVE pages, ``group``
     of them a step, every kv head at once.
 
@@ -830,7 +830,15 @@ def _paged_decode_kernel(pt_ref, off_ref, q_ref, k_hbm, v_hbm, *rest,
     is float32 (``_mxu_f32``).  Quantized pools: a token's scale
     multiplies its columns of the scores (K) and of the probabilities
     (V); ``ks_ref`` / ``vs_ref`` hold the row's scales already laid out
-    a column each."""
+    a column each.
+
+    ``window`` (static; None: no lower bound, the program is what it was
+    before windows existed) bounds the row's loop from below as well: it
+    starts at the row's first in-window page, ``max(offset + 1 - window,
+    0) // page_size``, the partial first page is masked by position, and
+    the page table is read as a ring (logical page ``p`` at entry ``p %
+    N``).  A page that fell out of the window costs neither a step nor a
+    copy."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -848,16 +856,26 @@ def _paged_decode_kernel(pt_ref, off_ref, q_ref, k_hbm, v_hbm, *rest,
     def live_pages(row):
         return off_ref[row] // page_size + 1
 
+    def first_page(row):
+        return jnp.maximum(off_ref[row] + 1 - window, 0) // page_size
+
+    def pages_to_read(row):
+        # no window: the expression (and so the program) of before
+        return live_pages(row) if window is None else \
+            live_pages(row) - first_page(row)
+
     def wide(x):
         """int8 / fp8 values as bfloat16, which holds each of them."""
         return x.astype(jnp.bfloat16) if x.dtype.itemsize == 1 else x
 
     def copies(row, g, slot, act):
         """``act`` on the page copies of group ``g`` of ``row``."""
-        first = g * group
+        first = g * group if window is None else \
+            first_page(row) + g * group
 
         def one(j, carry):
-            page = pt_ref[row, first + j]
+            page = pt_ref[row, first + j] if window is None else \
+                pt_ref[row, (first + j) % pt_ref.shape[1]]
             at = pl.ds(pl.multiple_of(j * page_rows, page_rows), page_rows)
             act(pltpu.make_async_copy(k_hbm.at[page], kbuf.at[slot, at],
                                       sem.at[0, slot]))
@@ -877,7 +895,7 @@ def _paged_decode_kernel(pt_ref, off_ref, q_ref, k_hbm, v_hbm, *rest,
         copies(0, 0, 0, lambda c: c.start())
 
     off = off_ref[b]
-    n_groups = (live_pages(b) + group - 1) // group
+    n_groups = (pages_to_read(b) + group - 1) // group
     slot0 = slot_ref[0]
     q = q_ref[...]
     col = jax.lax.broadcasted_iota(jnp.int32, (h, cols), 1)
@@ -902,7 +920,12 @@ def _paged_decode_kernel(pt_ref, off_ref, q_ref, k_hbm, v_hbm, *rest,
         if quant:
             at = pl.ds(pl.multiple_of(g * cols, cols), cols)
             s = s * ks_ref[:, at]
-        s = jnp.where(own & (g * tokens + tok <= off), s, NEG_INF)
+        if window is None:
+            seen = g * tokens + tok <= off
+        else:
+            pos = first_page(b) * page_size + g * tokens + tok
+            seen = (pos <= off) & (pos > off - window)
+        s = jnp.where(own & seen, s, NEG_INF)
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
         p = jnp.exp(s - m_new)
         alpha = jnp.exp(m_prev - m_new)
@@ -921,7 +944,8 @@ def _paged_decode_kernel(pt_ref, off_ref, q_ref, k_hbm, v_hbm, *rest,
 
 
 def paged_decode_attention(q, k_pool, v_pool, page_table, offsets,
-                           scale=None, k_scale=None, v_scale=None):
+                           scale=None, k_scale=None, v_scale=None,
+                           window=None):
     """Single-token decode attention over a paged KV cache.
 
     q: [B, H, D] this step's queries; k_pool/v_pool: [P, page_size,
@@ -943,7 +967,14 @@ def paged_decode_attention(q, k_pool, v_pool, page_table, offsets,
     and a query head on the lanes of its kv head.  The caller asks
     ``paged_decode_pages_per_step`` first; a pool it answers 0 for is
     read by the XLA gather lane.
+
+    ``window``: row b attends positions ``offsets[b] - window <  t <=
+    offsets[b]`` through a ring page table (``_paged_decode_kernel``);
+    not with quantized pools.
     """
+    if window is not None and k_scale is not None:
+        raise ValueError("paged decode kernel: quantized pools have no "
+                         "window lane; the caller reads them by XLA")
     d = q.shape[-1]
     psz, h_kv = k_pool.shape[1:3]
     group = paged_decode_pages_per_step(psz, h_kv, d,
@@ -958,12 +989,14 @@ def paged_decode_attention(q, k_pool, v_pool, page_table, offsets,
     return _paged_decode_call(
         q, k_pool, v_pool, page_table, offsets, k_scale, v_scale,
         scale=float(scale) if scale is not None else 1.0 / math.sqrt(d),
-        group=group, interpret=_interpret())
+        group=group, interpret=_interpret(),
+        window=None if window is None else int(window))
 
 
-@functools.partial(jax.jit, static_argnames=("scale", "group", "interpret"))
+@functools.partial(jax.jit, static_argnames=("scale", "group", "interpret",
+                                             "window"))
 def _paged_decode_call(q, k_pool, v_pool, page_table, offsets, k_scale,
-                       v_scale, *, scale, group, interpret):
+                       v_scale, *, scale, group, interpret, window=None):
     """``paged_decode_attention`` at a fixed step size.  A program of its
     own inside the caller's: the layers of a model trace and lower ONE
     kernel between them (16 of them cost Mistral's tick 4 s of set-up
@@ -1011,7 +1044,7 @@ def _paged_decode_call(q, k_pool, v_pool, page_table, offsets, k_scale,
     kernel = functools.partial(
         _paged_decode_kernel, scale=scale, page_size=psz, group=group,
         token_rows=token_rows, q_heads_a_row=n_rep * heads_a_row,
-        quant=quant)
+        quant=quant, **({} if window is None else {"window": window}))
     buf = pltpu.VMEM((2, group * page_rows, 128), k_pool.dtype)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,

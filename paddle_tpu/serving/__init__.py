@@ -20,7 +20,7 @@ from .api import (  # noqa: F401
     RecurrentStateError, RequestCancelledError, RequestOutput,
     SamplingParams,
     SchedulerStallError, ServingConfig, ServingError,
-    UnknownAdapterError,
+    UnknownAdapterError, WindowLayerError,
 )
 from .compiled_tick import (  # noqa: F401
     CompiledServingTick, TickFallbackWarning,
@@ -39,7 +39,7 @@ __all__ = [
     "PagedKVCache", "PrefixTree", "ServingError",
     "QueueFullError", "DeadlineExceededError", "EngineShutdownError",
     "SchedulerStallError", "NoReplicaError", "PageMigrationError",
-    "RequestCancelledError", "RecurrentStateError",
+    "RequestCancelledError", "RecurrentStateError", "WindowLayerError",
     "AdapterConfigError", "UnknownAdapterError", "AdapterPool",
     "serving_stats", "reset_serving_stats", "reset_router_stats",
     "ServingRouter", "RouterConfig", "HashRing", "ServingFleet",

@@ -96,6 +96,18 @@ class RecurrentStateError(ServingError, ValueError):
     names the mechanism — never a wrong answer mid-decode."""
 
 
+class WindowLayerError(ServingError, ValueError):
+    """The configuration asks for a mechanism that cannot yet serve a
+    model with sliding_attention layers (what
+    ``model.config.layer_windows()`` reports): such a layer keeps a ring
+    of pages a slot and gives up the page that fell out of its window, so
+    a shared prefix's pages (``enable_prefix_cache``), a speculative
+    window's ``rollback`` and page export / adoption have nothing to
+    share, rewind or send for those layers.  Raised at ``Engine``
+    construction (and by the cache calls themselves) and names the layer
+    kind — never a wrong answer mid-decode."""
+
+
 @dataclass(frozen=True)
 class SamplingParams:
     """Per-request decoding knobs — the same semantics (and HF processor

@@ -12,8 +12,7 @@ occupancy the Python glue WAS the tokens/sec ceiling.
 :class:`CompiledServingTick` captures the full tick as one program over
 device-resident scheduler state:
 
-- **state** — last tokens, generated-token ring buffers, per-slot
-  counts/limits/eos ids, alive masks, cache offsets, per-slot sampling
+- **state** — last tokens, per-slot counts/limits/eos ids, alive masks, cache offsets, per-slot sampling
   params (temperature/top-k/top-p/repetition-penalty vectors + seen
   masks + per-request RNG keys) all live as fixed-shape device arrays;
   the page pools and page table are the ``PagedKVCache``'s own device
@@ -24,15 +23,17 @@ device-resident scheduler state:
   chain + sampling, the token append, eos/max-length finish codes, and
   the offset advance; the batched-argmax fast path compiles its own
   leaner variant so an all-greedy batch stays bitwise the old argmax;
-- **host boundary** — per tick the host reads back ONE small
-  ``[num_slots]`` finish-code vector, and reads it one tick late: tick
+- **host boundary** — per tick the host reads back two small
+  ``[num_slots]`` vectors, the finish codes and the tick's own tokens
+  (appended to the requests' lists there: a live request's list grows
+  every tick), and reads them one tick late: tick
   n+1 is launched from tick n's outputs as they stand (device futures)
   BEFORE tick n's codes are read, so the host's per-tick work runs under
   the next program instead of between two.  At most one tick is in
   flight unread; whatever reads or rewrites what it holds collects it
   first (``drain``).  Request admission and completion (and deadline
-  eviction — a wall-clock decision) are the only times token buffers
-  cross to the host.
+  eviction — a wall-clock decision) are the only times the scheduler
+  state is re-made from the host.
 
 The prefill chunk call is a member of the same program family (ISSUE
 27): ``serving_prefill_r<rows>``, ONE donated program per call over the
@@ -178,17 +179,55 @@ def request_key(sp):
 
 
 # ---------------------------------------------------------------------------
+# routing counts of expert layers: out of the program with its outputs
+# ---------------------------------------------------------------------------
+
+def routing_counts(views, valid_len):
+    """int32 [E_held + 2] from the ``moe_counts`` an expert layer left in
+    its cache view (models/cohere_moe.py): the (token, held expert) pairs
+    computed for each held expert, summed over the layers; then the
+    experts hit, summed over the layers; then the tokens routed (real
+    positions x layers).  None where no view carries any."""
+    counts = [v["moe_counts"]._data_ for v in views if "moe_counts" in v]
+    if not counts:
+        return None
+    hit = sum(jnp.sum(c > 0) for c in counts)
+    routed = jnp.sum(valid_len) * len(counts)
+    return jnp.concatenate([sum(counts), jnp.stack([hit, routed])]) \
+        .astype(jnp.int32)
+
+
+def count_routing(vec, prefill=False):
+    """Add one program's ``routing_counts`` (on the host) to the
+    registry: ``serving.moe.pairs_local``, ``.experts_hit``,
+    ``.tokens_routed`` and ``.pairs_by_expert.<e>``; a prefill member's
+    pairs and experts hit under ``serving.moe.prefill.*`` as well (a
+    tick's are the rest)."""
+    pairs = vec[:-2]
+    stats.incr("moe.pairs_local", int(pairs.sum()))
+    stats.incr("moe.experts_hit", int(vec[-2]))
+    stats.incr("moe.tokens_routed", int(vec[-1]))
+    if prefill:
+        stats.incr("moe.prefill.pairs", int(pairs.sum()))
+        stats.incr("moe.prefill.experts_hit", int(vec[-2]))
+    for e, n in enumerate(pairs.tolist()):
+        if n:
+            stats.incr(f"moe.pairs_by_expert.{e}", n)
+
+
+# ---------------------------------------------------------------------------
 # the compiled tick
 # ---------------------------------------------------------------------------
 
 class _Launched:
     """One tick the device has been given and the host has not read."""
-    __slots__ = ("fin", "active", "counts", "overlapped", "host_ms")
+    __slots__ = ("fin", "tok", "active", "overlapped", "host_ms", "moe")
 
-    def __init__(self, fin, active, counts, overlapped):
+    def __init__(self, fin, tok, active, overlapped, moe=None):
+        self.moe = moe                  # routing counts, a future, or None
+        self.tok = tok                  # [num_slots] this tick's tokens
         self.fin = fin                  # [num_slots] finish codes, a future
         self.active = active            # slot -> request the host ran live
-        self.counts = counts            # generated counts after this tick
         self.overlapped = overlapped    # launched over an unread tick
         self.host_ms = 0.0              # build + launch; deliver is added
 
@@ -214,11 +253,9 @@ class CompiledServingTick:
         self._sigs = {}                # (mode, donating) -> arg avals
         self._prefill = {}             # (rows, donating) -> compiled member
         self._dev = None               # device state dict
-        self._rep = {}                 # slot -> req at last rebuild
         self._mut_seen = -1            # engine mutation counter synced
         self._h_counts = None          # host mirror of generated counts
         self._h_limits = None          # host copy of the token limits
-        self._ahead = False            # device tokens not yet on host
         self._pending = None           # the _Launched tick not yet read
         self._sublayers = None
         # the static blocker (speculation) is known at construction:
@@ -329,7 +366,7 @@ class CompiledServingTick:
     # ------------------------------------------------------------------
 
     def _replay_model(self, tokens, pools, pt, off, caps, lora_idx=None,
-                      state_rows=None, valid_len=None):
+                      state_rows=None, valid_len=None, pt_w=None):
         """The captured model call, replayed while ``jax.jit`` traces a
         member of the family: ``tokens`` [rows, s] against the flat
         ``pools`` through page table ``pt`` [rows, pages_per_slot] at
@@ -338,28 +375,33 @@ class CompiledServingTick:
         the pool's persistent per-slot vector.  ``state_rows`` and
         ``valid_len`` are for the layers that keep a recurrent state:
         each row's state row (None: row i is slot i) and how many of its
-        positions are real.  Returns the [rows, s, V] logits and the
-        functionally-updated pools, flat."""
+        positions are real; ``pt_w`` is the window layers' ring table
+        (None: the model has one kind of paged layer).  Returns the
+        [rows, s, V] logits, the functionally-updated pools, flat, and
+        the routing counts of the expert layers (`routing_counts`; None
+        where no layer reports any)."""
         eng = self.eng
         cache = eng.cache
         tracer = BindTracer(rng_key=None)
         _state.STATE.tracer = tracer
         try:
             with Installed(list(zip(self._caps, caps))):
-                views = cache.views_over(pools, pt, off, state_rows,
-                                         valid_len)
+                views = cache.views_over(
+                    pools, pt, off, state_rows, valid_len,
+                    **({} if pt_w is None else {"window_table": pt_w}))
                 idx = None if lora_idx is None else Tensor(lora_idx)
                 with eng._lora_ctx(idx):
                     logits_t = eng.model(Tensor(tokens), caches=views)
                 logits = logits_t._data_
                 new_pools = cache.flat_pools(views)
+                moe = routing_counts(views, valid_len)
         finally:
             _state.STATE.tracer = None
             tracer.rollback_mutations()
-        return logits, new_pools
+        return logits, new_pools, moe
 
     def _traced(self, mode, pools, pt, off, last, counts, alive, seen,
-                out, limits, eos, temp, topk, topp, pen, keys, caps):
+                limits, eos, temp, topk, topp, pen, keys, caps, pt_w=None):
         # dead/prefilling rows feed token 0 exactly like the uncompiled
         # step's zero-filled tok_in; their scratch writes are causally
         # masked (and prefill re-writes its positions next chunk) either
@@ -367,11 +409,10 @@ class CompiledServingTick:
         tok_in = jnp.where(alive, last, jnp.zeros_like(last))[:, None]
         # a recurrent state has no mask to hide a write behind: only the
         # rows that decode may move theirs (a row mid-prefill keeps what
-        # its chunks have built)
-        valid = alive.astype(jnp.int32) if self.eng.cache.has_state \
-            else None
-        logits, new_pools = self._replay_model(tok_in, pools, pt, off, caps,
-                                               valid_len=valid)
+        # its chunks have built); an expert layer counts those rows
+        logits, new_pools, moe = self._replay_model(
+            tok_in, pools, pt, off, caps,
+            valid_len=alive.astype(jnp.int32), pt_w=pt_w)
         logits = logits[:, -1, :]
 
         ns = logits.shape[0]
@@ -384,9 +425,6 @@ class CompiledServingTick:
                                 keys, counts)
         tok = jnp.where(alive, tok, last)
         rows = jnp.arange(ns)
-        idx = jnp.clip(counts, 0, out.shape[1] - 1)
-        new_out = out.at[rows, idx].set(
-            jnp.where(alive, tok, out[rows, idx]))
         new_seen = seen.at[rows, tok].set(seen[rows, tok] | alive)
         new_counts = counts + alive.astype(counts.dtype)
         eos_hit = alive & (eos >= 0) & (tok == eos)
@@ -396,27 +434,31 @@ class CompiledServingTick:
         new_alive = alive & (fin == 0)
         new_last = jnp.where(alive, tok, last)
         new_off = off + alive.astype(off.dtype)
+        # the tick's own tokens leave with ``fin`` (-1: the row made
+        # none): the host appends them to the requests tick by tick
+        made = jnp.where(alive, tok, -1).astype(jnp.int32)
         return (new_pools, new_off, new_last, new_counts,
-                new_alive, new_seen, new_out, fin)
+                new_alive, new_seen, fin, made, moe)
 
     def _build_jit(self, mode, donating):
         from ..core.op_cache import ensure_compile_cache
         ensure_compile_cache()      # tier-2 persistent XLA compile cache
 
-        def serving_tick(pools, pt, off, last, counts, alive, seen, out,
-                         limits, eos, temp, topk, topp, pen, keys, caps):
+        def serving_tick(pools, pt, off, last, counts, alive, seen,
+                         limits, eos, temp, topk, topp, pen, keys, caps,
+                         pt_w):
             return self._traced(mode, pools, pt, off, last, counts,
-                                alive, seen, out, limits, eos, temp,
-                                topk, topp, pen, keys, caps)
+                                alive, seen, limits, eos, temp,
+                                topk, topp, pen, keys, caps, pt_w)
 
         # the program's name on the trace's ``XLA Modules`` line
         serving_tick.__name__ = serving_tick.__qualname__ = \
             "serving_tick_" + mode
 
         # every buffer the tick replaces is donated: the pools and the
-        # last/counts/alive/seen/out scheduler state (``off`` is not —
-        # on a dirty tick it is the cache's own offset array)
-        donate = (0, 3, 4, 5, 6, 7) if donating else ()
+        # last/counts/alive/seen scheduler state (``off`` is not — on a
+        # dirty tick it is the cache's own offset array)
+        donate = (0, 3, 4, 5, 6) if donating else ()
         return jax.jit(serving_tick, donate_argnums=donate)
 
     def lowered_text(self, mode="greedy"):
@@ -437,8 +479,9 @@ class CompiledServingTick:
 
     def prefill_buckets(self):
         """Row counts the family has a prefill member for: the powers
-        of two below ``num_slots``, and ``num_slots``."""
-        ns = self.eng.cache.num_slots
+        of two below the most rows one call takes (``num_slots``, or
+        fewer by the call's token budget), and that number."""
+        ns = self.eng._prefill_rows
         rows, out = 1, []
         while rows < ns:
             out.append(rows)
@@ -450,12 +493,14 @@ class CompiledServingTick:
         num_slots = self.eng.cache.num_slots
 
         def serving_prefill(pools, pt, off, tokens, last, lora_idx,
-                            state_rows, caps):
+                            state_rows, caps, pt_w, valid):
             # the pad positions after a row's last real one must leave a
-            # recurrent state as it was
-            logits, new_pools = self._replay_model(
+            # recurrent state as it was; ``valid`` is the host's count of
+            # each row's real positions, 0 for a surplus row (routing
+            # counts leave those out)
+            logits, new_pools, moe = self._replay_model(
                 tokens, pools, pt, off, caps, lora_idx, state_rows,
-                last + 1 if has_state else None)
+                valid, pt_w)
             # each row's logits at its last real position: all the host
             # ever reads of a chunk — padded to [num_slots, V], the eager
             # lane's shape, so that the host's row slices are the same
@@ -463,7 +508,7 @@ class CompiledServingTick:
             picked = jnp.take_along_axis(
                 logits, last[:, None, None], axis=1)[:, 0]
             picked = jnp.pad(picked, ((0, num_slots - rows), (0, 0)))
-            return new_pools, picked
+            return new_pools, picked, moe
 
         # never ``serving_tick``: readers of the device trace tell ticks
         # from the other programs by that
@@ -495,7 +540,9 @@ class CompiledServingTick:
                         np.zeros((rows, chunk), np.int32),
                         np.zeros(rows, np.int32), lora,
                         cache.state_rows((), rows) if cache.has_state
-                        else None, caps)
+                        else None, caps,
+                        cache.prefill_window_table((), rows),
+                        np.zeros(rows, np.int32))
                 lowered[rows] = jit.lower(*args)
             key = (f"prefill_r{rows}", donating)
             self._jits[key] = jit
@@ -552,16 +599,21 @@ class CompiledServingTick:
             table, off = cache.prefill_table(slots, starts, rows)
             state_rows = cache.state_rows(slots, rows) \
                 if cache.has_state else None
+            table_w = cache.prefill_window_table(slots, rows)
+            valid = np.zeros(rows, np.int32)
+            valid[:len(slots)] = last[:len(slots)] + 1
         with span("serving.prefill.model"):
             # TRACE_LOCK as in the tick: parameter slots may hold another
             # engine's tracers while it traces
             with TRACE_LOCK:
                 pools, caps = self._donated_and_captured()
-                new_pools, picked = program(pools, table, off, tokens,
-                                            last, lora_rows, state_rows,
-                                            caps)
+                new_pools, picked, moe = program(
+                    pools, table, off, tokens, last, lora_rows, state_rows,
+                    caps, table_w, valid)
             # the call's device time belongs to the call's span
             picked.block_until_ready()
+            if moe is not None:
+                count_routing(np.asarray(moe), prefill=True)
         with span("serving.prefill.absorb"):
             cache.absorb_pools(new_pools)
         stats.incr("prefill.compiled_hits")
@@ -574,25 +626,9 @@ class CompiledServingTick:
     def flush_to_host(self):
         """Materialize device-side token progress back into the request
         objects (the step the uncompiled lane needs before it can take
-        over mid-request).  Token/seen/last bookkeeping only — stats
-        were already counted per tick.  Collects the tick in flight
-        first: its tokens are part of that progress."""
+        over mid-request): every collected tick has handed its tokens
+        over already, so collecting the tick in flight is all of it."""
         self.drain()
-        if not self._ahead or self._dev is None:
-            return
-        self._ahead = False
-        eng = self.eng
-        out_np = np.asarray(self._dev["out"])
-        for slot, req in self._rep.items():
-            if eng._active.get(slot) is not req:
-                continue
-            have = len(req.tokens)
-            count = int(self._h_counts[slot])
-            for tok in out_np[slot, have:count].tolist():
-                req.tokens.append(int(tok))
-                req.last_token = int(tok)
-                if req.seen is not None:
-                    req.seen[int(tok)] = True
         self._dev = None            # force a rebuild before the next tick
 
     def _rebuild(self):
@@ -602,7 +638,6 @@ class CompiledServingTick:
         cache = eng.cache
         ns = cache.num_slots
         vocab = eng.cfg.vocab_size
-        width = eng.max_len
         last = np.zeros(ns, np.int32)
         counts = np.zeros(ns, np.int32)
         limits = np.full(ns, np.iinfo(np.int32).max, np.int32)
@@ -614,13 +649,10 @@ class CompiledServingTick:
         pen = np.ones(ns, np.float32)
         keys = np.zeros((ns, 2), np.uint32)
         seen = np.zeros((ns, vocab), bool)
-        out = np.zeros((ns, width), np.int32)
         for slot, req in eng._active.items():
             alive[slot] = True
             last[slot] = req.last_token
-            n = len(req.tokens)
-            counts[slot] = n
-            out[slot, :n] = req.tokens
+            counts[slot] = len(req.tokens)
             limits[slot] = min(req.max_new_tokens,
                                eng.max_len - req.prompt.size)
             if req.eos_token_id is not None:
@@ -642,11 +674,10 @@ class CompiledServingTick:
             "alive": jnp.asarray(alive), "temp": jnp.asarray(temp),
             "topk": jnp.asarray(topk), "topp": jnp.asarray(topp),
             "pen": jnp.asarray(pen), "keys": jnp.asarray(keys),
-            "seen": jnp.asarray(seen), "out": jnp.asarray(out),
+            "seen": jnp.asarray(seen),
         }
         self._h_counts = counts.copy()
         self._h_limits = limits
-        self._rep = dict(eng._active)
         self._mut_seen = eng._mut
 
     # ------------------------------------------------------------------
@@ -703,13 +734,28 @@ class CompiledServingTick:
         for slot in active:
             cache.ensure_capacity(slot, int(cache.offsets[slot]))
         # pages held against pages promised, summed over ticks
-        stats.incr("kv.page_ticks_in_use", cache.pages_in_use)
+        stats.incr("kv.page_ticks_in_use",
+                   cache.pages_in_use + cache.window_pages_in_use)
         stats.incr("kv.page_ticks_reserved",
-                   cache.usable_pages - cache.available_pages)
+                   cache.usable_pages - cache.available_pages
+                   + cache.window_pages_promised)
+        if cache.ring_pages:
+            # what the window layers hold against what one shared table
+            # would have held for them, and the tokens a window layer's
+            # decode read covers against the contexts
+            ctx = cache.offsets[list(active)] + 1
+            stats.incr("kv.window.page_ticks_held",
+                       cache.window_pages_in_use)
+            stats.incr("kv.window.page_ticks_full_equiv",
+                       cache.pages_in_use)
+            stats.incr("kv.window.token_ticks",
+                       int(np.minimum(ctx, cache.window).sum()))
+            stats.incr("kv.context_token_ticks", int(ctx.sum()))
         # page table / offsets: host mutations (admission, release,
         # growth) flow through the cache's own lazy flush; steady-state
         # ticks ride the previous program's device outputs
         pt, off = cache.table_arrays()
+        pt_w = cache.window_table_array()
         mode = "greedy" if all(
             r.sampling.greedy and not r.sampling.uses_penalty
             for r in active.values()) else "mixed"
@@ -720,9 +766,9 @@ class CompiledServingTick:
         d = self._dev
         pools, caps = self._donated_and_captured()
         args = (pools, pt, off, d["last"], d["counts"],
-                d["alive"], d["seen"], d["out"], d["limits"],
+                d["alive"], d["seen"], d["limits"],
                 d["eos"], d["temp"], d["topk"], d["topp"],
-                d["pen"], d["keys"], caps)
+                d["pen"], d["keys"], caps, pt_w)
         if key not in self._sigs:
             self._sigs[key] = jax.tree.map(
                 lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), args)
@@ -794,7 +840,7 @@ class CompiledServingTick:
                 jit, args = self._build_args(live)
             with span("serving.tick.launch"):
                 (new_pools, new_off, new_last, new_counts, new_alive,
-                 new_seen, new_out, fin) = jit(*args)
+                 new_seen, fin, made, moe) = jit(*args)
                 rows = list(live)
                 # a row that ends by eos in the tick in flight keeps its
                 # device offset while this mirror moves on: a dirty flush
@@ -804,12 +850,9 @@ class CompiledServingTick:
                 offsets_np[rows] += 1
                 cache.absorb_tick(new_pools, new_off, offsets_np)
                 self._dev.update(last=new_last, counts=new_counts,
-                                 alive=new_alive, seen=new_seen,
-                                 out=new_out)
+                                 alive=new_alive, seen=new_seen)
                 self._h_counts[rows] += 1
-                self._ahead = True
-        return _Launched(fin, live, self._h_counts.copy(),
-                         self._pending is not None)
+        return _Launched(fin, made, live, self._pending is not None, moe)
 
     def _collect(self):
         """Read the finish codes of the tick in flight — the one blocking
@@ -826,15 +869,19 @@ class CompiledServingTick:
                 if self.eng._active.get(slot) is req}
         with span("serving.tick.sync") as sync:
             fin_np = np.asarray(tick.fin)
-            ending = self._ending(rows, fin_np)
-            # a row that ends hands its tokens over.  This tick's own
-            # ``out`` was donated to its successor: the newest holds the
-            # same tokens (a dead row's row is left as it was, a live
-            # row's grows past its count), and reading it waits for the
-            # tick in flight — the refill's drain would, a moment later
-            out_np = np.asarray(self._dev["out"]) if ending else None
+            # the same program's outputs as ``fin``: no wait of their own
+            toks = np.asarray(tick.tok)
+            if tick.moe is not None:
+                count_routing(np.asarray(tick.moe))
         with span("serving.tick.deliver") as deliver:
-            self._deliver(tick, rows, ending, out_np)
+            for slot, req in rows.items():
+                tok = int(toks[slot])
+                if tok >= 0:        # -1: the row ran dead on the device
+                    req.tokens.append(tok)
+                    req.last_token = tok
+                    if req.seen is not None:
+                        req.seen[tok] = True
+            self._deliver(tick, rows, self._ending(rows, fin_np))
         stats.observe("tick.host_ms", tick.host_ms + deliver.ms)
         return sync.ms + deliver.ms
 
@@ -853,7 +900,7 @@ class CompiledServingTick:
                 ending[slot] = "eos" if fin_np[slot] == 1 else "length"
         return ending
 
-    def _deliver(self, tick, rows, ending, out_np):
+    def _deliver(self, tick, rows, ending):
         """One collected tick's accounting and deliveries: counters,
         deadline eviction, completions, releases."""
         eng = self.eng
@@ -875,14 +922,11 @@ class CompiledServingTick:
         now = time.monotonic()
         for slot, reason in ending.items():
             req = rows[slot]
-            count = int(tick.counts[slot])
-            req.tokens = [int(t) for t in out_np[slot, :count]]
-            req.last_token = req.tokens[-1]
             if reason is None:
                 from .api import DeadlineExceededError
                 eng._fail(req, DeadlineExceededError(
                     f"request {req.id} exceeded its deadline after "
-                    f"{count} token(s)"))
+                    f"{len(req.tokens)} token(s)"))
                 stats.incr("requests_evicted_deadline")
             else:
                 eng._complete(req, reason, now)

@@ -38,7 +38,7 @@ from ..utils import fault_injection as _fi
 from .api import (DeadlineExceededError, EngineShutdownError,
                   QueueFullError, RecurrentStateError,
                   RequestCancelledError, RequestOutput, SamplingParams,
-                  SchedulerStallError, ServingConfig)
+                  SchedulerStallError, ServingConfig, WindowLayerError)
 from ..models.generation import recurrent_layer_states
 
 
@@ -115,6 +115,21 @@ class _ReqTrace:
                            latency_ms=latency_ms)
 
 
+#: tokens one prefill chunk call computes at most (rows x chunk): what
+#: bounds its activations, whatever the model and however many slots
+PREFILL_CALL_TOKENS = 2048
+
+
+def _window_layers(cfg):
+    """What ``cfg.layer_windows()`` says each layer's attention sees (None:
+    every position; else its latest ``window``), or None when the config
+    has no such method or no layer has a window."""
+    windows = getattr(cfg, "layer_windows", None)
+    windows = None if windows is None else windows()
+    return windows if windows and any(w is not None for w in windows) \
+        else None
+
+
 class Engine:
     """`Engine(model).start()`; then `submit()` (async, returns a
     `Future[RequestOutput]`) or `generate()` (sync).  `shutdown()` stops
@@ -161,6 +176,11 @@ class Engine:
             self._layer_states = recurrent_layer_states(
                 self.cfg, next(iter(model.parameters()))._data_.dtype)
         self._refuse_for_recurrent_state()
+        # which layers' attention sees a window of positions only: those
+        # keep a ring of pages a slot behind a page table of their own
+        # (None where no layer does)
+        self._layer_windows = _window_layers(self.cfg)
+        self._refuse_for_window_layers()
         self._pages_peak = 0
         self._queue: deque[_Request] = deque()
         self._active: dict[int, _Request] = {}
@@ -271,6 +291,35 @@ class Engine:
                 f"role={self.scfg.role!r}: {why}, and page export / "
                 "migration carries keys and values only")
 
+    def _refuse_for_window_layers(self):
+        """Typed refusals, at construction, of what cannot serve
+        sliding_attention layers yet (docs/SERVING.md "Page tables by
+        layer kind"): each would share, rewind or send pages that a
+        window layer's ring has already given up."""
+        draft = self.scfg.draft_model
+        if draft is not None and _window_layers(draft.config) is not None:
+            raise WindowLayerError(
+                "draft_model has sliding_attention layers: the draft "
+                "cache keeps one page table")
+        if self._layer_windows is None:
+            return
+        n = sum(w is not None for w in self._layer_windows)
+        why = f"{n} of the model's layers are sliding_attention layers"
+        if self.scfg.enable_prefix_cache:
+            raise WindowLayerError(
+                f"enable_prefix_cache=True: {why}, whose ring keeps no "
+                "page of a prefix that fell out of the window for the "
+                "PrefixTree to share; pass enable_prefix_cache=False")
+        if self._spec_k > 0:
+            raise WindowLayerError(
+                f"speculation_k={self._spec_k}: {why}, and rollback "
+                "cannot bring back the page a rejected tail's ring entry "
+                "overwrote")
+        if self.scfg.role != "mixed":
+            raise WindowLayerError(
+                f"role={self.scfg.role!r}: {why}, and export_pages / "
+                "adopt_pages carry one page table's pages")
+
     @property
     def migrator(self):
         return self._migrator
@@ -282,6 +331,11 @@ class Engine:
                 "migrator: page export / migration carries keys and "
                 "values only, and the model's layers keep a recurrent "
                 "state")
+        if fn is not None and self._layer_windows is not None:
+            raise WindowLayerError(
+                "migrator: export_pages / adopt_pages carry one page "
+                "table's pages, and the model's sliding_attention layers "
+                "keep a ring of their own")
         self._migrator = fn
 
     # ---------------- lifecycle ----------------
@@ -338,7 +392,10 @@ class Engine:
             page_size=self._page_size,
             num_pages=self.scfg.kv_pool_pages,
             dtype=self.scfg.cache_dtype,
-            layer_states=self._layer_states)
+            layer_states=self._layer_states,
+            layer_windows=self._layer_windows,
+            # the widest run of positions one call writes
+            window_slack=min(self.scfg.prefill_chunk_tokens, slot_len))
         stats.set_value("state.bytes", cache.state_bytes)
         stats.set_value("kv.pages_spanned",
                         cache.num_slots * cache.pages_per_slot)
@@ -347,6 +404,10 @@ class Engine:
         # one compiled prefill program: every chunk is this wide
         self._chunk = min(self.scfg.prefill_chunk_tokens,
                           cache.capacity)
+        # requests that share one chunk call, first come first served
+        # (the rest wait a round): the call's token budget over a chunk
+        self._prefill_rows = max(1, min(cache.num_slots,
+                                        PREFILL_CALL_TOKENS // self._chunk))
         self._prefilling.clear()
         self._pages_peak = 0
         if self._spec:
@@ -1107,6 +1168,7 @@ class Engine:
         reqs = list(self._prefilling)       # each holds a slot: <= B
         chunk = self._chunk
         tgt = [r for r in reqs if r.prefill_pos < r.prompt.size]
+        tgt = tgt[:self._prefill_rows]
         if tgt:
             logits, starts = self._prefill_chunk_call(
                 self.model, self.cache, tgt,
@@ -1147,7 +1209,7 @@ class Engine:
             # cache must hold the whole prompt before the request can
             # decode speculatively (no shared pages on the draft side)
             dr = [r for r in reqs if r.draft_prefill_pos
-                  < r.prompt.size]
+                  < r.prompt.size][:self._prefill_rows]
             if dr:
                 _, dstarts = self._prefill_chunk_call(
                     self.scfg.draft_model, self.draft_cache, dr,
@@ -1218,6 +1280,7 @@ class Engine:
             else None
         starts = []
         useful = 0
+        seen_full = seen_window = 0
         for row, (req, off) in enumerate(zip(reqs, offs)):
             start = min(off, cap - chunk)
             if start != off and cache.has_state:  # pragma: no cover
@@ -1228,6 +1291,12 @@ class Engine:
             tokens[row, :end - start] = req.prompt[start:end]
             last[row] = end - 1 - start
             useful += end - off
+            if cache.ring_pages:
+                # positions the new tokens see in a full layer and in a
+                # window layer
+                seen = np.arange(off, end, dtype=np.int64) + 1
+                seen_full += int(seen.sum())
+                seen_window += int(np.minimum(seen, cache.window).sum())
             cache.ensure_capacity(req.slot, end - 1)
             starts.append(start)
             if lora_rows is not None:
@@ -1250,6 +1319,9 @@ class Engine:
         stats.incr("prefill.tokens_computed", rows * chunk)
         stats.incr("prefill.tokens_useful", useful)
         stats.incr("prefill.launches", launches)
+        if cache.ring_pages:
+            stats.incr("prefill.context_tokens", seen_full)
+            stats.incr("prefill.window_context_tokens", seen_window)
         return logits, starts
 
     def _prefill_chunk_eager(self, model, cache, slots, starts, tokens,

@@ -38,6 +38,23 @@ reservation it needs.  ``reset_state`` zeroes a slot's rows in place at
 admission; a batch row finds its state row through ``state_rows`` (the
 slot, or the scratch row for the surplus rows of a prefill call) and
 says through ``valid_len`` how many of its positions are real.
+
+**Page tables by layer kind.**  A layer whose attention sees only the
+latest ``window`` positions (``layer_windows``: what the model's config
+says of each layer) must not hold the pages that fell out of it while a
+full-attention layer of the same model keeps them — so the two kinds do
+not share page ids.  Window layers get pools, a page table
+``[num_slots, ring_pages]`` and a free list of their own.  A slot's
+window table is a RING: logical page ``p`` (``position // page_size``)
+lives at entry ``p % ring_pages``, pages are taken from the window free
+list as the sequence first grows into an entry, and once the ring is
+full a new logical page reuses the entry of the page that fell out
+(``ring_pages = ceil((window + slack) / page_size) + 1``, ``slack`` the
+widest chunk one call writes, so the page reused is out of the window
+of every position the call computes).  A slot never holds more than
+``ring_pages`` window pages; ``release`` returns the pages of both
+kinds.  A model with one kind of paged layer builds exactly the one
+table and the pools it always built.
 """
 from __future__ import annotations
 
@@ -66,7 +83,7 @@ class PagedKVCache:
 
     def __init__(self, num_layers, num_slots, max_len, num_kv_heads,
                  head_dim, page_size=16, num_pages=None, dtype="float32",
-                 layer_states=None):
+                 layer_states=None, layer_windows=None, window_slack=1):
         self.num_slots = int(num_slots)
         self.page_size = int(page_size)
         self.max_len = int(max_len)
@@ -100,6 +117,30 @@ class PagedKVCache:
         if len(layer_states) != num_layers:
             raise ValueError(f"{len(layer_states)} layer_states for "
                              f"{num_layers} layers")
+        layer_windows = list(layer_windows or [None] * num_layers)
+        if len(layer_windows) != num_layers:
+            raise ValueError(f"{len(layer_windows)} layer_windows for "
+                             f"{num_layers} layers")
+        widths = {int(w) for w in layer_windows if w is not None}
+        if len(widths) > 1:
+            raise ValueError(
+                f"window layers of {len(widths)} widths {sorted(widths)}: "
+                "one ring table serves one width")
+        #: positions a window layer's attention sees (None: no such layer)
+        self.window = widths.pop() if widths else None
+        #: entries of a slot's window table; the pages a slot's window
+        #: layers can hold at most
+        self.ring_pages = 0
+        if self.window is not None:
+            ring = -(-(self.window + int(window_slack)) // self.page_size) + 1
+            # a window as long as the slot keeps what a full layer keeps
+            self.ring_pages = ring if ring < self.pages_per_slot else 0
+        self.table_w = np.zeros((self.num_slots, self.ring_pages), np.int32)
+        self._free_w = list(range(self.num_slots * self.ring_pages, 0, -1))
+        self._private_w = {}     # slot -> [window page ids, by ring entry]
+        self._reserved_w = {}    # slot -> window pages it may still claim
+        self._wrapped = {}       # slot -> highest logical page written
+        pool_shape_w = [len(self._free_w) + 1] + pool_shape[1:]
         #: the state row that surplus rows of a prefill call read and
         #: write (a slot's row is its index)
         self.scratch_row = self.num_slots
@@ -107,8 +148,10 @@ class PagedKVCache:
         #: per layer, the names of the device arrays a model call updates
         #: (``flat_pools`` order)
         self._keys = []
-        for state in layer_states:
+        for state, window in zip(layer_states, layer_windows):
             if state is not None:
+                if window is not None:
+                    raise ValueError("a recurrent layer has no window")
                 # a row a slot, no page table: the recurrent layer's
                 # fixed-size state
                 lay = {name: Tensor(jnp.zeros(
@@ -118,13 +161,18 @@ class PagedKVCache:
                 lay.update(state_rows=None, valid_len=None)
                 self.layers.append(lay)
                 continue
-            lay = {"k_pool": Tensor(jnp.zeros(pool_shape,
-                                              dtype=store_dtype)),
-                   "v_pool": Tensor(jnp.zeros(pool_shape,
-                                              dtype=store_dtype)),
+            ring = window is not None and self.ring_pages > 0
+            shape = pool_shape_w if ring else pool_shape
+            lay = {"k_pool": Tensor(jnp.zeros(shape, dtype=store_dtype)),
+                   "v_pool": Tensor(jnp.zeros(shape, dtype=store_dtype)),
                    "page_table": None, "offset": None,
                    "page_size": self.page_size}
+            if window is not None:
+                lay["window"] = int(window)
             keys = ("k_pool", "v_pool")
+            if quant and ring:
+                raise ValueError("a quantized cache has no window layers "
+                                 "yet: the ring pools keep no scales")
             if quant:
                 # one float32 scale per cached token position, stored
                 # page-major alongside the pools: a write only ever
@@ -139,6 +187,10 @@ class PagedKVCache:
             self.layers.append(lay)
         self._paged = tuple(i for i, st in enumerate(layer_states)
                             if st is None)
+        #: the paged layers behind the ring table
+        self._ringed = tuple(
+            i for i in self._paged
+            if layer_windows[i] is not None and self.ring_pages > 0)
         self._stateful = tuple(i for i, st in enumerate(layer_states)
                                if st is not None)
         self._reset_jits = {}       # donating or not -> the reset program
@@ -218,6 +270,19 @@ class PagedKVCache:
         minus what already-admitted requests may still claim."""
         return len(self._free_pages) - sum(self._reserved.values())
 
+    @property
+    def window_pages_in_use(self):
+        """Pages the slots' window tables hold (0 with no window kind)."""
+        return self.num_slots * self.ring_pages - len(self._free_w)
+
+    @property
+    def window_pages_promised(self):
+        """Window pages held or promised to admitted requests."""
+        return self.window_pages_in_use + sum(self._reserved_w.values())
+
+    def window_pages_held(self, slot):
+        return len(self._private_w.get(slot, ()))
+
     # ---------------- slot lifecycle ----------------
     def allocate(self, reserve_pages, shared_pages=()):
         """Reserve a slot whose sequence may grow into `reserve_pages`
@@ -233,6 +298,12 @@ class PagedKVCache:
         self._shared[slot] = len(shared_pages)
         self._private[slot] = []
         self._reserved[slot] = int(reserve_pages)
+        if self.ring_pages:
+            # the window pool has a ring a slot: the promise cannot fail
+            self._private_w[slot] = []
+            self._reserved_w[slot] = min(int(reserve_pages),
+                                         self.ring_pages)
+            self._wrapped[slot] = -1
         self.offsets[slot] = 0
         self._dirty = True
         return slot
@@ -247,6 +318,11 @@ class PagedKVCache:
         self._free_pages.extend(self._private.pop(slot, ()))
         self._shared.pop(slot, None)
         self._reserved.pop(slot, None)
+        if self.ring_pages:
+            self._free_w.extend(self._private_w.pop(slot, ()))
+            self._reserved_w.pop(slot, None)
+            self._wrapped.pop(slot, None)
+            self.table_w[slot, :] = 0
         self.table[slot, :] = 0
         self.offsets[slot] = 0
         self._free_slots.append(slot)
@@ -272,6 +348,30 @@ class PagedKVCache:
             self.table[slot, assigned] = page
             assigned += 1
             self._dirty = True
+        if self.ring_pages:
+            self._grow_ring(slot, need_idx)
+
+    def _grow_ring(self, slot, need_idx):
+        """The window table's side of ``ensure_capacity``: ring entries
+        up to logical page ``need_idx``'s get a page the first time the
+        sequence grows into them; past the ring's length a logical page
+        reuses the entry of the one that fell out of the window."""
+        held = self._private_w[slot]
+        while len(held) <= min(need_idx, self.ring_pages - 1):
+            if not self._free_w or self._reserved_w[slot] <= 0:
+                raise RuntimeError(     # pragma: no cover - a ring a slot
+                    f"slot {slot} grew past its window reservation")
+            page = self._free_w.pop()
+            self._reserved_w[slot] -= 1
+            self.table_w[slot, len(held)] = page
+            held.append(page)
+            self._dirty = True
+        seen = self._wrapped[slot]
+        if need_idx > seen:
+            reused = max(0, need_idx - max(seen, self.ring_pages - 1))
+            if reused:
+                stats.incr("kv.window.pages_reclaimed", reused)
+            self._wrapped[slot] = need_idx
 
     def set_offset(self, slot, off):
         self.offsets[slot] = int(off)
@@ -290,6 +390,7 @@ class PagedKVCache:
         offset passes them, and overwritten first.  Tree-owned (shared)
         pages are never touched — they hold prompt tokens, which are
         always behind the horizon."""
+        self._refuse_window("rollback")
         shared = self._shared.get(slot, 0)
         keep = max(int(new_off) // self.page_size + 1, shared)
         priv = self._private[slot]
@@ -317,6 +418,7 @@ class PagedKVCache:
         """Transfer the page at `table_index` of the slot's table from
         slot-private to caller (tree) ownership; returns its id.  The
         slot keeps using the page — only who frees it changes."""
+        self._refuse_window("make_shared")
         shared = self._shared.get(slot, 0)
         # the shared prefix stays contiguous: pages become shared in
         # order, so the boundary just advances
@@ -354,6 +456,7 @@ class PagedKVCache:
         k_pages = np.asarray(k_pages)
         v_pages = np.asarray(v_pages)
         self._refuse_state("adopt_pages")
+        self._refuse_window("adopt_pages")
         pool = np.asarray(self.layers[0]["k_pool"]._data_)
         want = (len(self.layers),) + pool.shape[1:]
         if k_pages.ndim != 5 or k_pages.shape[0] != want[0] or \
@@ -424,6 +527,7 @@ class PagedKVCache:
         migrates; tree ownership stays here), scales None for float
         pools."""
         self._refuse_state("export_pages")
+        self._refuse_window("export_pages")
         off = int(self.offsets[slot])
         n = max(1, -(-off // self.page_size))
         ids = [int(p) for p in self.table[slot, :n]]
@@ -448,27 +552,54 @@ class PagedKVCache:
                 f"{what}: {len(self._stateful)} layers keep a recurrent "
                 "state per slot, which pages do not carry")
 
+    def _refuse_window(self, what):
+        if self.ring_pages:
+            from .api import WindowLayerError
+            raise WindowLayerError(
+                f"{what}: {len(self._ringed)} sliding_attention layers "
+                f"keep a ring of {self.ring_pages} pages a slot, and a "
+                "page that fell out of the window is gone")
+
     # ---------------- device views ----------------
     def layer_caches(self, live=None):
         """Per-layer cache dicts for the batched decode step.  Flushes
         the (single, shared) offsets + page-table device arrays if any
         host-side mutation happened since the last call.  ``live`` (the
-        slots that decode this step) is what the recurrent layers'
-        ``valid_len`` is made of: every other row's state stays as it
-        was."""
+        slots that decode this step) is what every layer's ``valid_len``
+        is made of: every other row's recurrent state stays as it was,
+        and a layer that counts what it computes counts those rows."""
         self._flush()
-        if self._stateful:
-            valid = np.zeros(self.num_slots, np.int32)
-            valid[list(live or ())] = 1
-            valid = Tensor(jnp.asarray(valid))
-            for i in self._stateful:
-                self.layers[i].update(state_rows=None, valid_len=valid)
+        valid = np.zeros(self.num_slots, np.int32)
+        valid[list(live or ())] = 1
+        valid = Tensor(jnp.asarray(valid))
+        for i in self._stateful:
+            self.layers[i]["state_rows"] = None
+        for lay in self.layers:
+            lay["valid_len"] = valid
         return self.layers
 
     def table_arrays(self):
         """(page table, offsets) as the device holds them, flushed."""
         self._flush()
         return self._pt._data_, self._off._data_
+
+    def window_table_array(self):
+        """The window layers' page table as the device holds it, flushed;
+        None with no window kind."""
+        if not self.ring_pages:
+            return None
+        self._flush()
+        return self._pt_w._data_
+
+    def prefill_window_table(self, slots, rows):
+        """``prefill_table`` for the window layers: row i carries
+        ``slots[i]``'s ring; None with no window kind."""
+        if not self.ring_pages:
+            return None
+        table = np.zeros((rows, self.ring_pages), np.int32)
+        for row, slot in enumerate(slots):
+            table[row] = self.table_w[slot]
+        return table
 
     def prefill_table(self, slots, starts, rows):
         """Host arrays for one batched prefill-chunk call of ``rows``
@@ -484,24 +615,29 @@ class PagedKVCache:
         return table, off
 
     def views_over(self, pools_flat, page_table, offset, state_rows=None,
-                   valid_len=None):
+                   valid_len=None, window_table=None):
         """Per-layer cache dicts over ``pools_flat`` (each layer's device
         arrays, flat in ``flat_pools`` order): the paged layers behind
         one page table and offset vector, the recurrent layers behind
-        ``state_rows`` (None: row i is slot i) and ``valid_len`` (None:
-        every position is real)."""
+        ``state_rows`` (None: row i is slot i); the window layers behind
+        ``window_table``.  Every view carries ``valid_len``, each row's
+        count of real positions (None: every position is real)."""
         pt, off = Tensor(page_table), Tensor(offset)
+        pt_w = None if window_table is None else Tensor(window_table)
         rows = None if state_rows is None else Tensor(state_rows)
         valid = None if valid_len is None else Tensor(valid_len)
         views = []
         it = iter(pools_flat)
-        for keys in self._keys:
+        for i, keys in enumerate(self._keys):
             view = {k: Tensor(next(it)) for k in keys}
             if "k_pool" in view:
-                view.update(page_table=pt, offset=off,
-                            page_size=self.page_size)
+                view.update(page_table=pt_w if i in self._ringed else pt,
+                            offset=off, page_size=self.page_size)
+                if "window" in self.layers[i]:
+                    view["window"] = self.layers[i]["window"]
             else:
-                view.update(state_rows=rows, valid_len=valid)
+                view["state_rows"] = rows
+            view["valid_len"] = valid
             views.append(view)
         return views
 
@@ -524,13 +660,17 @@ class PagedKVCache:
         with `absorb_view`; until then the old and the new pools are
         both alive."""
         table, off = self.prefill_table(slots, starts, self.num_slots)
-        if not self._stateful:
-            rows = valid = None
-        else:
+        rows = None
+        if self._stateful:
             rows = jnp.asarray(self.state_rows(slots, self.num_slots))
+        if valid is not None:
+            valid = np.asarray(valid).copy()
+            valid[len(slots):] = 0          # the surplus rows are nobody's
             valid = jnp.asarray(valid)
-        return self.views_over(self.flat_pools(), jnp.asarray(table),
-                               jnp.asarray(off), rows, valid)
+        table_w = self.prefill_window_table(slots, self.num_slots)
+        return self.views_over(
+            self.flat_pools(), jnp.asarray(table), jnp.asarray(off), rows,
+            valid, None if table_w is None else jnp.asarray(table_w))
 
     def absorb_view(self, views):
         """Adopt the functionally-updated pools (and per-page scales)
@@ -574,9 +714,12 @@ class PagedKVCache:
         # the arguments of a tick still in flight
         self._off = off = Tensor(jnp.asarray(self.offsets.copy()))
         self._pt = pt = Tensor(jnp.asarray(self.table.copy()))
+        if self.ring_pages:
+            self._pt_w = Tensor(jnp.asarray(self.table_w.copy()))
         for i in self._paged:
             self.layers[i]["offset"] = off
-            self.layers[i]["page_table"] = pt
+            self.layers[i]["page_table"] = \
+                self._pt_w if i in self._ringed else pt
         self._dirty = False
 
 

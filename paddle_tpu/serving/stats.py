@@ -125,6 +125,35 @@ def declare_tick_stats():
             ("kv.page_ticks_reserved", "KV pages held or promised to "
                                        "admitted requests, summed over "
                                        "ticks"),
+            ("kv.window.page_ticks_held", "pages the slots' window "
+                                          "tables held, summed over ticks"),
+            ("kv.window.page_ticks_full_equiv", "pages one shared table "
+                                                "would have held for the "
+                                                "window layers, summed "
+                                                "over ticks"),
+            ("kv.window.pages_reclaimed", "ring entries a new logical "
+                                          "page reused: pages that fell "
+                                          "out of the window"),
+            ("kv.window.token_ticks", "tokens a window layer's decode "
+                                      "read covers, summed over rows and "
+                                      "ticks"),
+            ("kv.context_token_ticks", "context lengths of the decoding "
+                                       "rows, summed over ticks (a model "
+                                       "with window layers)"),
+            ("prefill.context_tokens", "positions the prefilled tokens "
+                                       "see in a full attention layer (a "
+                                       "model with window layers)"),
+            ("prefill.window_context_tokens", "positions they see in a "
+                                              "window layer"),
+            ("moe.pairs_local", "(token, held expert) pairs the expert "
+                                "layers computed"),
+            ("moe.prefill.pairs", "of those pairs, the prefill members'"),
+            ("moe.prefill.experts_hit", "of the experts hit, the prefill "
+                                        "members'"),
+            ("moe.tokens_routed", "tokens the expert layers routed "
+                                  "(real positions x layers)"),
+            ("moe.experts_hit", "held experts with at least one pair, "
+                                "summed over layers and programs"),
             ("prefill.tokens_computed", "token positions the prefill "
                                         "chunk calls computed"),
             ("prefill.tokens_useful", "of those, new prompt tokens"),
@@ -352,6 +381,17 @@ def serving_stats():
     Pallas kernel or the XLA gather lane (process-wide, not reset at
     engine start: a program is traced once and run many times).
 
+    Window-layer and expert-layer quantities (None or zero for a model
+    with neither): ``window_pages_held_share`` — pages the slots' window
+    rings held over the pages one shared table would have held for those
+    layers, summed over the compiled ticks — and
+    ``window_pages_reclaimed`` (ring entries reused);
+    ``expert_pairs_per_token`` — (token, held expert) pairs the expert
+    layers computed over the tokens they routed (``k * held / experts``
+    for an even router) — and ``expert_gmm_kernel_traces`` /
+    ``expert_gmm_xla_lane_traces``, the lanes the grouped expert product
+    took when traced (process-wide, like the paged read's).
+
     Recurrent-state quantities (a model whose layers keep a per-slot
     state beside the pages, zero otherwise): ``state_bytes`` (the state
     arrays' size), ``state_resets`` and ``state_reset_ms_avg`` (an
@@ -459,6 +499,16 @@ def serving_stats():
                                        / g("tick.compiled_hits"))
         if g("tick.compiled_hits") else None,
         "kv_pages_spanned_per_tick": g("kv.pages_spanned"),
+        "expert_pairs_per_token": (g("moe.pairs_local")
+                                   / g("moe.tokens_routed"))
+        if g("moe.tokens_routed") else None,
+        "expert_gmm_kernel_traces": s.get("pallas.expert_gmm.kernel", 0),
+        "expert_gmm_xla_lane_traces": s.get("pallas.expert_gmm.xla_lane",
+                                            0),
+        "window_pages_held_share": (g("kv.window.page_ticks_held")
+                                    / g("kv.window.page_ticks_full_equiv"))
+        if g("kv.window.page_ticks_full_equiv") else None,
+        "window_pages_reclaimed": g("kv.window.pages_reclaimed"),
         "paged_decode_kernel_traces": s.get(
             "pallas.paged_decode.kernel", 0),
         "paged_decode_xla_lane_traces": s.get(

@@ -536,3 +536,43 @@ def test_overlap_and_drain_counters(model, tick_flag):
     assert snap["tick_overlap_share"] >= 0.8
     assert snap["tick_fallbacks"] == 0
     assert snap["tokens_generated"] == sum(o.output_ids.size for o in outs)
+
+
+@pytest.mark.parametrize("sampling", [
+    None, SamplingParams(temperature=1.0, top_k=20, seed=5,
+                         repetition_penalty=1.3)],
+    ids=["greedy", "seeded-penalty"])
+def test_every_tick_hands_its_tokens_to_the_host(model, tick_flag, sampling):
+    """The tick returns its own tokens beside ``fin`` and the host
+    appends them at the tick's delivery, so a live request's token list
+    grows tick by tick, not at an admission's or a completion's flush —
+    and the served tokens are the uncompiled scheduler's."""
+    prompts = _prompts([9, 14], seed=21)
+    subs = [(p, 24, sampling, None) for p in prompts]
+    plain, _, _ = _serve(model, subs, compiled=False, flags=tick_flag)
+    cfg = ServingConfig(num_slots=2, max_queue=4)
+    held = []
+    tick_flag["FLAGS_compiled_tick"] = True
+    eng = Engine(model, cfg).start()
+    try:
+        deliver = eng._tick._deliver
+
+        def spy(tick, rows, ending):
+            held.append(sum(len(r.tokens) for r in rows.values()))
+            deliver(tick, rows, ending)
+
+        eng._tick._deliver = spy
+        with eng._work:
+            futs = [eng.submit(p, max_new_tokens=24, sampling=sampling)
+                    for p in prompts]
+        outs = [f.result(timeout=300) for f in futs]
+        snap = eng.stats()
+    finally:
+        eng.shutdown()
+    for a, b in zip(plain, outs):
+        np.testing.assert_array_equal(a.output_ids, b.output_ids)
+    assert snap["tick_fallbacks"] == 0
+    # both rows decode side by side: each delivered tick found two tokens
+    # more on the requests' lists than the one before it
+    steps = np.diff(held)
+    assert len(held) >= 20 and steps.max() == 2 and (steps > 0).all()

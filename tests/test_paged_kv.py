@@ -302,10 +302,11 @@ def test_paged_admits_more_sequences_than_preallocation(model):
 # op / kernel equivalence
 # ------------------------------------------------------------------
 
-def test_paged_op_bitwise_matches_dense_op():
+def test_paged_op_matches_dense_op():
     """Same logical cache through the paged layout and the dense slot
-    layout → bit-identical attention output (the engine's bit-equality
-    guarantee reduces to this)."""
+    layout: the same attention output to float32 rounding (the paged
+    read folds its keys into an online softmax, the dense op divides
+    first), and bit-identical writes."""
     from paddle_tpu.core.tensor import Tensor
     from paddle_tpu.incubate.nn import functional as IF
     rng = np.random.default_rng(3)
@@ -334,7 +335,7 @@ def test_paged_op_bitwise_matches_dense_op():
     out_p, kp, vp = IF.paged_masked_multihead_attention(
         Tensor(q), Tensor(k), Tensor(v), Tensor(k_pool),
         Tensor(v_pool), Tensor(table), Tensor(offs), psz)
-    np.testing.assert_array_equal(_np(out_d), _np(out_p))
+    np.testing.assert_allclose(_np(out_d), _np(out_p), rtol=2e-6, atol=2e-7)
     # and the write landed in the right page/position
     for b in range(B):
         pg = table[b, offs[b] // psz]
@@ -435,6 +436,164 @@ def test_paged_pallas_kernel_matches_gather_path(case, monkeypatch):
         scale if scale is not None else 1.0 / np.sqrt(D))
     np.testing.assert_allclose(np.asarray(out.astype(jnp.float32)), ref,
                                rtol=tol, atol=tol)
+
+
+def _ring_cache(rng, B, ring, psz, Hkv, D, off, window, dt):
+    """Pools and ring tables holding, for each row, the keys of the
+    positions its window can see at offset ``off[b]`` (logical page p at
+    entry p % ring), 1e4 everywhere else (finite, as stale pages are: a
+    masked key still meets a probability of exactly 0): a read that
+    strays out of the window swamps the result.  Returns (k_pool, v_pool, table, {b: {pos: (k, v)}})."""
+    import jax.numpy as jnp
+    P = 1 + B * ring
+    k_pool = np.full((P, psz, Hkv, D), 1e4, np.float32)
+    v_pool = np.full((P, psz, Hkv, D), 1e4, np.float32)
+    table = rng.permutation(np.arange(1, P)).reshape(B, ring).astype(np.int32)
+    kept = {}
+    for b in range(B):
+        kept[b] = {}
+        for pos in range(max(0, off[b] + 1 - window), off[b] + 1):
+            page = table[b, (pos // psz) % ring]
+            kv = rng.normal(size=(2, Hkv, D)).astype(np.float32)
+            k_pool[page, pos % psz], v_pool[page, pos % psz] = kv
+            kept[b][pos] = kv
+    return (jnp.asarray(k_pool, dt), jnp.asarray(v_pool, dt),
+            jnp.asarray(table), kept)
+
+
+def _window_reference(q, kept, scale):
+    B, H, D = q.shape
+    ref = np.zeros((B, H, D))
+    for b in range(B):
+        pos = sorted(kept[b])
+        k = np.stack([kept[b][p][0] for p in pos]).astype(np.float64)
+        v = np.stack([kept[b][p][1] for p in pos]).astype(np.float64)
+        rep = H // k.shape[1]
+        for h in range(H):
+            s = k[:, h // rep] @ q[b, h].astype(np.float64) * scale
+            p = np.exp(s - s.max())
+            ref[b, h] = (p / p.sum()) @ v[:, h // rep]
+    return ref
+
+
+#  window  ring entries  offsets: the row's context is off + 1
+_WINDOW_CASES = {
+    "window-over-the-context": (24, 6, [5, 17, 20]),
+    "window-equal-to-the-context": (24, 6, [23, 23, 0]),
+    "window-under-the-context": (24, 6, [24, 37, 100]),
+    "window-not-a-page-multiple": (21, 6, [30, 21, 77]),
+    "window-of-one-page-wraps-often": (8, 3, [8, 63, 200]),
+}
+
+
+@pytest.mark.parametrize("group", [0, 2], ids=["one-step", "steps-of-2"])
+@pytest.mark.parametrize("case", sorted(_WINDOW_CASES))
+def test_paged_kernel_lower_bound_matches_the_window(case, group,
+                                                     monkeypatch):
+    """The kernel starts at the row's first in-window page, masks the
+    partial first page by position and reads the table as a ring: it
+    agrees with a plain softmax over exactly the window's positions, and
+    so does the XLA lane; every position outside the window holds 1e4."""
+    import jax.numpy as jnp
+    from paddle_tpu.core.tensor import Tensor
+    from paddle_tpu.incubate.nn import functional as IF
+    from paddle_tpu.pallas import flash_attention as fa
+    window, ring, off = _WINDOW_CASES[case]
+    B, H, Hkv, D, psz = 3, 16, 8, 16, 8
+    monkeypatch.setenv("PADDLE_TPU_PALLAS_INTERPRET", "1")
+    if group:
+        monkeypatch.setattr(fa, "_PAGED_STEP_BYTES",
+                            group * psz * Hkv * D * 4)
+    rng = np.random.default_rng(sorted(_WINDOW_CASES).index(case))
+    off = np.array(off, np.int32)
+    kj, vj, pt, kept = _ring_cache(rng, B, ring, psz, Hkv, D, off, window,
+                                   jnp.float32)
+    q = rng.normal(size=(B, H, D)).astype(np.float32)
+    ref = _window_reference(q, kept, 1.0 / np.sqrt(D))
+    out = fa.paged_decode_attention(jnp.asarray(q), kj, vj, pt,
+                                    jnp.asarray(off), window=window)
+    np.testing.assert_allclose(np.asarray(out), ref, rtol=1e-5, atol=1e-5)
+    # the XLA lane, through the op: it writes the new token first, so
+    # hand it the cache one token short and that token as k, v
+    monkeypatch.delenv("PADDLE_TPU_PALLAS_INTERPRET")
+    new_k = np.stack([kept[b][off[b]][0] for b in range(B)])[:, None]
+    new_v = np.stack([kept[b][off[b]][1] for b in range(B)])[:, None]
+    xla, _, _ = IF.paged_masked_multihead_attention(
+        Tensor(q[:, None]), Tensor(new_k), Tensor(new_v), Tensor(kj),
+        Tensor(vj), Tensor(pt), Tensor(off), psz, window=window)
+    np.testing.assert_allclose(_np(xla)[:, 0], ref, rtol=1e-5, atol=1e-5)
+
+
+def test_chunk_read_over_a_ring_sees_each_querys_window():
+    """A prefill chunk over a ring table: every query of the chunk sees
+    its own ``window`` latest positions, the chunk's own keys written
+    first, in blocks of keys."""
+    import jax.numpy as jnp
+    from paddle_tpu.core.tensor import Tensor
+    from paddle_tpu.incubate.nn import functional as IF
+    B, H, Hkv, D, psz, S, window = 2, 4, 2, 8, 4, 8, 10
+    ring = -(-(window + S) // psz) + 1
+    rng = np.random.default_rng(9)
+    start = np.array([0, 37], np.int32)
+    last = start + S - 1
+    kj, vj, pt, kept = _ring_cache(rng, B, ring, psz, Hkv, D, last,
+                                   window + S - 1, jnp.float32)
+    new_k = np.stack([[kept[b][p][0] for p in range(start[b], last[b] + 1)]
+                      for b in range(B)])
+    new_v = np.stack([[kept[b][p][1] for p in range(start[b], last[b] + 1)]
+                      for b in range(B)])
+    q = rng.normal(size=(B, S, H, D)).astype(np.float32)
+    out, _, _ = IF.paged_masked_multihead_attention(
+        Tensor(q), Tensor(new_k), Tensor(new_v), Tensor(kj), Tensor(vj),
+        Tensor(pt), Tensor(start), psz, window=window)
+    for i in range(S):
+        seen = {b: {p: kv for p, kv in kept[b].items()
+                    if start[b] + i - window < p <= start[b] + i}
+                for b in range(B)}
+        np.testing.assert_allclose(
+            _np(out)[:, i], _window_reference(q[:, i], seen,
+                                              1.0 / np.sqrt(D)),
+            rtol=1e-5, atol=1e-5)
+
+
+def test_no_window_traces_the_kernel_it_traced_before():
+    """Without a window the lower bound is a static None, not a traced
+    zero: the call's jaxpr is, to the character, what the kernel traced
+    before windows existed (the digests are of the parent commit's
+    trace), so a model with one kind of paged layer keeps the tick
+    program the compile cache holds."""
+    import hashlib
+    import os
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.pallas import flash_attention as fa
+    want = {
+        "float32": "36660f5cec23471c0a6aa2a68145f141152209be5db7e88b0c1e"
+                   "7561580d1e92",
+        "bfloat16": "35d90a0b743832243e28cf32d32fcfc690c50b42959c072e2d7"
+                    "c68a66753eebb"}
+    saved = os.environ.get("PADDLE_TPU_PALLAS_INTERPRET")
+    os.environ["PADDLE_TPU_PALLAS_INTERPRET"] = "1"
+    try:
+        for name, digest in want.items():
+            b, h, hkv, d, psz, n = 3, 8, 2, 128, 8, 6
+            q = jnp.zeros((b, h, d), name)
+            pool = jnp.zeros((b * n + 1, psz, hkv, d), name)
+            pt = jnp.zeros((b, n), jnp.int32)
+            off = jnp.zeros((b,), jnp.int32)
+            text = str(jax.make_jaxpr(
+                lambda *a: fa.paged_decode_attention(*a))(q, pool, pool, pt,
+                                                          off))
+            assert hashlib.sha256(text.encode()).hexdigest() == digest, name
+            windowed = str(jax.make_jaxpr(
+                lambda *a: fa.paged_decode_attention(*a, window=16))(
+                    q, pool, pool, pt, off))
+            assert windowed != text
+    finally:
+        if saved is None:
+            del os.environ["PADDLE_TPU_PALLAS_INTERPRET"]
+        else:
+            os.environ["PADDLE_TPU_PALLAS_INTERPRET"] = saved
 
 
 @pytest.mark.parametrize("page_size,h_kv,d,itemsize,pages", [

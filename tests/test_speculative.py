@@ -357,6 +357,32 @@ def test_int8_paged_op_allclose_dense():
                                rtol=0.05, atol=0.05)
 
 
+def test_int8_paged_chunk_reads_its_own_keys_with_their_scales():
+    """A chunk of several tokens written into int8 pages is read back
+    through the scales the write just made, not the ones it found."""
+    from paddle_tpu.core.tensor import Tensor
+    from paddle_tpu.incubate.nn import functional as IF
+    rng = np.random.default_rng(5)
+    B, S, H, D, psz, N = 2, 4, 2, 8, 8, 3
+    q, k, v = (rng.normal(size=(B, S, H, D)).astype(np.float32) * 3
+               for _ in range(3))
+    offs = Tensor(np.array([0, 5], np.int32))
+    cache = {
+        "k_pool": Tensor(np.zeros((1 + B * N, psz, H, D), np.int8)),
+        "v_pool": Tensor(np.zeros((1 + B * N, psz, H, D), np.int8)),
+        "k_scale": Tensor(np.ones((1 + B * N, psz), np.float32)),
+        "v_scale": Tensor(np.ones((1 + B * N, psz), np.float32)),
+        "page_table": Tensor(np.arange(1, 1 + B * N, dtype=np.int32)
+                             .reshape(B, N)),
+        "offset": offs, "page_size": psz,
+    }
+    out_q = IF.paged_cache_attention(Tensor(q), Tensor(k), Tensor(v), cache)
+    zeros = Tensor(np.zeros((B, N * psz, H, D), np.float32))
+    out_d, _, _ = IF.masked_multihead_attention(
+        Tensor(q), Tensor(k), Tensor(v), zeros, zeros, offs)
+    np.testing.assert_allclose(_np(out_q), _np(out_d), rtol=0.05, atol=0.1)
+
+
 def test_int8_engine_pages_halve_at_equal_load(model):
     """The capacity claim: int8 pages pack 2x the tokens in half the
     bytes, so the pages-in-use peak at equal token load halves vs the

@@ -227,6 +227,72 @@ def test_paged_decode_compiles(v5e, case):
         calls[0]), calls[0]
 
 
+#          slots h    h_kv d    page entries window
+_WINDOWED = {
+    # the sparse-expert cell's tick: 128 query heads on 8 kv heads; a
+    # window layer's ring of (4096 + 512) / 16 + 1 pages, the full
+    # layer's table of 8192 / 16
+    "window-ring": (32, 128, 8, 128, 16, 289, 4096),
+    "full-table-of-the-same-model": (32, 128, 8, 128, 16, 512, None),
+    "window-not-a-page-multiple": (4, 32, 8, 128, 16, 40, 500),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_WINDOWED))
+def test_paged_decode_with_a_window_compiles(v5e, case):
+    """The lower bound of the row's page loop and the ring indexing are
+    scalar arithmetic on the prefetched offsets: still one kernel named
+    ``paged_decode``."""
+    import re
+    b, h, h_kv, d, psz, n, window = _WINDOWED[case]
+    pool = (1 + b * n, psz, h_kv, d)
+
+    def f(q, k, v, pt, off):
+        return fa.paged_decode_attention(q, k, v, pt, off, window=window)
+
+    text = _compile(f, [((b, h, d), BF16), (pool, BF16), (pool, BF16),
+                        ((b, n), jnp.int32), ((b,), jnp.int32)],
+                    SingleDeviceSharding(v5e[0]), compiled=True)
+    calls = [line.strip() for line in text.splitlines()
+             if "tpu_custom_call" in line]
+    assert len(calls) == 1, calls
+    assert re.match(r"^(ROOT )?%paged_decode[\w.]* = ", calls[0]), calls[0]
+
+
+# ------------------------------------------------------------ expert gmm
+
+#        tokens hidden width held k
+_GMM = {
+    "tick-32-sessions": (32, 4096, 4096, 16, 8),
+    "prefill-chunk-512": (512, 4096, 4096, 16, 8),
+    "prefill-4-rows": (2048, 4096, 4096, 16, 8),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_GMM))
+def test_expert_gmm_compiles(v5e, case):
+    """The routed experts' part at the sparse-expert cell's widths: two
+    kernels named ``expert_gmm`` (gate and up fused, then down) whose
+    tile -> expert map and tiles in use are scalar-prefetched."""
+    import re
+    from paddle_tpu.pallas import moe
+    t, h, f, held, k = _GMM[case]
+
+    def fn(x, experts, gates, wg, wu, wd):
+        return moe.routed_experts(x, experts, gates, wg, wu, wd, (0, held))
+
+    text = _compile(fn, [((t, h), BF16), ((t, k), jnp.int32), ((t, k), F32),
+                         ((held, h, f), BF16), ((held, h, f), BF16),
+                         ((held, f, h), BF16)],
+                    SingleDeviceSharding(v5e[0]), compiled=True)
+    calls = [line.strip() for line in text.splitlines()
+             if "tpu_custom_call" in line]
+    assert len(calls) == 2, calls
+    for call in calls:
+        # as chipbench/kernels/expert_ffn/pallas_expert_gmm.json
+        assert re.match(r"^(ROOT )?%expert_gmm[\w.]* = ", call), call
+
+
 def _kernels_through_the_op(device, b, h, h_kv, d, psz, n, pool_dt):
     """Kernels in the framework op's single-token step (page write +
     read) over a pool of these shapes."""
