@@ -37,6 +37,7 @@ import argparse
 import gc
 import json
 import os
+import re
 import sys
 import time
 import warnings
@@ -221,6 +222,18 @@ def train_phase(sizes, dry_run):
     return model, cfg, losses
 
 
+def pool_sized_copies(hlo_text, element_counts):
+    """The ``copy`` instructions of an optimized HLO text whose operand
+    has as many elements as a page pool."""
+    found = []
+    for line in hlo_text.splitlines():
+        m = re.search(r"= \w+\[([\d,]*)\]\S* copy\(", line)
+        if m and m.group(1) and int(np.prod(
+                [int(n) for n in m.group(1).split(",")])) in element_counts:
+            found.append(line.strip()[:160])
+    return found
+
+
 def serve_phase(model, cfg, sizes, dry_run):
     import paddle_tpu as paddle
     from paddle_tpu.serving import Engine, ServingConfig
@@ -242,6 +255,9 @@ def serve_phase(model, cfg, sizes, dry_run):
                 f"{sum(sizes.new_tokens)} tokens in "
                 f"{time.perf_counter() - t0:.1f}s (compiles included)")
             tick_text = eng._tick.lowered_text("greedy")
+            tick_hlo = eng._tick.lowered_text("greedy", optimized=True)
+            pool_elements = {int(np.prod(lay["k_pool"].shape))
+                             for lay in eng.cache.layers}
         finally:
             eng.shutdown()
     no_fallback_warnings(caught, "serve")
@@ -270,6 +286,14 @@ def serve_phase(model, cfg, sizes, dry_run):
     assert snap["scheduler_restarts"] == 0
     assert tick_text is not None, "no greedy tick program ran"
     pallas_calls(tick_text, "serve tick", dry_run)
+    # GPT-2's pools (12 kv heads of 64) live as the paged kernel reads
+    # them, so the tick program relays none of them
+    say(f"serve: kv_pools_lane_dense={snap['kv_pools_lane_dense']} of "
+        f"kv_pools={snap['kv_pools']}")
+    assert snap["kv_pools_lane_dense"] == snap["kv_pools"] > 0
+    pool_copies = pool_sized_copies(tick_hlo, pool_elements)
+    assert not pool_copies, \
+        f"the tick program copies a whole page pool: {pool_copies[:4]}"
 
     # one request against model.generate() on the same device
     k = 2
@@ -333,6 +357,15 @@ def paged_kernel_phase(cfg, sizes):
             kf, vf = kp[pt], vp[pt]
         out = fa.paged_decode_attention(q, kp, vp, pt, off,
                                         k_scale=ks, v_scale=vs)
+        # the same bytes as the cache stores pools of this head size
+        dense = (pool[0], *fa.paged_pool_page_shape(
+            psz, h, d, kp.dtype.itemsize))
+        if dense != pool:
+            same = fa.paged_decode_attention(
+                q, kp.reshape(dense), vp.reshape(dense), pt, off,
+                k_scale=ks, v_scale=vs, h_kv=h)
+            assert bool(jnp.all(same == out)), \
+                f"{what} pages: a lane-dense pool reads differently"
         # the reference's f32 einsums would run at bf16 matmul precision
         # on a TPU by default; at "highest" only the order of the f32
         # sums differs from the kernel's

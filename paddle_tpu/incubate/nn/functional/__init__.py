@@ -289,9 +289,10 @@ _CHUNK_KEY_BLOCK = 512
 _ONE_BLOCK_SCORE_BYTES = 32 << 20
 
 
-@functools.partial(jax.jit, static_argnames=("psz", "scale", "window"))
+@functools.partial(jax.jit,
+                   static_argnames=("psz", "h_kv", "scale", "window"))
 def _paged_block_attend(qa, kp, vp, pt, off, ks=None, vs=None, *, psz,
-                        scale, window):
+                        h_kv, scale, window):
     """Attention of ``qa`` [B, S, Hq, D] at positions ``off .. off + S -
     1`` over each row's LIVE pages — and, with ``window``, over its
     in-window pages alone — in blocks of ``_CHUNK_KEY_BLOCK`` keys:
@@ -303,13 +304,16 @@ def _paged_block_attend(qa, kp, vp, pt, off, ks=None, vs=None, *, psz,
     a single-token read, a short chunk of a few rows over a short slot)
     is one block without a loop.
     With ``window`` the table is a ring (logical page ``p`` at entry
-    ``p % N``; the same entry where the table spans the slot).  A
-    ``jax.jit`` of its own inside the caller's program: the layers of a
-    model trace it once between them, not once each."""
+    ``p % N``; the same entry where the table spans the slot).  The
+    pools are ``[P, psz, h_kv, D]`` or lane-dense ``[P, rows, 128]``:
+    what is reshaped to ``[.., psz, h_kv, D]`` is the GATHERED block,
+    the live pages of one step, never a pool.  A ``jax.jit`` of its own
+    inside the caller's program: the layers of a model trace it once
+    between them, not once each."""
     import math as _math
     from ....quantization import dequantize_kv
     b, s, h_q, d = qa.shape
-    h_kv, n_tab = kp.shape[2], pt.shape[1]
+    n_tab = pt.shape[1]
     rep = h_q // h_kv
     sc = scale if scale is not None else 1.0 / _math.sqrt(d)
     one_block = b * h_q * s * n_tab * psz * 4 <= _ONE_BLOCK_SCORE_BYTES
@@ -328,9 +332,10 @@ def _paged_block_attend(qa, kp, vp, pt, off, ks=None, vs=None, *, psz,
     pt = pt.astype(jnp.int32)
 
     def gather(pool, scales, phys):
+        pages = pool[phys].reshape(b, kb, psz, h_kv, d)
         if quant:
-            return dequantize_kv(pool[phys], scales[phys])
-        return pool[phys]
+            pages = dequantize_kv(pages, scales[phys])
+        return pages.reshape(b, blk, h_kv, d)
 
     def body(j, carry):
         m_prev, l_prev, acc = carry
@@ -339,8 +344,8 @@ def _paged_block_attend(qa, kp, vp, pt, off, ks=None, vs=None, *, psz,
         idx = lp % n_tab if window is not None else \
             jnp.minimum(lp, n_tab - 1)
         phys = jnp.take_along_axis(pt, idx, axis=1)
-        kblk = gather(kp, ks, phys).reshape(b, blk, h_kv, d)
-        vblk = gather(vp, vs, phys).reshape(b, blk, h_kv, d)
+        kblk = gather(kp, ks, phys)
+        vblk = gather(vp, vs, phys)
         k_pos = (lp[:, :, None] * psz
                  + jnp.arange(psz, dtype=jnp.int32)).reshape(b, blk)
         sco = jnp.einsum("bqhrd,bkhd->bhrqk", qg, kblk.astype(cdt),
@@ -376,9 +381,14 @@ def paged_masked_multihead_attention(q, k, v, k_pool, v_pool, page_table,
     static-shape for TPU).
 
     q/k/v: [B, S, H, D] new tokens; k_pool/v_pool: [P, page_size, Hkv,
-    D] fixed page pools shared by every sequence; page_table: int32
-    [B, N] mapping each row's logical pages to physical pool pages;
-    offset: int32 [B] tokens already cached per row.  Writes the new
+    D] fixed page pools shared by every sequence, or the same bytes
+    lane-dense, [P, page_size * Hkv * D / 128, 128], as `PagedKVCache`
+    stores a pool of narrow heads the decode kernel can host (told
+    apart by ``ndim``; Hkv is ``k``'s: such a pool is written, read and
+    handed back in that shape, and no program reshapes a whole pool);
+    page_table: int32 [B, N] mapping each row's logical pages to
+    physical pool pages; offset: int32 [B] tokens already cached per
+    row.  Writes the new
     K/V through the page table at offset..offset+S per row (rows whose
     table entries are 0 scatter into the reserved scratch page — how
     free/ungrown slots ride the static batch harmlessly), then reads
@@ -456,21 +466,37 @@ def paged_masked_multihead_attention(q, k, v, k_pool, v_pool, page_table,
         entry = pos // psz if window is None else (pos // psz) % n_pages
         page_ids = jnp.take_along_axis(pt.astype(jnp.int32), entry, axis=1)
         in_page = pos % psz
+        h_kv = ka.shape[2]
+        if kp.ndim == 3:
+            # a lane-dense pool: a token's [h_kv, d] values are the
+            # whole 128-lane rows in_page * token_rows + r of its page.
+            # A scatter of single rows: XLA's own scatter on the chip,
+            # where one of [token_rows, 128] windows becomes a loop
+            token_rows = h_kv * d // 128
+            pages = page_ids[..., None]
+            rows = in_page[..., None] * token_rows + \
+                jnp.arange(token_rows, dtype=jnp.int32)
+
+            def put(pool, vals):
+                return pool.at[pages, rows].set(
+                    vals.reshape(b, s, token_rows, 128))
+        else:
+            def put(pool, vals):
+                return pool.at[page_ids, in_page].set(vals)
         if quant:
             ks, vs = scales
             qmax = 127.0 if kp.dtype == jnp.int8 else 448.0
             qk, sk = quantize_kv_rows(ka, qmax, kp.dtype)
             qv, sv = quantize_kv_rows(va, qmax, vp.dtype)
-            kp = kp.at[page_ids, in_page].set(qk)
-            vp = vp.at[page_ids, in_page].set(qv)
+            kp, vp = put(kp, qk), put(vp, qv)
             ks = ks.at[page_ids, in_page].set(sk)
             vs = vs.at[page_ids, in_page].set(sv)
         else:
-            kp = kp.at[page_ids, in_page].set(ka.astype(kp.dtype))
-            vp = vp.at[page_ids, in_page].set(va.astype(vp.dtype))
+            kp = put(kp, ka.astype(kp.dtype))
+            vp = put(vp, va.astype(vp.dtype))
         use_kernel = kernels_on and not (quant and window is not None) \
             and _fa.paged_decode_pages_per_step(
-                psz, kp.shape[2], d, kp.dtype.itemsize) > 0
+                psz, h_kv, d, kp.dtype.itemsize) > 0
         if s_new == 1:
             _monitor.incr("pallas.paged_decode.kernel" if use_kernel
                           else "pallas.paged_decode.xla_lane")
@@ -479,11 +505,12 @@ def paged_masked_multihead_attention(q, k, v, k_pool, v_pool, page_table,
                 qa[:, 0], kp, vp, pt.astype(jnp.int32), off,
                 scale=scale,
                 k_scale=ks if quant else None,
-                v_scale=vs if quant else None, window=window)[:, None]
+                v_scale=vs if quant else None, window=window,
+                h_kv=h_kv)[:, None]
         else:
             out = _paged_block_attend(
                 qa, kp, vp, pt, off, *((ks, vs) if quant else ()), psz=psz,
-                scale=None if scale is None else float(scale),
+                h_kv=h_kv, scale=None if scale is None else float(scale),
                 window=window)
         if quant:
             return out, kp, vp, ks, vs

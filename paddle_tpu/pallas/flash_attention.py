@@ -777,6 +777,25 @@ def paged_decode_pages_per_step(page_size, h_kv, d, itemsize):
     return 1 << (g.bit_length() - 1)
 
 
+def paged_pool_page_shape(page_size, h_kv, d, itemsize):
+    """The shape a page has while its pool LIVES on the device
+    (serving/paged_kv.py): the kernel's lane-dense ``(rows, 128)``, or
+    ``(page_size, h_kv, d)`` as it is written.  The same kind of rule,
+    on the same four numbers.
+
+    A pool the kernel hosts whose heads are narrower than the 128 lanes
+    lives as the kernel reads it: XLA keeps a 4-D array of such a head
+    size in another layout than the kernel's view, and relays the whole
+    pool on every way between the two.  At ``d = 128`` the 4-D array
+    already is that view tile for tile (the reshape is a bitcast), and
+    its page write, a scatter of whole ``[h_kv, 128]`` tiles, is the
+    cheaper one: it stays.  A pool the kernel refuses stays too."""
+    if d >= 128 or not paged_decode_pages_per_step(page_size, h_kv, d,
+                                                   itemsize):
+        return (page_size, h_kv, d)
+    return (page_size * h_kv * d // 128, 128)
+
+
 def _mxu_f32(lhs, rhs, rhs_contracts):
     """``lhs [m, k]`` times ``rhs`` (contracting its dim ``rhs_contracts``)
     accumulated in float32 with no bit of an operand dropped, whatever
@@ -945,11 +964,13 @@ def _paged_decode_kernel(pt_ref, off_ref, q_ref, k_hbm, v_hbm, *rest,
 
 def paged_decode_attention(q, k_pool, v_pool, page_table, offsets,
                            scale=None, k_scale=None, v_scale=None,
-                           window=None):
+                           window=None, h_kv=None):
     """Single-token decode attention over a paged KV cache.
 
     q: [B, H, D] this step's queries; k_pool/v_pool: [P, page_size,
-    H_kv, D] physical page pools; page_table: int32 [B, N] logical →
+    H_kv, D] physical page pools, or lane-dense [P, rows, 128] as the
+    kernel sees them (``h_kv`` then says how many kv heads a token has:
+    the shape no longer does); page_table: int32 [B, N] logical →
     physical page map; offsets: int32 [B] — row b attends positions
     <= offsets[b] (its freshly written token included).  With
     ``k_scale``/``v_scale`` ([P, page_size] float32) the pools hold
@@ -962,9 +983,11 @@ def paged_decode_attention(q, k_pool, v_pool, page_table, offsets,
     them a step, by double-buffered DMAs through the scalar-prefetched
     page table (``_paged_decode_kernel``): the work follows the
     contexts, not the slots' capacity.  The kernel sees a pool as
-    ``[P, rows, 128]`` (the same bytes where D is 128; one relayout by
-    XLA where D is less, as a pool of such a head size needed before)
-    and a query head on the lanes of its kv head.  The caller asks
+    ``[P, rows, 128]`` and a query head on the lanes of its kv head: a
+    pool that lives in that shape (serving/paged_kv.py) goes to the
+    kernel as it is; a 4-D pool is reshaped here, which is the same
+    bytes where D is 128 and one relayout of the pool by XLA where D is
+    less.  The caller asks
     ``paged_decode_pages_per_step`` first; a pool it answers 0 for is
     read by the XLA gather lane.
 
@@ -976,7 +999,14 @@ def paged_decode_attention(q, k_pool, v_pool, page_table, offsets,
         raise ValueError("paged decode kernel: quantized pools have no "
                          "window lane; the caller reads them by XLA")
     d = q.shape[-1]
-    psz, h_kv = k_pool.shape[1:3]
+    if k_pool.ndim == 4:
+        psz, h_kv = k_pool.shape[1:3]
+    elif h_kv is None:
+        raise ValueError("paged decode kernel: a lane-dense pool "
+                         f"{k_pool.shape} does not say its kv heads; "
+                         "pass h_kv")
+    else:
+        psz = k_pool.shape[1] * 128 // (h_kv * d)
     group = paged_decode_pages_per_step(psz, h_kv, d,
                                         k_pool.dtype.itemsize)
     while group > page_table.shape[1]:
@@ -990,22 +1020,23 @@ def paged_decode_attention(q, k_pool, v_pool, page_table, offsets,
         q, k_pool, v_pool, page_table, offsets, k_scale, v_scale,
         scale=float(scale) if scale is not None else 1.0 / math.sqrt(d),
         group=group, interpret=_interpret(),
-        window=None if window is None else int(window))
+        window=None if window is None else int(window), h_kv=int(h_kv))
 
 
 @functools.partial(jax.jit, static_argnames=("scale", "group", "interpret",
-                                             "window"))
+                                             "window", "h_kv"))
 def _paged_decode_call(q, k_pool, v_pool, page_table, offsets, k_scale,
-                       v_scale, *, scale, group, interpret, window=None):
+                       v_scale, *, scale, group, interpret, window=None,
+                       h_kv):
     """``paged_decode_attention`` at a fixed step size.  A program of its
     own inside the caller's: the layers of a model trace and lower ONE
     kernel between them (16 of them cost Mistral's tick 4 s of set-up
-    when each call site traced its own)."""
+    when each call site traced its own).  A lane-dense pool is the
+    kernel's operand as it stands; a 4-D one is reshaped to it."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     b, h, d = q.shape
-    n_pool, psz, h_kv, _ = k_pool.shape
     n_pages = page_table.shape[1]
     quant = k_scale is not None
     page_table = page_table.astype(jnp.int32)
@@ -1024,9 +1055,15 @@ def _paged_decode_call(q, k_pool, v_pool, page_table, offsets, k_scale,
                             lambda bi, pt, off: (bi, 0, 0))
     hbm_spec = pl.BlockSpec(memory_space=pl.ANY)
     in_specs = [row_spec, hbm_spec, hbm_spec]
-    page_rows = psz * token_rows
-    operands = [qk, k_pool.reshape(n_pool, page_rows, 128),
-                v_pool.reshape(n_pool, page_rows, 128)]
+    if k_pool.ndim == 4:
+        n_pool, psz = k_pool.shape[:2]
+        page_rows = psz * token_rows
+        k_pool = k_pool.reshape(n_pool, page_rows, 128)
+        v_pool = v_pool.reshape(n_pool, page_rows, 128)
+    else:
+        page_rows = k_pool.shape[1]
+        psz = page_rows // token_rows
+    operands = [qk, k_pool, v_pool]
     if quant:
         # a row's scales, a column of the kernel's score matrix each
         # ((token, row of the token) order), padded to whole steps: a

@@ -461,16 +461,20 @@ class CompiledServingTick:
         donate = (0, 3, 4, 5, 6) if donating else ()
         return jax.jit(serving_tick, donate_argnums=donate)
 
-    def lowered_text(self, mode="greedy"):
+    def lowered_text(self, mode="greedy", optimized=False):
         """StableHLO text of a tick program that has run in ``mode`` —
         what a reader checks to see which kernels are in it (Pallas
-        kernels appear as ``tpu_custom_call``).  None if none has."""
+        kernels appear as ``tpu_custom_call``); with ``optimized`` the
+        compiled program's HLO, layouts and the compiler's own copies
+        included.  None if none has."""
         from ..core.state import no_grad
         for key, sig in self._sigs.items():
             if key[0] == mode:
                 # re-traces through the model, as the scheduler loop does
                 with TRACE_LOCK, no_grad():
-                    return self._jits[key].lower(*sig).as_text()
+                    low = self._jits[key].lower(*sig)
+                    return low.compile().as_text() if optimized \
+                        else low.as_text()
         return None
 
     # ------------------------------------------------------------------

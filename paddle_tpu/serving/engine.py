@@ -399,6 +399,8 @@ class Engine:
         stats.set_value("state.bytes", cache.state_bytes)
         stats.set_value("kv.pages_spanned",
                         cache.num_slots * cache.pages_per_slot)
+        stats.set_value("kv.pools", cache.pools)
+        stats.set_value("kv.pools_lane_dense", cache.pools_lane_dense)
         self.prefix_tree = PrefixTree(self._page_size) \
             if self.scfg.enable_prefix_cache else None
         # one compiled prefill program: every chunk is this wide

@@ -11,6 +11,18 @@ regime:
   Shapes never change: the decode step stays ONE compiled XLA program
   (page-table/offset *values* are runtime data), while physical pages
   are assigned to a slot lazily as its sequence grows.
+- **Lane-dense pages.**  A pool the paged decode kernel can host whose
+  heads are narrower than the 128 lanes (``paged_pool_page_shape(
+  page_size, H, D, itemsize)``: a rule on those four numbers, nothing
+  else) LIVES as the kernel reads it, ``[num_pages, page_size *
+  H * D / 128, 128]`` — the same bytes in the same order, a page's
+  ``[page_size, H, D]`` row-major IS its ``[rows, 128]`` row-major — so
+  no program ever relays a whole pool to get from the shape the write
+  wants to the shape the kernel wants (at ``D = 64`` that was three
+  copies a pool a tick).  At ``D = 128`` the 4-D pool is that view
+  already, and a pool the kernel refuses is read by XLA: both stay
+  4-D.  ``page_shape`` is a page as the host formats carry it,
+  ``stored_page_shape`` as the device holds it.
 - **Scratch page 0** is never allocated.  Free slots (and table entries
   not yet grown into) point at it, so the static-shape batch's dummy
   writes land in scratch and the per-row causal mask keeps every live
@@ -112,7 +124,16 @@ class PagedKVCache:
         #: scale arrays; None for plain float storage
         self.quant_dtype = dtype if quant else None
         store_dtype = quant[0] if quant else dtype
-        pool_shape = [total, self.page_size, num_kv_heads, head_dim]
+        #: a page as the host formats carry it (``export_pages``,
+        #: ``adopt_pages``): [page_size, H, D]
+        self.page_shape = (self.page_size, int(num_kv_heads),
+                           int(head_dim))
+        from ..pallas.flash_attention import paged_pool_page_shape
+        #: a page as it lives on the device: the paged decode kernel's
+        #: [rows, 128] where the rule says so, else ``page_shape``
+        self.stored_page_shape = paged_pool_page_shape(
+            *self.page_shape, jnp.dtype(store_dtype).itemsize)
+        pool_shape = [total, *self.stored_page_shape]
         layer_states = list(layer_states or [None] * num_layers)
         if len(layer_states) != num_layers:
             raise ValueError(f"{len(layer_states)} layer_states for "
@@ -195,6 +216,17 @@ class PagedKVCache:
                                if st is not None)
         self._reset_jits = {}       # donating or not -> the reset program
         self._flush()
+
+    @property
+    def pools(self):
+        """How many page pools the cache holds (K and V of every paged
+        layer, both tables')."""
+        return 2 * len(self._paged)
+
+    @property
+    def pools_lane_dense(self):
+        """Of ``pools``, those stored ``[pages, rows, 128]``."""
+        return self.pools if len(self.stored_page_shape) == 2 else 0
 
     # ---------------- recurrent state ----------------
     @property
@@ -457,8 +489,8 @@ class PagedKVCache:
         v_pages = np.asarray(v_pages)
         self._refuse_state("adopt_pages")
         self._refuse_window("adopt_pages")
-        pool = np.asarray(self.layers[0]["k_pool"]._data_)
-        want = (len(self.layers),) + pool.shape[1:]
+        pool = self.layers[0]["k_pool"]._data_
+        want = (len(self.layers),) + self.page_shape
         if k_pages.ndim != 5 or k_pages.shape[0] != want[0] or \
                 k_pages.shape[2:] != want[1:] or \
                 v_pages.shape != k_pages.shape:
@@ -500,9 +532,12 @@ class PagedKVCache:
         if quant:
             k_scales = np.asarray(k_scales)
             v_scales = np.asarray(v_scales)
-        # page-at-a-time scatter: every update is the SAME [page_size,
-        # H, D] shape whatever the payload's page count, so the install
-        # compiles once ever instead of once per distinct n
+        # the wire's [page_size, H, D] is the stored page's bytes
+        stored = k_pages.shape[:2] + self.stored_page_shape
+        k_pages, v_pages = k_pages.reshape(stored), v_pages.reshape(stored)
+        # page-at-a-time scatter: every update is the SAME page shape
+        # whatever the payload's page count, so the install compiles
+        # once ever instead of once per distinct n
         for li, lay in enumerate(self.layers):
             kp, vp = lay["k_pool"]._data_, lay["v_pool"]._data_
             for j, pid in enumerate(pages):
@@ -538,8 +573,9 @@ class PagedKVCache:
             if self.quant_dtype is not None:
                 kss.append(np.asarray(lay["k_scale"]._data_)[ids])
                 vss.append(np.asarray(lay["v_scale"]._data_)[ids])
-        k = np.ascontiguousarray(np.stack(ks))
-        v = np.ascontiguousarray(np.stack(vs))
+        wire = (len(ks), n) + self.page_shape
+        k = np.ascontiguousarray(np.stack(ks)).reshape(wire)
+        v = np.ascontiguousarray(np.stack(vs)).reshape(wire)
         if self.quant_dtype is None:
             return off, k, v, None, None
         return off, k, v, np.ascontiguousarray(np.stack(kss)), \

@@ -184,6 +184,12 @@ def declare_tick_stats():
     _registry.gauge(PREFIX + "kv.pages_spanned",
                     "page-table entries of the decode batch: slots x "
                     "pages a slot")
+    _registry.gauge(PREFIX + "kv.pools",
+                    "page pools the cache holds: K and V of every paged "
+                    "layer")
+    _registry.gauge(PREFIX + "kv.pools_lane_dense",
+                    "of those, the pools stored [pages, rows, 128] as the "
+                    "paged decode kernel reads them")
 
 
 def declare_migration_stats():
@@ -380,6 +386,10 @@ def serving_stats():
     count the traces of a single-token paged read that chose the
     Pallas kernel or the XLA gather lane (process-wide, not reset at
     engine start: a program is traced once and run many times).
+    ``kv_pools_lane_dense`` of ``kv_pools``: the page pools stored as
+    the kernel reads them, ``[pages, rows, 128]`` — all of them or none,
+    by the kernel's rule on ``page_size``, kv heads, head size and
+    element size (serving/paged_kv.py).
 
     Window-layer and expert-layer quantities (None or zero for a model
     with neither): ``window_pages_held_share`` — pages the slots' window
@@ -499,6 +509,8 @@ def serving_stats():
                                        / g("tick.compiled_hits"))
         if g("tick.compiled_hits") else None,
         "kv_pages_spanned_per_tick": g("kv.pages_spanned"),
+        "kv_pools": g("kv.pools"),
+        "kv_pools_lane_dense": g("kv.pools_lane_dense"),
         "expert_pairs_per_token": (g("moe.pairs_local")
                                    / g("moe.tokens_routed"))
         if g("moe.tokens_routed") else None,

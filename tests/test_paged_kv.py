@@ -596,7 +596,7 @@ def test_no_window_traces_the_kernel_it_traced_before():
             os.environ["PADDLE_TPU_PALLAS_INTERPRET"] = saved
 
 
-@pytest.mark.parametrize("page_size,h_kv,d,itemsize,pages", [
+_RULE_SHAPES = [
     (16, 8, 128, 2, 16),       # mistral-7b: 32 KB pages, 512 KB a step
     (16, 8, 64, 2, 32),        # granite-4.0-h-micro: two heads a row
     (16, 12, 64, 4, 8),        # gpt-2 124m, float32 pages
@@ -606,7 +606,10 @@ def test_no_window_traces_the_kernel_it_traced_before():
     (16, 8, 256, 2, 0),        # a head wider than the lanes
     (4, 8, 128, 2, 64),        # small pages: more of them a step
     (2, 2, 64, 4, 0),          # a page of 2 rows is no whole tile
-])
+]
+
+
+@pytest.mark.parametrize("page_size,h_kv,d,itemsize,pages", _RULE_SHAPES)
 def test_paged_decode_pages_per_step_is_a_rule_on_shapes(
         page_size, h_kv, d, itemsize, pages):
     from paddle_tpu.pallas.flash_attention import \
@@ -659,6 +662,277 @@ def test_paged_decode_lane_is_counted_where_it_is_traced(
     stats = serving_stats()
     assert stats["paged_decode_kernel_traces"] == after["kernel"]
     assert stats["paged_decode_xla_lane_traces"] == after["xla_lane"]
+
+
+# ------------------------------------------------------------------
+# lane-dense pools: a pool of narrow heads lives as the kernel reads it
+# ------------------------------------------------------------------
+
+_POOL_DTYPES = {4: "float32", 2: "bfloat16", 1: "int8"}
+
+
+@pytest.mark.parametrize("table", ["full", "ring"])
+@pytest.mark.parametrize("page_size,h_kv,d,itemsize,pages", _RULE_SHAPES)
+def test_pool_lives_in_the_shape_the_rule_gives(
+        page_size, h_kv, d, itemsize, pages, table):
+    """The cache stores a pool ``[P, rows, 128]`` exactly where the
+    kernel hosts it and its heads are narrower than the lanes; every
+    other pool stays ``[P, page_size, H, D]`` — for the full table's
+    pools and the ring table's alike, from shapes alone."""
+    dtype = _POOL_DTYPES[itemsize]
+    ring = table == "ring"
+    kw = {"layer_windows": [page_size, None], "window_slack": 1} \
+        if ring else {}
+    if ring and dtype == "int8":
+        with pytest.raises(ValueError, match="no window layers"):
+            PagedKVCache(2, 2, 4 * page_size, h_kv, d,
+                         page_size=page_size, dtype=dtype, **kw)
+        return
+    cache = PagedKVCache(2, 2, 4 * page_size, h_kv, d,
+                         page_size=page_size, dtype=dtype, **kw)
+    dense = pages > 0 and d < 128
+    page = (page_size * h_kv * d // 128, 128) if dense \
+        else (page_size, h_kv, d)
+    assert cache.page_shape == (page_size, h_kv, d)
+    assert cache.stored_page_shape == page
+    assert cache.pools == 4
+    assert cache.pools_lane_dense == (4 if dense else 0)
+    assert bool(cache.ring_pages) is ring
+    for i, lay in enumerate(cache.layers):
+        n = 2 * cache.ring_pages + 1 if ring and i == 0 else 2 * 4 + 1
+        for key in ("k_pool", "v_pool"):
+            assert tuple(lay[key].shape) == (n,) + page, (i, key)
+            assert lay[key]._data_.dtype.itemsize == itemsize
+    if (page_size, h_kv, d) == (16, 8, 64):     # granite-4.0-h-micro
+        assert cache.stored_page_shape == (64, 128)
+
+
+_DENSE_CASES = [(lane, s_new, kind)
+                for lane in ("xla", "kernel")
+                for s_new in (1, 5)
+                for kind in ("plain", "ring", "int8")
+                if not (lane == "kernel" and s_new > 1)]
+
+
+@pytest.mark.parametrize("lane,s_new,kind", _DENSE_CASES)
+def test_op_on_a_lane_dense_pool_equals_the_op_on_the_4d_pool(
+        lane, s_new, kind, monkeypatch):
+    """The same bytes as ``[P, page_size, H, D]`` and as ``[P, rows,
+    128]`` through the op: outputs and the written pools (and scales)
+    bit-equal — a single token on both lanes, a chunk, a window ring,
+    int8 pages."""
+    from paddle_tpu.core.tensor import Tensor
+    from paddle_tpu.incubate.nn import functional as IF
+    if lane == "kernel":
+        monkeypatch.setenv("PADDLE_TPU_PALLAS_INTERPRET", "1")
+    else:
+        monkeypatch.delenv("PADDLE_TPU_PALLAS_INTERPRET", raising=False)
+    rng = np.random.default_rng(11)
+    B, H, Hkv, D, psz, N = 3, 4, 2, 64, 8, 4
+    P, rows = 1 + B * N, psz * Hkv * D // 128
+    window = 11 if kind == "ring" else None
+    table = rng.permutation(np.arange(1, P)).reshape(B, N).astype(np.int32)
+    # a ring's positions run past the table: logical page p at p % N
+    offs = np.array([3, 17, 26 if window is None else 41], np.int32)
+    q = rng.normal(size=(B, s_new, H, D)).astype(np.float32)
+    k = rng.normal(size=(B, s_new, Hkv, D)).astype(np.float32)
+    v = rng.normal(size=(B, s_new, Hkv, D)).astype(np.float32)
+    kw = {}
+    if kind == "int8":
+        k_pool = rng.integers(-127, 128, (P, psz, Hkv, D)).astype(np.int8)
+        v_pool = rng.integers(-127, 128, (P, psz, Hkv, D)).astype(np.int8)
+        scales = [rng.uniform(0.005, 0.03, (P, psz)).astype(np.float32)
+                  for _ in range(2)]
+    else:
+        k_pool = rng.normal(size=(P, psz, Hkv, D)).astype(np.float32)
+        v_pool = rng.normal(size=(P, psz, Hkv, D)).astype(np.float32)
+
+    def call(shape):
+        if kind == "int8":
+            kw.update(k_scale=Tensor(scales[0]), v_scale=Tensor(scales[1]))
+        res = IF.paged_masked_multihead_attention(
+            Tensor(q), Tensor(k), Tensor(v),
+            Tensor(k_pool.reshape(shape)), Tensor(v_pool.reshape(shape)),
+            Tensor(table), Tensor(offs), psz, window=window, **kw)
+        return [_np(r) for r in res]
+
+    four, dense = call((P, psz, Hkv, D)), call((P, rows, 128))
+    assert dense[1].shape == dense[2].shape == (P, rows, 128)
+    assert len(four) == len(dense) == (5 if kind == "int8" else 3)
+    np.testing.assert_array_equal(dense[0], four[0])
+    for got, want in zip(dense[1:3], four[1:3]):
+        np.testing.assert_array_equal(got.reshape(want.shape), want)
+    for got, want in zip(dense[3:], four[3:]):
+        np.testing.assert_array_equal(got, want)
+    # and the write did land: row b's first new token, in its page
+    b = 1
+    entry = (offs[b] // psz) % N if window is not None else offs[b] // psz
+    page = four[1][table[b, entry], offs[b] % psz]
+    if kind == "int8":
+        np.testing.assert_allclose(
+            page.astype(np.float32) * four[3][table[b, entry],
+                                              offs[b] % psz],
+            k[b, 0], atol=0.03)
+    else:
+        np.testing.assert_array_equal(page, k[b, 0])
+
+
+@pytest.mark.parametrize("dtype", ["float32", "int8"])
+def test_lane_dense_pages_migrate_in_the_wire_shape(dtype):
+    """``export_pages`` -> ``adopt_pages`` at head size 64: the wire
+    carries ``[layers, n, page_size, H, D]`` whatever shape the pools
+    live in, the adopted pages are bit-equal, and a pool of another
+    geometry — the same bytes a page — still refuses them."""
+    import jax.numpy as jnp
+    from paddle_tpu.core.tensor import Tensor
+    from paddle_tpu.serving import PageMigrationError
+    rng = np.random.default_rng(5)
+    psz, Hkv, D = 16, 2, 64
+    a = PagedKVCache(2, 2, 64, Hkv, D, page_size=psz, dtype=dtype)
+    assert a.stored_page_shape == (16, 128)
+    slot = a.allocate(4)
+    a.ensure_capacity(slot, 47)           # 3 pages assigned
+    a.set_offset(slot, 37)
+    for lay in a.layers:
+        for key in ("k_pool", "v_pool", "k_scale", "v_scale"):
+            if key not in lay:
+                continue
+            shp, dt = lay[key].shape, lay[key]._data_.dtype
+            vals = rng.integers(-127, 127, shp) if dt == jnp.int8 \
+                else rng.random(shp)
+            lay[key] = Tensor(jnp.asarray(vals, dt))
+    off, k, v, ks, vs = a.export_pages(slot)
+    assert off == 37 and k.shape == v.shape == (2, 3, psz, Hkv, D)
+    assert (ks is None) == (dtype == "float32")
+    for li in range(2):                   # a page's bytes, reshaped
+        pool = np.asarray(a.layers[li]["k_pool"]._data_)
+        for j in range(3):
+            np.testing.assert_array_equal(
+                k[li, j], pool[a.table[slot, j]].reshape(psz, Hkv, D))
+    b = PagedKVCache(2, 2, 64, Hkv, D, page_size=psz, num_pages=8,
+                     dtype=dtype)
+    s2 = b.adopt_pages(1, off, k, v, ks, vs)
+    assert s2 is not None and int(b.offsets[s2]) == 37
+    for li in range(2):
+        for key in ("k_pool", "v_pool", "k_scale", "v_scale"):
+            if key not in a.layers[li]:
+                continue
+            pa = np.asarray(a.layers[li][key]._data_)
+            pb = np.asarray(b.layers[li][key]._data_)
+            assert pb.shape[1:] == pa.shape[1:]
+            for j in range(3):
+                np.testing.assert_array_equal(
+                    pb[b.table[s2, j]], pa[a.table[slot, j]])
+    # 4 kv heads of 32: as many bytes a page, another geometry
+    c = PagedKVCache(2, 2, 64, 4, 32, page_size=psz, dtype=dtype)
+    assert c.stored_page_shape == a.stored_page_shape
+    with pytest.raises(PageMigrationError, match="does not fit"):
+        c.adopt_pages(1, off, k, v, ks, vs)
+
+
+@pytest.fixture(scope="module")
+def model64():
+    """Two kv heads of 64: a pool of theirs lives lane-dense."""
+    from paddle_tpu.models import GPTForCausalLM, gpt_config
+    paddle.seed(0)
+    m = GPTForCausalLM(gpt_config(
+        "gpt2-124m", num_layers=2, hidden_size=128, num_heads=2,
+        vocab_size=512, max_seq_len=128))
+    m.eval()
+    return m
+
+
+@pytest.mark.parametrize("lane", ["xla", "kernel"])
+def test_tick_program_reshapes_no_pool(model64, lane, monkeypatch):
+    """The traced tick program of a head-size-64 engine holds no
+    ``reshape`` / ``transpose`` of an array as large as a page pool, on
+    the XLA lane and with the decode kernel in it: the pools go through
+    the program in the shape they live in."""
+    import re
+    if lane == "kernel":
+        monkeypatch.setenv("PADDLE_TPU_PALLAS_INTERPRET", "1")
+    else:
+        monkeypatch.delenv("PADDLE_TPU_PALLAS_INTERPRET", raising=False)
+    (p,) = _prompts([9], seed=6)
+    cfg = ServingConfig(num_slots=2, max_seq_len=64, page_size=8,
+                        prefill_chunk_tokens=8)
+    with Engine(model64, cfg) as eng:
+        eng.submit(p, max_new_tokens=3).result(timeout=300)
+        text = eng._tick.lowered_text("greedy")
+        pools = [lay["k_pool"] for lay in eng.cache.layers]
+        snap = eng.stats()
+    assert text is not None
+    assert snap["kv_pools"] == snap["kv_pools_lane_dense"] == 4
+    assert snap["paged_decode_kernel_traces" if lane == "kernel"
+                else "paged_decode_xla_lane_traces"] > 0
+    assert all(tuple(t.shape)[1:] == (8, 128) for t in pools)
+    count = int(np.prod(pools[0].shape))
+    assert text.count(f"tensor<{'x'.join(map(str, pools[0].shape))}x") > 0
+    moved = []
+    for line in text.splitlines():
+        if not re.search(r"stablehlo\.(reshape|transpose)\b", line):
+            continue
+        for dims in re.findall(r"tensor<((?:\d+x)+)[a-z]", line):
+            if int(np.prod([int(n) for n in dims[:-1].split("x")])) \
+                    == count:
+                moved.append(line.strip()[:160])
+    assert not moved, moved[:4]
+
+
+@pytest.mark.parametrize("speculation", [0, 2], ids=["tick", "spec-k2"])
+def test_lane_dense_engine_equals_the_eager_lane(model64, speculation):
+    """Greedy tokens of a head-size-64 engine — lane-dense pools, prefix
+    cache on, through the compiled tick and with speculation (whose
+    draft cache stores the same way) — equal the eager reference lane's
+    and sequential ``generate()``'s."""
+    import warnings
+    from paddle_tpu.models import GPTForCausalLM, gpt_config
+    from paddle_tpu.utils import flags as _flags
+    paddle.seed(1)
+    draft = GPTForCausalLM(gpt_config(
+        "gpt2-124m", num_layers=1, hidden_size=128, num_heads=2,
+        vocab_size=512, max_seq_len=128))
+    draft.eval()
+    shared = _prompts([24], seed=7)[0]
+    prompts = [np.concatenate([shared, t])
+               for t in _prompts([5, 9, 3], seed=8)]
+    news = [12, 7, 10]
+    spec = {"draft_model": draft, "speculation_k": speculation} \
+        if speculation else {}
+
+    def serve(compiled):
+        saved = _flags._FLAGS["FLAGS_compiled_tick"]
+        _flags._FLAGS["FLAGS_compiled_tick"] = compiled
+        try:
+            cfg = ServingConfig(num_slots=2, max_seq_len=64, page_size=8,
+                                prefill_chunk_tokens=16,
+                                enable_prefix_cache=True, **spec)
+            with warnings.catch_warnings():
+                # speculation latches the uncompiled iteration, loudly
+                warnings.simplefilter("ignore")
+                with Engine(model64, cfg) as eng:
+                    assert eng.cache.pools_lane_dense == 4
+                    if speculation:
+                        assert eng.draft_cache.pools_lane_dense == 2
+                    outs = [eng.submit(p, max_new_tokens=n)
+                            .result(timeout=300)
+                            for p, n in zip(prompts, news)]
+                    snap = eng.stats()
+            return [o.output_ids for o in outs], snap
+        finally:
+            _flags._FLAGS["FLAGS_compiled_tick"] = saved
+
+    got, snap = serve(True)
+    want, _ = serve(False)
+    assert snap["prefix_cache_hits"] > 0
+    if speculation:
+        assert snap["spec_windows"] > 0
+    else:
+        assert snap["tick_compiled_hits"] > 0
+        assert snap["tick_fallbacks"] == 0
+    for g, w, p, n in zip(got, want, prompts, news):
+        np.testing.assert_array_equal(g, w)
+        np.testing.assert_array_equal(g, _ref_greedy(model64, p, n))
 
 
 def test_paged_metrics_reach_prometheus(model):

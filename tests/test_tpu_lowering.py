@@ -227,6 +227,60 @@ def test_paged_decode_compiles(v5e, case):
         calls[0]), calls[0]
 
 
+#          rows new h   h_kv d   page pages pool
+_STORED = {
+    "granite-tick": (64, 1, 32, 8, 64, 16, 72, BF16),
+    "granite-prefill-row": (1, 64, 32, 8, 64, 16, 72 * 64, BF16),
+    "gpt2-f32-tick": (8, 1, 12, 12, 64, 16, 64, F32),
+    "gpt2-int8-tick": (8, 1, 12, 12, 64, 32, 32, I8),
+    "mistral-tick": (32, 1, 32, 8, 128, 16, 72, BF16),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_STORED))
+def test_paged_op_on_the_stored_pool_copies_no_pool(v5e, case):
+    """The page write and the read of one attention layer, over donated
+    pools in the shape ``PagedKVCache`` stores them, compiled for the
+    chip: the optimized program holds no ``copy`` of an array as large
+    as a pool.  (At head size 64 a 4-D pool cost three a pool: XLA's
+    layout for it, the row-major write, the kernel's lane-dense view.)"""
+    from paddle_tpu.core.state import no_grad
+    from paddle_tpu.core.tensor import Tensor
+    from paddle_tpu.incubate.nn import functional as IF
+    b, s, h, h_kv, d, psz, n, pool_dt = _STORED[case]
+    item = jnp.dtype(pool_dt).itemsize
+    page = fa.paged_pool_page_shape(psz, h_kv, d, item)
+    assert page == ((psz * h_kv * d // 128, 128) if d < 128
+                    else (psz, h_kv, d))
+    pool = (1 + b * n, *page)
+    quant = item == 1
+    act = F32 if pool_dt == F32 else BF16
+
+    def f(q, k, v, pools, pt, off):
+        kw = dict(zip(("k_scale", "v_scale"), map(Tensor, pools[2:])))
+        with no_grad():
+            out = IF.paged_masked_multihead_attention(
+                Tensor(q), Tensor(k), Tensor(v), Tensor(pools[0]),
+                Tensor(pools[1]), Tensor(pt), Tensor(off), psz, **kw)
+        return tuple(t._data_ for t in out)
+
+    one = SingleDeviceSharding(v5e[0])
+
+    def sds(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one)
+    pools = (sds(pool, pool_dt),) * 2
+    if quant:
+        pools += (sds((pool[0], psz), F32),) * 2
+    text = jax.jit(f, donate_argnums=(3,)).lower(
+        sds((b, s, h, d), act), sds((b, s, h_kv, d), act),
+        sds((b, s, h_kv, d), act), pools, sds((b, n), jnp.int32),
+        sds((b,), jnp.int32)).compile().as_text()
+    assert _n_kernels(text) == (1 if s == 1 else 0)
+    # the reading chip_smoke.py's serve leg takes of its tick on the chip
+    from chip_smoke import pool_sized_copies
+    assert not pool_sized_copies(text, {int(np.prod(pool))})
+
+
 #          slots h    h_kv d    page entries window
 _WINDOWED = {
     # the sparse-expert cell's tick: 128 query heads on 8 kv heads; a

@@ -10,6 +10,12 @@ checkout of it, and the bytes floor (the live contexts' K and V once at
 the chip's HBM rate).  The kernel's worst gap to the gather lane at
 "highest" matmul precision is printed beside each row.
 
+Each shape's pools are built as ``PagedKVCache`` would store them
+(``paged_pool_page_shape``: ``[pages, rows, 128]`` for heads narrower
+than the lanes the kernel hosts, else ``[pages, page_size, H_kv, D]``),
+so the timed program holds no relayout of a pool; the parent's kernel
+gets the 4-D pools its own cache stored.
+
 A call is timed inside ONE program that makes it ``--calls`` times in
 sequence (each call's query depends on the one before), so the host's
 dispatch is not in the number.
@@ -60,9 +66,11 @@ def parent_kernel(checkout):
     return mod.paged_decode_attention
 
 
-def gather_lane(q, kp, vp, pt, off, scale):
+def gather_lane(q, kp, vp, pt, off, scale, page):
+    """The XLA read over the gathered pages; ``page`` = (page_size,
+    H_kv, D), which a lane-dense pool's shape no longer says."""
     b, n = pt.shape
-    psz, h_kv, d = kp.shape[1:]
+    psz, h_kv, d = page
     return _cache_attend(q[:, None], kp[pt].reshape(b, n * psz, h_kv, d),
                          vp[pt].reshape(b, n * psz, h_kv, d), off,
                          scale)[:, 0]
@@ -116,15 +124,17 @@ def main():
         b, h, h_kv, d, psz, n, pool_dt, q_dt, scale = shapes[name]
         rng = np.random.default_rng(29)
         pool = (1 + b * n, psz, h_kv, d)
-        kp = jnp.asarray(rng.standard_normal(pool), pool_dt)
-        vp = jnp.asarray(rng.standard_normal(pool), pool_dt)
+        item = jnp.dtype(pool_dt).itemsize
+        stored = (pool[0], *fa.paged_pool_page_shape(psz, h_kv, d, item))
+        kp4 = jnp.asarray(rng.standard_normal(pool), pool_dt)
+        vp4 = jnp.asarray(rng.standard_normal(pool), pool_dt)
+        kp, vp = kp4.reshape(stored), vp4.reshape(stored)
         q = jnp.asarray(rng.standard_normal((b, h, d)), q_dt)
         table = rng.permutation(np.arange(1, pool[0])).reshape(b, n) \
             .astype(np.int32)
-        item = jnp.dtype(pool_dt).itemsize
         say(f"\n{name}: {b} rows, {h}/{h_kv} heads of {d}, pages of {psz}, "
-            f"{n} a row, {jnp.dtype(pool_dt).name} pool; pages a step by "
-            "the rule: "
+            f"{n} a row, {jnp.dtype(pool_dt).name} pool stored "
+            f"{list(stored)}; pages a step by the rule: "
             f"{fa.paged_decode_pages_per_step(psz, h_kv, d, item)}")
         head = "  ".join(f"{'*' if s == rule_bytes else ''}{s >> 10}K"
                          .rjust(7) for s in steps)
@@ -139,21 +149,25 @@ def main():
                 floor = float((off + 1).sum()) * 2 * h_kv * d * item \
                     / HBM_BYTES_PER_S * 1e6
                 ops = (q, kp, vp, jnp.asarray(pt), jnp.asarray(off))
+
+                def kernel(*a):
+                    return fa.paged_decode_attention(*a, scale=scale,
+                                                     h_kv=h_kv)
+
+                def lane(*a):
+                    return gather_lane(*a, scale, pool[1:])
                 cells = []
                 for step in steps:
                     fa._PAGED_STEP_BYTES = step
-                    cells.append(timed(
-                        lambda *a: fa.paged_decode_attention(
-                            *a, scale=scale), args.calls, *ops, zero))
+                    cells.append(timed(kernel, args.calls, *ops, zero))
                 fa._PAGED_STEP_BYTES = rule_bytes
-                xla = timed(lambda *a: gather_lane(*a, scale), args.calls,
-                            *ops, zero)
+                xla = timed(lane, args.calls, *ops, zero)
                 par = timed(lambda *a: old(*a, scale=scale), args.calls,
-                            *ops, zero) if old else float("nan")
+                            q, kp4, vp4, *ops[3:], zero) \
+                    if old else float("nan")
                 with jax.default_matmul_precision("highest"):
-                    ref = jax.jit(lambda *a: gather_lane(*a, scale))(*ops)
-                got = jax.jit(lambda *a: fa.paged_decode_attention(
-                    *a, scale=scale))(*ops)
+                    ref = jax.jit(lane)(*ops)
+                got = jax.jit(kernel)(*ops)
                 gap = float(jnp.max(jnp.abs(
                     got.astype(jnp.float32) - ref.astype(jnp.float32))))
                 say(f"{ctx:>8} {empty:>5}  "
