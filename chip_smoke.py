@@ -17,7 +17,11 @@ seed), and checks what comes out by the repo's own means:
    which 4 of 16 are held) through the same engine, at shapes the
    kernels host: which lane the windowed paged read and the grouped
    expert product took is printed, and on a TPU has to be the kernel's.
-4. hybrid — with >= 4 devices: ``ParallelGPTForCausalLM`` at the same
+4. latent — a small ``SarvamMLAForCausalLM`` (latent attention over a
+   latent page store, a dense layer then sparse experts) through the
+   same engine, at rows the latent decode kernel hosts: on a TPU the
+   single-token read has to be the kernel's, never the XLA lane's.
+5. hybrid — with >= 4 devices: ``ParallelGPTForCausalLM`` at the same
    widths under ``fleet.init`` (mp 2, dp the rest) through the same
    ``CompiledTrainStep``, losses against phase 1.
 
@@ -379,31 +383,20 @@ def paged_kernel_phase(cfg, sizes):
         assert err <= PAGED_KERNEL_ATOL
 
 
-def sparse_phase(dry_run):
-    """The sparse-expert family through the compiled tick and the prefill
-    member; a lane that silently fell to XLA on the chip shows here."""
-    import paddle_tpu as paddle
-    from paddle_tpu.models.cohere_moe import (CohereMoeConfig,
-                                              CohereMoeForCausalLM)
+def small_family_run(model, what, seed, lane_names):
+    """Four requests of a small model of another family through the
+    compiled tick and the prefill member at shapes its kernels host:
+    (``serving_stats()``, the phase's trace counts of ``lane_names``),
+    the compiled lanes and zero fallbacks asserted."""
     from paddle_tpu.serving import Engine, ServingConfig
     from paddle_tpu.serving.stats import serving_stats
     from paddle_tpu.utils import monitor
 
-    paddle.seed(SEED)
-    # 8 kv heads of 128 in pages of 16, hidden and expert width 256: the
-    # paged kernel and expert_gmm both host these
-    cfg = CohereMoeConfig(
-        vocab_size=512, hidden_size=256, num_layers=4, num_heads=16,
-        num_kv_heads=8, head_dim=128, sliding_window=64,
-        intermediate_size=256, num_experts_published=16,
-        num_experts_per_tok=4, num_shared_experts=2, held_experts=(4, 4),
-        max_seq_len=256, initializer_range=0.05)
-    model = CohereMoeForCausalLM(cfg)
     model.eval()
-    rng = np.random.default_rng(SEED + 2)
+    rng = np.random.default_rng(seed)
     lens, new = [100, 150, 40, 90], [40, 24, 48, 32]
-    prompts = [rng.integers(0, cfg.vocab_size, (n,)).astype(np.int32)
-               for n in lens]
+    prompts = [rng.integers(0, model.config.vocab_size, (n,))
+               .astype(np.int32) for n in lens]
     before = monitor.all_stats()
     with warnings.catch_warnings(record=True) as caught:
         warnings.simplefilter("always")
@@ -416,34 +409,87 @@ def sparse_phase(dry_run):
             outs = [f.result(timeout=900) for f in futs]
         finally:
             eng.shutdown()
-    no_fallback_warnings(caught, "sparse")
+    no_fallback_warnings(caught, what)
     assert [len(o.output_ids) for o in outs] == new
     snap, after = serving_stats(), monitor.all_stats()
     lanes = {name: after.get(name, 0) - before.get(name, 0)
-             for name in ("pallas.paged_decode.kernel",
-                          "pallas.paged_decode.xla_lane",
-                          "pallas.expert_gmm.kernel",
-                          "pallas.expert_gmm.xla_lane")}
-    say(f"sparse: tick_compiled_hits={snap['tick_compiled_hits']} "
+             for name in lane_names}
+    say(f"{what}: tick_compiled_hits={snap['tick_compiled_hits']} "
         f"tick_fallbacks={snap['tick_fallbacks']} "
         f"prefill_fallbacks={snap['prefill_fallbacks']} "
         f"tick_overlap_share={snap['tick_overlap_share']:.3f} "
-        f"expert_pairs_per_token={snap['expert_pairs_per_token']:.3f} "
-        f"window_pages_held_share={snap['window_pages_held_share']:.3f} "
-        f"paged_decode_kernel_traces={snap['paged_decode_kernel_traces']} "
-        f"paged_decode_xla_lane_traces="
-        f"{snap['paged_decode_xla_lane_traces']}")
-    say("sparse: traces in this phase " + " ".join(
+        f"expert_pairs_per_token={snap['expert_pairs_per_token']:.3f}")
+    say(f"{what}: traces in this phase " + " ".join(
         f"{k}={v}" for k, v in lanes.items()))
     assert snap["tick_compiled_hits"] > 0 and snap["tick_fallbacks"] == 0
     assert snap["prefill_compiled_hits"] > 0
     assert snap["prefill_fallbacks"] == 0
     assert snap["tick_overlap_share"] > 0
+    return snap, lanes
+
+
+def sparse_phase(dry_run):
+    """The sparse-expert family through the compiled tick and the prefill
+    member; a lane that silently fell to XLA on the chip shows here."""
+    import paddle_tpu as paddle
+    from paddle_tpu.models.cohere_moe import (CohereMoeConfig,
+                                              CohereMoeForCausalLM)
+
+    paddle.seed(SEED)
+    # 8 kv heads of 128 in pages of 16, hidden and expert width 256: the
+    # paged kernel and expert_gmm both host these
+    cfg = CohereMoeConfig(
+        vocab_size=512, hidden_size=256, num_layers=4, num_heads=16,
+        num_kv_heads=8, head_dim=128, sliding_window=64,
+        intermediate_size=256, num_experts_published=16,
+        num_experts_per_tok=4, num_shared_experts=2, held_experts=(4, 4),
+        max_seq_len=256, initializer_range=0.05)
+    snap, lanes = small_family_run(
+        CohereMoeForCausalLM(cfg), "sparse", SEED + 2,
+        ("pallas.paged_decode.kernel", "pallas.paged_decode.xla_lane",
+         "pallas.expert_gmm.kernel", "pallas.expert_gmm.xla_lane"))
+    say(f"sparse: window_pages_held_share="
+        f"{snap['window_pages_held_share']:.3f} "
+        f"paged_decode_kernel_traces={snap['paged_decode_kernel_traces']} "
+        f"paged_decode_xla_lane_traces="
+        f"{snap['paged_decode_xla_lane_traces']}")
     assert 0 < snap["window_pages_held_share"] < 1
     if not dry_run:
         assert lanes["pallas.paged_decode.kernel"] > 0, lanes
         assert lanes["pallas.expert_gmm.kernel"] > 0, lanes
         assert lanes["pallas.expert_gmm.xla_lane"] == 0, lanes
+
+
+def latent_phase(dry_run):
+    """The latent-attention family through the compiled tick and the
+    prefill member: rows in a latent page store, the decode read in the
+    absorbed form.  A read that silently fell to the XLA lane on the chip
+    shows here."""
+    import paddle_tpu as paddle
+    from paddle_tpu.models.sarvam_mla import (SarvamMLAConfig,
+                                              SarvamMLAForCausalLM)
+
+    paddle.seed(SEED)
+    # rows of 128 + 64 values in 256 lanes, pages of 16: the latent
+    # decode kernel hosts these in bfloat16 and float32 alike
+    cfg = SarvamMLAConfig(
+        vocab_size=512, hidden_size=256, num_layers=3, num_heads=8,
+        kv_lora_rank=128, qk_nope_head_dim=64, qk_rope_head_dim=64,
+        v_head_dim=64, intermediate_size=512, moe_intermediate_size=256,
+        num_experts_published=16, num_experts_per_tok=4,
+        num_shared_experts=1, held_experts=(4, 4), max_seq_len=256,
+        initializer_range=0.05)
+    snap, lanes = small_family_run(
+        SarvamMLAForCausalLM(cfg), "latent", SEED + 3,
+        ("pallas.mla_decode.kernel", "pallas.mla_decode.xla_lane",
+         "pallas.expert_gmm.kernel", "pallas.expert_gmm.xla_lane"))
+    say(f"latent: kv_latent_pools={snap['kv_latent_pools']} "
+        f"kv_latent_row_bytes={snap['kv_latent_row_bytes']}")
+    assert snap["kv_latent_pools"] == cfg.num_layers
+    assert snap["kv_pools"] == 0
+    if not dry_run:
+        assert lanes["pallas.mla_decode.kernel"] > 0, lanes
+        assert lanes["pallas.mla_decode.xla_lane"] == 0, lanes
 
 
 def hybrid_phase(cfg, sizes, one_chip_losses, dry_run):
@@ -524,6 +570,7 @@ def main():
     del model
     gc.collect()
     sparse_phase(args.dry_run)
+    latent_phase(args.dry_run)
     if jax.device_count() >= 4:
         hybrid_phase(cfg, sizes, losses, args.dry_run)
     else:

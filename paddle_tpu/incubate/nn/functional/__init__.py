@@ -289,40 +289,21 @@ _CHUNK_KEY_BLOCK = 512
 _ONE_BLOCK_SCORE_BYTES = 32 << 20
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("psz", "h_kv", "scale", "window"))
-def _paged_block_attend(qa, kp, vp, pt, off, ks=None, vs=None, *, psz,
-                        h_kv, scale, window):
-    """Attention of ``qa`` [B, S, Hq, D] at positions ``off .. off + S -
-    1`` over each row's LIVE pages — and, with ``window``, over its
-    in-window pages alone — in blocks of ``_CHUNK_KEY_BLOCK`` keys:
-    a block's pages are gathered through the page table, scored in the
-    operands' type with float32 accumulation, and folded into a float32
-    online softmax, so nothing of size ``[Hq, S, capacity]`` ever
-    exists.  The loop runs as many blocks as the longest row needs; a
-    read small enough over the whole table (``_ONE_BLOCK_SCORE_BYTES``:
-    a single-token read, a short chunk of a few rows over a short slot)
-    is one block without a loop.
-    With ``window`` the table is a ring (logical page ``p`` at entry
-    ``p % N``; the same entry where the table spans the slot).  The
-    pools are ``[P, psz, h_kv, D]`` or lane-dense ``[P, rows, 128]``:
-    what is reshaped to ``[.., psz, h_kv, D]`` is the GATHERED block,
-    the live pages of one step, never a pool.  A ``jax.jit`` of its own
-    inside the caller's program: the layers of a model trace it once
-    between them, not once each."""
-    import math as _math
-    from ....quantization import dequantize_kv
-    b, s, h_q, d = qa.shape
+def _blocked_attend(qg, off, pt, gather_kv, *, s, d_v, psz, kb, one_block,
+                    window, sc, cdt, out_dtype):
+    """The blocked online softmax of a paged read: ``qg`` [B, S, H_kv,
+    rep, D_k] at positions ``off .. off + S - 1`` against each row's
+    live pages (with ``window``: its in-window pages alone, the table a
+    ring), ``kb`` pages a block.  ``gather_kv(phys)`` turns a block's
+    physical page ids [B, kb] into its keys [B, kb * psz, H_kv, D_k] and
+    values [B, kb * psz, H_kv, D_v] — what a page holds is the store
+    kind's to say.  Scores in ``cdt`` with float32 accumulation,
+    statistics and sums in float32; as many blocks as the longest row
+    needs (``one_block``: one, no loop).  Returns [B, S, H_kv * rep,
+    D_v]."""
+    b, _, h_kv, rep, _ = qg.shape
     n_tab = pt.shape[1]
-    rep = h_q // h_kv
-    sc = scale if scale is not None else 1.0 / _math.sqrt(d)
-    one_block = b * h_q * s * n_tab * psz * 4 <= _ONE_BLOCK_SCORE_BYTES
-    kb = n_tab if one_block else max(1, min(n_tab, _CHUNK_KEY_BLOCK // psz))
     blk = kb * psz
-    quant = ks is not None
-    kdt = jnp.float32 if quant else kp.dtype
-    cdt = jnp.promote_types(qa.dtype, kdt)
-    qg = qa.astype(cdt).reshape(b, s, h_kv, rep, d)
     q_pos = off[:, None] + jnp.arange(s, dtype=jnp.int32)[None, :]  # [b,s]
     first_tok = jnp.zeros_like(off) if window is None else \
         jnp.maximum(off - (window - 1), 0)
@@ -331,12 +312,6 @@ def _paged_block_attend(qa, kp, vp, pt, off, ks=None, vs=None, *, psz,
     n_blocks = jnp.max(-(-(last_page - first_page + 1) // kb))
     pt = pt.astype(jnp.int32)
 
-    def gather(pool, scales, phys):
-        pages = pool[phys].reshape(b, kb, psz, h_kv, d)
-        if quant:
-            pages = dequantize_kv(pages, scales[phys])
-        return pages.reshape(b, blk, h_kv, d)
-
     def body(j, carry):
         m_prev, l_prev, acc = carry
         lp = first_page[:, None] + j * kb + \
@@ -344,8 +319,7 @@ def _paged_block_attend(qa, kp, vp, pt, off, ks=None, vs=None, *, psz,
         idx = lp % n_tab if window is not None else \
             jnp.minimum(lp, n_tab - 1)
         phys = jnp.take_along_axis(pt, idx, axis=1)
-        kblk = gather(kp, ks, phys)
-        vblk = gather(vp, vs, phys)
+        kblk, vblk = gather_kv(phys)
         k_pos = (lp[:, :, None] * psz
                  + jnp.arange(psz, dtype=jnp.int32)).reshape(b, blk)
         sco = jnp.einsum("bqhrd,bkhd->bhrqk", qg, kblk.astype(cdt),
@@ -364,12 +338,107 @@ def _paged_block_attend(qa, kp, vp, pt, off, ks=None, vs=None, *, psz,
 
     init = (jnp.full((b, h_kv, rep, s, 1), -1e30, jnp.float32),
             jnp.zeros((b, h_kv, rep, s, 1), jnp.float32),
-            jnp.zeros((b, h_kv, rep, s, d), jnp.float32))
+            jnp.zeros((b, h_kv, rep, s, d_v), jnp.float32))
     _, l, acc = body(0, init) if one_block else \
         jax.lax.fori_loop(0, n_blocks, body, init)
     out = acc / jnp.maximum(l, 1e-30)
-    return jnp.transpose(out, (0, 3, 1, 2, 4)).reshape(b, s, h_q, d) \
-        .astype(qa.dtype)
+    return jnp.transpose(out, (0, 3, 1, 2, 4)) \
+        .reshape(b, s, h_kv * rep, d_v).astype(out_dtype)
+
+
+def _block_pages(b, h_q, s, n_tab, psz):
+    """(pages a block of the blocked read gathers, whether the whole
+    table is one block)."""
+    one_block = b * h_q * s * n_tab * psz * 4 <= _ONE_BLOCK_SCORE_BYTES
+    return (n_tab if one_block
+            else max(1, min(n_tab, _CHUNK_KEY_BLOCK // psz))), one_block
+
+
+@functools.partial(jax.jit,
+                   static_argnames=("psz", "h_kv", "scale", "window"))
+def _paged_block_attend(qa, kp, vp, pt, off, ks=None, vs=None, *, psz,
+                        h_kv, scale, window):
+    """Attention of ``qa`` [B, S, Hq, D] at positions ``off .. off + S -
+    1`` over each row's LIVE pages — and, with ``window``, over its
+    in-window pages alone — in blocks of ``_CHUNK_KEY_BLOCK`` keys:
+    a block's pages are gathered through the page table, scored in the
+    operands' type with float32 accumulation, and folded into a float32
+    online softmax (``_blocked_attend``), so nothing of size ``[Hq, S,
+    capacity]`` ever exists.  The loop runs as many blocks as the
+    longest row needs; a read small enough over the whole table
+    (``_ONE_BLOCK_SCORE_BYTES``: a single-token read, a short chunk of a
+    few rows over a short slot) is one block without a loop.
+    With ``window`` the table is a ring (logical page ``p`` at entry
+    ``p % N``; the same entry where the table spans the slot).  The
+    pools are ``[P, psz, h_kv, D]`` or lane-dense ``[P, rows, 128]``:
+    what is reshaped to ``[.., psz, h_kv, D]`` is the GATHERED block,
+    the live pages of one step, never a pool.  A ``jax.jit`` of its own
+    inside the caller's program: the layers of a model trace it once
+    between them, not once each."""
+    import math as _math
+    from ....quantization import dequantize_kv
+    b, s, h_q, d = qa.shape
+    n_tab = pt.shape[1]
+    rep = h_q // h_kv
+    sc = scale if scale is not None else 1.0 / _math.sqrt(d)
+    kb, one_block = _block_pages(b, h_q, s, n_tab, psz)
+    blk = kb * psz
+    quant = ks is not None
+    kdt = jnp.float32 if quant else kp.dtype
+    cdt = jnp.promote_types(qa.dtype, kdt)
+    qg = qa.astype(cdt).reshape(b, s, h_kv, rep, d)
+
+    def gather(pool, scales, phys):
+        pages = pool[phys].reshape(b, kb, psz, h_kv, d)
+        if quant:
+            pages = dequantize_kv(pages, scales[phys])
+        return pages.reshape(b, blk, h_kv, d)
+
+    return _blocked_attend(
+        qg, off, pt,
+        lambda phys: (gather(kp, ks, phys), gather(vp, vs, phys)),
+        s=s, d_v=d, psz=psz, kb=kb, one_block=one_block, window=window,
+        sc=sc, cdt=cdt, out_dtype=qa.dtype)
+
+
+@functools.partial(jax.jit,
+                   static_argnames=("psz", "nope", "width", "scale"))
+def _latent_block_attend(qa, pool, pt, off, w_kvb, *, psz, nope, width,
+                         scale):
+    """A prefill chunk's read of a LATENT store: ``qa`` [B, S, H, nope +
+    rope] (rotated) at positions ``off .. off + S - 1`` over each row's
+    live latent pages ``[P, psz, lanes]`` in the blocks of
+    ``_paged_block_attend``.  A gathered block's rows ``[c, k_r]`` are
+    UP-PROJECTED once (``w_kvb`` [rank, H * (nope + v)]: a head's key is
+    ``[W_uk,h c, k_r]``, its value ``W_uv,h c``) and attended with
+    ``nope + rope``-wide keys and ``v``-wide values through the same
+    blocked online softmax — at chunk lengths the absorbed form would
+    cost about twice the FLOPs.  ``width`` = rank + rope: the row's
+    values among the pool's lanes."""
+    b, s, h, d_k = qa.shape
+    rank = w_kvb.shape[0]
+    d_v = w_kvb.shape[1] // h - nope
+    n_tab = pt.shape[1]
+    kb, one_block = _block_pages(b, h, s, n_tab, psz)
+    blk = kb * psz
+    cdt = jnp.promote_types(qa.dtype, pool.dtype)
+    qg = qa.astype(cdt).reshape(b, s, h, 1, d_k)
+
+    def gather(phys):
+        rows = pool[phys].reshape(b, blk, pool.shape[-1])
+        with jax.named_scope("mla_up_project"):
+            kv = jnp.einsum("bkc,cn->bkn", rows[..., :rank], w_kvb,
+                            preferred_element_type=jnp.float32) \
+                .astype(cdt).reshape(b, blk, h, nope + d_v)
+        k_r = jnp.broadcast_to(rows[:, :, None, rank:width].astype(cdt),
+                               (b, blk, h, width - rank))
+        return (jnp.concatenate([kv[..., :nope], k_r], axis=-1),
+                kv[..., nope:])
+
+    return _blocked_attend(
+        qg, off, pt, gather, s=s, d_v=d_v, psz=psz, kb=kb,
+        one_block=one_block, window=None, sc=scale, cdt=cdt,
+        out_dtype=qa.dtype)
 
 
 def paged_masked_multihead_attention(q, k, v, k_pool, v_pool, page_table,
@@ -528,7 +597,13 @@ def paged_cache_attention(q, k, v, cache, scale=None):
     functionally-updated pools — and scales, when quantized — back into
     the dict, and returns the attention output.  The single cache-path
     entry point the model families share, so adding a storage format
-    never touches four attention call sites again."""
+    never touches four attention call sites again.  A layer dict of
+    another store kind is refused by name: a latent store's rows have no
+    heads and no V (``paged_latent_attention``)."""
+    if "latent_pool" in cache:
+        raise ValueError(
+            "paged_cache_attention reads a K/V page store; this layer's "
+            "cache is a latent page store: call paged_latent_attention")
     if cache.get("k_scale") is not None:
         out, kp, vp, ks, vs = paged_masked_multihead_attention(
             q, k, v, cache["k_pool"], cache["v_pool"],
@@ -542,6 +617,80 @@ def paged_cache_attention(q, k, v, cache, scale=None):
             cache["page_table"], cache["offset"], cache["page_size"],
             scale=scale, window=cache.get("window"))
     cache["k_pool"], cache["v_pool"] = kp, vp
+    return out
+
+
+def paged_latent_attention(q, row, w_kvb, cache, *, nope_dim, scale):
+    """Latent (low-rank) attention against one LATENT layer dict of a
+    `PagedKVCache` (``latent_pool`` [P, page_size, lanes]: one row
+    ``[c, k_r]`` a token, padded to whole lane tiles; the same page
+    table and offsets as any paged layer).
+
+    q: [B, S, H, nope + rope] the new tokens' queries, the rope part
+    rotated; row: [B, S, rank + rope] their latent rows ``[c, rope(k_r)]``
+    (``c`` normed); w_kvb: [rank, H * (nope + v)], a head's columns
+    ``[W_uk,h | W_uv,h]``.  Writes the rows through the page table at
+    offset..offset+S, then reads:
+
+    - a single token (S = 1) in the ABSORBED form — ``q~_h = W_uk,h^T
+      q_nope,h``, every head against the same rows, ``o_h = W_uv,h
+      sum_t p_t c_t`` — through the Pallas kernel
+      ``pallas.mla.mla_decode_attention`` on a TPU (and in interpret
+      mode) or its XLA lane, counted where traced
+      (``pallas.mla_decode.kernel`` / ``.xla_lane``);
+    - a chunk (S > 1) in the UP-PROJECTED form, ``_latent_block_attend``:
+      each gathered block of rows becomes per-head keys and values once.
+
+    Both equal ``softmax(q_h . [W_uk,h c, k_r] * scale) W_uv,h c`` in
+    exact arithmetic.  Returns the heads' outputs [B, S, H, v]; the
+    updated pool goes back into ``cache``."""
+    from ....pallas import mla as _mla
+    psz = int(cache["page_size"])
+    width = int(cache["latent_width"])
+    nope = int(nope_dim)
+    scale = float(scale)
+    s_new = q.shape[1]
+
+    def fn(qa, ra, wa, pool, pt, off):
+        b, s, h, _ = qa.shape
+        rank = wa.shape[0]
+        if ra.shape[-1] != width:
+            raise ValueError(f"latent rows of {ra.shape[-1]} values for a "
+                             f"store of rows of {width}")
+        lanes = pool.shape[-1]
+        off = off.astype(jnp.int32)
+        pos = off[:, None] + jnp.arange(s, dtype=jnp.int32)[None, :]
+        page_ids = jnp.take_along_axis(pt.astype(jnp.int32), pos // psz,
+                                       axis=1)
+        pad = ((0, 0), (0, 0), (0, lanes - width))
+        pool = pool.at[page_ids, pos % psz].set(
+            jnp.pad(ra.astype(pool.dtype), pad))
+        if s_new > 1:
+            return _latent_block_attend(
+                qa, pool, pt, off, wa, psz=psz, nope=nope, width=width,
+                scale=scale), pool
+        w3 = wa.reshape(rank, h, -1)
+        with jax.named_scope("mla_absorb"):
+            qt = jnp.einsum("bhd,chd->bhc", qa[:, 0, :, :nope],
+                            w3[..., :nope],
+                            preferred_element_type=jnp.float32)
+        q_lat = jnp.pad(jnp.concatenate(
+            [qt.astype(pool.dtype), qa[:, 0, :, nope:].astype(pool.dtype)],
+            axis=-1), pad)
+        with jax.named_scope("mla_decode"):
+            ot = _mla.mla_decode(q_lat, pool, pt.astype(jnp.int32), off,
+                                 rank, scale)
+        with jax.named_scope("mla_absorb"):
+            out = jnp.einsum("bhc,chd->bhd", ot.astype(wa.dtype),
+                             w3[..., nope:],
+                             preferred_element_type=jnp.float32)
+        return out.astype(qa.dtype)[:, None], pool
+
+    out, pool = apply_op(
+        "paged_latent_attention", fn,
+        (q, row, w_kvb, cache["latent_pool"], cache["page_table"],
+         cache["offset"]))
+    cache["latent_pool"] = pool
     return out
 
 

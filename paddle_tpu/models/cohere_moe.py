@@ -52,7 +52,7 @@ from ..core.dispatch import apply_op
 from ..nn import Layer, Linear, Embedding, LayerNorm, LayerList
 from ..nn import functional as F
 from ..nn.initializer import Normal, ParamAttr
-from ..pallas import moe as _moe
+from .sparse_experts import SparseExpertMLP
 from ..tensor_ops import manipulation as MA
 from ..incubate.nn import functional as IF
 
@@ -229,91 +229,19 @@ class CohereMoeAttention(Layer):
         return self.o_proj(MA.reshape(out, [b, s, cfg.num_heads * d]))
 
 
-class _ExpertStack(Layer):
-    """``count`` gated-SiLU experts, their three matrices stacked."""
-
-    def __init__(self, config: CohereMoeConfig, count):
-        super().__init__()
-        h, f = config.hidden_size, config.intermediate_size
-        std = config.initializer_range
-        self.gate_proj = self.create_parameter(
-            (count, h, f), default_initializer=Normal(0.0, std))
-        self.up_proj = self.create_parameter(
-            (count, h, f), default_initializer=Normal(0.0, std))
-        self.down_proj = self.create_parameter(
-            (count, f, h), default_initializer=Normal(
-                0.0, std / math.sqrt(2 * config.num_layers)))
-
-
-class CohereSparseMLP(Layer):
-    """The expert layer that is told which experts it holds: routes over
-    all, computes what its own give, drops nothing, static shapes."""
+class CohereSparseMLP(SparseExpertMLP):
+    """``models/sparse_experts.py``'s layer at this family's settings:
+    no selection bias, gates that sum to 1, the shared experts'
+    "average"."""
 
     def __init__(self, config: CohereMoeConfig):
-        super().__init__()
+        std = config.initializer_range
+        super().__init__(
+            config.hidden_size, config.intermediate_size,
+            config.num_experts_published, config.held_experts,
+            config.num_experts_per_tok, config.num_shared_experts, std,
+            down_std=std / math.sqrt(2 * config.num_layers))
         self.config = config
-        w_init = ParamAttr(initializer=Normal(0.0, config.initializer_range))
-        self.gate = Linear(config.hidden_size, config.num_experts_published,
-                           weight_attr=w_init, bias_attr=False)
-        self.experts = _ExpertStack(config, config.num_experts_held)
-        self.shared_experts = _ExpertStack(config,
-                                           config.num_shared_experts)
-        #: "average": the mean of the shared experts' outputs
-        self.shared_scale = 1.0 / config.num_shared_experts
-
-    def routed(self, x, valid=None):
-        """The held routed experts' part of the layer for ``x`` [B, S,
-        h], and the pairs computed for each held expert ([E_held] int32;
-        with ``valid`` [B], each row's count of real positions, the
-        others' pairs are computed and not counted)."""
-        cfg = self.config
-        b, s, h = x.shape
-        held, k = cfg.held_experts, cfg.num_experts_per_tok
-        ex = self.experts
-
-        def routed(xa, wr, wg, wu, wd, *valid):
-            tokens = xa.reshape(b * s, h)
-            experts, gates = _moe.route_sigmoid_topk(
-                _moe.router_logits(tokens, wr), k)
-            real = None
-            if valid:
-                real = (jnp.arange(s)[None, :] < valid[0][:, None]) \
-                    .reshape(-1)
-            y, counts = _moe.routed_experts(tokens, experts, gates, wg, wu,
-                                            wd, held, real)
-            return y.reshape(b, s, h), counts
-
-        args = (x, self.gate.weight, ex.gate_proj, ex.up_proj, ex.down_proj)
-        return apply_op("routed_experts", routed,
-                        args if valid is None else args + (valid,))
-
-    def forward(self, x, cache=None):
-        cfg = self.config
-        b, s, h = x.shape
-        valid = None if cache is None else cache.get("valid_len")
-        sh = self.shared_experts
-
-        def shared(xa, wg, wu, wd):
-            tokens = xa.reshape(b * s, h)
-            acc = None
-            for j in range(cfg.num_shared_experts):
-                # an expert at a time: slicing the stack's first axis is
-                # a view, a product over the stacked axis a transpose
-                y = jnp.matmul(jax.nn.silu(jnp.matmul(tokens, wg[j]))
-                               * jnp.matmul(tokens, wu[j]), wd[j]) \
-                    .astype(jnp.float32)
-                acc = y if acc is None else acc + y
-            return (acc * self.shared_scale).astype(xa.dtype) \
-                .reshape(b, s, h)
-
-        with named_scope("moe"):
-            y, counts = self.routed(x, valid)
-        with named_scope("moe_shared"):
-            y = y + apply_op("shared_experts", shared,
-                             (x, sh.gate_proj, sh.up_proj, sh.down_proj))
-        if valid is not None:
-            cache["moe_counts"] = counts
-        return y
 
 
 class CohereMoeBlock(Layer):

@@ -198,6 +198,14 @@ def generate(model, input_ids, max_new_tokens=32, temperature=0.0,
                 "which the dense caches built here do not have; serve it "
                 "through serving.Engine (page tables by layer kind) or "
                 "pass use_cache=False")
+        latents = getattr(cfg, "layer_latents", None)
+        if latents is not None and any(w is not None for w in latents()):
+            raise NotImplementedError(
+                f"generate(use_cache=True) for {type(model).__name__}: "
+                "its layers cache one latent row a token, which the dense "
+                "key/value caches built here do not hold; serve it "
+                "through serving.Engine (latent pages) or pass "
+                "use_cache=False")
         if hasattr(model, "init_caches"):
             # a model whose layers do not all keep keys and values
             # builds its own per-layer caches

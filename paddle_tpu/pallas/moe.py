@@ -48,13 +48,23 @@ def router_logits(x, w):
                       precision=jax.lax.Precision.HIGHEST)
 
 
-def route_sigmoid_topk(router_logits, k):
+def route_sigmoid_topk(router_logits, k, bias=None, scale=None):
     """``router_logits`` [T, E] float32 -> (experts [T, k] int32, gates
     [T, k] float32): the ``k`` largest sigmoid scores, normalised to sum
-    1 over the chosen."""
+    1 over the chosen.  ``bias`` [E] (a selection bias, as bias-corrected
+    load balancing keeps one) is added to the scores for the CHOICE
+    alone: the gates are the chosen experts' unbiased scores.  ``scale``
+    multiplies the normalised gates (a routed scaling factor).  With
+    neither, the program is what it was before either existed."""
     scores = jax.nn.sigmoid(router_logits.astype(jnp.float32))
-    top, idx = jax.lax.top_k(scores, k)
-    return idx.astype(jnp.int32), top / jnp.sum(top, axis=-1, keepdims=True)
+    if bias is None:
+        top, idx = jax.lax.top_k(scores, k)
+    else:
+        _, idx = jax.lax.top_k(scores + bias.astype(jnp.float32), k)
+        top = jnp.take_along_axis(scores, idx, axis=-1)
+    experts = idx.astype(jnp.int32)
+    gates = top / jnp.sum(top, axis=-1, keepdims=True)
+    return experts, gates if scale is None else gates * scale
 
 
 def row_tile(tokens):

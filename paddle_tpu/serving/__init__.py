@@ -16,7 +16,7 @@ from __future__ import annotations
 from .adapters import AdapterPool  # noqa: F401
 from .api import (  # noqa: F401
     AdapterConfigError, DeadlineExceededError, EngineShutdownError,
-    NoReplicaError, PageMigrationError, QueueFullError,
+    LatentStoreError, NoReplicaError, PageMigrationError, QueueFullError,
     RecurrentStateError, RequestCancelledError, RequestOutput,
     SamplingParams,
     SchedulerStallError, ServingConfig, ServingError,
@@ -40,6 +40,7 @@ __all__ = [
     "QueueFullError", "DeadlineExceededError", "EngineShutdownError",
     "SchedulerStallError", "NoReplicaError", "PageMigrationError",
     "RequestCancelledError", "RecurrentStateError", "WindowLayerError",
+    "LatentStoreError",
     "AdapterConfigError", "UnknownAdapterError", "AdapterPool",
     "serving_stats", "reset_serving_stats", "reset_router_stats",
     "ServingRouter", "RouterConfig", "HashRing", "ServingFleet",

@@ -108,6 +108,20 @@ class WindowLayerError(ServingError, ValueError):
     kind — never a wrong answer mid-decode."""
 
 
+class LatentStoreError(ServingError, ValueError):
+    """The configuration asks for a mechanism that cannot yet serve a
+    model whose layers keep a latent page store (what
+    ``model.config.layer_latents()`` reports: one row a token, no heads,
+    no V): page export / adoption and migration carry a K/V page store's
+    ``k`` and ``v`` pages by name, quantized storage keeps per-page K/V
+    scales, and a draft model's mirror cache holds keys and values only.
+    What moves pages through the page table alone — prefix sharing,
+    speculative rollback — works unchanged: a latent page is a page.
+    Raised at ``Engine`` construction (and by the cache calls
+    themselves) and names the store kind — never a wrong answer
+    mid-decode."""
+
+
 @dataclass(frozen=True)
 class SamplingParams:
     """Per-request decoding knobs — the same semantics (and HF processor

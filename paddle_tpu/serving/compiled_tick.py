@@ -743,18 +743,20 @@ class CompiledServingTick:
         stats.incr("kv.page_ticks_reserved",
                    cache.usable_pages - cache.available_pages
                    + cache.window_pages_promised)
+        if cache.ring_pages or cache.latent_pools:
+            # the contexts a window or latent layer's decode read covers
+            ctx = cache.offsets[list(active)] + 1
+            stats.incr("kv.context_token_ticks", int(ctx.sum()))
         if cache.ring_pages:
             # what the window layers hold against what one shared table
             # would have held for them, and the tokens a window layer's
             # decode read covers against the contexts
-            ctx = cache.offsets[list(active)] + 1
             stats.incr("kv.window.page_ticks_held",
                        cache.window_pages_in_use)
             stats.incr("kv.window.page_ticks_full_equiv",
                        cache.pages_in_use)
             stats.incr("kv.window.token_ticks",
                        int(np.minimum(ctx, cache.window).sum()))
-            stats.incr("kv.context_token_ticks", int(ctx.sum()))
         # page table / offsets: host mutations (admission, release,
         # growth) flow through the cache's own lazy flush; steady-state
         # ticks ride the previous program's device outputs

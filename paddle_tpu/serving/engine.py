@@ -36,7 +36,7 @@ from ..observability import tracing
 from ..observability.tracing import span
 from ..utils import fault_injection as _fi
 from .api import (DeadlineExceededError, EngineShutdownError,
-                  QueueFullError, RecurrentStateError,
+                  LatentStoreError, QueueFullError, RecurrentStateError,
                   RequestCancelledError, RequestOutput, SamplingParams,
                   SchedulerStallError, ServingConfig, WindowLayerError)
 from ..models.generation import recurrent_layer_states
@@ -130,6 +130,16 @@ def _window_layers(cfg):
         else None
 
 
+def _latent_layers(cfg):
+    """What ``cfg.layer_latents()`` says each layer caches a token (None:
+    keys and values; else the width of its one latent row), or None when
+    the config has no such method or no layer is latent."""
+    latents = getattr(cfg, "layer_latents", None)
+    latents = None if latents is None else latents()
+    return latents if latents and any(w is not None for w in latents) \
+        else None
+
+
 class Engine:
     """`Engine(model).start()`; then `submit()` (async, returns a
     `Future[RequestOutput]`) or `generate()` (sync).  `shutdown()` stops
@@ -144,7 +154,7 @@ class Engine:
             model.eval()            # serving never wants dropout
         self.max_len = self.scfg.max_seq_len or self.cfg.max_seq_len
         self._kv_heads = getattr(self.cfg, "num_kv_heads",
-                                 self.cfg.num_heads)
+                                 getattr(self.cfg, "num_heads", None))
         from ..quantization import kv_quant_params
         self._quant = kv_quant_params(self.scfg.cache_dtype) is not None
         # a quantized page packs 2x the baseline page's tokens in half
@@ -181,6 +191,10 @@ class Engine:
         # (None where no layer does)
         self._layer_windows = _window_layers(self.cfg)
         self._refuse_for_window_layers()
+        # which layers cache one latent row a token instead of keys and
+        # values: paged like any other, in a store of their own shape
+        self._layer_latents = _latent_layers(self.cfg)
+        self._refuse_for_latent_layers()
         self._pages_peak = 0
         self._queue: deque[_Request] = deque()
         self._active: dict[int, _Request] = {}
@@ -320,6 +334,30 @@ class Engine:
                 f"role={self.scfg.role!r}: {why}, and export_pages / "
                 "adopt_pages carry one page table's pages")
 
+    def _refuse_for_latent_layers(self):
+        """Typed refusals, at construction, of what cannot serve a latent
+        page store yet (docs/SERVING.md "Latent pages").  Prefix sharing
+        and speculative rollback move pages through the page table alone
+        and are NOT refused: a latent page is a page."""
+        draft = self.scfg.draft_model
+        if draft is not None and _latent_layers(draft.config) is not None:
+            raise LatentStoreError(
+                "draft_model has layers that keep a latent page store: "
+                "the draft cache holds keys and values only")
+        if self._layer_latents is None:
+            return
+        n = sum(w is not None for w in self._layer_latents)
+        why = f"{n} of the model's layers keep a latent page store"
+        if self._quant:
+            raise LatentStoreError(
+                f"cache_dtype={self.scfg.cache_dtype!r}: {why}, one row a "
+                "token with no per-page K/V scales; pass a float "
+                "cache_dtype")
+        if self.scfg.role != "mixed":
+            raise LatentStoreError(
+                f"role={self.scfg.role!r}: {why}, and export_pages / "
+                "adopt_pages carry a K/V page store's k and v pages")
+
     @property
     def migrator(self):
         return self._migrator
@@ -336,6 +374,11 @@ class Engine:
                 "migrator: export_pages / adopt_pages carry one page "
                 "table's pages, and the model's sliding_attention layers "
                 "keep a ring of their own")
+        if fn is not None and self._layer_latents is not None:
+            raise LatentStoreError(
+                "migrator: export_pages / adopt_pages carry a K/V page "
+                "store's k and v pages, and the model's layers keep a "
+                "latent page store")
         self._migrator = fn
 
     # ---------------- lifecycle ----------------
@@ -388,19 +431,22 @@ class Engine:
             slot_len = -(-slot_len // chunk) * chunk
         cache = PagedKVCache(
             self.cfg.num_layers, self.scfg.num_slots, slot_len,
-            self._kv_heads, self.cfg.head_dim,
+            self._kv_heads, getattr(self.cfg, "head_dim", None),
             page_size=self._page_size,
             num_pages=self.scfg.kv_pool_pages,
             dtype=self.scfg.cache_dtype,
             layer_states=self._layer_states,
             layer_windows=self._layer_windows,
             # the widest run of positions one call writes
-            window_slack=min(self.scfg.prefill_chunk_tokens, slot_len))
+            window_slack=min(self.scfg.prefill_chunk_tokens, slot_len),
+            layer_latents=self._layer_latents)
         stats.set_value("state.bytes", cache.state_bytes)
         stats.set_value("kv.pages_spanned",
                         cache.num_slots * cache.pages_per_slot)
         stats.set_value("kv.pools", cache.pools)
         stats.set_value("kv.pools_lane_dense", cache.pools_lane_dense)
+        stats.set_value("kv.latent_pools", cache.latent_pools)
+        stats.set_value("kv.latent_row_bytes", cache.latent_row_bytes)
         self.prefix_tree = PrefixTree(self._page_size) \
             if self.scfg.enable_prefix_cache else None
         # one compiled prefill program: every chunk is this wide
@@ -1293,12 +1339,14 @@ class Engine:
             tokens[row, :end - start] = req.prompt[start:end]
             last[row] = end - 1 - start
             useful += end - off
-            if cache.ring_pages:
-                # positions the new tokens see in a full layer and in a
-                # window layer
+            if cache.ring_pages or cache.latent_pools:
+                # positions the new tokens see in a full (or latent)
+                # layer and in a window layer
                 seen = np.arange(off, end, dtype=np.int64) + 1
                 seen_full += int(seen.sum())
-                seen_window += int(np.minimum(seen, cache.window).sum())
+                if cache.ring_pages:
+                    seen_window += int(
+                        np.minimum(seen, cache.window).sum())
             cache.ensure_capacity(req.slot, end - 1)
             starts.append(start)
             if lora_rows is not None:
@@ -1321,8 +1369,9 @@ class Engine:
         stats.incr("prefill.tokens_computed", rows * chunk)
         stats.incr("prefill.tokens_useful", useful)
         stats.incr("prefill.launches", launches)
-        if cache.ring_pages:
+        if cache.ring_pages or cache.latent_pools:
             stats.incr("prefill.context_tokens", seen_full)
+        if cache.ring_pages:
             stats.incr("prefill.window_context_tokens", seen_window)
         return logits, starts
 

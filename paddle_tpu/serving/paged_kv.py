@@ -67,6 +67,18 @@ of every position the call computes).  A slot never holds more than
 ``ring_pages`` window pages; ``release`` returns the pages of both
 kinds.  A model with one kind of paged layer builds exactly the one
 table and the pools it always built.
+
+**Latent pages.**  A layer of latent (low-rank) attention caches ONE row
+a token and no heads (``layer_latents``: what the model's config says of
+each layer — the row's width, or None).  Such a layer is a paged layer
+like any other — the same page table, free list, offsets and
+reservations; a latent page is a page — but its kind owns its page's
+shape: one pool ``latent_pool`` ``[num_pages, page_size, lanes]``, the
+row padded to whole 128-lane tiles (``pallas.mla.latent_row_lanes``: 576
+values live in 640 lanes) so that a page is the matrix the latent decode
+kernel's DMA moves and a row's value part is a lane-aligned slice of its
+key.  What carries K and V by name (``export_pages`` / ``adopt_pages``,
+quantized storage) refuses a latent store by name.
 """
 from __future__ import annotations
 
@@ -93,9 +105,10 @@ class PagedKVCache:
     the offsets + page table ONCE per scheduler iteration.
     """
 
-    def __init__(self, num_layers, num_slots, max_len, num_kv_heads,
-                 head_dim, page_size=16, num_pages=None, dtype="float32",
-                 layer_states=None, layer_windows=None, window_slack=1):
+    def __init__(self, num_layers, num_slots, max_len, num_kv_heads=None,
+                 head_dim=None, page_size=16, num_pages=None, dtype="float32",
+                 layer_states=None, layer_windows=None, window_slack=1,
+                 layer_latents=None):
         self.num_slots = int(num_slots)
         self.page_size = int(page_size)
         self.max_len = int(max_len)
@@ -124,24 +137,60 @@ class PagedKVCache:
         #: scale arrays; None for plain float storage
         self.quant_dtype = dtype if quant else None
         store_dtype = quant[0] if quant else dtype
-        #: a page as the host formats carry it (``export_pages``,
-        #: ``adopt_pages``): [page_size, H, D]
-        self.page_shape = (self.page_size, int(num_kv_heads),
-                           int(head_dim))
-        from ..pallas.flash_attention import paged_pool_page_shape
-        #: a page as it lives on the device: the paged decode kernel's
-        #: [rows, 128] where the rule says so, else ``page_shape``
-        self.stored_page_shape = paged_pool_page_shape(
-            *self.page_shape, jnp.dtype(store_dtype).itemsize)
-        pool_shape = [total, *self.stored_page_shape]
-        layer_states = list(layer_states or [None] * num_layers)
-        if len(layer_states) != num_layers:
-            raise ValueError(f"{len(layer_states)} layer_states for "
-                             f"{num_layers} layers")
-        layer_windows = list(layer_windows or [None] * num_layers)
-        if len(layer_windows) != num_layers:
-            raise ValueError(f"{len(layer_windows)} layer_windows for "
-                             f"{num_layers} layers")
+
+        def per_layer(values, what):
+            values = list(values or [None] * num_layers)
+            if len(values) != num_layers:
+                raise ValueError(f"{len(values)} {what} for "
+                                 f"{num_layers} layers")
+            return values
+
+        layer_states = per_layer(layer_states, "layer_states")
+        layer_windows = per_layer(layer_windows, "layer_windows")
+        layer_latents = per_layer(layer_latents, "layer_latents")
+        # each kind of paged layer owns its page's shape: as the host
+        # formats carry it, and as it lives on the device
+        kinds = {"state" if st is not None else
+                 "latent" if lat is not None else "kv"
+                 for st, lat in zip(layer_states, layer_latents)}
+        self.page_shape = self.stored_page_shape = None
+        if "kv" in kinds:
+            if num_kv_heads is None or head_dim is None:
+                raise ValueError("layers that cache keys and values need "
+                                 "num_kv_heads and head_dim")
+            from ..pallas.flash_attention import paged_pool_page_shape
+            #: a K/V page as the host formats carry it (``export_pages``,
+            #: ``adopt_pages``): [page_size, H, D]
+            self.page_shape = (self.page_size, int(num_kv_heads),
+                               int(head_dim))
+            #: a K/V page as it lives on the device: the paged decode
+            #: kernel's [rows, 128] where the rule says so, else
+            #: ``page_shape``
+            self.stored_page_shape = paged_pool_page_shape(
+                *self.page_shape, jnp.dtype(store_dtype).itemsize)
+        widths = {int(w) for w in layer_latents if w is not None}
+        if len(widths) > 1:
+            raise ValueError(f"latent rows of {len(widths)} widths "
+                             f"{sorted(widths)}: one store holds one")
+        #: values of a latent layer's cached row (None: no such layer)
+        self.latent_width = widths.pop() if widths else None
+        #: a latent page as it lives on the device: [page_size, lanes]
+        self.latent_page_shape = None
+        #: bytes of one cached row as the arithmetic counts them
+        self.latent_row_bytes = 0
+        if self.latent_width is not None:
+            if quant:
+                from .api import LatentStoreError
+                raise LatentStoreError(
+                    f"cache_dtype={dtype!r}: a latent store keeps one row "
+                    "a token and no per-page K/V scales; pass a float "
+                    "cache_dtype")
+            from ..pallas.mla import latent_row_lanes
+            self.latent_page_shape = (
+                self.page_size, latent_row_lanes(self.latent_width))
+            self.latent_row_bytes = \
+                self.latent_width * jnp.dtype(store_dtype).itemsize
+        pool_shape = [total, *(self.stored_page_shape or ())]
         widths = {int(w) for w in layer_windows if w is not None}
         if len(widths) > 1:
             raise ValueError(
@@ -169,10 +218,12 @@ class PagedKVCache:
         #: per layer, the names of the device arrays a model call updates
         #: (``flat_pools`` order)
         self._keys = []
-        for state, window in zip(layer_states, layer_windows):
+        for state, window, latent in zip(layer_states, layer_windows,
+                                         layer_latents):
             if state is not None:
-                if window is not None:
-                    raise ValueError("a recurrent layer has no window")
+                if window is not None or latent is not None:
+                    raise ValueError("a recurrent layer has no window "
+                                     "and no latent rows")
                 # a row a slot, no page table: the recurrent layer's
                 # fixed-size state
                 lay = {name: Tensor(jnp.zeros(
@@ -181,6 +232,18 @@ class PagedKVCache:
                 self._keys.append(tuple(state))
                 lay.update(state_rows=None, valid_len=None)
                 self.layers.append(lay)
+                continue
+            if latent is not None:
+                if window is not None:
+                    raise ValueError("a latent layer has no window")
+                self.layers.append({
+                    "latent_pool": Tensor(jnp.zeros(
+                        (total,) + self.latent_page_shape,
+                        dtype=store_dtype)),
+                    "latent_width": self.latent_width,
+                    "page_table": None, "offset": None,
+                    "page_size": self.page_size})
+                self._keys.append(("latent_pool",))
                 continue
             ring = window is not None and self.ring_pages > 0
             shape = pool_shape_w if ring else pool_shape
@@ -214,19 +277,28 @@ class PagedKVCache:
             if layer_windows[i] is not None and self.ring_pages > 0)
         self._stateful = tuple(i for i, st in enumerate(layer_states)
                                if st is not None)
+        #: the paged layers that keep latent rows
+        self._latent = tuple(i for i in self._paged
+                             if layer_latents[i] is not None)
         self._reset_jits = {}       # donating or not -> the reset program
         self._flush()
 
     @property
     def pools(self):
-        """How many page pools the cache holds (K and V of every paged
-        layer, both tables')."""
-        return 2 * len(self._paged)
+        """How many K/V page pools the cache holds (K and V of every
+        layer that caches keys and values, both tables')."""
+        return 2 * (len(self._paged) - len(self._latent))
+
+    @property
+    def latent_pools(self):
+        """How many latent page pools the cache holds (one a latent
+        layer)."""
+        return len(self._latent)
 
     @property
     def pools_lane_dense(self):
         """Of ``pools``, those stored ``[pages, rows, 128]``."""
-        return self.pools if len(self.stored_page_shape) == 2 else 0
+        return self.pools if len(self.stored_page_shape or ()) == 2 else 0
 
     # ---------------- recurrent state ----------------
     @property
@@ -275,6 +347,19 @@ class PagedKVCache:
         so a snapshot, or a check against a reference, reads it here."""
         return {i: {k: np.asarray(self.layers[i][k]._data_[slot])
                     for k in self._keys[i]} for i in self._stateful}
+
+    def read_latent(self, slot):
+        """``{layer: array [offset, latent_width]}``: a host copy of the
+        rows ``slot`` holds in every latent page pool, in position
+        order, as the calls that wrote them left them (the prefill
+        members and the ticks, whatever else was live) — what a check
+        against a reference reads.  A released slot has none."""
+        n = int(self.offsets[slot])
+        pages = self.table[slot][:-(-n // self.page_size)]
+        return {i: np.asarray(
+            self.layers[i]["latent_pool"]._data_[jnp.asarray(pages)]
+            .reshape(-1, self.latent_page_shape[-1])[:n, :self.latent_width])
+            for i in self._latent}
 
     def state_rows(self, slots, rows):
         """int32 [rows]: the state row of each row of a prefill call —
@@ -489,6 +574,7 @@ class PagedKVCache:
         v_pages = np.asarray(v_pages)
         self._refuse_state("adopt_pages")
         self._refuse_window("adopt_pages")
+        self._refuse_latent("adopt_pages")
         pool = self.layers[0]["k_pool"]._data_
         want = (len(self.layers),) + self.page_shape
         if k_pages.ndim != 5 or k_pages.shape[0] != want[0] or \
@@ -497,7 +583,8 @@ class PagedKVCache:
             raise PageMigrationError(
                 f"page payload {k_pages.shape}/{v_pages.shape} does not "
                 f"fit a [{want[0]}, n, {want[1]}, {want[2]}, {want[3]}] "
-                "pool (layers/page_size/heads/head_dim mismatch)")
+                "K/V page pool (layers/page_size/heads/head_dim "
+                "mismatch)")
         if k_pages.dtype != pool.dtype:
             raise PageMigrationError(
                 f"page payload dtype {k_pages.dtype} != pool dtype "
@@ -563,6 +650,7 @@ class PagedKVCache:
         pools."""
         self._refuse_state("export_pages")
         self._refuse_window("export_pages")
+        self._refuse_latent("export_pages")
         off = int(self.offsets[slot])
         n = max(1, -(-off // self.page_size))
         ids = [int(p) for p in self.table[slot, :n]]
@@ -587,6 +675,15 @@ class PagedKVCache:
             raise RecurrentStateError(
                 f"{what}: {len(self._stateful)} layers keep a recurrent "
                 "state per slot, which pages do not carry")
+
+    def _refuse_latent(self, what):
+        if self._latent:
+            from .api import LatentStoreError
+            raise LatentStoreError(
+                f"{what}: {len(self._latent)} layers keep a latent page "
+                f"store (one row of {self.latent_width} values a token), "
+                "and the page payload carries a K/V page store's k and v "
+                "pages")
 
     def _refuse_window(self, what):
         if self.ring_pages:
@@ -666,11 +763,12 @@ class PagedKVCache:
         it = iter(pools_flat)
         for i, keys in enumerate(self._keys):
             view = {k: Tensor(next(it)) for k in keys}
-            if "k_pool" in view:
+            if "k_pool" in view or "latent_pool" in view:
                 view.update(page_table=pt_w if i in self._ringed else pt,
                             offset=off, page_size=self.page_size)
-                if "window" in self.layers[i]:
-                    view["window"] = self.layers[i]["window"]
+                for name in ("window", "latent_width"):
+                    if name in self.layers[i]:
+                        view[name] = self.layers[i][name]
             else:
                 view["state_rows"] = rows
             view["valid_len"] = valid

@@ -190,6 +190,12 @@ def declare_tick_stats():
     _registry.gauge(PREFIX + "kv.pools_lane_dense",
                     "of those, the pools stored [pages, rows, 128] as the "
                     "paged decode kernel reads them")
+    _registry.gauge(PREFIX + "kv.latent_pools",
+                    "latent page pools the cache holds: one a layer of "
+                    "latent attention")
+    _registry.gauge(PREFIX + "kv.latent_row_bytes",
+                    "bytes of one cached latent row as the arithmetic "
+                    "counts them (its values, not the lanes it lives in)")
 
 
 def declare_migration_stats():
@@ -402,6 +408,13 @@ def serving_stats():
     ``expert_gmm_xla_lane_traces``, the lanes the grouped expert product
     took when traced (process-wide, like the paged read's).
 
+    Latent-store quantities (a model whose layers keep latent rows, zero
+    otherwise): ``kv_latent_pools`` (one pool a latent layer) and
+    ``kv_latent_row_bytes`` (a cached row's values times the pool's
+    element size), and ``mla_decode_kernel_traces`` /
+    ``mla_decode_xla_lane_traces``, the lanes the single-token latent
+    read took when traced (process-wide, like the paged read's).
+
     Recurrent-state quantities (a model whose layers keep a per-slot
     state beside the pages, zero otherwise): ``state_bytes`` (the state
     arrays' size), ``state_resets`` and ``state_reset_ms_avg`` (an
@@ -511,6 +524,11 @@ def serving_stats():
         "kv_pages_spanned_per_tick": g("kv.pages_spanned"),
         "kv_pools": g("kv.pools"),
         "kv_pools_lane_dense": g("kv.pools_lane_dense"),
+        "kv_latent_pools": g("kv.latent_pools"),
+        "kv_latent_row_bytes": g("kv.latent_row_bytes"),
+        "mla_decode_kernel_traces": s.get("pallas.mla_decode.kernel", 0),
+        "mla_decode_xla_lane_traces": s.get("pallas.mla_decode.xla_lane",
+                                            0),
         "expert_pairs_per_token": (g("moe.pairs_local")
                                    / g("moe.tokens_routed"))
         if g("moe.tokens_routed") else None,

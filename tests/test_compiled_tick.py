@@ -576,3 +576,80 @@ def test_every_tick_hands_its_tokens_to_the_host(model, tick_flag, sampling):
     # more on the requests' lists than the one before it
     steps = np.diff(held)
     assert len(held) >= 20 and steps.max() == 2 and (steps > 0).all()
+
+
+# the families' tick programs, pinned (ISSUE 34) ----------------------------
+_PINNED = {
+    # family: (greedy tick, one-row prefill member): sha256[:16] of the
+    # StableHLO of a tiny engine's programs, as PR 33's tree traced them
+    "llama": ("dca425a4f34f1eae", "a0a8ad78c7ff9b02"),
+    "cohere_moe": ("fdad0e74642cca58", "3bfb9711f5616c51"),
+    "granite_hybrid": ("6e97f6d411d56c2d", "85229164b3cdb108"),
+}
+
+_HASH_SCRIPT = r"""
+import hashlib, json, sys
+import numpy as np
+import paddle_tpu as paddle
+from paddle_tpu.serving import Engine, ServingConfig
+
+def digest(text):
+    return hashlib.sha256(text.encode()).hexdigest()[:16]
+
+def programs(model, vocab):
+    model.eval()
+    eng = Engine(model, ServingConfig(
+        num_slots=2, max_seq_len=64, page_size=4, prefill_chunk_tokens=8,
+        enable_prefix_cache=False)).start()
+    ids = np.random.default_rng(0).integers(0, vocab, (13,)).astype("int32")
+    eng.generate(ids, max_new_tokens=6)
+    out = [digest(eng._tick.lowered_text("greedy")),
+           digest(eng._tick.lowered_text("prefill_r1"))]
+    eng.shutdown()
+    return out
+
+paddle.seed(0)
+from paddle_tpu.models import LlamaForCausalLM, llama_config
+from paddle_tpu.models.cohere_moe import (
+    TINY_COHERE_MOE, CohereMoeConfig, CohereMoeForCausalLM)
+from paddle_tpu.models.granite_hybrid import (
+    TINY_GRANITE_HYBRID, GraniteHybridConfig, GraniteHybridForCausalLM)
+print(json.dumps({
+    "llama": programs(LlamaForCausalLM(llama_config(
+        "tiny", vocab_size=256, max_seq_len=64)), 256),
+    "cohere_moe": programs(CohereMoeForCausalLM(
+        CohereMoeConfig(**TINY_COHERE_MOE)), 256),
+    "granite_hybrid": programs(GraniteHybridForCausalLM(
+        GraniteHybridConfig(**TINY_GRANITE_HYBRID)),
+        TINY_GRANITE_HYBRID["vocab_size"])}))
+"""
+
+
+@pytest.fixture(scope="module")
+def family_program_hashes():
+    """The three families' tick and prefill programs, hashed in a fresh
+    process (names and counters of this process's earlier traces stay out
+    of the text)."""
+    import json
+    import os
+    import subprocess
+    import sys
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=root)
+    env.pop("PADDLE_TPU_PALLAS_INTERPRET", None)
+    env.pop("XLA_FLAGS", None)
+    p = subprocess.run([sys.executable, "-c", _HASH_SCRIPT], cwd=root,
+                       env=env, capture_output=True, text=True, timeout=600)
+    assert p.returncode == 0, p.stderr[-2000:]
+    return json.loads(p.stdout.strip().splitlines()[-1])
+
+
+@pytest.mark.parametrize("family", sorted(_PINNED))
+def test_a_familys_tick_program_is_the_one_pinned(family,
+                                                  family_program_hashes):
+    """A change to code the families share (the routing call's new
+    arguments, the blocked chunk read's shared helper, the cache's
+    per-kind page shapes: ISSUE 34) must leave a family that uses none
+    of it the program it traced before — or say which changed and why,
+    and re-pin."""
+    assert tuple(family_program_hashes[family]) == _PINNED[family]

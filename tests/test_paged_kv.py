@@ -951,3 +951,176 @@ def test_paged_metrics_reach_prometheus(model):
                    "serving_prefix_cache_misses",
                    "serving_prefill_chunk_ms"):
         assert series in text, f"{series} missing from exposition"
+
+
+# ---------------- the latent store: a third kind of per-layer pages --------
+def _latent_cache(**kw):
+    base = dict(num_layers=3, num_slots=3, max_len=64, page_size=4,
+                layer_latents=[40, 40, 40])
+    base.update(kw)
+    return PagedKVCache(**base)
+
+
+def test_latent_kind_owns_its_page_shape():
+    """A latent layer is ONE pool of rows padded to whole lane tiles
+    behind the same table; the K/V kind's shapes are not built at all
+    for a model without K/V layers, and both kinds live side by side."""
+    cache = _latent_cache()
+    assert cache.latent_pools == 3 and cache.pools == 0
+    assert cache.pools_lane_dense == 0
+    assert cache.page_shape is None and cache.stored_page_shape is None
+    assert cache.latent_page_shape == (4, 128)
+    assert cache.latent_width == 40 and cache.latent_row_bytes == 160
+    for lay in cache.layers:
+        assert set(lay) >= {"latent_pool", "page_table", "offset",
+                            "page_size", "latent_width"}
+        assert "k_pool" not in lay
+        assert tuple(lay["latent_pool"].shape) == (3 * 16 + 1, 4, 128)
+    assert len(cache.flat_pools()) == 3
+    # a row of 576 lives in 640 lanes
+    wide = _latent_cache(num_layers=1, layer_latents=[576], page_size=16,
+                         dtype="bfloat16")
+    assert wide.latent_page_shape == (16, 640)
+    assert wide.latent_row_bytes == 1152
+    # K/V layers and latent layers in one cache, one table
+    mixed = PagedKVCache(2, 2, 32, 2, 16, page_size=4,
+                         layer_latents=[None, 40])
+    assert mixed.pools == 2 and mixed.latent_pools == 1
+    assert mixed.page_shape == (4, 2, 16)
+    assert [sorted(k for k in lay if k.endswith("pool"))
+            for lay in mixed.layers] == [["k_pool", "v_pool"],
+                                         ["latent_pool"]]
+    assert mixed.layers[0]["page_table"] is mixed.layers[1]["page_table"]
+    with pytest.raises(ValueError, match="num_kv_heads"):
+        PagedKVCache(2, 2, 32, page_size=4, layer_latents=[None, 40])
+    with pytest.raises(ValueError, match="one store holds one"):
+        _latent_cache(layer_latents=[40, 48, 40])
+    with pytest.raises(ValueError, match="no window"):
+        _latent_cache(layer_windows=[8, None, None])
+
+
+def test_latent_store_allocate_grow_release_and_reuse():
+    """The slot lifecycle is the page table's: a latent page is a page."""
+    cache = _latent_cache()
+    free0 = cache.free_page_count
+    a = cache.allocate(16)
+    b = cache.allocate(10)
+    assert cache.available_pages == free0 - 26
+    for pos in range(37):
+        cache.ensure_capacity(a, pos)
+    assert cache.pages_in_use == 10
+    assert (cache.table[a, :10] > 0).all() and not cache.table[a, 10:].any()
+    cache.ensure_capacity(b, 5)
+    assert not set(cache.table[a, :10]) & set(cache.table[b, :2])
+    # views carry every latent layer behind the one table
+    views = cache.views_over(cache.flat_pools(), *cache.table_arrays())
+    assert all("latent_pool" in v and v["latent_width"] == 40
+               for v in views)
+    assert views[0]["page_table"] is views[2]["page_table"]
+    cache.absorb_pools(cache.flat_pools(views))
+    held = list(cache.table[a, :10])
+    cache.release(a)
+    assert cache.pages_in_use == 2 and not cache.table[a].any()
+    # the next tenant of the slot gets the pages back, from offset 0
+    c = cache.allocate(16)
+    assert c == a and cache.offsets[c] == 0
+    for pos in range(37):
+        cache.ensure_capacity(c, pos)
+    assert set(cache.table[c, :10]) == set(held)
+    cache.release(b)
+    cache.release(c)
+    assert cache.free_page_count == free0
+    assert cache.allocate(free0 + 1) is None
+
+
+def test_latent_store_keeps_rollback_and_sharing_and_refuses_export():
+    """What moves pages through the table alone works unchanged; what
+    carries K and V by name refuses the store kind by name."""
+    from paddle_tpu.serving import LatentStoreError, PageMigrationError
+    cache = _latent_cache()
+    slot = cache.allocate(16)
+    for pos in range(30):
+        cache.ensure_capacity(slot, pos)
+    avail = cache.available_pages
+    cache.rollback(slot, 9)                 # pages 3.. go back
+    assert cache.pages_in_use == 3 and cache.available_pages == avail
+    page = cache.make_shared(slot, 0)       # the prefix tree's transfer
+    assert page == cache.table[slot, 0]
+    cache.release(slot)
+    assert cache.pages_in_use == 1          # the shared page is the tree's
+    cache.reclaim(page)
+    assert cache.pages_in_use == 0
+    slot = cache.allocate(4)
+    with pytest.raises(LatentStoreError, match="latent page store"):
+        cache.export_pages(slot)
+    with pytest.raises(LatentStoreError, match="adopt_pages"):
+        cache.adopt_pages(1, 4, np.zeros((3, 1, 4, 2, 16), np.float32),
+                          np.zeros((3, 1, 4, 2, 16), np.float32))
+    with pytest.raises(LatentStoreError, match="cache_dtype"):
+        _latent_cache(dtype="int8")
+    # the K/V store's own geometry check names the kind it refused
+    plain = PagedKVCache(2, 2, 32, 2, 16, page_size=4)
+    with pytest.raises(PageMigrationError, match="K/V page pool"):
+        plain.adopt_pages(1, 4, np.zeros((2, 1, 4, 2, 8), np.float32),
+                          np.zeros((2, 1, 4, 2, 8), np.float32))
+
+
+def test_latent_op_writes_rows_through_the_table():
+    """The op's write lands each new row at (page of the table, row of
+    the page), padded lanes zero, other pages untouched."""
+    import jax.numpy as jnp
+    from paddle_tpu.incubate.nn import functional as IF
+    cache = _latent_cache(num_layers=1, layer_latents=[40], num_slots=2)
+    for n in (9, 3):
+        slot = cache.allocate(16)
+        cache.ensure_capacity(slot, n)
+    rng = np.random.default_rng(0)
+    rows = rng.standard_normal((2, 6, 40)).astype("float32")
+    q = rng.standard_normal((2, 6, 4, 24)).astype("float32")
+    w = rng.standard_normal((32, 4 * 32)).astype("float32")
+    cache.set_offset(0, 3)
+    view = cache.layer_caches()[0]
+    with paddle.no_grad():
+        out = IF.paged_latent_attention(
+            paddle.to_tensor(q), paddle.to_tensor(rows),
+            paddle.to_tensor(w), view, nope_dim=16, scale=0.2)
+    assert tuple(out.shape) == (2, 6, 4, 16)
+    pool = np.asarray(view["latent_pool"]._data_)
+    for slot, start in ((0, 3), (1, 0)):
+        for j in range(6):
+            pos = start + j
+            got = pool[cache.table[slot, pos // 4], pos % 4]
+            np.testing.assert_array_equal(got[:40], rows[slot, j])
+            assert not got[40:].any()
+    touched = {int(cache.table[s, p]) for s, n in ((0, 9), (1, 6))
+               for p in range(-(-n // 4))}
+    for page in range(1, pool.shape[0]):
+        if page not in touched:
+            assert not pool[page].any()
+
+
+def test_read_latent_gives_a_slots_rows_in_position_order():
+    """``read_latent`` hands back what the op wrote for one slot, row by
+    position, the padded lanes gone, as far as the slot's offset."""
+    from paddle_tpu.incubate.nn import functional as IF
+    cache = _latent_cache(num_layers=2, layer_latents=[40, 40], num_slots=2)
+    for n in (9, 6):
+        cache.ensure_capacity(cache.allocate(16), n)
+    rng = np.random.default_rng(1)
+    rows = rng.standard_normal((2, 2, 6, 40)).astype("float32")
+    q = rng.standard_normal((2, 6, 4, 24)).astype("float32")
+    w = rng.standard_normal((32, 4 * 32)).astype("float32")
+    views = cache.layer_caches()
+    with paddle.no_grad():
+        for view, layer_rows in zip(views, rows):
+            IF.paged_latent_attention(
+                paddle.to_tensor(q), paddle.to_tensor(layer_rows),
+                paddle.to_tensor(w), view, nope_dim=16, scale=0.2)
+    cache.absorb_view(views)
+    cache.set_offset(0, 6)
+    cache.set_offset(1, 5)
+    for slot, n in ((0, 6), (1, 5)):
+        got = cache.read_latent(slot)
+        assert sorted(got) == [0, 1]
+        for layer in (0, 1):
+            np.testing.assert_array_equal(got[layer], rows[layer, slot, :n])

@@ -347,6 +347,74 @@ def test_expert_gmm_compiles(v5e, case):
         assert re.match(r"^(ROOT )?%expert_gmm[\w.]* = ", call), call
 
 
+# --------------------------------------------------------- latent decode
+
+#         rows heads row lanes page pages-a-slot
+_MLA = {
+    "longdoc-reason-56-tick": (56, 64, 640, 16, 1280),
+    "prefill-bucket-of-1": (1, 64, 640, 16, 1280),
+    "short-table": (4, 64, 640, 16, 8),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_MLA))
+def test_mla_decode_compiles(v5e, case):
+    """The latent decode kernel at the latent cell's widths (64 heads
+    against rows of 576 values living in 640 lanes, pages of 16): one
+    kernel named ``mla_decode`` over the pool left in HBM."""
+    import re
+    from paddle_tpu.pallas import mla
+    b, h, lanes, psz, n = _MLA[case]
+    assert mla.latent_row_lanes(576) == lanes
+
+    def f(q, pool, pt, off):
+        return mla.mla_decode_attention(q, pool, pt, off, 512, 0.1352)
+
+    text = _compile(f, [((b, h, lanes), BF16),
+                        ((1 + b * n, psz, lanes), BF16),
+                        ((b, n), jnp.int32), ((b,), jnp.int32)],
+                    SingleDeviceSharding(v5e[0]), compiled=True)
+    calls = [line.strip() for line in text.splitlines()
+             if "tpu_custom_call" in line]
+    assert len(calls) == 1, calls
+    # as chipbench/kernels/mla_decode_attention/pallas_mla_decode.json
+    assert re.match(r"^(ROOT )?%mla_decode[\w.]* = ", calls[0]), calls[0]
+
+
+@pytest.mark.parametrize("s", [1, 512], ids=["tick", "chunk"])
+def test_latent_attention_through_the_op_compiles(v5e, s):
+    """The framework op over a latent pool at the cell's widths: the
+    single-token step (row write, absorb products, the kernel) and a
+    512-token chunk (row write, blocked up-projected read: no kernel);
+    neither copies the pool."""
+    from chip_smoke import pool_sized_copies
+    from paddle_tpu.core.state import no_grad
+    from paddle_tpu.core.tensor import Tensor
+    from paddle_tpu.incubate.nn import functional as IF
+    b, h, n, psz = (56, 64, 1280, 16) if s == 1 else (1, 64, 1280, 16)
+    pool = (1 + 56 * n, psz, 640)
+
+    def f(q, row, w, pl_, pt, off):
+        cache = {"latent_pool": Tensor(pl_), "page_table": Tensor(pt),
+                 "offset": Tensor(off), "page_size": psz,
+                 "latent_width": 576}
+        with no_grad():
+            out = IF.paged_latent_attention(
+                Tensor(q), Tensor(row), Tensor(w), cache, nope_dim=128,
+                scale=0.1352)
+        return out._data_, cache["latent_pool"]._data_
+
+    args = [jax.ShapeDtypeStruct(sh, dt,
+                                 sharding=SingleDeviceSharding(v5e[0]))
+            for sh, dt in [((b, s, h, 192), BF16), ((b, s, 576), BF16),
+                           ((512, h * 256), BF16), (pool, BF16),
+                           ((b, n), jnp.int32), ((b,), jnp.int32)]]
+    program = jax.jit(f, donate_argnums=(3,)).lower(*args).compile()
+    text = program.as_text()
+    assert _n_kernels(text) == (1 if s == 1 else 0)
+    assert not pool_sized_copies(text, {math.prod(pool)})
+
+
 def _kernels_through_the_op(device, b, h, h_kv, d, psz, n, pool_dt):
     """Kernels in the framework op's single-token step (page write +
     read) over a pool of these shapes."""
