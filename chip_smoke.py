@@ -213,7 +213,15 @@ def train_phase(sizes, dry_run):
 
     import paddle_tpu as paddle
 
+    from paddle_tpu.utils import monitor
+
+    def flash_traces():
+        stats = monitor.all_stats()
+        return {name: stats.get(name, 0) for name in
+                ("pallas.flash.resident", "pallas.flash.streamed")}
+
     cfg = gpt_config("gpt2-124m", **sizes.model)
+    before = flash_traces()
     with warnings.catch_warnings(record=True) as caught:
         warnings.simplefilter("always")
         paddle.seed(SEED)
@@ -223,6 +231,13 @@ def train_phase(sizes, dry_run):
         _, losses, _ = train_model(model, cfg, sizes, [0], dry_run,
                                    "train")
     no_fallback_warnings(caught, "train")
+    # every flash kernel of the step walks a resident head (a rule on
+    # the call's shapes: flash_attention._walk_vmem_bytes); none streams
+    paths = {k: v - before[k] for k, v in flash_traces().items()}
+    say(f"train: flash kernel traces by path {paths}")
+    if not dry_run or os.environ.get("PADDLE_TPU_PALLAS_INTERPRET") == "1":
+        assert paths["pallas.flash.resident"] > 0, paths
+        assert paths["pallas.flash.streamed"] == 0, paths
     return model, cfg, losses
 
 

@@ -5,22 +5,39 @@ paddle/phi/kernels/gpu/flash_attn_kernel.cu:203 → phi::dynload::flash_attn_fwd
 backward at paddle/phi/kernels/gpu/flash_attn_grad_kernel.cu; dropout args at
 flash_attn_kernel.cu:203; varlen variant at incubate/nn/functional/
 variable_length_memory_efficient_attention.py).  TPU-native realization:
-Pallas kernels that tile Q into VMEM blocks and stream K/V blocks **via the
-grid** (one K/V block resident at a time, double-buffered by the Mosaic
-pipeline), with online softmax in fp32 scratch accumulators.  Backward is the
-flash-attention backward: probabilities are recomputed per block from the
-saved logsumexp — never an O(S^2) materialization — with a dK/dV kernel
-(streaming Q innermost) and a dQ kernel (streaming K/V innermost).
+three Pallas kernels — forward, dK/dV, dQ — over (q tile, key tile)
+products with an online softmax in fp32 scratch accumulators; the
+backward recomputes the probabilities per tile from the saved logsumexp,
+never an O(S^2) materialization.  A tile's products take their operands
+in the type they arrive in and accumulate in float32 (``_mxu``).
+
+How a tile's operands reach VMEM is a rule on the call's shapes
+(``_walk_vmem_bytes``), not an argument:
+
+- **the walk**: a grid step is one (head, q block) — for dK/dV one (kv
+  head, key block) — and holds the OTHER side of the products in VMEM for
+  the whole head (K and V; for dK/dV the Q, dO, lse and delta of the
+  q-heads that share the kv head), fetched once a head.  A loop inside
+  the kernel counts that side's tiles from the first to the last live
+  one: under a causal mask a tile past the diagonal costs neither a step
+  nor a fetch, and only the tiles that straddle the diagonal build the
+  position mask.  Every call whose head fits the VMEM budget walks.
+- **the streamed grid**: a grid step is one (head, q block, key block),
+  the key blocks (dK/dV: the q blocks) stream through the innermost grid
+  axis, double-buffered by the Mosaic pipeline.  What the walk cannot
+  host takes it: an ``[S, S]`` mask, which is streamed by blocks, and a
+  head too long for the budget.
 
 Feature coverage (all composable, fwd AND bwd):
 
-- **causal** masking with dead-block skipping (clamped index maps dedupe the
-  skipped fetches).
+- **causal** masking: the walk's loop ends at the diagonal; the streamed
+  grid skips dead blocks (clamped index maps dedupe the skipped fetches).
 - **attention dropout** on the probabilities via a counter-based in-kernel
   PRNG (position+seed hash) — the identical keep-mask is regenerated in the
   backward kernels, so no O(S^2) mask is ever materialized.
 - **additive/boolean masks** of shape [B|1, H|1, S, S], streamed block-wise
-  through the grid (the analog of the reference's attn_mask path).
+  through the grid (the analog of the reference's attn_mask path; such a
+  call never walks).
 - **segment ids** [B, S]: packed-varlen attention — tokens attend only
   within their segment (the TPU-native replacement for the reference's
   cu_seqlens varlen kernels; padding is just a dedicated segment id).
@@ -34,8 +51,9 @@ Layout: the public op takes [batch, seq, heads, head_dim] (the reference's
 flash-attn layout); internally the kernels run on [batch*heads, seq, d] so
 the block's trailing two dims are (seq_block, d) — Mosaic requires the last
 two block dims to be (8k, 128k) or equal to the array dims, which a
-squeezed head dim in second-to-last position violates.  The relayout is one
-XLA transpose each way, negligible next to the attention itself.
+squeezed head dim in second-to-last position violates.  From [B, S, H, D]
+that is one XLA transpose each way around every call; ``head_major=True``
+([B, H, S, D], what the models hand over) makes it a free reshape.
 
 Falls back to a fused XLA attention for shapes that don't tile (seq not a
 multiple of 128, head_dim > 256, mask shapes outside [B|1, H|1, S, S]).
@@ -151,32 +169,39 @@ def _from_bh(y, b, h, head_major=False):
     return y.reshape(b, h, s, d).transpose(0, 2, 1, 3)
 
 
-def _apply_masks(s, *, causal, q_start, k_start, block_q, block_k,
-                 qseg=None, kseg=None, mask=None):
+def _apply_masks(s, *, causal, q_start, k_start, qseg=None, kseg=None,
+                 mask=None, transposed=False):
     """Score masking shared by all three kernels: causal position mask,
-    same-segment mask (varlen packing), additive attention mask."""
+    same-segment mask (varlen packing), additive attention mask.  ``s``
+    is a tile ``[block_q, block_k]``, or ``[block_k, block_q]`` when
+    ``transposed`` (the dKV kernel's)."""
+    q_axis = 1 if transposed else 0
     if causal:
-        q_pos = q_start + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 0)
-        k_pos = k_start + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 1)
+        q_pos = q_start + jax.lax.broadcasted_iota(jnp.int32, s.shape,
+                                                   q_axis)
+        k_pos = k_start + jax.lax.broadcasted_iota(jnp.int32, s.shape,
+                                                   1 - q_axis)
         s = jnp.where(q_pos >= k_pos, s, NEG_INF)
     if qseg is not None:
-        # qseg (block_q, 1) vs kseg (1, block_k) broadcast — no relayout
+        # a column (block, 1) against a row (1, block) — no relayout
         s = jnp.where(qseg == kseg, s, NEG_INF)
     if mask is not None:
-        s = s + mask
+        s = s + (mask.T if transposed else mask)
     return s
 
 
-def _dropout_uniform(seed, head, q_start, k_start, block_q, block_k):
+def _dropout_uniform(seed, head, q_start, k_start, block_q, block_k,
+                     transposed=False):
     """Counter-based stateless uniform(0,1) per (head, q_pos, k_pos):
     a murmur-style integer hash, regenerated identically in forward and
-    backward so the same probabilities drop — no mask is materialized."""
+    backward so the same probabilities drop — no mask is materialized.
+    ``[block_q, block_k]``, or ``[block_k, block_q]`` when transposed."""
+    shape = (block_k, block_q) if transposed else (block_q, block_k)
+    q_axis = 1 if transposed else 0
     qp = (q_start + jax.lax.broadcasted_iota(
-        jnp.int32, (block_q, block_k), 0)).astype(jnp.uint32)
+        jnp.int32, shape, q_axis)).astype(jnp.uint32)
     kp = (k_start + jax.lax.broadcasted_iota(
-        jnp.int32, (block_q, block_k), 1)).astype(jnp.uint32)
+        jnp.int32, shape, 1 - q_axis)).astype(jnp.uint32)
     x = qp * jnp.uint32(0x9E3779B1) + kp * jnp.uint32(0x85EBCA77)
     x = x ^ (seed.astype(jnp.uint32)
              + head.astype(jnp.uint32) * jnp.uint32(0x27D4EB2F))
@@ -208,16 +233,160 @@ def _unpack_rest(rest, *, dropout, has_mask, has_seg):
 
 
 # ------------------------------------------------------------------
-# Pallas forward: grid (B*H, num_q, num_kv), K/V streamed by the grid
+# One (q tile, key tile) of each of the three kernels.  The walk and
+# the streamed grid differ in how a tile's operands reach VMEM and in
+# who counts the tiles (a loop in the kernel, or the grid); what a tile
+# computes is this, for both.
+# ------------------------------------------------------------------
+
+_NT = ((1,), (1,))       # a [m, d] · b [n, d]ᵀ
+_NN = ((1,), (0,))       # a [m, n] · b [n, d]
+
+
+def _mxu(a, b, contract):
+    """One product on the matrix unit, accumulated in float32.  The
+    operands go in the type they arrive in: two equal 16-bit tiles
+    multiply exactly in one pass — which is all Mosaic's default made
+    of float32 tiles too, so casting bfloat16 tiles up bought converts
+    and VMEM, no precision.  float32 operands take the full-precision
+    passes, said here and not left to a default."""
+    if a.dtype != b.dtype:
+        wide = jnp.promote_types(a.dtype, b.dtype)
+        a, b = a.astype(wide), b.astype(wide)
+    precision = (jax.lax.Precision.HIGHEST if a.dtype == jnp.float32
+                 else jax.lax.Precision.DEFAULT)
+    return jax.lax.dot_general(a, b, (contract, ((), ())),
+                               precision=precision,
+                               preferred_element_type=jnp.float32)
+
+
+def _tile_scores(q, k, *, scale, causal, q_start, k_start, qseg, kseg,
+                 mask, transposed=False):
+    """The tile's masked scores: ``[q rows, k rows]``, or the other way
+    round when ``transposed``."""
+    s = (_mxu(k, q, _NT) if transposed else _mxu(q, k, _NT)) * scale
+    return _apply_masks(s, causal=causal, q_start=q_start, k_start=k_start,
+                        qseg=qseg, kseg=kseg, mask=mask,
+                        transposed=transposed)
+
+
+def _tile_keep(seed_ref, head, dropout, where, q_rows, k_rows):
+    """The tile's dropout keep-mask (None without dropout): the same
+    hash of (head, q position, key position) in all three kernels."""
+    if dropout <= 0.0:
+        return None
+    u = _dropout_uniform(seed_ref[0, 0], head, where["q_start"],
+                         where["k_start"], q_rows, k_rows,
+                         where.get("transposed", False))
+    return u >= dropout
+
+
+def _fwd_tile(q, k, v, m_scr, l_scr, acc_scr, *, seed_ref, head, dropout,
+              **where):
+    """The online softmax's update by one tile of keys: scratch (m, l,
+    acc) carries the running max / normalizer / weighted sum of a q
+    block across its tiles."""
+    s = _tile_scores(q, k, **where)
+    m_prev = m_scr[:]
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+    p = jnp.exp(s - m_new)
+    if where["mask"] is not None or where["qseg"] is not None:
+        # fully-masked rows: m_new == NEG_INF makes exp(s-m) == 1 —
+        # zero them so such rows emit 0, not garbage
+        p = jnp.where(s > NEG_INF * 0.5, p, 0.0)
+    alpha = jnp.exp(m_prev - m_new)
+    m_scr[:] = m_new
+    l_scr[:] = alpha * l_scr[:] + jnp.sum(p, axis=-1, keepdims=True)
+    keep = _tile_keep(seed_ref, head, dropout, where, *s.shape)
+    if keep is not None:
+        # softmax normalizes over the UNdropped probabilities; dropout
+        # applies to what multiplies V
+        p = jnp.where(keep, p, 0.0) / (1.0 - dropout)
+    acc_scr[:] = alpha * acc_scr[:] + _mxu(p.astype(v.dtype), v, _NN)
+
+
+def _bwd_tile_p_dp(q, k, v, do, lse, *, seed_ref, head, dropout, **where):
+    """What both backward kernels recompute of a tile from the saved
+    lse: p, p̃ (p after dropout: what multiplied V) and dp (already
+    through the keep-mask) — ``[q rows, k rows]`` with ``lse`` a
+    column, or transposed with ``lse`` a row."""
+    transposed = where.get("transposed", False)
+    s = _tile_scores(q, k, **where)
+    p = jnp.exp(s - lse)
+    if where["mask"] is not None or where["qseg"] is not None:
+        # fully-masked rows: lse == NEG_INF would give exp(0) == 1
+        p = jnp.where(s > NEG_INF * 0.5, p, 0.0)
+    dp = _mxu(v, do, _NT) if transposed else _mxu(do, v, _NT)
+    keep = _tile_keep(seed_ref, head, dropout, where, q.shape[0],
+                      k.shape[0])
+    if keep is None:
+        return p, p, dp
+    return (p, jnp.where(keep, p, 0.0) / (1.0 - dropout),
+            jnp.where(keep, dp, 0.0) / (1.0 - dropout))
+
+
+def _dkv_tile(q, k, v, do, lse, delta, dk_scr, dv_scr, **kw):
+    """dKV works on the TRANSPOSED tile, ``[k rows, q rows]``: pᵀ and
+    dsᵀ are what its two accumulating products contract, so every
+    product is a plain one (no tile is transposed on the way to the
+    matrix unit), and lse and delta are lane-dense rows ``[1, q rows]``."""
+    p, p_v, dp = _bwd_tile_p_dp(q, k, v, do, lse, transposed=True, **kw)
+    # dv += p̃ᵀ do;  dsᵀ = pᵀ * (dpᵀ - delta) * scale;  dk += dsᵀ q
+    dv_scr[:] += _mxu(p_v.astype(do.dtype), do, _NN)
+    ds = p * (dp - delta) * kw["scale"]
+    dk_scr[:] += _mxu(ds.astype(q.dtype), q, _NN)
+
+
+def _dq_tile(q, k, v, do, lse, delta, dq_scr, **kw):
+    p, _, dp = _bwd_tile_p_dp(q, k, v, do, lse, **kw)
+    ds = p * (dp - delta) * kw["scale"]
+    dq_scr[:] += _mxu(ds.astype(k.dtype), k, _NN)
+
+
+def _walk_tiles(step, first, clear_from, clear_to, last):
+    """``step(t, diag)`` for the tiles ``first <= t < last`` of a walk:
+    those in ``[clear_from, clear_to)`` lie wholly under the causal
+    diagonal and skip the position mask (``diag`` False), the ones that
+    straddle it build it."""
+    def run(lo, hi, diag):
+        def body(t, carry):
+            step(t, diag)
+            return carry
+        jax.lax.fori_loop(lo, hi, body, 0)
+    run(first, clear_from, True)
+    run(clear_from, clear_to, False)
+    run(clear_to, last, True)
+
+
+def _key_walk(step, *, causal, q_start, block_q, block_k, seq):
+    """The key tiles a q block sees (forward, dQ): all of them, or under
+    a causal mask those up to its diagonal — first the ones wholly
+    under it, then the straddling ones.  A tile past the diagonal costs
+    neither a step nor a fetch."""
+    if not causal:
+        return _walk_tiles(step, 0, 0, seq // block_k, seq // block_k)
+    clear = (q_start + 1) // block_k
+    _walk_tiles(step, 0, 0, clear, (q_start + block_q - 1) // block_k + 1)
+
+
+def _query_walk(step, *, causal, k_start, block_q, block_k, seq):
+    """The q tiles that see a key block (dKV): from the first that
+    reaches its diagonal; wholly under it from ``clear`` on."""
+    n_q = seq // block_q
+    if not causal:
+        return _walk_tiles(step, 0, 0, n_q, n_q)
+    clear = jnp.minimum((k_start + block_k + block_q - 2) // block_q, n_q)
+    _walk_tiles(step, k_start // block_q, clear, n_q, n_q)
+
+
+# ------------------------------------------------------------------
+# Pallas forward.  Walk: grid (B*H, num_q), the head's K/V resident,
+# key tiles counted by a loop in the kernel.  Streamed: grid (B*H,
+# num_q, num_kv), a key tile a grid step.
 # ------------------------------------------------------------------
 
 def _fwd_kernel(q_ref, k_ref, v_ref, *rest, scale, causal, block_q, block_k,
-                dropout, has_mask, has_seg):
-    """One (bh, q_block, kv_block) step of the online softmax.
-
-    The kv grid axis is innermost: scratch (m, l, acc) carries the running
-    max / normalizer / weighted sum across kv steps for a fixed q block.
-    """
+                dropout, has_mask, has_seg, walk):
     from jax.experimental import pallas as pl
 
     (seed_ref, mask_ref, qseg_ref, kseg_ref,
@@ -225,69 +394,61 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *rest, scale, causal, block_q, block_k,
         rest, dropout=dropout, has_mask=has_mask, has_seg=has_seg)
 
     n = pl.program_id(0)
-    i = pl.program_id(1)
-    j = pl.program_id(2)
-    num_kv = pl.num_programs(2)
+    q_start = pl.program_id(1) * block_q
 
-    @pl.when(j == 0)
-    def _init():
+    def init():
         m_scr[:] = jnp.full_like(m_scr, NEG_INF)
         l_scr[:] = jnp.zeros_like(l_scr)
         acc_scr[:] = jnp.zeros_like(acc_scr)
 
-    q_start = i * block_q
+    def finalize():
+        l = jnp.maximum(l_scr[:], 1e-30)  # noqa: E741
+        o_ref[:] = (acc_scr[:] / l).astype(o_ref.dtype)
+        lse_ref[:] = (m_scr[:] + jnp.log(l)).astype(lse_ref.dtype)
+
+    def tile(k, v, k_start, diag, kseg, mask):
+        _fwd_tile(q_ref[:], k, v, m_scr, l_scr, acc_scr,
+                  seed_ref=seed_ref, head=n, dropout=dropout, scale=scale,
+                  causal=diag, q_start=q_start, k_start=k_start,
+                  qseg=qseg_ref[:] if has_seg else None, kseg=kseg,
+                  mask=mask)
+
+    if walk:
+        def step(t, diag):
+            at = pl.ds(pl.multiple_of(t * block_k, block_k), block_k)
+            tile(k_ref[at, :], v_ref[at, :], t * block_k, diag,
+                 kseg_ref[:, at] if has_seg else None, None)
+        init()
+        _key_walk(step, causal=causal, q_start=q_start, block_q=block_q,
+                  block_k=block_k, seq=k_ref.shape[0])
+        finalize()
+        return
+
+    j = pl.program_id(2)
     k_start = j * block_k
-    # Entire block above the causal diagonal contributes nothing: skip the
-    # matmuls (the DMA already happened; autotune trades block_k against
-    # the wasted fetches).
+    pl.when(j == 0)(init)
+    # Entire block above the causal diagonal contributes nothing: skip
+    # the products (the clamped index map already spared the fetch)
     live = (q_start + block_q - 1 >= k_start) if causal else True
 
     @pl.when(live)
     def _compute():
-        q = q_ref[:].astype(jnp.float32)
-        k = k_ref[:].astype(jnp.float32)
-        v = v_ref[:].astype(jnp.float32)
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale
-        s = _apply_masks(
-            s, causal=causal, q_start=q_start, k_start=k_start,
-            block_q=block_q, block_k=block_k,
-            qseg=qseg_ref[:] if has_seg else None,
-            kseg=kseg_ref[:] if has_seg else None,
-            mask=mask_ref[:].astype(jnp.float32) if has_mask else None)
-        m_prev = m_scr[:]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        if has_mask or has_seg:
-            # fully-masked rows: m_new == NEG_INF makes exp(s-m) == 1 —
-            # zero them so such rows emit 0, not garbage
-            p = jnp.where(s > NEG_INF * 0.5, p, 0.0)
-        alpha = jnp.exp(m_prev - m_new)
-        m_scr[:] = m_new
-        l_scr[:] = alpha * l_scr[:] + jnp.sum(p, axis=-1, keepdims=True)
-        if dropout > 0.0:
-            # softmax normalizes over the UNdropped probabilities; dropout
-            # applies to what multiplies V
-            u = _dropout_uniform(seed_ref[0, 0], n, q_start, k_start,
-                                 block_q, block_k)
-            p = jnp.where(u >= dropout, p, 0.0) / (1.0 - dropout)
-        acc_scr[:] = alpha * acc_scr[:] + jnp.dot(
-            p, v, preferred_element_type=jnp.float32)
+        tile(k_ref[:], v_ref[:], k_start, causal,
+             kseg_ref[:] if has_seg else None,
+             mask_ref[:].astype(jnp.float32) if has_mask else None)
 
-    @pl.when(j == num_kv - 1)
-    def _finalize():
-        l = jnp.maximum(l_scr[:], 1e-30)  # noqa: E741
-        o_ref[:] = (acc_scr[:] / l).astype(o_ref.dtype)
-        lse_ref[:] = (m_scr[:] + jnp.log(l)).astype(lse_ref.dtype)
+    pl.when(j == pl.num_programs(2) - 1)(finalize)
 
 
 def _feature_specs(*, b, s, h, h_kv, block_q, block_k, dropout, mask, qseg,
                    kseg, q_axis, kv_axis, head_of, batch_of, causal,
                    grid_qi=None):
-    """(in_specs, inputs) for the optional seed/mask/segment inputs, shared
-    by the three kernels.  head_of/batch_of map grid indices to the global
-    q-head / batch; grid_qi maps grid indices to the (clamped) q block."""
+    """(in_specs, inputs) for the optional seed/mask/segment inputs of a
+    streamed grid, shared by the three kernels.  head_of/batch_of map
+    grid indices to the global q-head / batch; grid_qi (the dKV call's)
+    maps grid indices to the (clamped) q block, and says that the
+    kernel's tiles are transposed: its q side's segment ids are the
+    row, its key side's the column."""
     from jax.experimental import pallas as pl
 
     specs, inputs = [], []
@@ -319,9 +480,22 @@ def _feature_specs(*, b, s, h, h_kv, block_q, block_k, dropout, mask, qseg,
                 qi = g[q_axis]
                 j = jnp.minimum(j, (qi * block_q + block_q - 1) // block_k)
             return (batch_of(*g), 0, j)
-        specs.append(pl.BlockSpec((None, block_q, 1), qseg_index))
-        specs.append(pl.BlockSpec((None, 1, block_k), kseg_index))
-        inputs.extend([qseg, kseg])
+        if grid_qi is None:
+            specs.append(pl.BlockSpec((None, block_q, 1), qseg_index))
+            specs.append(pl.BlockSpec((None, 1, block_k), kseg_index))
+            inputs.extend([qseg, kseg])
+        else:
+            # the same ids: kseg [B, 1, S] is the row, qseg the column
+            def swapped(index):
+                def at(*g):
+                    bi, x, y = index(*g)
+                    return (bi, y, x)
+                return at
+            specs.append(pl.BlockSpec((None, 1, block_q),
+                                      swapped(qseg_index)))
+            specs.append(pl.BlockSpec((None, block_k, 1),
+                                      swapped(kseg_index)))
+            inputs.extend([kseg, qseg])
     return specs, inputs
 
 
@@ -342,6 +516,71 @@ def _causal_kv_spec(block_q, block_k, d, q_axis, kv_axis, causal,
     return pl.BlockSpec((None, block_k, d), index)
 
 
+def _seed_and_segment_specs(dropout, seed, qseg, kseg, qseg_spec, kseg_spec):
+    """(in_specs, inputs) of the optional inputs a walk hosts."""
+    from jax.experimental import pallas as pl
+
+    specs, inputs = [], []
+    if dropout > 0.0:
+        specs.append(pl.BlockSpec((1, 1), lambda *g: (0, 0)))
+        inputs.append(seed)
+    if qseg is not None:
+        specs += [qseg_spec, kseg_spec]
+        inputs += [qseg, kseg]
+    return specs, inputs
+
+
+def _q_block_specs(b, h, h_kv, s, d, block_q, block_k, *, walk, causal,
+                   dropout, mask, qseg, kseg, seed):
+    """What the forward and the dQ call share: a grid step is a q block
+    of one head.  → (grid, q/o spec, lse/delta spec, k/v spec, the
+    optional inputs' specs, those inputs).  Walk: grid (B*H, num_q),
+    the head's K/V resident; streamed: grid (B*H, num_q, num_kv)."""
+    from jax.experimental import pallas as pl
+
+    n_rep = h // h_kv
+
+    def kv_row(n):
+        return (n // h) * h_kv + (n % h) // n_rep
+    qo_spec = pl.BlockSpec((None, block_q, d), lambda n, i, *_: (n, i, 0))
+    lse_spec = pl.BlockSpec((None, block_q, 1), lambda n, i, *_: (n, i, 0))
+    if walk:
+        grid = (b * h, s // block_q)
+        kv_spec = pl.BlockSpec((None, s, d), lambda n, i: (kv_row(n), 0, 0))
+        feat_specs, feat_inputs = _seed_and_segment_specs(
+            dropout, seed, qseg, kseg,
+            pl.BlockSpec((None, block_q, 1), lambda n, i: (n // h, i, 0)),
+            pl.BlockSpec((None, 1, s), lambda n, i: (n // h, 0, 0)))
+    else:
+        grid = (b * h, s // block_q, s // block_k)
+        kv_spec = _causal_kv_spec(block_q, block_k, d, q_axis=1, kv_axis=2,
+                                  causal=causal, kv_row=kv_row)
+        feat_specs, feat_inputs = _feature_specs(
+            b=b, s=s, h=h, h_kv=h_kv, block_q=block_q, block_k=block_k,
+            dropout=dropout, mask=mask, qseg=qseg, kseg=kseg,
+            q_axis=1, kv_axis=2, head_of=lambda *g: g[0] % h,
+            batch_of=lambda *g: g[0] // h, causal=causal)
+        if dropout > 0.0:
+            feat_inputs[0] = seed
+    return grid, qo_spec, lse_spec, kv_spec, feat_specs, feat_inputs
+
+
+def _call_walk_bytes(q, k, head_major, has_mask, has_seg):
+    """``_walk_vmem_bytes`` of a call, from its operands."""
+    _, h, h_kv, s, d = _dims(q, k, head_major)
+    return _walk_vmem_bytes(s, d, max(q.dtype.itemsize, k.dtype.itemsize),
+                            h // h_kv, has_mask, has_seg)
+
+
+def _dims(q, k, head_major):
+    """(batch, heads, kv heads, seq, head size) of a call's q and k."""
+    if head_major:
+        b, h, s, d = q.shape
+        return b, h, k.shape[1], s, d
+    b, s, h, d = q.shape
+    return b, h, k.shape[2], s, d
+
+
 def _pallas_flash_fwd(q, k, v, mask=None, qseg=None, kseg=None, seed=None,
                       *, causal, scale, block_q, block_k, dropout=0.0,
                       head_major=False):
@@ -352,34 +591,19 @@ def _pallas_flash_fwd(q, k, v, mask=None, qseg=None, kseg=None, seed=None,
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    if head_major:
-        b, h, s, d = q.shape
-        h_kv = k.shape[1]
-    else:
-        b, s, h, d = q.shape
-        h_kv = k.shape[2]
-    n_rep = h // h_kv
+    b, h, h_kv, s, d = _dims(q, k, head_major)
     block_q = min(block_q, s)
     block_k = min(block_k, s)
-    grid = (b * h, s // block_q, s // block_k)
     has_mask, has_seg = mask is not None, qseg is not None
+    walk = _call_walk_bytes(q, k, head_major, has_mask, has_seg)
     kernel = functools.partial(_fwd_kernel, scale=scale, causal=causal,
                                block_q=block_q, block_k=block_k,
                                dropout=dropout, has_mask=has_mask,
-                               has_seg=has_seg)
-    qo_spec = pl.BlockSpec((None, block_q, d), lambda n, i, j: (n, i, 0))
-    kv_spec = _causal_kv_spec(block_q, block_k, d, q_axis=1, kv_axis=2,
-                              causal=causal,
-                              kv_row=lambda n: (n // h) * h_kv
-                              + (n % h) // n_rep)
-    lse_spec = pl.BlockSpec((None, block_q, 1), lambda n, i, j: (n, i, 0))
-    feat_specs, feat_inputs = _feature_specs(
-        b=b, s=s, h=h, h_kv=h_kv, block_q=block_q, block_k=block_k,
-        dropout=dropout, mask=mask, qseg=qseg, kseg=kseg,
-        q_axis=1, kv_axis=2, head_of=lambda *g: g[0] % h,
-        batch_of=lambda *g: g[0] // h, causal=causal)
-    if dropout > 0.0:
-        feat_inputs[0] = seed
+                               has_seg=has_seg, walk=bool(walk))
+    grid, qo_spec, lse_spec, kv_spec, feat_specs, feat_inputs = \
+        _q_block_specs(b, h, h_kv, s, d, block_q, block_k, walk=walk,
+                       causal=causal, dropout=dropout, mask=mask,
+                       qseg=qseg, kseg=kseg, seed=seed)
     out, lse = pl.pallas_call(
         kernel,
         grid=grid,
@@ -392,22 +616,25 @@ def _pallas_flash_fwd(q, k, v, mask=None, qseg=None, kseg=None, seed=None,
                         pltpu.VMEM((block_q, d), jnp.float32)],
         interpret=_interpret(),
         name="flash_fwd",
+        **_traced(walk, block_q, block_k),
     )(_to_bh(q, head_major), _to_bh(k, head_major),
       _to_bh(v, head_major), *feat_inputs)
     return _from_bh(out, b, h, head_major), lse.reshape(b, h, s, 1)
 
 
 # ------------------------------------------------------------------
-# Pallas backward: dK/dV kernel (Q innermost) + dQ kernel (K/V innermost)
+# Pallas backward: a dK/dV kernel (a key block against the q tiles
+# that see it) and a dQ kernel (a q block against its key tiles).
+# Walk: the q side (dKV: Q, dO, lse, delta of the heads that share the
+# kv head) or the key side (dQ: K, V) is resident and a loop counts the
+# tiles; streamed: they are the grid's innermost axis.
 # ------------------------------------------------------------------
 
 def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                     *rest, scale, causal, block_q, block_k, dropout,
-                    has_mask, has_seg, h, h_kv, num_q):
-    """grid (B*H_kv, num_kv, num_q*n_rep): accumulate dK/dV for one kv
-    block while streaming (q_head_rep, q_block) innermost — GQA heads
-    sharing this kv head accumulate into the same scratch.  p is
-    recomputed per block from the saved lse."""
+                    has_mask, has_seg, h, h_kv, num_q, walk):
+    """p is recomputed per tile from the saved lse; GQA heads sharing
+    this kv head accumulate into the same scratch."""
     from jax.experimental import pallas as pl
 
     (seed_ref, mask_ref, qseg_ref, kseg_ref,
@@ -415,76 +642,60 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         rest, dropout=dropout, has_mask=has_mask, has_seg=has_seg)
 
     n = pl.program_id(0)   # b * h_kv + kv_head
-    j = pl.program_id(1)   # kv block
-    r = pl.program_id(2)   # rep * num_q + q block (innermost)
-    num_r = pl.num_programs(2)
-    i = r % num_q
+    k_start = pl.program_id(1) * block_k
     n_rep = h // h_kv
-    # global q-head id (matches the forward's grid index 0) for dropout
-    head = (n // h_kv) * h + (n % h_kv) * n_rep + r // num_q
 
-    @pl.when(r == 0)
-    def _init():
+    def init():
         dk_scr[:] = jnp.zeros_like(dk_scr)
         dv_scr[:] = jnp.zeros_like(dv_scr)
 
-    q_start = i * block_q
-    k_start = j * block_k
+    def finalize():
+        dk_ref[:] = dk_scr[:].astype(dk_ref.dtype)
+        dv_ref[:] = dv_scr[:].astype(dv_ref.dtype)
+
+    def tile(q, do, lse, delta, head, q_start, diag, qseg, mask):
+        _dkv_tile(q, k_ref[:], v_ref[:], do, lse, delta, dk_scr, dv_scr,
+                  seed_ref=seed_ref, head=head, dropout=dropout,
+                  scale=scale, causal=diag, q_start=q_start,
+                  k_start=k_start, qseg=qseg,
+                  kseg=kseg_ref[:] if has_seg else None, mask=mask)
+
+    if walk:
+        def head_walk(rep, carry):
+            def step(t, diag):
+                at = pl.ds(pl.multiple_of(t * block_q, block_q), block_q)
+                tile(q_ref[rep, at, :], do_ref[rep, at, :],
+                     lse_ref[rep, :, at], delta_ref[rep, :, at],
+                     n * n_rep + rep, t * block_q, diag,
+                     qseg_ref[:, at] if has_seg else None, None)
+            _query_walk(step, causal=causal, k_start=k_start,
+                        block_q=block_q, block_k=block_k,
+                        seq=q_ref.shape[1])
+            return carry
+        init()
+        jax.lax.fori_loop(0, n_rep, head_walk, 0)
+        finalize()
+        return
+
+    r = pl.program_id(2)   # rep * num_q + q block (innermost)
+    q_start = (r % num_q) * block_q
+    # global q-head id (matches the forward's grid index 0) for dropout
+    head = (n // h_kv) * h + (n % h_kv) * n_rep + r // num_q
+    pl.when(r == 0)(init)
     live = (q_start + block_q - 1 >= k_start) if causal else True
 
     @pl.when(live)
     def _compute():
-        q = q_ref[:].astype(jnp.float32)
-        k = k_ref[:].astype(jnp.float32)
-        v = v_ref[:].astype(jnp.float32)
-        do = do_ref[:].astype(jnp.float32)
-        lse = lse_ref[:]          # [block_q, 1]
-        delta = delta_ref[:]      # [block_q, 1]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale
-        s = _apply_masks(
-            s, causal=causal, q_start=q_start, k_start=k_start,
-            block_q=block_q, block_k=block_k,
-            qseg=qseg_ref[:] if has_seg else None,
-            kseg=kseg_ref[:] if has_seg else None,
-            mask=mask_ref[:].astype(jnp.float32) if has_mask else None)
-        p = jnp.exp(s - lse)                       # [block_q, block_k]
-        if has_mask or has_seg:
-            # fully-masked rows: lse == NEG_INF would give exp(0) == 1
-            p = jnp.where(s > NEG_INF * 0.5, p, 0.0)
-        dp = jax.lax.dot_general(
-            do, v, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        if dropout > 0.0:
-            u = _dropout_uniform(seed_ref[0, 0], head, q_start, k_start,
-                                 block_q, block_k)
-            keep = u >= dropout
-            p_v = jnp.where(keep, p, 0.0) / (1.0 - dropout)
-            dp = jnp.where(keep, dp, 0.0) / (1.0 - dropout)
-        else:
-            p_v = p
-        # dv += p̃^T do
-        dv_scr[:] += jax.lax.dot_general(
-            p_v, do, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        # ds = p * (dp - delta) * scale;  dk += ds^T q
-        ds = p * (dp - delta) * scale
-        dk_scr[:] += jax.lax.dot_general(
-            ds, q, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+        tile(q_ref[:], do_ref[:], lse_ref[:], delta_ref[:], head, q_start,
+             causal, qseg_ref[:] if has_seg else None,
+             mask_ref[:].astype(jnp.float32) if has_mask else None)
 
-    @pl.when(r == num_r - 1)
-    def _finalize():
-        dk_ref[:] = dk_scr[:].astype(dk_ref.dtype)
-        dv_ref[:] = dv_scr[:].astype(dv_ref.dtype)
+    pl.when(r == pl.num_programs(2) - 1)(finalize)
 
 
 def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                    *rest, scale, causal, block_q, block_k, dropout,
-                   has_mask, has_seg):
-    """grid (B*H, num_q, num_kv): accumulate dQ for one q block while
-    streaming kv blocks."""
+                   has_mask, has_seg, walk):
     from jax.experimental import pallas as pl
 
     (seed_ref, mask_ref, qseg_ref, kseg_ref,
@@ -492,51 +703,44 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         rest, dropout=dropout, has_mask=has_mask, has_seg=has_seg)
 
     n = pl.program_id(0)
-    i = pl.program_id(1)   # q block
-    j = pl.program_id(2)   # kv block (innermost)
-    num_kv = pl.num_programs(2)
+    q_start = pl.program_id(1) * block_q
 
-    @pl.when(j == 0)
-    def _init():
+    def init():
         dq_scr[:] = jnp.zeros_like(dq_scr)
 
-    q_start = i * block_q
+    def finalize():
+        dq_ref[:] = dq_scr[:].astype(dq_ref.dtype)
+
+    def tile(k, v, k_start, diag, kseg, mask):
+        _dq_tile(q_ref[:], k, v, do_ref[:], lse_ref[:], delta_ref[:],
+                 dq_scr, seed_ref=seed_ref, head=n, dropout=dropout,
+                 scale=scale, causal=diag, q_start=q_start,
+                 k_start=k_start, qseg=qseg_ref[:] if has_seg else None,
+                 kseg=kseg, mask=mask)
+
+    if walk:
+        def step(t, diag):
+            at = pl.ds(pl.multiple_of(t * block_k, block_k), block_k)
+            tile(k_ref[at, :], v_ref[at, :], t * block_k, diag,
+                 kseg_ref[:, at] if has_seg else None, None)
+        init()
+        _key_walk(step, causal=causal, q_start=q_start, block_q=block_q,
+                  block_k=block_k, seq=k_ref.shape[0])
+        finalize()
+        return
+
+    j = pl.program_id(2)   # kv block (innermost)
     k_start = j * block_k
+    pl.when(j == 0)(init)
     live = (q_start + block_q - 1 >= k_start) if causal else True
 
     @pl.when(live)
     def _compute():
-        q = q_ref[:].astype(jnp.float32)
-        k = k_ref[:].astype(jnp.float32)
-        v = v_ref[:].astype(jnp.float32)
-        do = do_ref[:].astype(jnp.float32)
-        lse = lse_ref[:]
-        delta = delta_ref[:]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale
-        s = _apply_masks(
-            s, causal=causal, q_start=q_start, k_start=k_start,
-            block_q=block_q, block_k=block_k,
-            qseg=qseg_ref[:] if has_seg else None,
-            kseg=kseg_ref[:] if has_seg else None,
-            mask=mask_ref[:].astype(jnp.float32) if has_mask else None)
-        p = jnp.exp(s - lse)
-        if has_mask or has_seg:
-            p = jnp.where(s > NEG_INF * 0.5, p, 0.0)
-        dp = jax.lax.dot_general(
-            do, v, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        if dropout > 0.0:
-            u = _dropout_uniform(seed_ref[0, 0], n, q_start, k_start,
-                                 block_q, block_k)
-            dp = jnp.where(u >= dropout, dp, 0.0) / (1.0 - dropout)
-        ds = p * (dp - delta) * scale
-        dq_scr[:] += jnp.dot(ds, k, preferred_element_type=jnp.float32)
+        tile(k_ref[:], v_ref[:], k_start, causal,
+             kseg_ref[:] if has_seg else None,
+             mask_ref[:].astype(jnp.float32) if has_mask else None)
 
-    @pl.when(j == num_kv - 1)
-    def _finalize():
-        dq_ref[:] = dq_scr[:].astype(dq_ref.dtype)
+    pl.when(j == pl.num_programs(2) - 1)(finalize)
 
 
 def _pallas_flash_bwd(q, k, v, out, lse, dout, mask=None, qseg=None,
@@ -545,16 +749,12 @@ def _pallas_flash_bwd(q, k, v, out, lse, dout, mask=None, qseg=None,
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    if head_major:
-        b, h, s, d = q.shape
-        h_kv = k.shape[1]
-    else:
-        b, s, h, d = q.shape
-        h_kv = k.shape[2]
+    b, h, h_kv, s, d = _dims(q, k, head_major)
     n_rep = h // h_kv
     block_q = min(block_q, s)
     block_k = min(block_k, s)
     has_mask, has_seg = mask is not None, qseg is not None
+    walk = _call_walk_bytes(q, k, head_major, has_mask, has_seg)
     # delta_i = rowsum(dO_i * O_i): cheap elementwise+reduce, XLA fuses it
     eq = "bhsd,bhsd->bhs" if head_major else "bshd,bshd->bhs"
     delta = jnp.einsum(eq, dout.astype(jnp.float32),
@@ -562,42 +762,60 @@ def _pallas_flash_bwd(q, k, v, out, lse, dout, mask=None, qseg=None,
     q3, do3 = _to_bh(q, head_major), _to_bh(dout, head_major)
     k3, v3 = _to_bh(k, head_major), _to_bh(v, head_major)
     lse3 = lse.reshape(b * h, s, 1)
+    # the dKV kernel's tiles are transposed: lse and delta are rows there
+    lse_row, delta_row = lse.reshape(b * h, 1, s), delta.reshape(b * h, 1, s)
     num_q = s // block_q
+    common = dict(scale=scale, causal=causal, block_q=block_q,
+                  block_k=block_k, dropout=dropout, has_mask=has_mask,
+                  has_seg=has_seg, walk=bool(walk))
 
-    # ---- dK/dV: grid (b*h_kv, num_kv, num_q*n_rep) — GQA q-heads that
-    # share a kv head stream through the innermost axis and accumulate
-    def q_row(n, j, r):
-        return (n // h_kv) * h + (n % h_kv) * n_rep + r // num_q
+    # ---- dK/dV.  Walk: grid (b*h_kv, num_kv), the n_rep q-heads that
+    # share the kv head resident.  Streamed: grid (b*h_kv, num_kv,
+    # num_q*n_rep), those heads' q blocks through the innermost axis.
+    kv_spec_q = pl.BlockSpec((None, block_k, d), lambda n, j, *_: (n, j, 0))
+    if walk:
+        grid_q = (b * h_kv, s // block_k)
+        # rows n*n_rep … of [B*H, S, ·]: (b, kv head)'s q-heads
+        qo_spec_q = pl.BlockSpec((n_rep, s, d), lambda n, j: (n, 0, 0))
+        lse_spec_q = pl.BlockSpec((n_rep, 1, s), lambda n, j: (n, 0, 0))
+        # the same ids: kseg [B, 1, S] is the q side's row, qseg
+        # [B, S, 1] the key block's column
+        feat_specs_q, feat_inputs_q = _seed_and_segment_specs(
+            dropout, seed, kseg, qseg,
+            pl.BlockSpec((None, 1, s), lambda n, j: (n // h_kv, 0, 0)),
+            pl.BlockSpec((None, block_k, 1),
+                         lambda n, j: (n // h_kv, j, 0)))
+    else:
+        grid_q = (b * h_kv, s // block_k, num_q * n_rep)
 
-    def qi_clamped(n, j, r):
-        i = r % num_q
-        if causal:
-            i = jnp.maximum(i, (j * block_k) // block_q)
-        return i
+        def q_row(n, j, r):
+            return (n // h_kv) * h + (n % h_kv) * n_rep + r // num_q
 
-    qo_spec_q = pl.BlockSpec(
-        (None, block_q, d), lambda n, j, r: (q_row(n, j, r),
-                                             qi_clamped(n, j, r), 0))
-    lse_spec_q = pl.BlockSpec(
-        (None, block_q, 1), lambda n, j, r: (q_row(n, j, r),
-                                             qi_clamped(n, j, r), 0))
-    kv_spec_q = pl.BlockSpec((None, block_k, d), lambda n, j, r: (n, j, 0))
-    feat_specs_q, feat_inputs_q = _feature_specs(
-        b=b, s=s, h=h, h_kv=h_kv, block_q=block_q, block_k=block_k,
-        dropout=dropout, mask=mask, qseg=qseg, kseg=kseg,
-        q_axis=2, kv_axis=1,
-        head_of=lambda n, j, r: (n % h_kv) * n_rep + r // num_q,
-        batch_of=lambda n, j, r: n // h_kv, causal=causal,
-        grid_qi=qi_clamped)
-    if dropout > 0.0:
-        feat_inputs_q[0] = seed
-    dkv_kernel = functools.partial(
-        _bwd_dkv_kernel, scale=scale, causal=causal, block_q=block_q,
-        block_k=block_k, dropout=dropout, has_mask=has_mask,
-        has_seg=has_seg, h=h, h_kv=h_kv, num_q=num_q)
+        def qi_clamped(n, j, r):
+            i = r % num_q
+            if causal:
+                i = jnp.maximum(i, (j * block_k) // block_q)
+            return i
+
+        qo_spec_q = pl.BlockSpec(
+            (None, block_q, d), lambda n, j, r: (q_row(n, j, r),
+                                                 qi_clamped(n, j, r), 0))
+        lse_spec_q = pl.BlockSpec(
+            (None, 1, block_q), lambda n, j, r: (q_row(n, j, r), 0,
+                                                 qi_clamped(n, j, r)))
+        feat_specs_q, feat_inputs_q = _feature_specs(
+            b=b, s=s, h=h, h_kv=h_kv, block_q=block_q, block_k=block_k,
+            dropout=dropout, mask=mask, qseg=qseg, kseg=kseg,
+            q_axis=2, kv_axis=1,
+            head_of=lambda n, j, r: (n % h_kv) * n_rep + r // num_q,
+            batch_of=lambda n, j, r: n // h_kv, causal=causal,
+            grid_qi=qi_clamped)
+        if dropout > 0.0:
+            feat_inputs_q[0] = seed
     dk, dv = pl.pallas_call(
-        dkv_kernel,
-        grid=(b * h_kv, s // block_k, num_q * n_rep),
+        functools.partial(_bwd_dkv_kernel, h=h, h_kv=h_kv, num_q=num_q,
+                          **common),
+        grid=grid_q,
         in_specs=[qo_spec_q, kv_spec_q, kv_spec_q, qo_spec_q,
                   lse_spec_q, lse_spec_q] + feat_specs_q,
         out_specs=[kv_spec_q, kv_spec_q],
@@ -607,28 +825,17 @@ def _pallas_flash_bwd(q, k, v, out, lse, dout, mask=None, qseg=None,
                         pltpu.VMEM((block_k, d), jnp.float32)],
         interpret=_interpret(),
         name="flash_bwd_dkv",
-    )(q3, k3, v3, do3, lse3, delta, *feat_inputs_q)
+        **_traced(walk, block_q, block_k),
+    )(q3, k3, v3, do3, lse_row, delta_row, *feat_inputs_q)
 
-    # ---- dQ: grid (b*h, num_q, num_kv)
-    kv_row = lambda n: (n // h) * h_kv + (n % h) // n_rep  # noqa: E731
-    qo_spec = pl.BlockSpec((None, block_q, d), lambda n, i, j: (n, i, 0))
-    kv_spec = _causal_kv_spec(block_q, block_k, d, q_axis=1, kv_axis=2,
-                              causal=causal, kv_row=kv_row)
-    lse_spec = pl.BlockSpec((None, block_q, 1), lambda n, i, j: (n, i, 0))
-    feat_specs, feat_inputs = _feature_specs(
-        b=b, s=s, h=h, h_kv=h_kv, block_q=block_q, block_k=block_k,
-        dropout=dropout, mask=mask, qseg=qseg, kseg=kseg,
-        q_axis=1, kv_axis=2, head_of=lambda *g: g[0] % h,
-        batch_of=lambda *g: g[0] // h, causal=causal)
-    if dropout > 0.0:
-        feat_inputs[0] = seed
-    dq_kernel = functools.partial(
-        _bwd_dq_kernel, scale=scale, causal=causal, block_q=block_q,
-        block_k=block_k, dropout=dropout, has_mask=has_mask,
-        has_seg=has_seg)
+    # ---- dQ: a q block against its key tiles
+    grid, qo_spec, lse_spec, kv_spec, feat_specs, feat_inputs = \
+        _q_block_specs(b, h, h_kv, s, d, block_q, block_k, walk=walk,
+                       causal=causal, dropout=dropout, mask=mask,
+                       qseg=qseg, kseg=kseg, seed=seed)
     dq = pl.pallas_call(
-        dq_kernel,
-        grid=(b * h, num_q, s // block_k),
+        functools.partial(_bwd_dq_kernel, **common),
+        grid=grid,
         in_specs=[qo_spec, kv_spec, kv_spec, qo_spec, lse_spec, lse_spec]
         + feat_specs,
         out_specs=qo_spec,
@@ -636,6 +843,7 @@ def _pallas_flash_bwd(q, k, v, out, lse, dout, mask=None, qseg=None,
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
         interpret=_interpret(),
         name="flash_bwd_dq",
+        **_traced(walk, block_q, block_k),
     )(q3, k3, v3, do3, lse3, delta, *feat_inputs)
     return (_from_bh(dq, b, h, head_major),
             _from_bh(dk, b, h_kv, head_major),
@@ -693,35 +901,112 @@ def _flash_bwd_rule(causal, scale, dropout, block_q, block_k,
 _flash_core.defvjp(_flash_fwd_rule, _flash_bwd_rule)
 
 
-def _pick_blocks(s, d, which="fwd"):
-    """Block sizes: a sweep this process ran first (validated — a
-    non-dividing entry would truncate the grid and leave rows
-    unwritten), then shape heuristics.  `which` selects the direction:
-    the dkv/dq kernels prefer different shapes than the forward, so fwd
-    and bwd are swept and recorded separately."""
+# ------------------------------------------------------------------
+# The rule: which calls walk, and with which tiles.  The only place a
+# shape turns into a path or a block size.
+# ------------------------------------------------------------------
+
+# What a walk's resident operands may take of a v5e core's 128 MiB of
+# VMEM, and what its tiles and temporaries get beside them.
+_WALK_VMEM_BUDGET = 64 << 20
+_WALK_WORK_BYTES = 16 << 20
+
+
+def _walk_vmem_bytes(s, d, itemsize, n_rep, has_mask=False, has_seg=False):
+    """VMEM the resident operands of a call's walk take, or 0 where the
+    call takes the streamed grid.  A rule on what the call can see.
+
+    The walk holds one side of the products in VMEM for a whole head
+    and counts the other side's live tiles in a loop.  The largest
+    resident set of the three kernels is dKV's: Q and dO (``[s, d]``)
+    and lse and delta (float32 rows ``[1, s]``, which a tile of 8
+    sublanes holds: 32 bytes a position) of the ``n_rep`` q-heads that
+    share a kv head, and the q side's segment ids, such a row too,
+    where there are any; the pipeline keeps two buffers of each.  A
+    head that does not fit streams, and so does an ``[S, S]`` mask: no
+    side of it can be held."""
+    if has_mask:
+        return 0
+    row = s * 32
+    held = n_rep * (2 * s * d * itemsize + 2 * row) \
+        + (row if has_seg else 0)
+    return 2 * held if 2 * held <= _WALK_VMEM_BUDGET else 0
+
+
+def _traced(walk, block_q, block_k):
+    """Counts one trace of a kernel by its path (``pallas.flash.resident``
+    / ``pallas.flash.streamed``) and gives the ``pallas_call`` what the
+    path needs beside the grid: a walk's VMEM limit — its resident
+    bytes, and room for a dozen float32 tiles."""
+    from jax.experimental.pallas import tpu as pltpu
+    from ..utils import monitor
+
+    monitor.incr("pallas.flash.resident" if walk
+                 else "pallas.flash.streamed")
+    if not walk:
+        return {}
+    work = max(_WALK_WORK_BYTES, 12 * block_q * block_k * 4)
+    return {"compiler_params": pltpu.CompilerParams(
+        vmem_limit_bytes=walk + work)}
+
+
+# (q block, key tile) a direction, from ``docs/perf/
+# ubench_flash_train.pr35.log``: the cell's call (S 2,048, d 128,
+# bfloat16) and the same tokens at S 4,096 and 8,192.
+_WALK_BLOCKS = {"fwd": (1024, 1024), "bwd": (512, 512)}
+
+
+def _fit(target, s):
+    """``target`` (a power of two from 128 up) halved until it divides
+    ``s`` (a multiple of 128: ``_supports_pallas``)."""
+    block = target
+    while s % block:
+        block //= 2
+    return block
+
+
+def _pick_blocks(s, d, which="fwd", walk=False):
+    """(q block, key block) of a tile for a direction: a sweep this
+    process ran first (validated — a non-dividing entry would truncate
+    the grid and leave rows unwritten), then the table.  The backward's
+    two kernels prefer other shapes than the forward, so the two
+    directions are swept and recorded separately; a walk's tiles are
+    larger than a streamed step's, which a grid step's fetch bounds."""
     from .autotune import lookup
     cached = lookup(f"flash_attention.{which}", (s, d))
     if cached is not None and len(cached) == 2:
         bq, bk = int(cached[0]), int(cached[1])
         if 0 < bq <= s and 0 < bk <= s and s % bq == 0 and s % bk == 0:
             return bq, bk
+    if walk:
+        bq, bk = _WALK_BLOCKS[which]
+        return _fit(bq, s), _fit(bk, s)
     block_q = 256 if s % 256 == 0 else 128
     block_k = 512 if s % 512 == 0 else block_q
     return min(block_q, s), min(block_k, s)
 
 
+def _block_candidates(s):
+    """The (q block, key block) pairs ``autotune_blocks`` times at
+    sequence length ``s``: every pair of dividing powers of two from
+    128 to 1,024 whose float32 score tile stays within 2 MiB."""
+    sizes = [x for x in (128, 256, 512, 1024) if x <= s and s % x == 0]
+    return [(bq, bk) for bq in sizes for bk in sizes
+            if bq * bk * 4 <= 2 << 20]
+
+
 def autotune_blocks(s, d, dtype=jnp.bfloat16, batch=1, heads=1):
-    """Timed sweeps over divisor block sizes for (seq, head_dim); records
-    the winners for this process (reference: phi/kernels/autotune
-    switch_autotune.h).  Forward and backward are swept SEPARATELY —
-    the dkv/dq kernels prefer different shapes than the forward, and
-    each direction's choice feeds its own key.  A candidate the chip
-    refuses raises."""
+    """Timed sweeps over ``_block_candidates`` for (seq, head_dim);
+    records the winners for this process (reference: phi/kernels/
+    autotune switch_autotune.h).  Forward and backward are swept
+    SEPARATELY — the dkv/dq kernels prefer different shapes than the
+    forward, and each direction's choice feeds its own key.  The calls
+    take the path the rule gives them (a causal call of one head group:
+    the walk wherever the head fits).  A candidate the chip refuses
+    raises."""
     from . import autotune as at
 
-    cands = [(bq, bk)
-             for bq in (128, 256, 512) for bk in (128, 256, 512)
-             if bq <= s and bk <= s and s % bq == 0 and s % bk == 0]
+    cands = _block_candidates(s)
     if not cands:
         return _pick_blocks(s, d)
     key = jax.random.PRNGKey(0)
@@ -1205,10 +1490,11 @@ def flash_attention(query, key, value, attn_mask=None, dropout=0.0,
         else:
             shaped_ok = _supports_pallas(q, k, v, m, seg)
         if shaped_ok and not mask_trainable:
-            seq_len = q.shape[2] if head_major else q.shape[1]
-            block_q, block_k = _pick_blocks(seq_len, q.shape[-1])
-            block_qb, block_kb = _pick_blocks(seq_len, q.shape[-1],
-                                              which="bwd")
+            *_, seq_len, d_ = _dims(q, k, head_major)
+            walk = bool(_call_walk_bytes(q, k, head_major, m is not None,
+                                         seg is not None))
+            block_q, block_k = _pick_blocks(seq_len, d_, "fwd", walk)
+            block_qb, block_kb = _pick_blocks(seq_len, d_, "bwd", walk)
             mask_add = None
             if m is not None:
                 mask_add = (jnp.where(m, 0.0, NEG_INF).astype(jnp.float32)

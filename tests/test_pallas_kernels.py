@@ -29,8 +29,35 @@ def _qkv(b=2, s=256, h=2, d=64, dtype=jnp.float32, seed=0):
     return mk(), mk(), mk()
 
 
+_PATH_COUNTERS = ("pallas.flash.resident", "pallas.flash.streamed")
+
+
+def _path_traces():
+    from paddle_tpu.utils import monitor
+    stats = monitor.all_stats()
+    return [stats.get(name, 0) for name in _PATH_COUNTERS]
+
+
+@pytest.fixture(params=["walk", "streamed"])
+def flash_path(request, monkeypatch):
+    """Every kernel test runs over BOTH paths.  Which one a call takes
+    is a rule on its shapes (``fa._walk_vmem_bytes``), no argument: the
+    test forces the rule's answer by taking the walk's VMEM budget
+    away, and afterwards holds the two counters to it."""
+    if request.param == "streamed":
+        monkeypatch.setattr(fa, "_WALK_VMEM_BUDGET", 0)
+    before = _path_traces()
+    yield request.param
+    took = [after - b for after, b in zip(_path_traces(), before)]
+    walked, streamed = took
+    if request.param == "walk":
+        assert walked > 0 and streamed == 0, took
+    else:
+        assert streamed > 0 and walked == 0, took
+
+
 @pytest.mark.parametrize("causal", [False, True])
-def test_flash_forward_matches_xla(causal):
+def test_flash_forward_matches_xla(causal, flash_path):
     q, k, v = _qkv()
     sc = 1.0 / np.sqrt(q.shape[-1])
     out, lse = fa._pallas_flash_fwd(q, k, v, causal=causal, scale=sc,
@@ -49,7 +76,7 @@ def test_flash_forward_matches_xla(causal):
 
 
 @pytest.mark.parametrize("causal", [False, True])
-def test_flash_backward_matches_xla(causal):
+def test_flash_backward_matches_xla(causal, flash_path):
     q, k, v = _qkv(seed=1)
     sc = 1.0 / np.sqrt(q.shape[-1])
 
@@ -67,7 +94,7 @@ def test_flash_backward_matches_xla(causal):
                                    atol=5e-5, rtol=5e-5)
 
 
-def test_flash_mixed_blocks_bf16():
+def test_flash_mixed_blocks_bf16(flash_path):
     q, k, v = _qkv(b=1, s=384, h=2, d=128, dtype=jnp.bfloat16, seed=2)
     sc = 1.0 / np.sqrt(q.shape[-1])
     out, _ = fa._pallas_flash_fwd(q, k, v, causal=True, scale=sc,
@@ -171,7 +198,7 @@ def test_autotune_sweep_records_in_process_and_hides_no_failure():
 
 
 @pytest.mark.parametrize("bq,bk", [(128, 64), (64, 128)])
-def test_flash_backward_mixed_blocks_causal(bq, bk):
+def test_flash_backward_mixed_blocks_causal(bq, bk, flash_path):
     """Causal bwd with unequal block sizes exercises the clamped
     dead-block index maps (first-live-q and diagonal-kv math)."""
     q, k, v = _qkv(b=1, s=256, h=2, d=64, seed=4)
@@ -211,7 +238,7 @@ def _dense_dropout_ref(q, k, v, seed, rate, sc, causal=False):
 
 
 @pytest.mark.parametrize("causal", [False, True])
-def test_flash_dropout_matches_dense_hash(causal):
+def test_flash_dropout_matches_dense_hash(causal, flash_path):
     q, k, v = _qkv(b=1, s=256, h=2, d=64, seed=3)
     sc = 1.0 / np.sqrt(q.shape[-1])
     seed = jnp.full((1, 1), 1234, jnp.uint32)
@@ -262,8 +289,11 @@ def test_flash_mask_matches_xla(mask_kind):
         return (fa._xla_attention(q_, k_, v_, attn_mask=mask,
                                   scale=sc) ** 2).sum()
 
+    before = _path_traces()
     out = fa._flash_core(q, k, v, mask_add, None, None, None, False, sc,
                          0.0, 128, 128)
+    # an [S, S] mask is streamed by blocks: the grid, whatever the shape
+    assert [a - b_ for a, b_ in zip(_path_traces(), before)] == [0, 1]
     ref = fa._xla_attention(q, k, v, attn_mask=mask, scale=sc)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                atol=5e-5, rtol=5e-5)
@@ -275,7 +305,7 @@ def test_flash_mask_matches_xla(mask_kind):
 
 
 @pytest.mark.parametrize("causal", [False, True])
-def test_flash_segment_ids_varlen(causal):
+def test_flash_segment_ids_varlen(causal, flash_path):
     # packed varlen: two sequences of 160+96 tokens in one row
     b, s, h, d = 2, 256, 2, 64
     q, k, v = _qkv(b=b, s=s, h=h, d=d, seed=5)
@@ -308,7 +338,7 @@ def test_flash_segment_ids_varlen(causal):
 
 
 @pytest.mark.parametrize("causal", [False, True])
-def test_flash_gqa_native_kv_heads(causal):
+def test_flash_gqa_native_kv_heads(causal, flash_path):
     # K/V carry 2 heads, Q carries 4 — kernels must index q_head // n_rep
     # without materializing repeated K/V (VERDICT r2 item 4)
     b, s, h, h_kv, d = 2, 256, 4, 2, 64
@@ -339,7 +369,7 @@ def test_flash_gqa_native_kv_heads(causal):
                                    atol=2e-4, rtol=2e-4)
 
 
-def test_flash_all_features_combined():
+def test_flash_all_features_combined(flash_path):
     # GQA + segment ids + dropout + causal in one call: smoke + shapes +
     # determinism (same seed → same output)
     b, s, h, h_kv, d = 1, 256, 4, 2, 64
@@ -365,7 +395,7 @@ def test_flash_all_features_combined():
 
 
 @pytest.mark.parametrize("causal", [False, True])
-def test_flash_dropout_gqa_matches_dense_hash(causal):
+def test_flash_dropout_gqa_matches_dense_hash(causal, flash_path):
     # pins the fwd/bwd dropout-stream head-id algebra under GQA: the dkv
     # kernel reconstructs head = (n//h_kv)*h + (n%h_kv)*n_rep + r//num_q,
     # which must match the forward's grid index exactly
@@ -423,7 +453,7 @@ def test_flash_trainable_mask_gets_gradient():
 
 
 @pytest.mark.parametrize("causal", [False, True])
-def test_flash_head_major_matches_default_layout(causal):
+def test_flash_head_major_matches_default_layout(causal, flash_path):
     # [B, H, S, D] path (free reshape instead of transposes) must be
     # numerically identical to the [B, S, H, D] path, fwd and bwd
     q, k, v = _qkv(b=2, s=256, h=2, d=64, seed=11)
@@ -453,7 +483,7 @@ def test_flash_head_major_matches_default_layout(causal):
                                    np.asarray(gr), atol=1e-5)
 
 
-def test_flash_bwd_blocks_differ_from_fwd():
+def test_flash_bwd_blocks_differ_from_fwd(flash_path):
     # split fwd/bwd block choices: passing distinct bwd blocks must give
     # identical numerics (only scheduling differs)
     q, k, v = _qkv(b=1, s=256, h=2, d=64, seed=12)
@@ -471,3 +501,92 @@ def test_flash_bwd_blocks_differ_from_fwd():
     for a, b_ in zip(g_same, g_diff):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b_),
                                    atol=1e-5)
+
+
+def test_flash_bf16_d128_walk_streamed_and_xla_agree():
+    """The training cell's types at a small shape: bfloat16 tiles of
+    head size 128 go to the matrix unit as they are on both paths.
+    Walk, streamed grid and the XLA lane give the same forward and the
+    same three gradients to bfloat16's resolution; walk and grid,
+    which share every tile's arithmetic, much closer than that."""
+    b, s, h, d = 1, 512, 2, 128
+    q, k, v = _qkv(b=b, s=s, h=h, d=d, dtype=jnp.bfloat16, seed=13)
+    w = _qkv(b=b, s=s, h=h, d=d, dtype=jnp.bfloat16, seed=14)[0]
+    sc = 1.0 / np.sqrt(d)
+
+    def run(attn):
+        def loss(q_, k_, v_):
+            out = attn(q_, k_, v_)
+            return jnp.sum(out.astype(jnp.float32)
+                           * w.astype(jnp.float32)), out
+        (_, out), grads = jax.value_and_grad(
+            loss, argnums=(0, 1, 2), has_aux=True)(q, k, v)
+        return [np.asarray(x, np.float32) for x in (out, *grads)]
+
+    def kernels(q_, k_, v_):
+        return fa._flash_core(q_, k_, v_, None, None, None, None, True,
+                              sc, 0.0, 256, 128, 128, 256)
+
+    before = _path_traces()
+    walk = run(kernels)
+    assert [a - b_ for a, b_ in zip(_path_traces(), before)] == [3, 0]
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(fa, "_WALK_VMEM_BUDGET", 0)
+        grid = run(kernels)
+    assert [a - b_ for a, b_ in zip(_path_traces(), before)] == [3, 3]
+    xla = run(lambda q_, k_, v_: fa._xla_attention(
+        q_, k_, v_, causal=True, scale=sc))
+    for name, a, g, x in zip(("out", "dq", "dk", "dv"), walk, grid, xla):
+        assert a.dtype == np.float32 and np.isfinite(a).all(), name
+        scale = np.abs(x).max()
+        # one bfloat16 rounding of the result and of p / ds on the way
+        np.testing.assert_allclose(a, x, atol=2e-2 * scale, err_msg=name)
+        np.testing.assert_allclose(g, x, atol=2e-2 * scale, err_msg=name)
+        np.testing.assert_allclose(a, g, atol=8e-3 * scale, err_msg=name)
+
+
+def test_flash_walk_rule_and_counters():
+    """Which path a call takes is read off its shapes, in one place."""
+    budget = fa._WALK_VMEM_BUDGET
+    # the training cell: S 2,048, d 128, bfloat16, one q-head a kv head
+    cell = fa._walk_vmem_bytes(2048, 128, 2, 1)
+    assert 0 < cell <= budget
+    # Q, dO (512 KB each) and the lse, delta rows (64 KB each), twice
+    assert cell == 2 * (2 * 2048 * 128 * 2 + 2 * 2048 * 32)
+    # an [S, S] mask has no side that can be held: the streamed grid
+    assert fa._walk_vmem_bytes(2048, 128, 2, 1, has_mask=True) == 0
+    # a head over the budget: one of S 65,536, or eight q-heads a kv
+    # head (dKV holds the Q and dO of all eight) at S 8,192
+    assert fa._walk_vmem_bytes(65536, 128, 2, 1) == 0
+    assert fa._walk_vmem_bytes(8192, 128, 2, 8) == 0
+    assert fa._walk_vmem_bytes(32768, 128, 2, 1) > 0
+    assert fa._walk_vmem_bytes(8192, 128, 2, 4) > 0
+    # segment ids ride the walk, and are counted
+    assert fa._walk_vmem_bytes(2048, 128, 2, 1, has_seg=True) \
+        == cell + 2 * 2048 * 32
+    # the blocks: a walk's from the table, a streamed step's as before;
+    # every block divides the sequence
+    assert fa._pick_blocks(2048, 128, "fwd", True) \
+        == fa._WALK_BLOCKS["fwd"]
+    assert fa._pick_blocks(2048, 128, "bwd", True) \
+        == fa._WALK_BLOCKS["bwd"]
+    assert fa._pick_blocks(2048, 128, "fwd", False) == (256, 512)
+    for s in range(128, 2048 + 1, 128):
+        for which in ("fwd", "bwd"):
+            for walk in (False, True):
+                bq, bk = fa._pick_blocks(s, 128, which, walk)
+                assert s % bq == 0 and s % bk == 0, (s, which, walk)
+
+    # the counters: one a trace of a kernel, by the path it took
+    q, k, v = _qkv(b=1, s=256, h=2, d=64, seed=15)
+    mask = jnp.zeros((1, 1, 256, 256), jnp.float32)
+
+    def traces(mask_add):
+        before = _path_traces()
+        jax.grad(lambda q_: fa._flash_core(
+            q_, k, v, mask_add, None, None, None, True, 0.125, 0.0, 128,
+            128).sum())(q)
+        return [a - b_ for a, b_ in zip(_path_traces(), before)]
+
+    assert traces(None) == [3, 0]          # forward, dKV, dQ: the walk
+    assert traces(mask) == [0, 3]          # the mask streams, and runs

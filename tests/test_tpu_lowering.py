@@ -82,8 +82,12 @@ _FLASH = {
 def test_flash_fwd_bwd_compiles(v5e, case):
     b, s, h, h_kv, d, head_major, feats = _FLASH[case]
     dropout = 0.1 if "dropout" in feats else 0.0
-    bq, bk = fa._pick_blocks(s, d)
-    bqb, bkb = fa._pick_blocks(s, d, which="bwd")
+    # the path and the blocks the public op would give the call
+    walk = bool(fa._walk_vmem_bytes(s, d, 2, h // h_kv, "mask" in feats,
+                                    "seg" in feats))
+    assert walk == ("mask" not in feats)
+    bq, bk = fa._pick_blocks(s, d, "fwd", walk)
+    bqb, bkb = fa._pick_blocks(s, d, "bwd", walk)
 
     def f(q, k, v, mask, qseg, kseg, seed):
         def loss(q, k, v):
@@ -106,18 +110,78 @@ def test_flash_fwd_bwd_compiles(v5e, case):
 
 
 def test_flash_every_autotune_candidate_compiles(v5e):
-    """``autotune_blocks`` sweeps these nine block pairs on the chip and
-    no longer skips one that fails: each must compile."""
+    """``autotune_blocks`` sweeps these block pairs on the chip and no
+    longer skips one that fails: each must compile."""
     s, d = 1024, 64
-    for bq in (128, 256, 512):
-        for bk in (128, 256, 512):
-            def f(q, k, v, bq=bq, bk=bk):
-                return fa._flash_core(q, k, v, None, None, None, None,
-                                      True, 0.125, 0.0, bq, bk, None,
-                                      None, False)
-            text = _compile(f, [((1, s, 2, d), BF16)] * 3,
-                            SingleDeviceSharding(v5e[0]))
-            assert _n_kernels(text) == 1, (bq, bk)
+    cands = fa._block_candidates(s)
+    assert len(cands) == 15 and (1024, 512) in cands \
+        and (1024, 1024) not in cands
+    for bq, bk in cands:
+        def f(q, k, v, bq=bq, bk=bk):
+            return fa._flash_core(q, k, v, None, None, None, None,
+                                  True, 0.125, 0.0, bq, bk, None,
+                                  None, False)
+        text = _compile(f, [((1, s, 2, d), BF16)] * 3,
+                        SingleDeviceSharding(v5e[0]))
+        assert _n_kernels(text) == 1, (bq, bk)
+
+
+def _flash_vjp_program(v5e, b, h, h_kv, s, d, fwd, bwd):
+    """The forward and both backward kernels of a causal head-major
+    bfloat16 call at the given blocks, compiled for the chip."""
+    def f(q, k, v):
+        def loss(q, k, v):
+            out = fa._flash_core(q, k, v, None, None, None, None, True,
+                                 1.0 / math.sqrt(d), 0.0, *fwd, *bwd, True)
+            return jnp.sum(out.astype(F32))
+        return jax.value_and_grad(loss, argnums=(0, 1, 2))(q, k, v)
+    return _compile(f, [((b, h, s, d), BF16), ((b, h_kv, s, d), BF16),
+                        ((b, h_kv, s, d), BF16)],
+                    SingleDeviceSharding(v5e[0]))
+
+
+def _flash_path_traces():
+    from paddle_tpu.utils import monitor
+    stats = monitor.all_stats()
+    return [stats.get("pallas.flash.resident", 0),
+            stats.get("pallas.flash.streamed", 0)]
+
+
+def test_flash_walk_compiles_at_the_training_cells_shape(v5e):
+    """``gpt3-1.3b-1chip.train-2k``'s call — B 4, H 16, S 2,048, d 128,
+    bfloat16, causal, head-major — takes the walk by the rule, and its
+    three kernels compile for the chip with a head resident."""
+    b, h, s, d = 4, 16, 2048, 128
+    assert fa._walk_vmem_bytes(s, d, 2, 1) > 0
+    before = _flash_path_traces()
+    text = _flash_vjp_program(v5e, b, h, h, s, d,
+                              fa._pick_blocks(s, d, "fwd", True),
+                              fa._pick_blocks(s, d, "bwd", True))
+    assert _n_kernels(text) == 3
+    after = _flash_path_traces()
+    assert [a - b_ for a, b_ in zip(after, before)] == [3, 0]
+
+
+def test_flash_walk_every_pair_of_the_rule_compiles(v5e):
+    """Every (q block, key tile) pair ``_pick_blocks`` can hand a walk,
+    at a sequence length that draws it; and the longest head the
+    budget admits, with four q-heads a kv head resident in dKV."""
+    drawn = {}
+    for s in range(128, 4096 + 1, 128):
+        pairs = (fa._pick_blocks(s, 128, "fwd", True),
+                 fa._pick_blocks(s, 128, "bwd", True))
+        drawn.setdefault(pairs, s)
+    assert len(drawn) >= 3, drawn
+    for (fwd, bwd), s in drawn.items():
+        text = _flash_vjp_program(v5e, 1, 2, 2, s, 128, fwd, bwd)
+        assert _n_kernels(text) == 3, (s, fwd, bwd)
+    s = 8192
+    assert fa._walk_vmem_bytes(s, 128, 2, 4) > 0
+    assert fa._walk_vmem_bytes(2 * s, 128, 2, 4) == 0
+    text = _flash_vjp_program(v5e, 1, 8, 2, s, 128,
+                              fa._pick_blocks(s, 128, "fwd", True),
+                              fa._pick_blocks(s, 128, "bwd", True))
+    assert _n_kernels(text) == 3
 
 
 # ------------------------------------------------- rms norm, rope, adam
