@@ -20,12 +20,15 @@ from typing import Optional, Sequence
 import jax
 import jax.numpy as jnp
 
+from ..observability import tracing as _tracing
+
 
 class GradNode:
     """One autograd graph node = one recorded op."""
 
     __slots__ = ("name", "vjp_fn", "inputs", "out_avals", "single_output",
-                 "pure", "packed_saved", "saved_hooks", "__weakref__")
+                 "pure", "packed_saved", "saved_hooks", "scopes",
+                 "__weakref__")
 
     def __init__(self, name, vjp_fn, inputs, out_avals, single_output,
                  pure=None):
@@ -37,6 +40,9 @@ class GradNode:
         self.pure = pure              # primal fn, kept for create_graph replay
         self.packed_saved = None      # saved_tensors_hooks pack() results
         self.saved_hooks = None
+        # the program scopes open where the op ran: backward re-enters
+        # them, so its operations are named by layer kind too
+        self.scopes = _tracing.scope_path()
 
     def __repr__(self):
         return f"<GradNode {self.name}>"
@@ -57,6 +63,12 @@ class _EdgeRef:
         self._out_index = t._out_index
         self.stop_gradient = t.stop_gradient
         self._hooks = t._hooks
+
+
+def _accumulate(prev, g):
+    """Two cotangents of one value, summed: the tape's own work."""
+    with _tracing.scope("grad_accum"):
+        return prev + g
 
 
 def _is_float0(g):
@@ -148,10 +160,11 @@ def run_backward(tensors, grad_tensors=None, retain_graph=False,
             key = (id(node), tensor._out_index)
             id_to_node[id(node)] = node
             prev = node_cots.get(key)
-            node_cots[key] = g if prev is None else prev + g
+            node_cots[key] = g if prev is None else _accumulate(prev, g)
         else:
             prev = leaf_grads.get(id(tensor))
-            leaf_grads[id(tensor)] = g if prev is None else prev + g
+            leaf_grads[id(tensor)] = g if prev is None \
+                else _accumulate(prev, g)
 
     for t, g in zip(tensors, grad_tensors):
         if g is None:
@@ -179,7 +192,8 @@ def run_backward(tensors, grad_tensors=None, retain_graph=False,
                 # (e.g. an fp32 loss vjp feeding bf16 logits under AMP O2);
                 # jax.vjp requires an exact dtype match
                 if g.dtype != dtype:
-                    g = g.astype(dtype)
+                    with _tracing.backward_of(node.scopes):
+                        g = g.astype(dtype)
             cots.append(g)
         if not any_live:
             continue
@@ -214,7 +228,8 @@ def run_backward(tensors, grad_tensors=None, retain_graph=False,
                     t._hooks = e._hooks
                     hook_prims.append(t)
             else:
-                _, node.vjp_fn = jax.vjp(node.pure, *arrs)
+                with _tracing.backward_of(node.scopes):
+                    _, node.vjp_fn = jax.vjp(node.pure, *arrs)
             if not (retain_graph or create_graph):
                 node.packed_saved = None
         else:
@@ -225,7 +240,8 @@ def run_backward(tensors, grad_tensors=None, retain_graph=False,
             # is differentiable (reference: GeneralGrad create_graph,
             # paddle/fluid/eager/backward.cc:102).
             try:
-                in_grads = _symbolic_vjp(node, cots, prims=hook_prims)
+                with _tracing.backward_of(node.scopes):
+                    in_grads = _symbolic_vjp(node, cots, prims=hook_prims)
             finally:
                 # reverse: a tensor appearing twice in node.inputs (x*x)
                 # records the already-swapped value as its second "orig"
@@ -237,7 +253,8 @@ def run_backward(tensors, grad_tensors=None, retain_graph=False,
                 raise RuntimeError(
                     f"Trying to backward through {node.name} a second time "
                     "(use retain_graph=True)")
-            in_grads = node.vjp_fn(seed)
+            with _tracing.backward_of(node.scopes):
+                in_grads = node.vjp_fn(seed)
         for t, g in zip(node.inputs, in_grads):
             _add_cot(t, g)
         if not retain_graph and not create_graph:
@@ -288,7 +305,7 @@ def run_backward(tensors, grad_tensors=None, retain_graph=False,
                 # in-place accumulate (reference eager accumulation node):
                 # the grad object's identity stays stable across steps,
                 # which compiled segments rely on for capture-by-identity
-                t.grad._data = t.grad._data + g_t._data_
+                t.grad._data = _accumulate(t.grad._data, g_t._data_)
         if t._grad_node is not None:
             stack.extend(t._grad_node.inputs)
     return None

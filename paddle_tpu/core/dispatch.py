@@ -15,6 +15,7 @@ import jax
 from . import state as _state
 from .tensor import Tensor
 from .autograd import GradNode
+from ..observability.tracing import scope as _scope
 
 
 _DECOMP = None
@@ -46,13 +47,16 @@ def _amp_cast(name, arrays):
     else:
         return arrays
     out = []
-    for a in arrays:
-        if hasattr(a, "dtype") and a.dtype in (jax.numpy.float32,
-                                               jax.numpy.float16,
-                                               jax.numpy.bfloat16):
-            out.append(a.astype(target))
-        else:
-            out.append(a)
+    # a scope of its own under the layer's: a cast XLA does not fuse
+    # into its consumer is told from the layer's products by name
+    with _scope("amp_cast"):
+        for a in arrays:
+            if hasattr(a, "dtype") and a.dtype in (jax.numpy.float32,
+                                                   jax.numpy.float16,
+                                                   jax.numpy.bfloat16):
+                out.append(a.astype(target))
+            else:
+                out.append(a)
     return out
 
 

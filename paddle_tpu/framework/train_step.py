@@ -75,7 +75,8 @@ import jax.numpy as jnp
 
 from ..core import state as _state
 from ..core.tensor import Tensor
-from ..observability.tracing import span as _span
+from ..observability import scopes as _scopes
+from ..observability.tracing import scope, span as _span
 from ..utils.flags import flag as _flag
 from .capture import (USER_TRACE_ERRORS, BindTracer, Installed,
                       TraceEscape, describe_escape, run_discovery)
@@ -207,6 +208,7 @@ class CompiledTrainStep:
         self._mut_caps = []           # forward-mutated captures (buffers)
         self._jit_full = None
         self._jit_micro = None
+        self._programs = {}           # (update, batch signature) -> Compiled
         self._donating = None
         self._scaler_vec = None       # device [scale, good, bad] fp32
         self.check_static_eligibility()
@@ -569,12 +571,17 @@ class CompiledTrainStep:
                 y_t = Tensor(y) if y is not None else None
                 loss_t = self._forward(x_t, y_t)
                 bwd_t = loss_t
+                # the step's own lines carry scopes of their own kind
+                # (docs/OBSERVABILITY.md, "Names on the device"): no
+                # scope on a device operation means nobody named it
                 if svec is not None:
                     # scale is device state: multiply by the traced value
-                    bwd_t = bwd_t * Tensor(
-                        svec[0].astype(loss_t._data_.dtype))
+                    with scope("loss_scale"):
+                        bwd_t = bwd_t * Tensor(
+                            svec[0].astype(loss_t._data_.dtype))
                 if self._accum > 1:
-                    bwd_t = bwd_t * (1.0 / self._accum)
+                    with scope("grad_accum"):
+                        bwd_t = bwd_t * (1.0 / self._accum)
                 bwd_t.backward()
                 loss = loss_t._data_
                 grads = [p.grad._data_ for p in self._params]
@@ -614,8 +621,9 @@ class CompiledTrainStep:
         opt = self._opt
         scaler_on = svec is not None
         if scaler_on:
-            inv = 1.0 / svec[0]
-            grads = [g * inv.astype(g.dtype) for g in grads]
+            with scope("loss_scale"):
+                inv = 1.0 / svec[0]
+                grads = [g * inv.astype(g.dtype) for g in grads]
         if self._dp > 1 and self._shard_map:
             # the in-program analogue of _sync_grads' per-tensor
             # all_reduce + divide: one psum/pmean per gradient that XLA
@@ -623,15 +631,23 @@ class CompiledTrainStep:
             # hybrid lane needs no explicit pmean: differentiating the
             # global-batch loss already yields globally-reduced
             # gradients — XLA inserts and overlaps the dp all-reduce.)
-            grads = [jax.lax.pmean(g, "dp") for g in grads]
+            with scope("grad_sync"):
+                grads = [jax.lax.pmean(g, "dp") for g in grads]
+
+        def _found_inf():
+            with scope("found_inf"):
+                flags = [~jnp.isfinite(jnp.sum(g)) for g in grads]
+                found = jnp.any(jnp.stack(flags))
+                if self._dp > 1 and self._shard_map:
+                    # global decision — a scalar psum, not a host
+                    # round-trip
+                    found = jax.lax.pmax(found.astype(jnp.int32),
+                                         "dp").astype(jnp.bool_)
+            return found
+
         found = None
         if scaler_on:
-            flags = [~jnp.isfinite(jnp.sum(g)) for g in grads]
-            found = jnp.any(jnp.stack(flags))
-            if self._dp > 1 and self._shard_map:
-                # global decision — a scalar psum, not a host round-trip
-                found = jax.lax.pmax(found.astype(jnp.int32),
-                                     "dp").astype(jnp.bool_)
+            found = _found_inf()
             # eager parity: the check is armed only while scaling is
             # active (GradScaler.unscale_ skips it at scale == 1.0) —
             # unless the scaler always checks (the sentinel's unit-scale
@@ -645,11 +661,7 @@ class CompiledTrainStep:
                 # scaler-less runs: the sentinel arms the same
                 # found-inf check the AMP machinery uses, so non-finite
                 # steps are skipped in-program here too
-                flags = [~jnp.isfinite(jnp.sum(g)) for g in grads]
-                found = jnp.any(jnp.stack(flags))
-                if self._dp > 1 and self._shard_map:
-                    found = jax.lax.pmax(found.astype(jnp.int32),
-                                         "dp").astype(jnp.bool_)
+                found = _found_inf()
             # device-resident health vector [grad_norm_sq, skipped]:
             # the sentinel fetches a window of these in one batched
             # transfer at its check cadence — zero per-step host syncs.
@@ -664,18 +676,20 @@ class CompiledTrainStep:
                 return jnp.sum(jnp.stack(sq)) if sq \
                     else jnp.asarray(0.0, jnp.float32)
 
-            gnorm_sq = jax.lax.cond(
-                hmark > 0.5, _gnorm_sq,
-                lambda: jnp.asarray(-1.0, jnp.float32))
-            health = jnp.stack([gnorm_sq, found.astype(jnp.float32)])
+            with scope("grad_norm"):
+                gnorm_sq = jax.lax.cond(
+                    hmark > 0.5, _gnorm_sq,
+                    lambda: jnp.asarray(-1.0, jnp.float32))
+                health = jnp.stack([gnorm_sq, found.astype(jnp.float32)])
 
         if opt._grad_clip is not None:
-            pairs = opt._grad_clip(
-                [(p, Tensor(g)) for p, g in zip(self._params, grads)])
-            grads = [g._data_ for _, g in pairs]
+            with scope("grad_clip"):
+                pairs = opt._grad_clip(
+                    [(p, Tensor(g)) for p, g in zip(self._params, grads)])
+                grads = [g._data_ for _, g in pairs]
 
         new_step = step_arr + 1.0
-        with jax.named_scope("optimizer"):
+        with scope("optimizer"):
             new_params, new_states = type(opt)._fused_update(
                 opt, lr, new_step, list(param_arrs), grads, states,
                 lr_scales=self._lr_scales, wd_mask=self._wd_mask)
@@ -686,17 +700,20 @@ class CompiledTrainStep:
         skip = found
         new_svec = svec
         if skip is not None:
-            take = ~skip
-            new_params = [jnp.where(take, n, o)
-                          for n, o in zip(new_params, param_arrs)]
-            new_states = {
-                name: [None if n is None else jnp.where(take, n, o)
-                       for n, o in zip(vals, states[name])]
-                for name, vals in new_states.items()}
-            new_step = jnp.where(take, new_step, step_arr)
+            with scope("skip_select"):
+                take = ~skip
+                new_params = [jnp.where(take, n, o)
+                              for n, o in zip(new_params, param_arrs)]
+                new_states = {
+                    name: [None if n is None else jnp.where(take, n, o)
+                           for n, o in zip(vals, states[name])]
+                    for name, vals in new_states.items()}
+                new_step = jnp.where(take, new_step, step_arr)
         if scaler_on:
-            new_svec = self._scaler_update(svec, found)
-        zeroed = [jnp.zeros_like(g) for g in grads]
+            with scope("loss_scale"):
+                new_svec = self._scaler_update(svec, found)
+        with scope("grad_accum"):
+            zeroed = [jnp.zeros_like(g) for g in grads]
         return new_params, new_states, new_step, new_svec, zeroed, health
 
     def _scaler_update(self, svec, found):
@@ -836,6 +853,7 @@ class CompiledTrainStep:
         if self._donating is not None and self._donating != bool(
                 _flag("FLAGS_jit_donate_buffers", True)):
             self._jit_full = self._jit_micro = None   # flag flipped
+            self._programs.clear()
         jit = self._jit_full if update else self._jit_micro
         if jit is None:
             jit = self._build_jit(update, args)
@@ -848,7 +866,7 @@ class CompiledTrainStep:
             return self._run_eager(x, y, update)
 
         with _span("train.step.launch"):
-            out = jit(*args)
+            out = self._program(jit, update, args)(*args)
         with _span("train.step.adopt"):
             if update:
                 (loss, new_params, zeroed, new_states, new_step, new_svec,
@@ -879,6 +897,22 @@ class CompiledTrainStep:
             for t, arr in zip(self._mut_caps, mut_vals):
                 t._data_ = arr
         return Tensor(loss)
+
+    def _program(self, jit, update, args):
+        """The executable of ``jit`` for this batch signature, built the
+        first time the signature is met (trace, lower, compile or cache
+        load: what the jit's own first call would do) and called from
+        then on.  The step holds its executables so that each can hand
+        its HLO to ``observability.scopes``: device time by scope needs
+        no second compile."""
+        xa, ya, svec = args[0], args[1], args[7]
+        key = (update, xa.shape, xa.dtype,
+               None if ya is None else (ya.shape, ya.dtype), svec is None)
+        program = self._programs.get(key)
+        if program is None:
+            program = self._programs[key] = jit.lower(*args).compile()
+            _scopes.publish(program)
+        return program
 
     def _aliased(self, args, update):
         """Donation is unsound when one device buffer backs two donated

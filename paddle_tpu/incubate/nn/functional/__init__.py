@@ -17,6 +17,7 @@ import jax.numpy as jnp
 from ....core.dispatch import apply_op
 from ....core.tensor import Tensor
 from ....nn import functional as F
+from ....observability.tracing import scope
 
 
 def fused_rms_norm(x, norm_weight=None, norm_bias=None, epsilon=1e-6,
@@ -426,7 +427,7 @@ def _latent_block_attend(qa, pool, pt, off, w_kvb, *, psz, nope, width,
 
     def gather(phys):
         rows = pool[phys].reshape(b, blk, pool.shape[-1])
-        with jax.named_scope("mla_up_project"):
+        with scope("mla_up_project"):
             kv = jnp.einsum("bkc,cn->bkn", rows[..., :rank], w_kvb,
                             preferred_element_type=jnp.float32) \
                 .astype(cdt).reshape(b, blk, h, nope + d_v)
@@ -670,17 +671,17 @@ def paged_latent_attention(q, row, w_kvb, cache, *, nope_dim, scale):
                 qa, pool, pt, off, wa, psz=psz, nope=nope, width=width,
                 scale=scale), pool
         w3 = wa.reshape(rank, h, -1)
-        with jax.named_scope("mla_absorb"):
+        with scope("mla_absorb"):
             qt = jnp.einsum("bhd,chd->bhc", qa[:, 0, :, :nope],
                             w3[..., :nope],
                             preferred_element_type=jnp.float32)
         q_lat = jnp.pad(jnp.concatenate(
             [qt.astype(pool.dtype), qa[:, 0, :, nope:].astype(pool.dtype)],
             axis=-1), pad)
-        with jax.named_scope("mla_decode"):
+        with scope("mla_decode"):
             ot = _mla.mla_decode(q_lat, pool, pt.astype(jnp.int32), off,
                                  rank, scale)
-        with jax.named_scope("mla_absorb"):
+        with scope("mla_absorb"):
             out = jnp.einsum("bhc,chd->bhd", ot.astype(wa.dtype),
                              w3[..., nope:],
                              preferred_element_type=jnp.float32)

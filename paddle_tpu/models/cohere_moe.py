@@ -46,7 +46,7 @@ from dataclasses import dataclass
 
 import jax
 import jax.numpy as jnp
-from jax import named_scope
+from ..observability.tracing import scope
 
 from ..core.dispatch import apply_op
 from ..nn import Layer, Linear, Embedding, LayerNorm, LayerList
@@ -256,8 +256,8 @@ class CohereMoeBlock(Layer):
 
     def forward(self, x, cache=None):
         n = self.input_layernorm(x)
-        with named_scope("attn_window" if self.kind == "sliding_attention"
-                         else "attn_full"):
+        with scope("attn_window" if self.kind == "sliding_attention"
+                   else "attn_full"):
             a = self.self_attn(n, cache=cache)
         return x + a + self.mlp(n, cache=cache)
 
@@ -276,7 +276,7 @@ class CohereMoeModel(Layer):
                               epsilon=config.layer_norm_eps, bias_attr=False)
 
     def forward(self, input_ids, caches=None):
-        with named_scope("embed"):
+        with scope("embed"):
             x = self.embed_tokens(input_ids)
         for i, blk in enumerate(self.layers):
             x = blk(x, cache=None if caches is None else caches[i])
@@ -291,12 +291,12 @@ class CohereMoeForCausalLM(Layer):
 
     def forward(self, input_ids, labels=None, caches=None):
         hidden = self.model(input_ids, caches=caches)
-        with named_scope("head"):
+        with scope("head"):
             logits = F.linear(hidden, self.model.embed_tokens.weight.T)
             if self.config.logit_scale != 1.0:
                 logits = logits * self.config.logit_scale
         if labels is not None:
-            with named_scope("loss"):
+            with scope("loss"):
                 loss = F.cross_entropy(
                     MA.reshape(logits, [-1, self.config.vocab_size]),
                     MA.reshape(labels, [-1]))

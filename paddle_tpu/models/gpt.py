@@ -13,7 +13,7 @@ import math
 from dataclasses import dataclass, field
 
 import numpy as np
-from jax import named_scope
+from ..observability.tracing import scope
 
 from ..nn import Layer, Linear, Embedding, LayerNorm, Dropout, LayerList
 from ..nn import functional as F
@@ -136,9 +136,9 @@ class GPTBlock(Layer):
     def forward(self, x, cache=None):
         # scopes name a compiled program's operations by layer kind in
         # an xprof view (docs/OBSERVABILITY.md, "Names on the device")
-        with named_scope("attn"):
+        with scope("attn"):
             x = x + self.dropout(self.attn(self.ln_1(x), cache=cache))
-        with named_scope("mlp"):
+        with scope("mlp"):
             x = x + self.dropout(self.mlp(self.ln_2(x)))
         return x
 
@@ -160,7 +160,7 @@ class GPTModel(Layer):
 
     def forward(self, input_ids, position_ids=None, caches=None):
         b, s = input_ids.shape
-        with named_scope("embed"):
+        with scope("embed"):
             if position_ids is None:
                 position_ids = creation.arange(s, dtype="int32")
                 if caches is not None:
@@ -198,13 +198,13 @@ class GPTForCausalLM(Layer):
     def forward(self, input_ids, labels=None, position_ids=None,
                 caches=None):
         hidden = self.gpt(input_ids, position_ids, caches=caches)
-        with named_scope("head"):
+        with scope("head"):
             if self.lm_head is not None:
                 logits = self.lm_head(hidden)
             else:
                 logits = F.linear(hidden, self.gpt.wte.weight.T)
         if labels is not None:
-            with named_scope("loss"):
+            with scope("loss"):
                 loss = F.cross_entropy(
                     MA.reshape(logits, [-1, self.config.vocab_size]),
                     MA.reshape(labels, [-1]))

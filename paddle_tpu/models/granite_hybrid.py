@@ -34,7 +34,7 @@ from typing import NamedTuple
 import numpy as np
 import jax
 import jax.numpy as jnp
-from jax import named_scope
+from ..observability.tracing import scope
 
 from ..core.dispatch import apply_op
 from ..core.tensor import Tensor
@@ -364,12 +364,12 @@ class GraniteHybridBlock(Layer):
     def forward(self, x, cache=None):
         y = self.input_layernorm(x)
         if self.kind == "mamba":
-            with named_scope("mamba"):
+            with scope("mamba"):
                 x = x + self.mamba(y, cache=cache) * self.residual
         else:
-            with named_scope("attn"):
+            with scope("attn"):
                 x = x + self.self_attn(y, cache=cache) * self.residual
-        with named_scope("mlp"):
+        with scope("mlp"):
             x = x + self.shared_mlp(self.post_attention_layernorm(x)) \
                 * self.residual
         return x
@@ -388,7 +388,7 @@ class GraniteHybridModel(Layer):
         self.norm = RMSNorm(config.hidden_size, epsilon=config.rms_norm_eps)
 
     def forward(self, input_ids, caches=None):
-        with named_scope("embed"):
+        with scope("embed"):
             x = self.embed_tokens(input_ids) * \
                 self.config.embedding_multiplier
         for i, blk in enumerate(self.layers):
@@ -404,11 +404,11 @@ class GraniteHybridForCausalLM(Layer):
 
     def forward(self, input_ids, labels=None, caches=None):
         hidden = self.model(input_ids, caches=caches)
-        with named_scope("head"):
+        with scope("head"):
             logits = F.linear(hidden, self.model.embed_tokens.weight.T) \
                 * (1.0 / self.config.logits_scaling)
         if labels is not None:
-            with named_scope("loss"):
+            with scope("loss"):
                 loss = F.cross_entropy(
                     MA.reshape(logits, [-1, self.config.vocab_size]),
                     MA.reshape(labels, [-1]))

@@ -13,7 +13,7 @@ from __future__ import annotations
 import math
 from dataclasses import dataclass
 
-from jax import named_scope
+from ..observability.tracing import scope
 
 from ..nn import Layer, Linear, Embedding, RMSNorm, LayerList
 from ..nn import functional as F
@@ -163,9 +163,9 @@ class LlamaBlock(Layer):
     def forward(self, x, cache=None):
         # scopes name a compiled program's operations by layer kind in
         # an xprof view (docs/OBSERVABILITY.md, "Names on the device")
-        with named_scope("attn"):
+        with scope("attn"):
             x = x + self.self_attn(self.input_layernorm(x), cache=cache)
-        with named_scope("mlp"):
+        with scope("mlp"):
             x = x + self.mlp(self.post_attention_layernorm(x))
         return x
 
@@ -183,7 +183,7 @@ class LlamaModel(Layer):
         self.norm = RMSNorm(config.hidden_size, epsilon=config.rms_norm_eps)
 
     def forward(self, input_ids, caches=None):
-        with named_scope("embed"):
+        with scope("embed"):
             x = self.embed_tokens(input_ids)
         for i, blk in enumerate(self.layers):
             x = blk(x, cache=None if caches is None else caches[i])
@@ -203,14 +203,14 @@ class LlamaForCausalLM(Layer):
 
     def forward(self, input_ids, labels=None, caches=None):
         hidden = self.llama(input_ids, caches=caches)
-        with named_scope("head"):
+        with scope("head"):
             if self.lm_head is not None:
                 logits = self.lm_head(hidden)
             else:
                 logits = F.linear(hidden,
                                   self.llama.embed_tokens.weight.T)
         if labels is not None:
-            with named_scope("loss"):
+            with scope("loss"):
                 loss = F.cross_entropy(
                     MA.reshape(logits, [-1, self.config.vocab_size]),
                     MA.reshape(labels, [-1]))

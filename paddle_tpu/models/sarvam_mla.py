@@ -57,7 +57,7 @@ from dataclasses import dataclass, field
 
 import jax
 import jax.numpy as jnp
-from jax import named_scope
+from ..observability.tracing import scope
 
 from ..core.dispatch import apply_op
 from ..nn import Layer, Linear, Embedding, RMSNorm, LayerList
@@ -341,9 +341,9 @@ class SarvamMLABlock(Layer):
             else SarvamSparseMLP(config)
 
     def forward(self, x, cache=None):
-        with named_scope("attn_latent"):
+        with scope("attn_latent"):
             x = x + self.self_attn(self.input_layernorm(x), cache=cache)
-        with named_scope("mlp"):
+        with scope("mlp"):
             x = x + self.mlp(self.post_attention_layernorm(x), cache=cache)
         return x
 
@@ -362,7 +362,7 @@ class SarvamMLAModel(Layer):
         self.norm = RMSNorm(config.hidden_size, epsilon=config.rms_norm_eps)
 
     def forward(self, input_ids, caches=None):
-        with named_scope("embed"):
+        with scope("embed"):
             x = self.embed_tokens(input_ids)
         for i, blk in enumerate(self.layers):
             x = blk(x, cache=None if caches is None else caches[i])
@@ -381,10 +381,10 @@ class SarvamMLAForCausalLM(Layer):
 
     def forward(self, input_ids, labels=None, caches=None):
         hidden = self.model(input_ids, caches=caches)
-        with named_scope("head"):
+        with scope("head"):
             logits = self.lm_head(hidden)
         if labels is not None:
-            with named_scope("loss"):
+            with scope("loss"):
                 loss = F.cross_entropy(
                     MA.reshape(logits, [-1, self.config.vocab_size]),
                     MA.reshape(labels, [-1]))

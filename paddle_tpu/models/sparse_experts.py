@@ -20,7 +20,7 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-from jax import named_scope
+from ..observability.tracing import scope
 
 from ..core.dispatch import apply_op
 from ..nn import Layer
@@ -145,9 +145,9 @@ class SparseExpertMLP(Layer):
                 acc = acc * self.shared_scale
             return acc.astype(xa.dtype).reshape(b, s, h)
 
-        with named_scope("moe"):
+        with scope("moe"):
             y, counts = self.routed(x, valid)
-        with named_scope("moe_shared"):
+        with scope("moe_shared"):
             y = y + apply_op(
                 "shared_experts" if self.shared_scale is not None
                 else "shared_experts_sum", shared,

@@ -60,6 +60,7 @@ import threading
 import time
 from collections import deque
 
+import jax
 from jax.profiler import StepTraceAnnotation, TraceAnnotation
 
 from ..utils.flags import flag as _flag
@@ -340,6 +341,68 @@ class span:  # noqa: N801 - used as ``with span(...)``
             rec.end(status="ok" if exc_type is None
                     else exc_type.__name__, t1=t1)
         return False
+
+
+# ---------------- program scopes (names on the device) ----------------
+#: the marker the tape's backward adds to the scope it re-enters
+BACKWARD = "bwd"
+#: every name a ``scope`` was entered under in this process: what
+#: ``observability.scopes`` looks for in a compiled instruction's
+#: ``op_name`` (JAX's own path elements — ``while``, ``body``,
+#: ``jit(f)`` — are not scopes)
+scope_names: set = {BACKWARD}
+
+
+@contextlib.contextmanager
+def scope(name):
+    """``with scope("attn"):`` — a ``jax.named_scope`` the autograd tape
+    remembers.  Every operation traced inside carries ``name`` in its
+    ``op_name`` metadata; a ``GradNode`` made inside keeps
+    :func:`scope_path`, and ``run_backward`` re-enters that path plus
+    :data:`BACKWARD` round the node's VJP, so a compiled program's
+    backward is named by layer kind as its forward is
+    (docs/OBSERVABILITY.md, "Names on the device").  Metadata only: the
+    compiled bytes and the compile-cache key do not change."""
+    prev = getattr(_tls, "scopes", ())
+    _tls.scopes = prev + (name,)
+    scope_names.add(name)
+    try:
+        with jax.named_scope(name):
+            yield
+    finally:
+        _tls.scopes = prev
+
+
+def scope_path():
+    """The names of the scopes open on this thread, outermost first."""
+    return getattr(_tls, "scopes", ())
+
+
+class backward_of:  # noqa: N801 - used as ``with backward_of(...)``
+    """Re-enter ``path`` (a :func:`scope_path` kept from the forward),
+    less what of it is open already, and the :data:`BACKWARD` marker
+    under it.  One ``jax.named_scope`` of the joined names: the tape
+    enters this once a node, eagerly too."""
+
+    __slots__ = ("_path", "_prev", "_scope")
+
+    def __init__(self, path):
+        self._path = path
+
+    def __enter__(self):
+        prev = self._prev = getattr(_tls, "scopes", ())
+        path, shared = self._path, 0
+        while shared < min(len(prev), len(path)) \
+                and prev[shared] == path[shared]:
+            shared += 1
+        rest = path[shared:] + (BACKWARD,)
+        _tls.scopes = prev + rest
+        self._scope = jax.named_scope("/".join(rest))
+        self._scope.__enter__()
+
+    def __exit__(self, *exc):
+        _tls.scopes = self._prev
+        return self._scope.__exit__(*exc)
 
 
 # ---------------- thread-bound context (rpc propagation) ----------------

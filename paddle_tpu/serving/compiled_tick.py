@@ -68,8 +68,8 @@ import jax.numpy as jnp
 
 from . import stats
 from ..core import state as _state
-from ..observability import tracing
-from ..observability.tracing import span
+from ..observability import scopes, tracing
+from ..observability.tracing import scope, span
 from ..core.tensor import Tensor
 from ..framework.capture import (TRACE_LOCK, USER_TRACE_ERRORS, BindTracer,
                                  Installed, TraceEscape, describe_escape,
@@ -251,6 +251,7 @@ class CompiledServingTick:
         self._caps = []                # captured model tensors (params)
         self._jits = {}                # (mode, donating) -> jitted fn
         self._sigs = {}                # (mode, donating) -> arg avals
+        self._ticks = {}               # (mode, donating) -> compiled tick
         self._prefill = {}             # (rows, donating) -> compiled member
         self._dev = None               # device state dict
         self._mut_seen = -1            # engine mutation counter synced
@@ -406,7 +407,8 @@ class CompiledServingTick:
         # step's zero-filled tok_in; their scratch writes are causally
         # masked (and prefill re-writes its positions next chunk) either
         # way
-        tok_in = jnp.where(alive, last, jnp.zeros_like(last))[:, None]
+        with scope("tick_state"):
+            tok_in = jnp.where(alive, last, jnp.zeros_like(last))[:, None]
         # a recurrent state has no mask to hide a write behind: only the
         # rows that decode may move theirs (a row mid-prefill keeps what
         # its chunks have built); an expert layer counts those rows
@@ -416,27 +418,31 @@ class CompiledServingTick:
         logits = logits[:, -1, :]
 
         ns = logits.shape[0]
-        if mode == "greedy":
-            # the batched-argmax fast path, bitwise the uncompiled
-            # lane's S.argmax over raw last-position logits
-            tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        else:
-            tok = choose_tokens(logits, temp, topk, topp, pen, seen,
-                                keys, counts)
-        tok = jnp.where(alive, tok, last)
-        rows = jnp.arange(ns)
-        new_seen = seen.at[rows, tok].set(seen[rows, tok] | alive)
-        new_counts = counts + alive.astype(counts.dtype)
-        eos_hit = alive & (eos >= 0) & (tok == eos)
-        len_hit = alive & (new_counts >= limits)
-        fin = jnp.where(eos_hit, 1,
-                        jnp.where(len_hit, 2, 0)).astype(jnp.int32)
-        new_alive = alive & (fin == 0)
-        new_last = jnp.where(alive, tok, last)
-        new_off = off + alive.astype(off.dtype)
-        # the tick's own tokens leave with ``fin`` (-1: the row made
-        # none): the host appends them to the requests tick by tick
-        made = jnp.where(alive, tok, -1).astype(jnp.int32)
+        # the tick's own lines carry scopes of their own, as the model's
+        # layers do: no scope on a device operation means nobody named it
+        with scope("sample"):
+            if mode == "greedy":
+                # the batched-argmax fast path, bitwise the uncompiled
+                # lane's S.argmax over raw last-position logits
+                tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            else:
+                tok = choose_tokens(logits, temp, topk, topp, pen, seen,
+                                    keys, counts)
+        with scope("tick_state"):
+            tok = jnp.where(alive, tok, last)
+            rows = jnp.arange(ns)
+            new_seen = seen.at[rows, tok].set(seen[rows, tok] | alive)
+            new_counts = counts + alive.astype(counts.dtype)
+            eos_hit = alive & (eos >= 0) & (tok == eos)
+            len_hit = alive & (new_counts >= limits)
+            fin = jnp.where(eos_hit, 1,
+                            jnp.where(len_hit, 2, 0)).astype(jnp.int32)
+            new_alive = alive & (fin == 0)
+            new_last = jnp.where(alive, tok, last)
+            new_off = off + alive.astype(off.dtype)
+            # the tick's own tokens leave with ``fin`` (-1: the row made
+            # none): the host appends them to the requests tick by tick
+            made = jnp.where(alive, tok, -1).astype(jnp.int32)
         return (new_pools, new_off, new_last, new_counts,
                 new_alive, new_seen, fin, made, moe)
 
@@ -509,9 +515,10 @@ class CompiledServingTick:
             # ever reads of a chunk — padded to [num_slots, V], the eager
             # lane's shape, so that the host's row slices are the same
             # few programs whatever bucket ran
-            picked = jnp.take_along_axis(
-                logits, last[:, None, None], axis=1)[:, 0]
-            picked = jnp.pad(picked, ((0, num_slots - rows), (0, 0)))
+            with scope("pick_last"):
+                picked = jnp.take_along_axis(
+                    logits, last[:, None, None], axis=1)[:, 0]
+                picked = jnp.pad(picked, ((0, num_slots - rows), (0, 0)))
             return new_pools, picked, moe
 
         # never ``serving_tick``: readers of the device trace tell ticks
@@ -559,6 +566,7 @@ class CompiledServingTick:
                      for rows, low in lowered.items()}
             for rows, fut in built.items():
                 self._prefill[rows, donating] = fut.result()
+                scopes.publish(fut.result())
 
     def prefill_member(self, n):
         """(rows, program) of the compiled member that hosts a chunk call
@@ -713,7 +721,8 @@ class CompiledServingTick:
             self.flush_to_host()
             if not eng._active:
                 return True             # what it delivered finished them all
-            self._rebuild()
+            with span("serving.tick.rebuild"):
+                self._rebuild()
         return self._run()
 
     def drain(self):
@@ -778,7 +787,14 @@ class CompiledServingTick:
         if key not in self._sigs:
             self._sigs[key] = jax.tree.map(
                 lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), args)
-        return self._jits[key], args
+        if key not in self._ticks:
+            # built as the prefill members are, the first time the mode
+            # is met (what the jit's own first call would do): the tick
+            # holds its executable, so its HLO reaches the scope tables
+            # without a second compile
+            self._ticks[key] = self._jits[key].lower(*args).compile()
+            scopes.publish(self._ticks[key])
+        return self._ticks[key], args
 
     def _donated_and_captured(self):
         """What every member of the family takes beside its own rows:
@@ -843,10 +859,10 @@ class CompiledServingTick:
         # here would bake a leaked tracer into this engine's call
         with TRACE_LOCK:
             with span("serving.tick.build"):
-                jit, args = self._build_args(live)
+                program, args = self._build_args(live)
             with span("serving.tick.launch"):
                 (new_pools, new_off, new_last, new_counts, new_alive,
-                 new_seen, fin, made, moe) = jit(*args)
+                 new_seen, fin, made, moe) = program(*args)
                 rows = list(live)
                 # a row that ends by eos in the tick in flight keeps its
                 # device offset while this mirror moves on: a dirty flush

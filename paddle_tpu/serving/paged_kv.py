@@ -90,6 +90,7 @@ import jax.numpy as jnp
 
 from . import stats
 from ..core.tensor import Tensor
+from ..observability import scopes
 from ..observability.tracing import span
 from ..utils.flags import flag as _flag
 
@@ -330,8 +331,12 @@ class PagedKVCache:
                     a, jnp.zeros((1,) + a.shape[1:], a.dtype),
                     (row,) + (0,) * (a.ndim - 1)) for a in arrays)
 
+            # held as an executable, like the tick and its members:
+            # its HLO reaches the scope tables without a second compile
             self._reset_jits[donating] = jax.jit(
-                state_reset, donate_argnums=(0,) if donating else ())
+                state_reset, donate_argnums=(0,) if donating else ()
+            ).lower(self._state_arrays(), np.int32(slot)).compile()
+            scopes.publish(self._reset_jits[donating])
         with span("serving.state.reset"):
             it = iter(self._reset_jits[donating](self._state_arrays(),
                                                  np.int32(slot)))

@@ -259,7 +259,10 @@ def test_serving_phases_reach_the_profiler_trace(serve_run):
             "serving.prefill.model", "serving.prefill.absorb",
             "serving.tick", "serving.tick.build", "serving.tick.launch",
             "serving.tick.sync", "serving.tick.deliver",
-            "serving.publish"} <= names
+            "serving.tick.rebuild", "serving.publish"} <= names
+    # the re-upload of the scheduler state happens at mutations only
+    assert 0 < reg["serving.tick.rebuild_ms.count"] \
+        < reg["serving.tick.build_ms.count"]
     assert reg["serving.tick.compiled_hits"] > 0
     assert reg["serving.tick.fallbacks"] == 0
 
