@@ -339,16 +339,21 @@ class Adam(Optimizer):
         bc1 = 1.0 - b1 ** step_t
         bc2 = 1.0 - b2 ** step_t
         # Pallas fused-update lane (pallas/fused.py): TPU/interpret only,
-        # per-parameter shape-gated, bitwise-equal to the jnp sequence
-        # below (FLAGS_pallas_fused_optimizer; docs/TRAIN_STEP.md)
+        # per-parameter shape-gated, in place, bitwise-equal to the jnp
+        # sequence below (FLAGS_pallas_fused_optimizer; docs/TRAIN_STEP.md);
+        # a leaf traced counts under the lane it took
         from ..pallas import fused as _pf
+        from ..utils import monitor
         pallas_on = _pf.optimizer_kernels_enabled()
         new_p, new_m1, new_m2, new_mw = [], [], [], []
         for p, g, m1, m2, mw, s, use_wd in zip(
                 params, grads, states["moment1"], states["moment2"],
                 states["master"], lr_scales, wd_mask):
             w = mw if mw is not None else p.astype(jnp.float32)
-            if pallas_on and _pf.adam_update_supported(w):
+            in_place = pallas_on and _pf.adam_update_supported(w)
+            monitor.incr("pallas.adamw_update.in_place" if in_place
+                         else "pallas.adamw_update.xla_lane")
+            if in_place:
                 w, m1, m2 = _pf.adam_update_pallas(
                     w, g, m1, m2, lr * s, bc1, bc2, b1=b1, b2=b2, eps=eps,
                     wd=(wd if use_wd else 0.0), decoupled=self._decoupled)

@@ -12,12 +12,14 @@ re-reduces x², and accumulates the weight gradient across the sequential
 TPU grid in VMEM scratch (one kernel, no second pass).  Rope (neox style) has as its backward
 the forward kernel with negated sin (the rotation adjoint), so one kernel
 serves both directions.  The Adam update kernel streams (w, g, m1, m2)
-through VMEM row blocks and performs the EXACT elementwise fp32 sequence
-of ``optimizer.Adam._fused_update`` — same ops, same order — so the
-Pallas lane is bitwise-equal to the jnp lane (verified in interpreter
-mode by tests/test_train_step.py); it is gated by
+through VMEM blocks of each leaf's own layout, writes w, m1 and m2 in
+place, and performs the EXACT elementwise fp32 sequence of
+``optimizer.Adam._fused_update`` — same ops, same order — so the Pallas
+lane is bitwise-equal to the jnp lane in interpreter mode
+(tests/test_train_step.py; on the chip Mosaic and XLA round it an ulp
+apart, PERF.md §6 PR 37); it is gated by
 ``FLAGS_pallas_fused_optimizer`` and used only on TPU (or under
-interpret mode), only for shapes the row-blocking supports.
+interpret mode), for leaves of rank 2 and up.
 
 All kernels run in interpreter mode on CPU for CI (see
 flash_attention._interpret).
@@ -25,6 +27,7 @@ flash_attention._interpret).
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -273,16 +276,49 @@ def _adam_kernel(scal_ref, w_ref, g_ref, m1_ref, m2_ref,
     m2_out[:] = m2
 
 
-_ADAM_LANES = 128
+# Elements of one operand's block: the [1024, 128] blocks the kernel
+# streamed before PR 37, so the double-buffered working set (four
+# operands in, three out) stays where it was, ~7 MB of VMEM.
+_ADAM_BLOCK_ELEMS = 1024 * 128
+_LANES = 128
+
+
+def adam_update_blocks(shape, itemsizes=(4,)):
+    """The kernel's view of a leaf and its block, by one rule on the
+    leaf's shape and its operands' item sizes: ``(view, block)``, both
+    ``(rows, cols)`` for a matrix and ``(leading, rows, cols)`` above.
+
+    The view merges only the dimensions before the last two, which
+    leaves the tiled layout as it is: a bitcast, never a copy.  A block
+    spans the whole last dimension where ``_ADAM_BLOCK_ELEMS`` holds as
+    many rows as the narrowest operand's sublane tile (16 for a
+    bfloat16 gradient), else a lane-aligned share of it; rows come in
+    whole tiles, or all of them; where all rows fit, a block takes as
+    many leading indices as fit.  The grid is ``cdiv`` of view by block:
+    the update is elementwise, so a partial last block is harmless.
+    None where the kernel does not host the leaf (a scalar, an empty or
+    one-dimensional leaf: those take the jnp lane)."""
+    if len(shape) < 2 or math.prod(shape) == 0:
+        return None
+    *lead, r, c = (int(d) for d in shape)
+    view = (math.prod(lead), r, c)
+    sub = 32 // min(itemsizes)
+    cols = c if sub * c <= _ADAM_BLOCK_ELEMS else \
+        _ADAM_BLOCK_ELEMS // sub // _LANES * _LANES
+    lanes = -(-cols // _LANES) * _LANES
+    rows = _ADAM_BLOCK_ELEMS // lanes // sub * sub
+    if r > rows:
+        block = (1, rows, cols)
+    else:
+        held = -(-r // sub) * sub * lanes
+        block = (min(view[0], _ADAM_BLOCK_ELEMS // held), r, cols)
+    k = 3 if lead else 2
+    return view[-k:], block[-k:]
 
 
 def adam_update_supported(w):
-    """Row-blocking constraint: the fp32 working value must reshape to
-    [rows, 128] with rows a multiple of 8 (Mosaic sublane granularity)."""
-    n = 1
-    for d in w.shape:
-        n *= int(d)
-    return n % (_ADAM_LANES * 8) == 0
+    """Whether the fused kernel hosts this leaf (:func:`adam_update_blocks`)."""
+    return adam_update_blocks(w.shape) is not None
 
 
 def optimizer_kernels_enabled():
@@ -293,37 +329,36 @@ def optimizer_kernels_enabled():
 
 def adam_update_pallas(w, g, m1, m2, lr_s, bc1, bc2, *, b1, b2, eps, wd,
                        decoupled):
-    """Fused Adam/AdamW step for one parameter.
+    """Fused Adam/AdamW step for one parameter, in place.
 
-    w/m1/m2: fp32 working value and moments (any shape whose element
-    count satisfies :func:`adam_update_supported`); g: gradient (cast to
-    fp32 inside the kernel); lr_s/bc1/bc2: runtime scalars (traced).
-    Returns (new_w, new_m1, new_m2) with w's shape/dtype."""
+    w/m1/m2: fp32 working value and moments (a shape
+    :func:`adam_update_supported` admits); g: gradient (cast to fp32
+    inside the kernel); lr_s/bc1/bc2: runtime scalars (traced).  Each
+    leaf is read and written in the layout it lives in, and the results
+    alias w, m1 and m2: where the caller donates them XLA copies
+    nothing, else it copies each aliased input once.  Returns
+    (new_w, new_m1, new_m2) with w's shape/dtype."""
     from jax.experimental import pallas as pl
 
     shape = w.shape
-    n = w.size
-    rows = n // _ADAM_LANES
-    w2 = w.reshape(rows, _ADAM_LANES)
-    g2 = g.reshape(rows, _ADAM_LANES)
-    m1_2 = m1.reshape(rows, _ADAM_LANES)
-    m2_2 = m2.reshape(rows, _ADAM_LANES)
-    block = _pick_block_rows(rows, _ADAM_LANES)
-    grid = (rows // block,)
+    view, block = adam_update_blocks(
+        shape, (w.dtype.itemsize, g.dtype.itemsize))
+    grid = tuple(-(-v // b) for v, b in zip(view, block))
     scal = jnp.stack([jnp.asarray(lr_s, jnp.float32),
                       jnp.asarray(bc1, jnp.float32),
                       jnp.asarray(bc2, jnp.float32)]).reshape(1, 3)
-    row_spec = pl.BlockSpec((block, _ADAM_LANES), lambda i: (i, 0))
+    spec = pl.BlockSpec(block, lambda *ij: ij)
     out = pl.pallas_call(
         functools.partial(_adam_kernel, b1=b1, b2=b2, eps=eps, wd=wd,
                           decoupled=decoupled),
         grid=grid,
-        in_specs=[pl.BlockSpec((1, 3), lambda i: (0, 0)),
-                  row_spec, row_spec, row_spec, row_spec],
-        out_specs=[row_spec, row_spec, row_spec],
-        out_shape=[jax.ShapeDtypeStruct((rows, _ADAM_LANES), jnp.float32)] * 3,
+        in_specs=[pl.BlockSpec((1, 3), lambda *ij: (0, 0)),
+                  spec, spec, spec, spec],
+        out_specs=[spec, spec, spec],
+        out_shape=[jax.ShapeDtypeStruct(view, jnp.float32)] * 3,
+        input_output_aliases={1: 0, 3: 1, 4: 2},
         interpret=_interpret(),
         name="adamw_update",
-    )(scal, w2, g2, m1_2, m2_2)
-    return (out[0].reshape(shape), out[1].reshape(shape),
-            out[2].reshape(shape))
+    )(scal, w.reshape(view), g.reshape(view), m1.reshape(view),
+      m2.reshape(view))
+    return tuple(o.reshape(shape) for o in out)

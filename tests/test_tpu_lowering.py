@@ -10,6 +10,7 @@ rule in the code, which is asserted too.  The interpreter
 (``PADDLE_TPU_PALLAS_INTERPRET``) is off throughout.
 """
 import math
+import re
 
 import jax
 import jax.numpy as jnp
@@ -51,13 +52,14 @@ def _as_on_the_chip(monkeypatch):
     monkeypatch.setattr(fa, "_on_tpu", lambda: True)
 
 
-def _compile(fn, shapes, sharding, compiled=False):
-    """Lower + compile ``fn`` for the TPU; returns the StableHLO text,
-    or with ``compiled`` the compiled program's."""
+def _compile(fn, shapes, sharding, compiled=False, donate=()):
+    """Lower + compile ``fn`` for the TPU, its arguments ``donate``
+    donated; returns the StableHLO text, or with ``compiled`` the
+    compiled program's."""
     args = [None if s is None else
             jax.ShapeDtypeStruct(s[0], s[1], sharding=sharding)
             for s in shapes]
-    lowered = jax.jit(fn).lower(*args)
+    lowered = jax.jit(fn, donate_argnums=donate).lower(*args)
     program = lowered.compile()
     return program.as_text() if compiled else lowered.as_text()
 
@@ -219,21 +221,46 @@ def test_rope_neox_compiles_and_interleaved_is_deselected(v5e):
     assert _n_kernels(text) == 2
 
 
-@pytest.mark.parametrize("shape", [(50304, 768), (768, 3072),
-                                   (2048, 8192)])
+def _entry_moves(text, elements):
+    """The instructions of a compiled program's entry computation that
+    copy, reshape or transpose an array of ``elements`` elements."""
+    body = text[text.index("\nENTRY "):]
+    body = body[:body.index("\n}")]
+    found = []
+    for line in body.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%\S+ = (.+?) ([\w-]+)\(", line)
+        if m and m.group(2) in ("copy", "copy-start", "copy-done",
+                                "reshape", "transpose") and any(
+                math.prod(int(d) for d in dims.split(",") if d) == elements
+                for dims in re.findall(r"\w+\[([\d,]*)\]", m.group(1))):
+            found.append(line.strip()[:160])
+    return found
+
+
+# the leaves of gpt3-1.3b-1chip (embedding, a layer's four matrices, its
+# norms' and biases' vectors), a rank-3 leaf, an unpadded vocabulary's
+# rows (no multiple of 8), GPT-2 124M's embedding and MLP
+_ADAM_LEAVES = [(50304, 2048), (2048, 2048), (2048, 6144), (2048, 8192),
+                (8192, 2048), (2048,), (6144,), (8192,), (16, 2048, 128),
+                (50257, 2048), (50304, 768), (768, 3072)]
+
+
+@pytest.mark.parametrize("shape", _ADAM_LEAVES, ids=str)
 def test_fused_adam_compiles(v5e, shape):
+    """The optimizer's update of one leaf, its parameter and moments
+    donated as the compiled step donates them: a leaf of rank 2 and up
+    is one kernel that reads and writes the leaf where it lies, and the
+    entry copies, reshapes or transposes nothing of the leaf's size; a
+    1-D leaf takes the jnp lane, which copies nothing either."""
     assert fused.optimizer_kernels_enabled()
-    assert fused.adam_update_supported(jax.ShapeDtypeStruct(shape, F32))
-
-    def f(w, g, m1, m2, lr, bc1, bc2):
-        return fused.adam_update_pallas(
-            w, g, m1, m2, lr, bc1, bc2, b1=0.9, b2=0.999, eps=1e-8,
-            wd=0.01, decoupled=True)
-
-    text = _compile(f, [(shape, F32), (shape, BF16), (shape, F32),
-                        (shape, F32), ((), F32), ((), F32), ((), F32)],
-                    SingleDeviceSharding(v5e[0]))
-    assert _n_kernels(text) == 1
+    assert fused.adam_update_supported(
+        jax.ShapeDtypeStruct(shape, F32)) == (len(shape) >= 2)
+    text = _compile(_adamw_update(), [(shape, F32), (shape, BF16),
+                                      (shape, F32), (shape, F32)],
+                    SingleDeviceSharding(v5e[0]), compiled=True,
+                    donate=(0, 2, 3))
+    assert _n_kernels(text) == (1 if len(shape) >= 2 else 0)
+    assert _entry_moves(text, math.prod(shape)) == []
 
 
 # --------------------------------------------------------- paged decode
@@ -614,11 +641,11 @@ def _adamw_update():
         1e-4, parameters=[paddle.Parameter(np.zeros((8, 8), np.float32))])
 
     def f(p, g, m1, m2):
-        new_p, _ = opt._fused_update(
+        new_p, st = opt._fused_update(
             jnp.float32(1e-4), jnp.float32(1.0), [p], [g],
             {"moment1": [m1], "moment2": [m2], "master": [None]},
             lr_scales=(1.0,), wd_mask=(True,))
-        return new_p
+        return new_p, st["moment1"], st["moment2"]
     return f
 
 

@@ -12,6 +12,7 @@ semantic drift (wrong scale, missing bias correction, reordered
 update) would diverge far beyond ulp and fail these tests loudly.
 """
 import json
+import math
 import os
 import warnings
 
@@ -431,7 +432,9 @@ def test_pallas_adam_kernel_gating_and_closeness(monkeypatch):
     m1 = jnp.asarray(rng.standard_normal(shape) * 0.1, jnp.float32)
     m2 = jnp.asarray(rng.random(shape) * 0.01, jnp.float32)
     assert fused.adam_update_supported(w)
-    assert not fused.adam_update_supported(jnp.zeros((3, 5)))
+    assert fused.adam_update_supported(jnp.zeros((3, 5)))
+    assert not fused.adam_update_supported(jnp.zeros((640,)))
+    assert not fused.adam_update_supported(jnp.zeros(()))
 
     for wd, decoupled in ((0.0, False), (0.01, False), (0.01, True)):
         b1, b2, eps, lr = 0.9, 0.999, 1e-8, np.float32(0.003)
@@ -453,37 +456,107 @@ def test_pallas_adam_kernel_gating_and_closeness(monkeypatch):
                                        rtol=1e-6, atol=1e-8)
 
 
-@pytest.mark.parametrize("opt_kw", [
+@pytest.mark.parametrize("itemsizes", [(4, 4), (4, 2), (4, 1)])
+def test_adam_blocks_are_whole_tiles_within_the_budget(itemsizes):
+    """The block rule over every leaf it hosts: a block dimension is the
+    view's whole dimension or whole tiles of the narrowest operand (its
+    sublane rows, 128 lanes), and a block's padded tiles never exceed
+    ``_ADAM_BLOCK_ELEMS``."""
+    from paddle_tpu.pallas import fused
+
+    sub = 32 // min(itemsizes)
+    for lead in ((), (3,)):
+        for r in (1, 7, 8, 13, 16, 100, 1000, 5000):
+            for c in (1, 100, 128, 129, 2048, 8192, 8193, 16385, 100000):
+                view, block = fused.adam_update_blocks(lead + (r, c),
+                                                       itemsizes)
+                assert len(view) == len(block) == 2 + len(lead)
+                assert all(1 <= b <= v for b, v in zip(block, view))
+                assert block[-2] in (view[-2], block[-2] // sub * sub)
+                assert block[-1] in (view[-1], block[-1] // 128 * 128)
+                tiles = (math.prod(block[:-2]) * -(-block[-2] // sub) * sub
+                         * -(-block[-1] // 128) * 128)
+                assert tiles <= fused._ADAM_BLOCK_ELEMS, (lead, r, c, block)
+    assert fused.adam_update_blocks((2048,), itemsizes) is None
+
+
+_ADAM_OPTS = [
     dict(cls="AdamW", weight_decay=0.01),
     dict(cls="Adam", weight_decay=0.01),     # L2-coupled lane
     dict(cls="Adam", weight_decay=None),
-])
-def test_pallas_lane_through_optimizer_step_matches_flag_off(
-        monkeypatch, opt_kw):
-    """optimizer.step routes [rows,128]-tileable params through the
-    kernel when enabled; the full trajectory must stay BITWISE equal to
-    the flag-off jnp lane — the "exact" contract the flag promises."""
-    def run(enabled):
-        paddle.set_flags({"FLAGS_pallas_fused_optimizer": enabled})
-        paddle.seed(0)
-        w = paddle.Parameter(
-            np.random.default_rng(1).standard_normal(
-                (8, 128)).astype("float32"))
-        cls = getattr(paddle.optimizer, opt_kw["cls"])
-        kw = ({"weight_decay": opt_kw["weight_decay"]}
-              if opt_kw["weight_decay"] is not None else {})
-        opt = cls(0.01, parameters=[w], **kw)
-        for i in range(4):
-            w.grad = paddle.to_tensor(
-                np.full((8, 128), 0.1 * (i + 1), np.float32))
-            opt.step()
-            opt.clear_grad()
-        return w.numpy()
+]
+_ADAM_LANES = ("pallas.adamw_update.in_place",
+               "pallas.adamw_update.xla_lane")
 
+
+def _adam_trajectory(opt_kw, shape, enabled):
+    """Four ``optimizer.step`` calls on one leaf of ``shape``: the final
+    parameter and how many leaves each lane counted meanwhile."""
+    from paddle_tpu.utils import monitor
+
+    paddle.set_flags({"FLAGS_pallas_fused_optimizer": enabled})
+    paddle.seed(0)
+    w = paddle.Parameter(
+        np.random.default_rng(1).standard_normal(shape).astype("float32"))
+    cls = getattr(paddle.optimizer, opt_kw["cls"])
+    kw = ({"weight_decay": opt_kw["weight_decay"]}
+          if opt_kw["weight_decay"] is not None else {})
+    opt = cls(0.01, parameters=[w], **kw)
+    before = [monitor.all_stats().get(k, 0) for k in _ADAM_LANES]
+    for i in range(4):
+        w.grad = paddle.to_tensor(np.full(shape, 0.1 * (i + 1), np.float32))
+        opt.step()
+        opt.clear_grad()
+    counts = [monitor.all_stats().get(k, 0) - b
+              for k, b in zip(_ADAM_LANES, before)]
+    return w.numpy(), counts
+
+
+# leaves of one block (pallas.fused.adam_update_blocks): a whole tile,
+# rows that are no multiple of a tile, two rank-3 leaves, and a 1-D
+# leaf, which the rule gives the jnp lane
+@pytest.mark.parametrize("shape", [(8, 128), (13, 96), (3, 16, 40),
+                                   (2, 24, 96), (40,)], ids=str)
+@pytest.mark.parametrize("opt_kw", _ADAM_OPTS)
+def test_pallas_lane_through_optimizer_step_matches_flag_off(
+        monkeypatch, opt_kw, shape):
+    """optimizer.step routes every leaf of rank 2 and up through the
+    in-place kernel when enabled, and a 1-D leaf through the jnp lane;
+    the full trajectory must stay BITWISE equal to the flag-off jnp
+    lane — the "exact" contract the flag promises — and each leaf
+    traced counts once, under the lane the rule gave it."""
     monkeypatch.setenv("PADDLE_TPU_PALLAS_INTERPRET", "1")
-    on = run(True)
-    off = run(False)
+    on, on_counts = _adam_trajectory(opt_kw, shape, True)
+    off, off_counts = _adam_trajectory(opt_kw, shape, False)
     assert on.tobytes() == off.tobytes()
+    assert on_counts == ([1, 0] if len(shape) >= 2 else [0, 1])
+    assert off_counts == [0, 1]
+
+
+# under a block budget of 2,048 elements: a partial last row block, a
+# split last dimension with a partial lane block, a rank-3 leaf two
+# leading indices a block
+@pytest.mark.parametrize("shape", [(20, 96), (4, 300), (6, 2, 40)], ids=str)
+@pytest.mark.parametrize("opt_kw", _ADAM_OPTS)
+def test_pallas_lane_over_many_blocks_within_rounding(monkeypatch, opt_kw,
+                                                     shape):
+    """A grid of several steps reaches every element once, partial
+    blocks included: a block missed or updated twice is off by a step's
+    0.01.  Within rounding, not bitwise: the interpreter runs such a
+    grid as a loop whose body the CPU contracts into fmas otherwise
+    than the jnp lane's fusion, an ulp of the operands a step (the
+    parent's kernel differed so at (2048, 128) too); one block is
+    bitwise, above."""
+    from paddle_tpu.pallas import fused
+
+    monkeypatch.setattr(fused, "_ADAM_BLOCK_ELEMS", 2048)
+    view, block = fused.adam_update_blocks(shape)
+    assert math.prod(-(-v // b) for v, b in zip(view, block)) > 1
+    monkeypatch.setenv("PADDLE_TPU_PALLAS_INTERPRET", "1")
+    on, on_counts = _adam_trajectory(opt_kw, shape, True)
+    off, _ = _adam_trajectory(opt_kw, shape, False)
+    np.testing.assert_allclose(on, off, rtol=0, atol=1e-6)
+    assert on_counts == [1, 0]
 
 
 # ------------------------------------------------- hybrid dp x mp lane
