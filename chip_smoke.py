@@ -21,7 +21,12 @@ seed), and checks what comes out by the repo's own means:
    latent page store, a dense layer then sparse experts) through the
    same engine, at rows the latent decode kernel hosts: on a TPU the
    single-token read has to be the kernel's, never the XLA lane's.
-5. hybrid — with >= 4 devices: ``ParallelGPTForCausalLM`` at the same
+5. latent experts — a small ``NemotronHForCausalLM`` (Mamba-2, attention
+   and latent relu² expert blocks, each a mixer or a feed-forward part
+   alone) through the same engine: its expert layers hold no page and no
+   state row, and on a TPU the relu² up products have to run on the
+   ``expert_gmm_relu2`` kernel, never the XLA lane.
+6. hybrid — with >= 4 devices: ``ParallelGPTForCausalLM`` at the same
    widths under ``fleet.init`` (mp 2, dp the rest) through the same
    ``CompiledTrainStep``, losses against phase 1.
 
@@ -512,6 +517,41 @@ def latent_phase(dry_run):
         assert lanes["pallas.mla_decode.xla_lane"] == 0, lanes
 
 
+def latent_moe_phase(dry_run):
+    """The per-layer stack of Mamba-2, attention and LATENT expert blocks
+    through the compiled tick and the prefill member: its expert layers
+    hold nothing, and their relu² up products have to run on the
+    ``expert_gmm_relu2`` kernel on the chip, never the XLA lane."""
+    import paddle_tpu as paddle
+    from paddle_tpu.models.nemotron_h import (NemotronHConfig,
+                                              NemotronHForCausalLM)
+
+    paddle.seed(SEED)
+    # latent 128, expert width 256: expert_gmm hosts both products
+    cfg = NemotronHConfig(
+        vocab_size=512, hidden_size=256, num_layers=5,
+        layer_types=["moe", "mamba", "moe", "mamba", "attention"],
+        num_heads=2, num_kv_heads=1, head_dim=128, max_seq_len=256,
+        mamba_n_heads=8, mamba_d_head=64, mamba_d_state=128,
+        mamba_n_groups=2, mamba_chunk_size=32, moe_intermediate_size=256,
+        moe_latent_size=128, moe_shared_expert_intermediate_size=512,
+        num_experts_published=16, num_experts_per_tok=6,
+        held_experts=(4, 4), initializer_range=0.05)
+    snap, lanes = small_family_run(
+        NemotronHForCausalLM(cfg), "latent_moe", SEED + 4,
+        ("pallas.expert_gmm.relu2.kernel", "pallas.expert_gmm.relu2.xla_lane",
+         "pallas.expert_gmm.kernel", "pallas.expert_gmm.xla_lane"))
+    say(f"latent_moe: kv_layers_without_store="
+        f"{snap['kv_layers_without_store']} kv_pools={snap['kv_pools']} "
+        f"state_bytes={snap['state_bytes']}")
+    assert snap["kv_layers_without_store"] == 2
+    assert snap["kv_pools"] == 2 and snap["state_bytes"] > 0
+    if not dry_run:
+        assert lanes["pallas.expert_gmm.relu2.kernel"] > 0, lanes
+        assert lanes["pallas.expert_gmm.relu2.xla_lane"] == 0, lanes
+        assert lanes["pallas.expert_gmm.xla_lane"] == 0, lanes
+
+
 def hybrid_phase(cfg, sizes, one_chip_losses, dry_run):
     import jax
     import paddle_tpu.distributed as dist
@@ -591,6 +631,7 @@ def main():
     gc.collect()
     sparse_phase(args.dry_run)
     latent_phase(args.dry_run)
+    latent_moe_phase(args.dry_run)
     if jax.device_count() >= 4:
         hybrid_phase(cfg, sizes, losses, args.dry_run)
     else:

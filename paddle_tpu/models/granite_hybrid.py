@@ -146,6 +146,9 @@ class _MixDims(NamedTuple):
     taps: int
     chunk: int
     eps: float
+    #: groups of channels the gated norm normalises apart (1: all of
+    #: them, Granite's form)
+    norm_groups: int = 1
 
 
 @functools.partial(jax.jit, static_argnames=("dims",))
@@ -157,7 +160,7 @@ def _mamba2_mix(proj, conv_w, conv_b, dt_bias, a_log, d_skip, norm_w,
     none came in (a whole sequence from an empty state)."""
     f32 = jnp.float32
     b, s, _ = proj.shape
-    heads, p, g, n, taps, chunk, eps = dims
+    heads, p, g, n, taps, chunk, eps, norm_groups = dims
     d_inner = heads * p
     conv_dim = d_inner + 2 * g * n
     z, xbc, dt = jnp.split(proj.astype(f32),
@@ -208,10 +211,17 @@ def _mamba2_mix(proj, conv_w, conv_b, dt_bias, a_log, d_skip, norm_w,
             w, k, taps - 1, axis=0))(window, n_real)
         new_conv = conv_state.at[rows].set(tail.astype(conv_state.dtype))
     y = y + d_skip.astype(f32)[None, None, :, None] * x
-    # gate, then the norm over all channels
+    # gate, then the norm over all channels — or over each group of
+    # ``d_inner / norm_groups`` of them on its own
     y = y.reshape(b, s, d_inner) * jax.nn.silu(z)
-    y = y * jax.lax.rsqrt(jnp.mean(jnp.square(y), -1, keepdims=True)
-                          + eps) * norm_w.astype(f32)
+    if norm_groups == 1:
+        y = y * jax.lax.rsqrt(jnp.mean(jnp.square(y), -1, keepdims=True)
+                              + eps) * norm_w.astype(f32)
+    else:
+        y = y.reshape(b, s, norm_groups, d_inner // norm_groups)
+        y = y * jax.lax.rsqrt(jnp.mean(jnp.square(y), -1, keepdims=True)
+                              + eps)
+        y = y.reshape(b, s, d_inner) * norm_w.astype(f32)
     return y.astype(proj.dtype), new_conv, new_ssm
 
 
@@ -236,8 +246,16 @@ class _GateNorm(Layer):
 
 
 class GraniteMamba2Mixer(Layer):
-    def __init__(self, config: GraniteHybridConfig):
+    """The Mamba-2 mixer between its projections.  ``norm_groups``: the
+    groups of channels its gated norm normalises apart (1: all of them,
+    as Granite; ``nemotron_h`` normalises each of its ``n_groups``)."""
+
+    def __init__(self, config: GraniteHybridConfig, norm_groups=1):
         super().__init__()
+        if config.d_inner % norm_groups:
+            raise ValueError(f"{config.d_inner} channels do not split "
+                             f"into {norm_groups} norm groups")
+        self.norm_groups = int(norm_groups)
         self.config = cfg = config
         h, heads = cfg.hidden_size, cfg.mamba_n_heads
         w_init = ParamAttr(initializer=Normal(0.0, cfg.initializer_range))
@@ -273,7 +291,7 @@ class GraniteMamba2Mixer(Layer):
         dims = _MixDims(cfg.mamba_n_heads, cfg.mamba_d_head,
                         cfg.mamba_n_groups, cfg.mamba_d_state,
                         cfg.mamba_d_conv, cfg.mamba_chunk_size,
-                        cfg.rms_norm_eps)
+                        cfg.rms_norm_eps, self.norm_groups)
 
         def fn(*arrays):
             out = _mamba2_mix(*arrays, dims=dims)

@@ -6,15 +6,26 @@ with sparse experts share it (``models/cohere_moe.py``,
 ``top_k`` of ``s`` (of ``s + b`` with a selection bias ``b``, which moves
 the CHOICE alone); ``g_e = gate_scale * s_e / sum_{j in T} s_j`` (no
 ``gate_scale``: 1); the layer gives ``sum_{e in T, e held} g_e f_e(x)``
-plus its shared experts' outputs, their mean or their sum, each ``f`` a
-gated-SiLU MLP of ``expert_width``.  ``held_experts`` = (first, count) is
-one chip's share: the layer routes over all, computes what its own give
-through ``pallas.moe.routed_experts``, drops nothing, keeps static
-shapes; what the absent experts would add is left out.
+plus its shared experts' outputs, their mean or their sum, each ``f`` an
+MLP of ``expert_width`` with the activation ``expert_act``: "swiglu"
+(``(silu(x Wg) * (x Wu)) Wd``) or "relu2" (``relu(x Wu)^2 Wd``, one up
+matrix).  ``held_experts`` = (first, count) is one chip's share: the
+layer routes over all, computes what its own give through
+``pallas.moe.routed_experts``, drops nothing, keeps static shapes; what
+the absent experts would add is left out.
+
+A LATENT layer (``latent_size``; LatentMoE, ``models/nemotron_h.py``)
+runs its routed experts in a narrower space: ``l = W_lat_down x``, the
+routed sum over ``l``, then ``W_lat_up`` back to ``h``; the router reads
+``x`` itself, and the shared experts work at full width (of
+``shared_width``).  ``W_lat_up`` is linear, so the chips' shares, each
+through it, still add up to the whole layer.
 
 Leaves: ``gate.weight`` [h, E] the router (``gate.expert_bias`` [E] the
-selection bias), ``experts.{gate,up,down}_proj`` [E_held, in, out],
-``shared_experts.*`` [S, in, out].
+selection bias), ``experts.{gate,up,down}_proj`` [E_held, in, out] (no
+``gate_proj`` for "relu2"), ``shared_experts.*`` [S, in, out];
+``fc1_latent_proj.weight`` [h, latent] and ``fc2_latent_proj.weight``
+[latent, h] in a latent layer.
 """
 from __future__ import annotations
 
@@ -23,8 +34,8 @@ import jax.numpy as jnp
 from ..observability.tracing import scope
 
 from ..core.dispatch import apply_op
-from ..nn import Layer
-from ..nn.initializer import Normal
+from ..nn import Layer, Linear
+from ..nn.initializer import Normal, ParamAttr
 from ..pallas import moe as _moe
 
 
@@ -39,14 +50,24 @@ class Router(Layer):
                 (num_experts,), default_initializer=Normal(0.0, std))
 
 
-class ExpertStack(Layer):
-    """``count`` gated-SiLU experts, their three matrices stacked."""
+#: an expert's activations: ``pallas/moe.py``'s, less the plain product
+EXPERT_ACTS = tuple(a for a in _moe.ACTS if a is not None)
 
-    def __init__(self, hidden_size, width, count, std, down_std):
+
+class ExpertStack(Layer):
+    """``count`` experts of the activation ``act``, their matrices
+    stacked: "swiglu" has gate, up and down, "relu2" up and down."""
+
+    def __init__(self, hidden_size, width, count, std, down_std,
+                 act="swiglu"):
         super().__init__()
-        self.gate_proj = self.create_parameter(
-            (count, hidden_size, width),
-            default_initializer=Normal(0.0, std))
+        if act not in EXPERT_ACTS:
+            raise ValueError(f"expert act {act!r}: one of {EXPERT_ACTS}")
+        self.act = act
+        if act == "swiglu":
+            self.gate_proj = self.create_parameter(
+                (count, hidden_size, width),
+                default_initializer=Normal(0.0, std))
         self.up_proj = self.create_parameter(
             (count, hidden_size, width),
             default_initializer=Normal(0.0, std))
@@ -54,15 +75,24 @@ class ExpertStack(Layer):
             (count, width, hidden_size),
             default_initializer=Normal(0.0, down_std))
 
+    def matrices(self):
+        """(gate, up, down), or (up, down) for "relu2"."""
+        up_down = (self.up_proj, self.down_proj)
+        return up_down if self.act == "relu2" else (self.gate_proj,) + up_down
+
 
 class SparseExpertMLP(Layer):
     """See the module's text.  ``shared_reduce`` is ``"mean"`` or
     ``"sum"``; ``down_std`` is the down projections' initial deviation
-    where it is not ``std``."""
+    where it is not ``std``; ``expert_act`` the routed experts'
+    activation, ``latent_size`` the width they work at (None: ``h``);
+    ``shared_width`` the shared experts' width (None: the routed
+    experts'), of the routed experts' activation."""
 
     def __init__(self, hidden_size, expert_width, num_experts, held_experts,
                  top_k, num_shared, std, down_std=None, selection_bias=False,
-                 gate_scale=None, shared_reduce="mean"):
+                 gate_scale=None, shared_reduce="mean", expert_act="swiglu",
+                 latent_size=None, shared_width=None):
         super().__init__()
         if shared_reduce not in ("mean", "sum"):
             raise ValueError(f"shared_reduce {shared_reduce!r}: the shared "
@@ -72,10 +102,21 @@ class SparseExpertMLP(Layer):
         self.held_experts, self.top_k = tuple(held_experts), top_k
         self.gate_scale = gate_scale
         self.gate = Router(hidden_size, num_experts, std, selection_bias)
-        self.experts = ExpertStack(hidden_size, expert_width,
-                                   self.held_experts[1], std, down_std)
-        self.shared_experts = ExpertStack(hidden_size, expert_width,
-                                          num_shared, std, down_std)
+        inner = latent_size or hidden_size
+        self.experts = ExpertStack(inner, expert_width,
+                                   self.held_experts[1], std, down_std,
+                                   expert_act)
+        self.shared_experts = ExpertStack(
+            hidden_size, shared_width or expert_width, num_shared, std,
+            down_std, expert_act)
+        self.latent = latent_size is not None
+        if self.latent:
+            self.fc1_latent_proj = Linear(
+                hidden_size, latent_size, bias_attr=False,
+                weight_attr=ParamAttr(initializer=Normal(0.0, std)))
+            self.fc2_latent_proj = Linear(
+                latent_size, hidden_size, bias_attr=False,
+                weight_attr=ParamAttr(initializer=Normal(0.0, down_std)))
         #: what the sum of the shared experts' outputs is multiplied by
         #: (None: it stands as it is)
         self.shared_scale = 1.0 / num_shared if shared_reduce == "mean" \
@@ -101,57 +142,88 @@ class SparseExpertMLP(Layer):
 
     def routed(self, x, valid=None):
         """The held routed experts' part of the layer for ``x`` [B, S,
-        h], and the pairs computed for each held expert ([E_held] int32;
-        with ``valid`` [B], each row's count of real positions, the
-        others' pairs are computed and not counted)."""
+        h] (a latent layer's through its two latent projections), and
+        the pairs computed for each held expert ([E_held] int32; with
+        ``valid`` [B], each row's count of real positions, the others'
+        pairs are computed and not counted)."""
+        if not self.latent:
+            with scope("moe"):
+                return self._routed(x, x, valid)
+        with scope("moe_latent"):
+            inner = self.fc1_latent_proj(x)
+        with scope("moe"):
+            y, counts = self._routed(x, inner, valid)
+        with scope("moe_latent"):
+            return self.fc2_latent_proj(y), counts
+
+    def _routed(self, x, inner, valid):
+        """The routed sum over ``inner`` (``x`` or its latent), the
+        router over ``x``."""
         b, s, h = x.shape
-        held, ex = self.held_experts, self.experts
+        width = inner.shape[-1]
+        held, act = self.held_experts, self.experts.act
         leaves = self.router_leaves()
+        mats = self.experts.matrices()
+        latent = self.latent
 
         def routed(xa, *rest):
-            router, (wg, wu, wd, *valid) = \
-                rest[:len(leaves)], rest[len(leaves):]
+            if latent:
+                ia, rest = rest[0], rest[1:]
+            router, (*ws, wd), valid = (rest[:len(leaves)],
+                                        rest[len(leaves):][:len(mats)],
+                                        rest[len(leaves) + len(mats):])
+            wg, wu = ws if len(ws) == 2 else (None, *ws)
             tokens = xa.reshape(b * s, h)
             experts, gates = self.route(tokens, *router)
             real = None
             if valid:
                 real = (jnp.arange(s)[None, :] < valid[0][:, None]) \
                     .reshape(-1)
-            y, counts = _moe.routed_experts(tokens, experts, gates, wg, wu,
-                                            wd, held, real)
-            return y.reshape(b, s, h), counts
+            inner_t = ia.reshape(b * s, width) if latent else tokens
+            y, counts = _moe.routed_experts(inner_t, experts, gates, wg, wu,
+                                            wd, held, real, act=act)
+            return y.reshape(b, s, width), counts
 
-        args = (x,) + leaves + (ex.gate_proj, ex.up_proj, ex.down_proj)
-        return apply_op(
-            "routed_experts_biased" if len(leaves) > 1 else "routed_experts",
-            routed, args if valid is None else args + (valid,))
+        args = (x,) + ((inner,) if latent else ()) + leaves + mats
+        name = "routed_experts_biased" if len(leaves) > 1 \
+            else "routed_experts"
+        if latent or act != "swiglu":
+            name += f"_{act}" + ("_latent" if latent else "")
+        return apply_op(name, routed,
+                        args if valid is None else args + (valid,))
 
     def forward(self, x, cache=None):
         b, s, h = x.shape
         valid = None if cache is None else cache.get("valid_len")
         sh = self.shared_experts
+        act = sh.act
 
-        def shared(xa, wg, wu, wd):
+        def shared(xa, *ws):
             tokens = xa.reshape(b * s, h)
             acc = None
-            for j in range(wg.shape[0]):
+            for j in range(ws[-1].shape[0]):
                 # an expert at a time: slicing the stack's first axis is
                 # a view, a product over the stacked axis a transpose
-                y = jnp.matmul(jax.nn.silu(jnp.matmul(tokens, wg[j]))
-                               * jnp.matmul(tokens, wu[j]), wd[j]) \
-                    .astype(jnp.float32)
+                if act == "swiglu":
+                    wg, wu, wd = ws
+                    mid = jax.nn.silu(jnp.matmul(tokens, wg[j])) \
+                        * jnp.matmul(tokens, wu[j])
+                else:
+                    wu, wd = ws
+                    mid = jnp.square(jax.nn.relu(jnp.matmul(tokens, wu[j])))
+                y = jnp.matmul(mid, wd[j]).astype(jnp.float32)
                 acc = y if acc is None else acc + y
             if self.shared_scale is not None:
                 acc = acc * self.shared_scale
             return acc.astype(xa.dtype).reshape(b, s, h)
 
-        with scope("moe"):
-            y, counts = self.routed(x, valid)
+        y, counts = self.routed(x, valid)
+        name = "shared_experts" if self.shared_scale is not None \
+            else "shared_experts_sum"
+        if act != "swiglu":
+            name += f"_{act}"
         with scope("moe_shared"):
-            y = y + apply_op(
-                "shared_experts" if self.shared_scale is not None
-                else "shared_experts_sum", shared,
-                (x, sh.gate_proj, sh.up_proj, sh.down_proj))
+            y = y + apply_op(name, shared, (x,) + sh.matrices())
         if valid is not None:
             cache["moe_counts"] = counts
         return y

@@ -71,8 +71,14 @@ def row_tile(tokens):
     """Rows of one tile of the sorted buffer, from the call's tokens: a
     decode tick's few rows an expert want the smallest tile the matrix
     unit takes; a prefill chunk's tens a tile that keeps it busy while an
-    expert's weights stream."""
-    return 16 if tokens <= 64 else 128 if tokens <= 1024 else 256
+    expert's weights stream.  A 128-token tick of 22 pairs a token over
+    128 of 512 experts (~5.5 rows an expert) took 2,096 us a layer at
+    16 rows and 2,461 at 128 (v5e,
+    ``docs/perf/ubench_relu2_gmm.pr38.log``).  Only a call of 65-128
+    tokens took another tile when the bound moved from 64: no other
+    cell makes one (ticks of 32 and 56 rows; prefill members of whole
+    512-token chunks a row)."""
+    return 16 if tokens <= 128 else 128 if tokens <= 1024 else 256
 
 
 class Groups(NamedTuple):
@@ -145,10 +151,10 @@ def group_pairs(experts, gates, held, tm, valid=None):
 # the grouped matrix product
 # ------------------------------------------------------------------
 
-def _gmm_kernel(te_ref, used_ref, x_ref, *rest, gated):
+def _gmm_kernel(te_ref, used_ref, x_ref, *rest, act):
     from jax.experimental import pallas as pl
     del te_ref                      # consumed by the index maps
-    if gated:
+    if act == "swiglu":
         wg_ref, wu_ref, o_ref = rest
     else:
         w_ref, o_ref = rest
@@ -158,12 +164,21 @@ def _gmm_kernel(te_ref, used_ref, x_ref, *rest, gated):
         # float32 sums with no bit of an operand dropped, whatever the
         # process's default matmul precision
         x = x_ref[...]
-        if gated:
+        if act == "swiglu":
             g = _mxu_f32(x, wg_ref[...], 0)
             u = _mxu_f32(x, wu_ref[...], 0)
             o_ref[...] = (g * jax.nn.sigmoid(g) * u).astype(o_ref.dtype)
         else:
-            o_ref[...] = _mxu_f32(x, w_ref[...], 0).astype(o_ref.dtype)
+            y = _mxu_f32(x, w_ref[...], 0)
+            if act == "relu2":
+                y = jnp.square(jnp.maximum(y, 0.0))
+            o_ref[...] = y.astype(o_ref.dtype)
+
+
+#: the up product's activation -> (the weights it takes, the kernel's
+#: name in a trace); None: a plain product (the down projection)
+ACTS = {"swiglu": (2, "expert_gmm"), "relu2": (1, "expert_gmm_relu2"),
+        None: (1, "expert_gmm")}
 
 
 def gmm_block_n(k, n, itemsize):
@@ -178,8 +193,10 @@ def gmm_block_n(k, n, itemsize):
     return tn
 
 
-@functools.partial(jax.jit, static_argnames=("tm", "tn", "interpret"))
-def _gmm_call(x, weights, tile_expert, tiles_used, *, tm, tn, interpret):
+@functools.partial(jax.jit,
+                   static_argnames=("tm", "tn", "interpret", "act"))
+def _gmm_call(x, weights, tile_expert, tiles_used, *, tm, tn, interpret,
+              act):
     """``expert_gmm`` at fixed tiles; a program of its own inside the
     caller's, so the layers of a model lower ONE kernel a shape."""
     from jax.experimental import pallas as pl
@@ -187,7 +204,6 @@ def _gmm_call(x, weights, tile_expert, tiles_used, *, tm, tn, interpret):
 
     m, k = x.shape
     n = weights[0].shape[2]
-    gated = len(weights) == 2
     n_blocks = n // tn
 
     def tile_of(t, used):
@@ -209,24 +225,29 @@ def _gmm_call(x, weights, tile_expert, tiles_used, *, tm, tn, interpret):
         num_scalar_prefetch=2, grid=(m // tm, n_blocks),
         in_specs=[x_spec] + [w_spec] * len(weights), out_specs=o_spec)
     return pl.pallas_call(
-        functools.partial(_gmm_kernel, gated=gated),
+        functools.partial(_gmm_kernel, act=act),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((m, n), x.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary"),
             vmem_limit_bytes=_VMEM_LIMIT),
         interpret=pltpu.InterpretParams() if interpret else False,
-        name="expert_gmm",
+        name=ACTS[act][1],
     )(tile_expert, tiles_used, x, *weights)
 
 
-def expert_gmm_xla(x, weights, sizes):
+def expert_gmm_xla(x, weights, sizes, act=None):
     """The XLA lane: ``ragged_dot`` over the same sorted buffer (rows
-    past the groups come back zero)."""
+    past the groups come back zero), ``act`` as ``expert_gmm`` takes it."""
     prods = [jax.lax.ragged_dot(x, w, sizes,
                                 preferred_element_type=jnp.float32)
              for w in weights]
-    out = prods[0] if len(prods) == 1 else jax.nn.silu(prods[0]) * prods[1]
+    if act == "swiglu":
+        out = jax.nn.silu(prods[0]) * prods[1]
+    elif act == "relu2":
+        out = jnp.square(jnp.maximum(prods[0], 0.0))
+    else:
+        out = prods[0]
     return out.astype(x.dtype)
 
 
@@ -239,31 +260,40 @@ def kernel_hosts(x, weights, tm):
         and x.dtype == weights[0].dtype
 
 
-def expert_gmm(x, weights, groups, tm, lane=None):
+def expert_gmm(x, weights, groups, tm, lane=None, act=None):
     """``x`` [M, K], rows sorted by expert in tiles of ``tm``
-    (``group_pairs``), times the stacked ``weights`` ([E_held, K, N]; one
-    of them, or (gate, up): then the result is ``silu(x g) * (x u)``).
-    Rows of no pair come back as whatever the lane leaves there (zero or
-    unwritten): the caller reads pair rows only.  ``lane`` forces
-    ``"kernel"`` or ``"xla"``; None asks ``kernel_hosts``."""
+    (``group_pairs``), times the stacked ``weights`` ([E_held, K, N]):
+    (gate, up) with ``act`` "swiglu" gives ``silu(x g) * (x u)``; one
+    matrix with ``act`` "relu2" gives ``relu(x w)^2``, with None the
+    plain product.  Rows of no pair come back as whatever the
+    lane leaves there (zero or unwritten): the caller reads pair rows
+    only.  ``lane`` forces ``"kernel"`` or ``"xla"``; None asks
+    ``kernel_hosts``."""
     from ..utils import monitor
     weights = tuple(weights)
+    if ACTS[act][0] != len(weights):
+        raise ValueError(f"act {act!r} takes {ACTS[act][0]} weight "
+                         f"matrices, not {len(weights)}")
     use_kernel = kernel_hosts(x, weights, tm) if lane is None \
         else lane == "kernel"
-    monitor.incr("pallas.expert_gmm.kernel" if use_kernel
-                 else "pallas.expert_gmm.xla_lane")
+    lane_name = "kernel" if use_kernel else "xla_lane"
+    monitor.incr("pallas.expert_gmm." + lane_name)
+    if act == "relu2":
+        monitor.incr("pallas.expert_gmm.relu2." + lane_name)
     if not use_kernel:
-        return expert_gmm_xla(x, weights, groups.sizes)
+        return expert_gmm_xla(x, weights, groups.sizes, act)
     k, n = weights[0].shape[1:]
     return _gmm_call(x, weights, groups.tile_expert, groups.tiles_used,
                      tm=tm, tn=gmm_block_n(k, n, weights[0].dtype.itemsize),
-                     interpret=_interpret())
+                     interpret=_interpret(), act=act)
 
 
 def routed_experts(x, experts, gates, w_gate, w_up, w_down, held, valid=None,
-                   lane=None):
+                   lane=None, act="swiglu"):
     """The held experts' part of a sparse layer: ``sum_{e in T(t), e
-    held} g_e f_e(x_t)`` with ``f_e(x) = (silu(x Wg_e) * (x Wu_e)) Wd_e``.
+    held} g_e f_e(x_t)``, each expert ``f_e`` of the activation ``act``:
+    "swiglu", ``f_e(x) = (silu(x Wg_e) * (x Wu_e)) Wd_e``; "relu2",
+    ``f_e(x) = relu(x Wu_e)^2 Wd_e`` (no gate: ``w_gate`` is None).
     ``x`` [T, h]; ``experts``/``gates`` [T, k] over ALL experts (gates
     normalised over all k chosen); the stacked weights hold the
     ``held[1]`` experts from ``held[0]`` on.  Returns (y [T, h],
@@ -273,8 +303,9 @@ def routed_experts(x, experts, gates, w_gate, w_up, w_down, held, valid=None,
     groups = group_pairs(experts, gates, held, tm, valid)
     x_pad = jnp.concatenate([x, jnp.zeros((1, h), x.dtype)], axis=0)
     xs = x_pad[groups.row_token]                                 # [M, h]
-    act = expert_gmm(xs, (w_gate, w_up), groups, tm, lane)
-    out = expert_gmm(act, (w_down,), groups, tm, lane)
+    ups = (w_up,) if w_gate is None else (w_gate, w_up)
+    act_out = expert_gmm(xs, ups, groups, tm, lane, act=act)
+    out = expert_gmm(act_out, (w_down,), groups, tm, lane, act=None)
     m = out.shape[0]
     rows = groups.pair_row
     picked = out[jnp.minimum(rows, m - 1)]                       # [T, kk, h]

@@ -140,6 +140,15 @@ def _latent_layers(cfg):
         else None
 
 
+def _storeless_layers(cfg):
+    """What ``cfg.storeless_layers()`` says of each layer (True: it holds
+    no page and no state row — a feed-forward part alone), or None when
+    the config has no such method or no layer is one."""
+    bare = getattr(cfg, "storeless_layers", None)
+    bare = None if bare is None else bare()
+    return bare if bare and any(bare) else None
+
+
 class Engine:
     """`Engine(model).start()`; then `submit()` (async, returns a
     `Future[RequestOutput]`) or `generate()` (sync).  `shutdown()` stops
@@ -439,7 +448,8 @@ class Engine:
             layer_windows=self._layer_windows,
             # the widest run of positions one call writes
             window_slack=min(self.scfg.prefill_chunk_tokens, slot_len),
-            layer_latents=self._layer_latents)
+            layer_latents=self._layer_latents,
+            layer_storeless=_storeless_layers(self.cfg))
         stats.set_value("state.bytes", cache.state_bytes)
         stats.set_value("kv.pages_spanned",
                         cache.num_slots * cache.pages_per_slot)
@@ -447,6 +457,8 @@ class Engine:
         stats.set_value("kv.pools_lane_dense", cache.pools_lane_dense)
         stats.set_value("kv.latent_pools", cache.latent_pools)
         stats.set_value("kv.latent_row_bytes", cache.latent_row_bytes)
+        stats.set_value("kv.layers_without_store",
+                        cache.layers_without_store)
         self.prefix_tree = PrefixTree(self._page_size) \
             if self.scfg.enable_prefix_cache else None
         # one compiled prefill program: every chunk is this wide

@@ -79,6 +79,13 @@ values live in 640 lanes) so that a page is the matrix the latent decode
 kernel's DMA moves and a row's value part is a lane-aligned slice of its
 key.  What carries K and V by name (``export_pages`` / ``adopt_pages``,
 quantized storage) refuses a latent store by name.
+
+**Layers that hold nothing.**  A layer that is a feed-forward part
+alone (``layer_storeless``: what the model's config says of each layer)
+keeps nothing between calls: it claims no pool, no page-table entry and
+no state row, and its cache dict carries ``valid_len`` alone (an expert
+layer leaves its routing counts there).  ``layers_without_store`` counts
+them.
 """
 from __future__ import annotations
 
@@ -109,7 +116,7 @@ class PagedKVCache:
     def __init__(self, num_layers, num_slots, max_len, num_kv_heads=None,
                  head_dim=None, page_size=16, num_pages=None, dtype="float32",
                  layer_states=None, layer_windows=None, window_slack=1,
-                 layer_latents=None):
+                 layer_latents=None, layer_storeless=None):
         self.num_slots = int(num_slots)
         self.page_size = int(page_size)
         self.max_len = int(max_len)
@@ -149,11 +156,15 @@ class PagedKVCache:
         layer_states = per_layer(layer_states, "layer_states")
         layer_windows = per_layer(layer_windows, "layer_windows")
         layer_latents = per_layer(layer_latents, "layer_latents")
+        bare = [bool(b) for b in per_layer(layer_storeless,
+                                           "layer_storeless")]
         # each kind of paged layer owns its page's shape: as the host
-        # formats carry it, and as it lives on the device
-        kinds = {"state" if st is not None else
+        # formats carry it, and as it lives on the device; a layer that
+        # holds nothing owns none
+        kinds = {"none" if nothing else "state" if st is not None else
                  "latent" if lat is not None else "kv"
-                 for st, lat in zip(layer_states, layer_latents)}
+                 for st, lat, nothing in zip(layer_states, layer_latents,
+                                             bare)}
         self.page_shape = self.stored_page_shape = None
         if "kv" in kinds:
             if num_kv_heads is None or head_dim is None:
@@ -219,8 +230,16 @@ class PagedKVCache:
         #: per layer, the names of the device arrays a model call updates
         #: (``flat_pools`` order)
         self._keys = []
-        for state, window, latent in zip(layer_states, layer_windows,
-                                         layer_latents):
+        for state, window, latent, nothing in zip(
+                layer_states, layer_windows, layer_latents, bare):
+            if nothing:
+                if state is not None or window is not None or \
+                        latent is not None:
+                    raise ValueError("a layer that holds nothing has no "
+                                     "state, window or latent rows")
+                self._keys.append(())
+                self.layers.append({"valid_len": None})
+                continue
             if state is not None:
                 if window is not None or latent is not None:
                     raise ValueError("a recurrent layer has no window "
@@ -271,7 +290,10 @@ class PagedKVCache:
             self._keys.append(keys)
             self.layers.append(lay)
         self._paged = tuple(i for i, st in enumerate(layer_states)
-                            if st is None)
+                            if st is None and not bare[i])
+        #: the layers that hold nothing
+        self._storeless = tuple(i for i, nothing in enumerate(bare)
+                                if nothing)
         #: the paged layers behind the ring table
         self._ringed = tuple(
             i for i in self._paged
@@ -295,6 +317,11 @@ class PagedKVCache:
         """How many latent page pools the cache holds (one a latent
         layer)."""
         return len(self._latent)
+
+    @property
+    def layers_without_store(self):
+        """How many layers hold no page and no state row."""
+        return len(self._storeless)
 
     @property
     def pools_lane_dense(self):
@@ -774,7 +801,7 @@ class PagedKVCache:
                 for name in ("window", "latent_width"):
                     if name in self.layers[i]:
                         view[name] = self.layers[i][name]
-            else:
+            elif i in self._stateful:
                 view["state_rows"] = rows
             view["valid_len"] = valid
             views.append(view)

@@ -196,6 +196,9 @@ def declare_tick_stats():
     _registry.gauge(PREFIX + "kv.latent_row_bytes",
                     "bytes of one cached latent row as the arithmetic "
                     "counts them (its values, not the lanes it lives in)")
+    _registry.gauge(PREFIX + "kv.layers_without_store",
+                    "layers that hold no page and no state row: a "
+                    "feed-forward part alone")
 
 
 def declare_migration_stats():
@@ -415,6 +418,11 @@ def serving_stats():
     ``mla_decode_xla_lane_traces``, the lanes the single-token latent
     read took when traced (process-wide, like the paged read's).
 
+    ``kv_layers_without_store``: the model's layers that hold no page
+    and no state row (a feed-forward part alone), and
+    ``expert_gmm_relu2_kernel_traces`` / ``_xla_lane_traces``: of the
+    grouped products, the relu² experts' up products by lane.
+
     Recurrent-state quantities (a model whose layers keep a per-slot
     state beside the pages, zero otherwise): ``state_bytes`` (the state
     arrays' size), ``state_resets`` and ``state_reset_ms_avg`` (an
@@ -526,6 +534,11 @@ def serving_stats():
         "kv_pools_lane_dense": g("kv.pools_lane_dense"),
         "kv_latent_pools": g("kv.latent_pools"),
         "kv_latent_row_bytes": g("kv.latent_row_bytes"),
+        "kv_layers_without_store": g("kv.layers_without_store"),
+        "expert_gmm_relu2_kernel_traces": s.get(
+            "pallas.expert_gmm.relu2.kernel", 0),
+        "expert_gmm_relu2_xla_lane_traces": s.get(
+            "pallas.expert_gmm.relu2.xla_lane", 0),
         "mla_decode_kernel_traces": s.get("pallas.mla_decode.kernel", 0),
         "mla_decode_xla_lane_traces": s.get("pallas.mla_decode.xla_lane",
                                             0),

@@ -582,9 +582,12 @@ def test_every_tick_hands_its_tokens_to_the_host(model, tick_flag, sampling):
 _PINNED = {
     # family: (greedy tick, one-row prefill member): sha256[:16] of the
     # StableHLO of a tiny engine's programs, as PR 33's tree traced them
+    # (sarvam_mla: as PR 37's tree traced it, pinned in ISSUE 38 before
+    # the latent experts and the grouped norm entered the shared code)
     "llama": ("dca425a4f34f1eae", "a0a8ad78c7ff9b02"),
     "cohere_moe": ("fdad0e74642cca58", "3bfb9711f5616c51"),
     "granite_hybrid": ("6e97f6d411d56c2d", "85229164b3cdb108"),
+    "sarvam_mla": ("30552e1965361817", "0d26d7a780937357"),
 }
 
 _HASH_SCRIPT = r"""
@@ -614,6 +617,8 @@ from paddle_tpu.models.cohere_moe import (
     TINY_COHERE_MOE, CohereMoeConfig, CohereMoeForCausalLM)
 from paddle_tpu.models.granite_hybrid import (
     TINY_GRANITE_HYBRID, GraniteHybridConfig, GraniteHybridForCausalLM)
+from paddle_tpu.models.sarvam_mla import (
+    TINY_SARVAM_MLA, SarvamMLAConfig, SarvamMLAForCausalLM)
 print(json.dumps({
     "llama": programs(LlamaForCausalLM(llama_config(
         "tiny", vocab_size=256, max_seq_len=64)), 256),
@@ -621,13 +626,15 @@ print(json.dumps({
         CohereMoeConfig(**TINY_COHERE_MOE)), 256),
     "granite_hybrid": programs(GraniteHybridForCausalLM(
         GraniteHybridConfig(**TINY_GRANITE_HYBRID)),
-        TINY_GRANITE_HYBRID["vocab_size"])}))
+        TINY_GRANITE_HYBRID["vocab_size"]),
+    "sarvam_mla": programs(SarvamMLAForCausalLM(
+        SarvamMLAConfig(**TINY_SARVAM_MLA)), TINY_SARVAM_MLA["vocab_size"])}))
 """
 
 
 @pytest.fixture(scope="module")
 def family_program_hashes():
-    """The three families' tick and prefill programs, hashed in a fresh
+    """The four families' tick and prefill programs, hashed in a fresh
     process (names and counters of this process's earlier traces stay out
     of the text)."""
     import json

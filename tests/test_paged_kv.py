@@ -1124,3 +1124,34 @@ def test_read_latent_gives_a_slots_rows_in_position_order():
         assert sorted(got) == [0, 1]
         for layer in (0, 1):
             np.testing.assert_array_equal(got[layer], rows[layer, slot, :n])
+
+
+# ---------------------------------------------- layers that hold nothing
+
+
+def test_storeless_kind_owns_no_pool_table_entry_or_row():
+    """A layer that holds nothing (a feed-forward part alone) claims no
+    pool and no state row: the K/V layers keep theirs behind the one
+    table, the storeless ones' views carry ``valid_len`` alone, and the
+    slot lifecycle is the K/V layers' own."""
+    cache = PagedKVCache(3, 2, 32, 2, 16, page_size=4,
+                         layer_storeless=[True, False, True])
+    assert cache.layers_without_store == 2 and cache.pools == 2
+    assert cache.state_bytes == 0
+    assert [sorted(k for k in lay if k.endswith("pool"))
+            for lay in cache.layers] == [[], ["k_pool", "v_pool"], []]
+    assert len(cache.flat_pools()) == 2
+    slot = cache.allocate(4)
+    for pos in range(9):
+        cache.ensure_capacity(slot, pos)
+    views = cache.views_over(cache.flat_pools(), *cache.table_arrays(),
+                             valid_len=np.ones(2, np.int32))
+    assert set(views[0]) == set(views[2]) == {"valid_len"}
+    assert {"k_pool", "v_pool", "page_table", "offset"} <= set(views[1])
+    live = cache.layer_caches(live=[slot])
+    assert set(live[0]) == {"valid_len"}
+    cache.release(slot)
+    assert cache.pages_in_use == 0
+    # the same model with no storeless layer builds what it always built
+    plain = PagedKVCache(1, 2, 32, 2, 16, page_size=4)
+    assert plain.layers_without_store == 0 and plain.pools == 2

@@ -438,6 +438,42 @@ def test_expert_gmm_compiles(v5e, case):
         assert re.match(r"^(ROOT )?%expert_gmm[\w.]* = ", call), call
 
 
+#        tokens latent width held k
+_GMM_RELU2 = {
+    "tick-128-sessions": (128, 1024, 2688, 128, 22),
+    "prefill-4-rows": (2048, 1024, 2688, 128, 22),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_GMM_RELU2))
+def test_expert_gmm_relu2_compiles(v5e, case):
+    """The latent relu² experts at the LatentMoE cell's widths (up K 1,024
+    / N 2,688, down K 2,688 / N 1,024): the up product's kernel is named
+    ``expert_gmm_relu2`` and takes one weight matrix, the down product's
+    ``expert_gmm``; both are matched by the latent reader's pattern."""
+    import re
+    from paddle_tpu.pallas import moe
+    t, lat, f, held, k = _GMM_RELU2[case]
+
+    def fn(x, experts, gates, wu, wd):
+        return moe.routed_experts(x, experts, gates, None, wu, wd,
+                                  (0, held), act="relu2")
+
+    text = _compile(fn, [((t, lat), BF16), ((t, k), jnp.int32),
+                         ((t, k), F32), ((held, lat, f), BF16),
+                         ((held, f, lat), BF16)],
+                    SingleDeviceSharding(v5e[0]), compiled=True)
+    calls = [line.strip() for line in text.splitlines()
+             if "tpu_custom_call" in line]
+    assert len(calls) == 2, calls
+    names = sorted(re.match(r"^(?:ROOT )?%([a-z_0-9]+?)(?:\.\d+)? = ",
+                            c).group(1) for c in calls)
+    assert names == ["expert_gmm", "expert_gmm_relu2"], names
+    for call in calls:
+        # as chipbench/kernels/latent_expert_ffn/pallas_expert_gmm.json
+        assert re.match(r"^(ROOT )?%expert_gmm[\w.]* = ", call), call
+
+
 # --------------------------------------------------------- latent decode
 
 #         rows heads row lanes page pages-a-slot
