@@ -24,8 +24,10 @@ seed), and checks what comes out by the repo's own means:
 5. latent experts — a small ``NemotronHForCausalLM`` (Mamba-2, attention
    and latent relu² expert blocks, each a mixer or a feed-forward part
    alone) through the same engine: its expert layers hold no page and no
-   state row, and on a TPU the relu² up products have to run on the
-   ``expert_gmm_relu2`` kernel, never the XLA lane.
+   state row, its SSM state is stored lane-packed two heads a row of
+   lanes, and on a TPU the relu² up products have to run on the
+   ``expert_gmm_relu2`` kernel and the state update on ``ssm_update``,
+   never an XLA lane.
 6. hybrid — with >= 4 devices: ``ParallelGPTForCausalLM`` at the same
    widths under ``fleet.init`` (mp 2, dp the rest) through the same
    ``CompiledTrainStep``, losses against phase 1.
@@ -520,8 +522,9 @@ def latent_phase(dry_run):
 def latent_moe_phase(dry_run):
     """The per-layer stack of Mamba-2, attention and LATENT expert blocks
     through the compiled tick and the prefill member: its expert layers
-    hold nothing, and their relu² up products have to run on the
-    ``expert_gmm_relu2`` kernel on the chip, never the XLA lane."""
+    hold nothing, its SSM state is lane-packed (heads of 64: two a row of
+    lanes), and the relu² up products and the state update have to run
+    on their kernels on the chip, never an XLA lane."""
     import paddle_tpu as paddle
     from paddle_tpu.models.nemotron_h import (NemotronHConfig,
                                               NemotronHForCausalLM)
@@ -540,16 +543,21 @@ def latent_moe_phase(dry_run):
     snap, lanes = small_family_run(
         NemotronHForCausalLM(cfg), "latent_moe", SEED + 4,
         ("pallas.expert_gmm.relu2.kernel", "pallas.expert_gmm.relu2.xla_lane",
-         "pallas.expert_gmm.kernel", "pallas.expert_gmm.xla_lane"))
+         "pallas.expert_gmm.kernel", "pallas.expert_gmm.xla_lane",
+         "pallas.ssm_update.kernel", "pallas.ssm_update.xla_lane"))
     say(f"latent_moe: kv_layers_without_store="
         f"{snap['kv_layers_without_store']} kv_pools={snap['kv_pools']} "
-        f"state_bytes={snap['state_bytes']}")
+        f"state_bytes={snap['state_bytes']} "
+        f"state_heads_per_tile={snap['state_heads_per_tile']}")
     assert snap["kv_layers_without_store"] == 2
     assert snap["kv_pools"] == 2 and snap["state_bytes"] > 0
+    assert snap["state_heads_per_tile"] == 2
     if not dry_run:
         assert lanes["pallas.expert_gmm.relu2.kernel"] > 0, lanes
         assert lanes["pallas.expert_gmm.relu2.xla_lane"] == 0, lanes
         assert lanes["pallas.expert_gmm.xla_lane"] == 0, lanes
+        assert lanes["pallas.ssm_update.kernel"] > 0, lanes
+        assert lanes["pallas.ssm_update.xla_lane"] == 0, lanes
 
 
 def hybrid_phase(cfg, sizes, one_chip_losses, dry_run):

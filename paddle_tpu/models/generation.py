@@ -38,8 +38,9 @@ def init_kv_caches(num_layers, batch, max_len, num_heads, head_dim,
 
 def recurrent_layer_states(cfg, dtype="float32"):
     """What ``cfg.layer_states(dtype)`` says each layer keeps per
-    sequence (None: keys and values; else ``{name: (shape, dtype)}`` of
-    a fixed-size recurrent state), or None when the config has no such
+    sequence (None: keys and values; else ``{name: (shape, dtype[, the
+    shape a row is read back in])}`` of a fixed-size recurrent state),
+    or None when the config has no such
     method or every layer keeps keys and values."""
     states = getattr(cfg, "layer_states", None)
     states = None if states is None else states(dtype)

@@ -4,7 +4,7 @@ MLP (IBM, 2025; HF ``granitemoehybrid`` without experts).
 
 What sets it apart from the Llama family here: a layer may keep a
 RECURRENT state per sequence (the last ``d_conv - 1`` pre-activation
-``xBC`` rows and the ``[H, P, N]`` SSM state, float32) instead of keys
+``xBC`` rows and the SSM state, float32) instead of keys
 and values; attention carries no positional rotation and scales its
 scores by ``attention_multiplier``; the embedding, every residual branch
 and the logits carry fixed multipliers; embedding and head are tied.
@@ -13,12 +13,13 @@ Cache contract (one dict per layer, as ``caches``):
 
 - attention layers take what Llama's do — a paged dict
   (``serving.PagedKVCache``) or a dense ``{"k", "v", "offset"}``;
-- Mamba layers take ``{"conv_state": [R, K-1, C], "ssm_state": [R, H, P,
-  N], "state_rows": int32 [B] or None, "valid_len": int32 [B] or
-  None}``: batch row ``b`` reads and writes state row ``state_rows[b]``
-  (None: row ``b``), and only its first ``valid_len[b]`` positions are
-  real (None: all) — a pad position carries ``dt = 0`` and stays out of
-  the convolution's window, so it leaves the state as it was.
+- Mamba layers take ``{"conv_state": [R, K-1, C], "ssm_state": [R, H/k,
+  N, k P] (lane-packed: ``pallas/ssm.py``), "state_rows": int32 [B] or
+  None, "valid_len": int32 [B] or None}``: batch row ``b`` reads and
+  writes state row ``state_rows[b]`` (None: row ``b``), and only its
+  first ``valid_len[b]`` positions are real (None: all) — a pad
+  position carries ``dt = 0`` and stays out of the convolution's window,
+  so it leaves the state as it was.
 
 ``GraniteHybridConfig.layer_states()`` tells a cache manager which
 layers keep which state; ``init_caches`` builds the dense caches
@@ -116,11 +117,15 @@ class GraniteHybridConfig:
         the fixed-size state a recurrent layer keeps per sequence
         (``dtype``: the activations' type, for the convolution's window;
         the SSM state is float32 whatever that is: a recurrence of 1,000+
-        steps rounds at every one of them)."""
+        steps rounds at every one of them).  The SSM state is stored
+        lane-packed (``pallas.ssm.state_row_shape``); its third member is
+        the shape a row is read back in (``PagedKVCache.read_state``)."""
         state = {
             "conv_state": ((self.mamba_d_conv - 1, self.conv_dim), dtype),
-            "ssm_state": ((self.mamba_n_heads, self.mamba_d_head,
-                           self.mamba_d_state), "float32")}
+            "ssm_state": _ssm.state_spec(self.mamba_n_heads,
+                                         self.mamba_d_head,
+                                         self.mamba_d_state,
+                                         self.mamba_n_groups)}
         return [dict(state) if kind == "mamba" else None
                 for kind in self.layer_types]
 
@@ -188,7 +193,7 @@ def _mamba2_mix(proj, conv_w, conv_b, dt_bias, a_log, d_skip, norm_w,
     a = -jnp.exp(a_log.astype(f32))
 
     if not cached:
-        state0 = jnp.zeros((b, heads, p, n), f32)
+        state0 = jnp.zeros((b,) + _ssm.state_row_shape(heads, p, n, g), f32)
         _, y = _ssm.ssd_chunked(state0, x, dt, a, bm, cm, chunk)
         new_conv = new_ssm = None
     else:
@@ -445,8 +450,9 @@ class GraniteHybridForCausalLM(Layer):
                 cache = init_kv_caches(1, batch, max_len, cfg.num_kv_heads,
                                        cfg.head_dim, dtype=dtype)[0]
             else:
-                cache = {name: Tensor(jnp.zeros((batch,) + tuple(shape), dt))
-                         for name, (shape, dt) in state.items()}
+                cache = {name: Tensor(jnp.zeros((batch,) + tuple(spec[0]),
+                                                spec[1]))
+                         for name, spec in state.items()}
             caches.append(cache)
         # one clock for all layers: ``generate`` advances the first's
         offset = Tensor(jnp.zeros((), jnp.int32))

@@ -53,6 +53,7 @@ from ..nn import functional as F
 from ..nn.initializer import Normal, ParamAttr
 from ..observability.tracing import scope
 from ..tensor_ops import manipulation as MA
+from ..pallas import ssm as _ssm
 from .granite_hybrid import GraniteAttention, GraniteMamba2Mixer
 from .sparse_experts import SparseExpertMLP
 
@@ -149,8 +150,10 @@ class NemotronHConfig:
         the expert layers (which hold nothing: ``storeless_layers``)."""
         state = {
             "conv_state": ((self.mamba_d_conv - 1, self.conv_dim), dtype),
-            "ssm_state": ((self.mamba_n_heads, self.mamba_d_head,
-                           self.mamba_d_state), "float32")}
+            "ssm_state": _ssm.state_spec(self.mamba_n_heads,
+                                         self.mamba_d_head,
+                                         self.mamba_d_state,
+                                         self.mamba_n_groups)}
         return [dict(state) if kind == "mamba" else None
                 for kind in self.layer_types]
 
@@ -263,8 +266,9 @@ class NemotronHForCausalLM(Layer):
                 cache = init_kv_caches(1, batch, max_len, cfg.num_kv_heads,
                                        cfg.head_dim, dtype=dtype)[0]
             else:
-                cache = {name: Tensor(jnp.zeros((batch,) + tuple(shape), dt))
-                         for name, (shape, dt) in state.items()}
+                cache = {name: Tensor(jnp.zeros((batch,) + tuple(spec[0]),
+                                                spec[1]))
+                         for name, spec in state.items()}
             caches.append(cache)
         # one clock for all layers: ``generate`` advances the first's
         offset = Tensor(jnp.zeros((), jnp.int32))

@@ -451,6 +451,7 @@ class Engine:
             layer_latents=self._layer_latents,
             layer_storeless=_storeless_layers(self.cfg))
         stats.set_value("state.bytes", cache.state_bytes)
+        stats.set_value("state.heads_per_tile", cache.state_heads_per_tile)
         stats.set_value("kv.pages_spanned",
                         cache.num_slots * cache.pages_per_slot)
         stats.set_value("kv.pools", cache.pools)

@@ -49,7 +49,9 @@ scratch row, in arrays no page table indexes: the slot is all the
 reservation it needs.  ``reset_state`` zeroes a slot's rows in place at
 admission; a batch row finds its state row through ``state_rows`` (the
 slot, or the scratch row for the surplus rows of a prefill call) and
-says through ``valid_len`` how many of its positions are real.
+says through ``valid_len`` how many of its positions are real.  A state
+stored in another layout than it is read (the lane-packed SSM state,
+``pallas/ssm.py``) names the shape ``read_state`` hands it back in.
 
 **Page tables by layer kind.**  A layer whose attention sees only the
 latest ``window`` positions (``layer_windows``: what the model's config
@@ -99,6 +101,7 @@ from . import stats
 from ..core.tensor import Tensor
 from ..observability import scopes
 from ..observability.tracing import span
+from ..pallas import ssm
 from ..utils.flags import flag as _flag
 
 
@@ -227,6 +230,9 @@ class PagedKVCache:
         #: write (a slot's row is its index)
         self.scratch_row = self.num_slots
         self.layers = []
+        #: (layer, state name) -> the [H, P, N] a lane-packed row is read
+        #: back in
+        self._read_as = {}
         #: per layer, the names of the device arrays a model call updates
         #: (``flat_pools`` order)
         self._keys = []
@@ -245,10 +251,14 @@ class PagedKVCache:
                     raise ValueError("a recurrent layer has no window "
                                      "and no latent rows")
                 # a row a slot, no page table: the recurrent layer's
-                # fixed-size state
+                # fixed-size state — (stored shape, dtype[, the shape a
+                # row is read back in])
                 lay = {name: Tensor(jnp.zeros(
-                    (self.num_slots + 1,) + tuple(shape), dtype=dt))
-                    for name, (shape, dt) in state.items()}
+                    (self.num_slots + 1,) + tuple(spec[0]), dtype=spec[1]))
+                    for name, spec in state.items()}
+                self._read_as.update({(len(self.layers), name): spec[2]
+                                      for name, spec in state.items()
+                                      if len(spec) > 2})
                 self._keys.append(tuple(state))
                 lay.update(state_rows=None, valid_len=None)
                 self.layers.append(lay)
@@ -372,13 +382,26 @@ class PagedKVCache:
                     self.layers[i][k] = Tensor(next(it))
         stats.incr("state.resets")
 
+    @property
+    def state_heads_per_tile(self):
+        """Heads whose channels share one row of lanes in the stored SSM
+        state (``pallas.ssm.heads_per_tile``; 0 with no such state)."""
+        return max((shape[0] // self.layers[i][k].shape[1]
+                    for (i, k), shape in self._read_as.items()), default=0)
+
     def read_state(self, slot):
         """``{layer: {name: array}}``: a host copy of ``slot``'s row of
-        every state array.  The row is as the last call that named the
+        every state array, the SSM state as ``[H, P, N]`` whatever layout
+        it is stored in.  The row is as the last call that named the
         slot left it — a released slot's stays until ``reset_state`` —
         so a snapshot, or a check against a reference, reads it here."""
-        return {i: {k: np.asarray(self.layers[i][k]._data_[slot])
-                    for k in self._keys[i]} for i in self._stateful}
+        def host(i, k):
+            row = np.asarray(self.layers[i][k]._data_[slot])
+            shape = self._read_as.get((i, k))
+            return row if shape is None else ssm.unpack_state(row, shape[0])
+
+        return {i: {k: host(i, k) for k in self._keys[i]}
+                for i in self._stateful}
 
     def read_latent(self, slot):
         """``{layer: array [offset, latent_width]}``: a host copy of the

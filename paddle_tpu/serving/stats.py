@@ -181,6 +181,9 @@ def declare_tick_stats():
                         "state (ms)")
     _registry.gauge(PREFIX + "state.bytes",
                     "bytes of the per-slot recurrent state arrays")
+    _registry.gauge(PREFIX + "state.heads_per_tile",
+                    "heads whose channels share one row of lanes in the "
+                    "lane-packed SSM state (0 without one)")
     _registry.gauge(PREFIX + "kv.pages_spanned",
                     "page-table entries of the decode batch: slots x "
                     "pages a slot")
@@ -429,7 +432,10 @@ def serving_stats():
     admission's in-place reset of its slot's rows), and
     ``state_rows_live_share`` — of the state rows the compiled ticks
     passed through the update, the share that belonged to a decoding
-    request.
+    request; ``state_heads_per_tile``, the heads a row of lanes holds in
+    the lane-packed SSM state, and ``ssm_update_kernel_traces`` /
+    ``ssm_update_xla_lane_traces``, the lanes the one-token state update
+    took when traced (process-wide, like the paged read's).
 
     Speculative-decoding quantities (``speculation_k > 0``, zero
     otherwise): ``spec_windows`` (draft→verify→rollback iterations),
@@ -521,6 +527,10 @@ def serving_stats():
         "prefill_compiled_hits": g("prefill.compiled_hits"),
         "prefill_fallbacks": g("prefill.fallbacks"),
         "state_bytes": g("state.bytes"),
+        "state_heads_per_tile": g("state.heads_per_tile"),
+        "ssm_update_kernel_traces": s.get("pallas.ssm_update.kernel", 0),
+        "ssm_update_xla_lane_traces": s.get("pallas.ssm_update.xla_lane",
+                                            0),
         "state_resets": g("state.resets"),
         "state_reset_ms_avg": avg("state.reset_ms"),
         "state_rows_live_share": (g("state.row_ticks_live")

@@ -583,10 +583,12 @@ _PINNED = {
     # family: (greedy tick, one-row prefill member): sha256[:16] of the
     # StableHLO of a tiny engine's programs, as PR 33's tree traced them
     # (sarvam_mla: as PR 37's tree traced it, pinned in ISSUE 38 before
-    # the latent experts and the grouped norm entered the shared code)
+    # the latent experts and the grouped norm entered the shared code;
+    # granite_hybrid: re-pinned when its SSM state became lane-packed,
+    # ``[H/k, N, k P]``, which every Mamba layer of both programs reads)
     "llama": ("dca425a4f34f1eae", "a0a8ad78c7ff9b02"),
     "cohere_moe": ("fdad0e74642cca58", "3bfb9711f5616c51"),
-    "granite_hybrid": ("6e97f6d411d56c2d", "85229164b3cdb108"),
+    "granite_hybrid": ("562ab8ff00158789", "fae8fa0e61c21f1b"),
     "sarvam_mla": ("30552e1965361817", "0d26d7a780937357"),
 }
 

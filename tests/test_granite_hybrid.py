@@ -23,6 +23,7 @@ from paddle_tpu.serving import (Engine, PagedKVCache, RecurrentStateError,
                                 SamplingParams, ServingConfig,
                                 serving_stats)
 from paddle_tpu.utils import flags as _flags
+from paddle_tpu.utils import monitor
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, os.path.join(ROOT, "chipbench"))
@@ -215,6 +216,7 @@ def test_compiled_tick_runs_the_kernel(tiny, tick_flag, monkeypatch):
     monkeypatch.setattr(ssm, "ssm_update", counted)
     # the mixer is jitted: which lane it took is part of its trace
     granite_hybrid._mamba2_mix.clear_cache()
+    before = monitor.all_stats()
     try:
         with_kernel, st, _ = _serve(model, waves, True, max_new=8)
     finally:
@@ -222,9 +224,38 @@ def test_compiled_tick_runs_the_kernel(tiny, tick_flag, monkeypatch):
     for x, y in zip(plain[0], with_kernel[0]):
         np.testing.assert_array_equal(x, y)
     # the jitted mixer is traced once for all the layers; the state has
-    # slots + 1 rows
-    assert calls and {c[0] for c in calls} == {5}
+    # slots + 1 rows, each lane-packed: 4 heads of 16 a row of 64 lanes
+    assert calls and {c for c in calls} == {(5, 1, 16, 64)}
     assert st["tick_fallbacks"] == 0
+    traced = {lane: st[f"ssm_update_{lane}_traces"]
+              - before.get(f"pallas.ssm_update.{lane}", 0)
+              for lane in ("kernel", "xla_lane")}
+    assert traced == {"kernel": len(calls), "xla_lane": 0}
+
+
+def test_read_state_is_the_unpacked_store(tiny, tick_flag):
+    """The engine's cache keeps each Mamba layer's SSM rows lane-packed
+    (4 heads of 16 a row of lanes here; ``serving_stats()`` says so) and
+    ``read_state`` hands a slot's row back ``[H, P, N]``: the stored row
+    unpacked, element for element."""
+    model = tiny[0]
+    tick_flag["FLAGS_compiled_tick"] = True
+    with Engine(model, _cfg(num_slots=2)) as eng:
+        out = eng.generate(_prompts([19], seed=13)[0], max_new_tokens=5)
+        cache = eng.cache
+        got = cache.read_state(out.slot)
+    st = serving_stats()
+    assert st["state_heads_per_tile"] == cache.state_heads_per_tile == 4
+    assert st["ssm_update_xla_lane_traces"] > 0
+    assert got
+    for i, arrays in got.items():
+        stored = np.asarray(cache.layers[i]["ssm_state"]._data_[out.slot])
+        assert stored.shape == (1, 16, 64)
+        assert arrays["ssm_state"].shape == (4, 16, 16)
+        assert arrays["ssm_state"].dtype == np.float32
+        np.testing.assert_array_equal(arrays["ssm_state"],
+                                      ssm.unpack_state(stored, 4))
+        assert np.abs(arrays["ssm_state"]).max() > 0
 
 
 # (d) ------------------------------------------------------------------
@@ -326,21 +357,57 @@ def test_eos_row_keeps_its_state_through_the_dead_tick(tiny, tick_flag):
 
 
 # (e), (f) -------------------------------------------------------------
-def _ssm_inputs(batch, rows_total, seed=0, heads=8, p=16, n=16, g=1):
+def _ssm_inputs(batch, rows_total, seed=0, heads=8, p=16, n=16, g=1,
+                pack_g=None):
+    """(the state as ``[R, H, P, N]``, the same lane-packed as it is
+    stored for ``pack_g`` groups (default ``g``), x, dt, a, B, C)."""
     ks = jax.random.split(jax.random.PRNGKey(seed), 6)
-    state = jax.random.normal(ks[0], (rows_total, heads, p, n))
+    plain = jax.random.normal(ks[0], (rows_total, heads, p, n))
     x = jax.random.normal(ks[1], (batch, heads, p))
     dt = jax.nn.softplus(jax.random.normal(ks[2], (batch, heads)))
     a = -jnp.exp(jax.random.normal(ks[3], (heads,)))
     bm = jax.random.normal(ks[4], (batch, g, n))
     cm = jax.random.normal(ks[5], (batch, g, n))
-    return state, x, dt, a, bm, cm
+    k = ssm.heads_per_tile(heads, p, pack_g or g)
+    packed = ssm.pack_state(plain, k)
+    return plain, packed, x, dt, a, bm, cm
 
 
-@pytest.mark.parametrize("groups", [1, 2])
-def test_ssm_update_kernel_matches_xla_lane(monkeypatch, groups):
+def test_state_row_shape_by_the_rule():
+    """k = the largest divisor of the heads a group has with k P <= 128;
+    a row ``[H/k, N, k P]``; packing and unpacking are inverse."""
+    assert ssm.state_row_shape(64, 64, 128, 1) == (32, 128, 128)
+    assert ssm.state_row_shape(128, 64, 128, 8) == (64, 128, 128)
+    assert ssm.state_row_shape(8, 128, 128, 8) == (8, 128, 128)
+    assert ssm.state_row_shape(4, 16, 16, 1) == (1, 16, 64)
+    assert ssm.heads_per_tile(8, 16, 2) == 4
+    assert ssm.heads_per_tile(6, 40, 1) == 3
+    assert ssm.heads_per_tile(10, 40, 1) == 2       # 3 does not divide 10
+    assert ssm.heads_per_tile(8, 200, 1) == 1       # wider than the lanes
+    plain = np.arange(3 * 8 * 16 * 4, dtype=np.float32).reshape(3, 8, 16, 4)
+    packed = ssm.pack_state(plain, 4)
+    assert packed.shape == (3, 2, 4, 64)
+    # head 5 (tile 1, second of the tile), channel 3, state index 2
+    assert packed[0, 1, 2, 1 * 16 + 3] == plain[0, 5, 3, 2]
+    np.testing.assert_array_equal(ssm.unpack_state(packed, 8), plain)
+
+
+# the first two cases are the ones this test had (ids: their groups)
+@pytest.mark.parametrize("heads, p, n, groups, pack_groups", [
+    (8, 16, 16, 1, 1), (8, 16, 16, 2, 2), (16, 64, 32, 8, 8),
+    (8, 16, 16, 8, 8), (4, 128, 16, 1, 1), (8, 16, 16, 8, 1)],
+    ids=["1", "2", "g8", "one-head-a-group", "p128", "tile-spans-groups"])
+def test_ssm_update_kernel_matches_xla_lane(monkeypatch, heads, p, n,
+                                            groups, pack_groups):
+    """The kernel (interpreter) against the XLA lane over the lane-packed
+    state: k heads a row of lanes (8 and 4 of P 16, 2 of P 64 with
+    Nemotron's 8 groups), a head a group, a head of 128 lanes alone, and
+    a row packed for one group handed a B and C a head (a tile's heads
+    then read different groups)."""
     monkeypatch.setenv("PADDLE_TPU_PALLAS_INTERPRET", "1")
-    state, x, dt, a, bm, cm = _ssm_inputs(4, 7, g=groups)
+    _, state, x, dt, a, bm, cm = _ssm_inputs(4, 7, heads=heads, p=p, n=n,
+                                             g=groups, pack_g=pack_groups)
+    assert state.shape[1:] == ssm.state_row_shape(heads, p, n, pack_groups)
     dt = dt.at[2].set(0.0)                  # a row that is not decoding
     rows = jnp.asarray([5, 0, 3, 6], jnp.int32)     # 6: the scratch row
     want_s, want_y = ssm.ssm_step_xla(state, rows, x, dt, a, bm, cm)
@@ -350,6 +417,23 @@ def test_ssm_update_kernel_matches_xla_lane(monkeypatch, groups):
     # rows the call did not name, and the dt = 0 row, are as they were
     for untouched in (1, 2, 4, 3):
         np.testing.assert_array_equal(got_s[untouched], state[untouched])
+
+
+def test_packed_step_is_the_plain_recurrence():
+    """The XLA lane over the packed state is, element for element, the
+    recurrence over ``[H, P, N]`` by hand — the same float32 operations
+    in the same order (``y``: the same sum, another order)."""
+    plain, state, x, dt, a, bm, cm = _ssm_inputs(3, 5, heads=8, p=16,
+                                                 n=16, g=2)
+    rows = jnp.asarray([4, 1, 2], jnp.int32)
+    got_s, got_y = ssm.ssm_step_xla(state, rows, x, dt, a, bm, cm)
+    bh = jnp.repeat(bm, 4, axis=1)[:, :, None, :]
+    ch = jnp.repeat(cm, 4, axis=1)[:, :, None, :]
+    s = plain[rows] * jnp.exp(dt * a)[:, :, None, None] + \
+        (dt[:, :, None] * x)[..., None] * bh
+    np.testing.assert_array_equal(ssm.unpack_state(got_s, 8)[rows], s)
+    np.testing.assert_allclose(got_y, jnp.sum(s * ch, -1), rtol=1e-6,
+                               atol=1e-6)
 
 
 @pytest.mark.parametrize("sub_chunk", [64, 5], ids=["whole", "scanned"])
@@ -365,14 +449,15 @@ def test_chunked_form_matches_recurrence_across_chunks(sub_chunk):
     bm = jax.random.normal(ks[3], (batch, total, 1, n))
     cm = jax.random.normal(ks[4], (batch, total, 1, n))
     rows = jnp.arange(batch, dtype=jnp.int32)
-    s_rec = jnp.zeros((batch, heads, p, n))
+    row = ssm.state_row_shape(heads, p, n, 1)
+    s_rec = jnp.zeros((batch,) + row)
     ys = []
     for t in range(total):
         s_rec, y = ssm.ssm_step_xla(s_rec, rows, x[:, t], dt[:, t], a,
                                     bm[:, t], cm[:, t])
         ys.append(y)
     y_rec = jnp.stack(ys, axis=1)
-    s, pos, parts = jnp.zeros((batch, heads, p, n)), 0, []
+    s, pos, parts = jnp.zeros((batch,) + row), 0, []
     for length in lens:                     # the state carried across
         sl = slice(pos, pos + length)
         s, y = ssm.ssd_chunked(s, x[:, sl], dt[:, sl], a, bm[:, sl],
@@ -382,6 +467,50 @@ def test_chunked_form_matches_recurrence_across_chunks(sub_chunk):
     np.testing.assert_allclose(jnp.concatenate(parts, 1), y_rec,
                                rtol=2e-4, atol=2e-4)
     np.testing.assert_allclose(s, s_rec, rtol=2e-4, atol=2e-4)
+
+
+@pytest.mark.parametrize("lane", ["xla_lane", "kernel"])
+def test_prefill_then_decode_is_the_whole_sequence(monkeypatch, lane):
+    """A 13-token prompt through the chunked form into a stored row
+    (gathered and scattered in the packed layout, as the prefill member
+    does), then 6 tokens one at a time through ``ssm_step``: the outputs
+    and the last state are those of the chunked form over all 19 tokens,
+    and the state survives a round trip through ``[H, P, N]``."""
+    if lane == "kernel":
+        monkeypatch.setenv("PADDLE_TPU_PALLAS_INTERPRET", "1")
+    heads, p, n, g, total, prompt = 8, 16, 16, 2, 19, 13
+    ks = jax.random.split(jax.random.PRNGKey(9), 5)
+    x = jax.random.normal(ks[0], (1, total, heads, p))
+    dt = jax.nn.softplus(jax.random.normal(ks[1], (1, total, heads)))
+    a = -jnp.exp(jax.random.normal(ks[2], (heads,)))
+    bm = jax.random.normal(ks[3], (1, total, g, n))
+    cm = jax.random.normal(ks[4], (1, total, g, n))
+    row = ssm.state_row_shape(heads, p, n, g)
+    whole_s, whole_y = ssm.ssd_chunked(jnp.zeros((1,) + row), x, dt, a, bm,
+                                       cm, 8)
+    store = jnp.zeros((3,) + row)
+    rows = jnp.asarray([1], jnp.int32)
+    s, y = ssm.ssd_chunked(store[rows], x[:, :prompt], dt[:, :prompt], a,
+                           bm[:, :prompt], cm[:, :prompt], 8)
+    store = store.at[rows].set(s)
+    ys = [y]
+    before = monitor.all_stats().get("pallas.ssm_update." + lane, 0)
+    # a function of its own: which lane it takes is part of its trace
+    step = jax.jit(lambda *args: ssm.ssm_step(*args))
+    for t in range(prompt, total):
+        store, y = step(store, rows, x[:, t], dt[:, t], a, bm[:, t],
+                        cm[:, t])
+        ys.append(y[:, None])
+    assert monitor.all_stats().get("pallas.ssm_update." + lane, 0) \
+        == before + 1                       # one trace, by its lane
+    np.testing.assert_allclose(jnp.concatenate(ys, 1), whole_y, rtol=2e-4,
+                               atol=2e-4)
+    np.testing.assert_allclose(store[1], whole_s[0], rtol=2e-4, atol=2e-4)
+    np.testing.assert_array_equal(store[0], 0.0)   # rows not named
+    plain = ssm.unpack_state(store, heads)
+    assert plain.shape == (3, heads, p, n)
+    np.testing.assert_array_equal(
+        ssm.pack_state(plain, ssm.heads_per_tile(heads, p, g)), store)
 
 
 # (g) ------------------------------------------------------------------

@@ -591,23 +591,31 @@ def test_paged_decode_through_the_op_compiles(v5e):
 #         rows slots+1 heads head state groups
 _SSM = {
     "granite-4.0-h-micro-64-slots": (64, 65, 64, 64, 128, 1),
+    "nemotron-3-super-128-slots": (128, 129, 128, 64, 128, 8),
     "prefill-bucket-of-2": (2, 65, 64, 64, 128, 1),
     "two-groups": (8, 9, 16, 64, 128, 2),
+    "head-of-128-lanes": (8, 9, 8, 128, 128, 8),
 }
 
 
 @pytest.mark.parametrize("case", sorted(_SSM))
 def test_ssm_update_compiles(v5e, case):
-    """The decode step's in-place state update at Granite 4.0-H Micro's
-    widths: one kernel, the state aliased in and out."""
+    """The decode step's in-place state update at both serving cells'
+    widths (Granite 4.0-H Micro, Nemotron-3-Super) and a head that fills
+    the lanes alone: one kernel over the lane-packed state, aliased in
+    and out, and no copy of the state in the compiled program."""
     from paddle_tpu.pallas import ssm
     b, r, h, p, n, g = _SSM[case]
+    row = ssm.state_row_shape(h, p, n, g)
+    assert row[-1] == min(128, p * (h // g))
     text = _compile(ssm.ssm_step, [
-        ((r, h, p, n), F32), ((b,), jnp.int32), ((b, h, p), F32),
+        ((r, *row), F32), ((b,), jnp.int32), ((b, h, p), F32),
         ((b, h), F32), ((h,), F32), ((b, g, n), F32), ((b, g, n), F32)],
-        SingleDeviceSharding(v5e[0]))
+        SingleDeviceSharding(v5e[0]), compiled=True, donate=(0,))
     assert _n_kernels(text) == 1
     assert "ssm_update" in text
+    state = f"f32[{','.join(str(d) for d in (r, *row))}]"
+    assert not re.search(r"= " + re.escape(state) + r"\S* copy", text)
 
 
 # ----------------------------------------------------------- lora delta
