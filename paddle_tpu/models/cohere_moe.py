@@ -187,6 +187,14 @@ class CohereMoeAttention(Layer):
         self.o_proj = Linear(config.num_heads * d, h, weight_attr=out_init,
                              bias_attr=False)
 
+    #: the op the rotation is traced as
+    rotation_name = "rope_interleaved"
+
+    def rotate(self, x, pos):
+        """``x`` [B, S, H, D] (a raw array) rotated by ``pos`` [B, S] or
+        [S]: this family's interleaved pairs at ``rope_theta``."""
+        return rope_interleaved(x, pos, self.config.rope_theta)
+
     def forward(self, x, cache=None):
         cfg = self.config
         b, s, _ = x.shape
@@ -196,17 +204,15 @@ class CohereMoeAttention(Layer):
         v = MA.reshape(self.v_proj(x), [b, s, cfg.num_kv_heads, d])
         window = self.window
         if self.rotary:
-            theta = cfg.rope_theta
             off = None if cache is None else cache["offset"]
 
             def rotate(qa, ka, *off):
                 pos = jnp.arange(s, dtype=jnp.int32)
                 if off:
                     pos = off[0].astype(jnp.int32).reshape(-1, 1) + pos[None]
-                return (rope_interleaved(qa, pos, theta),
-                        rope_interleaved(ka, pos, theta))
+                return self.rotate(qa, pos), self.rotate(ka, pos)
 
-            q, k = apply_op("rope_interleaved", rotate,
+            q, k = apply_op(self.rotation_name, rotate,
                             (q, k) if off is None else (q, k, off))
         if cache is None:
             out = apply_op(

@@ -22,7 +22,7 @@ kernel of ``pallas/mla.py``.
 **Rotation**: ``deepseek_yarn`` over the ``rope`` dims, interleaved
 pairs ``(x_2m, x_2m+1)``: per frequency a blend of ``theta^(-2m/rope)``
 and the same over ``factor`` by the linear ramp between the correction
-dims of ``beta_fast`` and ``beta_slow`` (``yarn_inv_freq``); the
+dims of ``beta_fast`` and ``beta_slow`` (``models/rotary.py``); the
 cos/sin multiplier ``mscale / mscale_all_dim`` form is 1 for the
 published values; the softmax scale is ``(nope + rope)^-1/2 * (0.1 *
 mscale_all_dim * ln(factor) + 1)^2`` (``yarn_softmax_scale``).
@@ -52,7 +52,6 @@ are STACKED as in ``cohere_moe`` (``mlp.experts.{gate,up,down}_proj``
 """
 from __future__ import annotations
 
-import math
 from dataclasses import dataclass, field
 
 import jax
@@ -63,6 +62,8 @@ from ..core.dispatch import apply_op
 from ..nn import Layer, Linear, Embedding, RMSNorm, LayerList
 from ..nn import functional as F
 from ..nn.initializer import Normal, ParamAttr
+from .rotary import (
+    rope_pairs, yarn_cos_sin_scale, yarn_inv_freq, yarn_softmax_scale)
 from .sparse_experts import SparseExpertMLP
 from ..tensor_ops import manipulation as MA
 from ..incubate.nn import functional as IF
@@ -157,62 +158,6 @@ TINY_SARVAM_MLA = dict(
     held_experts=(0, 4), max_seq_len=64, initializer_range=0.1,
     rope_scaling=dict(_yarn(), factor=4.0,
                       original_max_position_embeddings=16))
-
-
-def _yarn_mscale(factor, mscale):
-    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
-
-
-def yarn_softmax_scale(q_head_dim, scaling):
-    """``q_head_dim^-1/2 * mscale(factor, mscale_all_dim)^2``."""
-    m = _yarn_mscale(scaling["factor"], scaling.get("mscale_all_dim", 0.0)) \
-        if scaling.get("mscale_all_dim", 0.0) else 1.0
-    return m * m / math.sqrt(q_head_dim)
-
-
-def yarn_cos_sin_scale(scaling):
-    """What multiplies cos and sin: ``mscale(factor, mscale) /
-    mscale(factor, mscale_all_dim)`` (1 for equal multipliers)."""
-    f = scaling["factor"]
-    return _yarn_mscale(f, scaling.get("mscale", 1.0)) / \
-        _yarn_mscale(f, scaling.get("mscale_all_dim", 0.0))
-
-
-def yarn_inv_freq(dim, theta, scaling):
-    """float32 [dim / 2]: per rotated pair, the blend of the plain
-    frequency ``theta^(-2m/dim)`` (kept where the pair turns more than
-    ``beta_fast`` times over the original context) and the same over
-    ``factor`` (where it turns fewer than ``beta_slow`` times), linear
-    between the two correction dims."""
-    factor = float(scaling["factor"])
-    orig = scaling["original_max_position_embeddings"]
-
-    def correction_dim(turns):
-        return dim * math.log(orig / (turns * 2 * math.pi)) \
-            / (2 * math.log(theta))
-
-    low = max(math.floor(correction_dim(scaling["beta_fast"])), 0)
-    high = min(math.ceil(correction_dim(scaling["beta_slow"])), dim - 1)
-    plain = 1.0 / (theta ** (jnp.arange(0, dim, 2, dtype=jnp.float32) / dim))
-    ramp = jnp.clip((jnp.arange(dim // 2, dtype=jnp.float32) - low)
-                    / max(high - low, 1e-3), 0.0, 1.0)
-    return plain / factor * ramp + plain * (1.0 - ramp)
-
-
-def rope_pairs(x, pos, inv_freq, mult=1.0):
-    """Rotate ``x`` [B, S, ..., D] by ``pos`` [B, S] (or [S]) over the
-    interleaved pairs ``(x_2m, x_2m+1)`` at ``inv_freq`` [D / 2];
-    float32 arithmetic, ``x``'s type back."""
-    ang = pos.astype(jnp.float32)[..., None] * inv_freq          # [.., D/2]
-    if ang.ndim == 2:
-        ang = ang[None]
-    ang = ang.reshape(ang.shape[:2] + (1,) * (x.ndim - 3) + ang.shape[-1:])
-    cos, sin = jnp.cos(ang) * mult, jnp.sin(ang) * mult
-    xf = x.astype(jnp.float32)
-    even, odd = xf[..., 0::2], xf[..., 1::2]
-    out = jnp.stack([even * cos - odd * sin, odd * cos + even * sin],
-                    axis=-1)
-    return out.reshape(x.shape).astype(x.dtype)
 
 
 def _whole_sequence_latent_attention(q, row, w_kvb, nope, scale):

@@ -1,12 +1,15 @@
 """The expert layer that is told which experts it holds, as the decoders
 with sparse experts share it (``models/cohere_moe.py``,
-``models/sarvam_mla.py``).
+``models/sarvam_mla.py``, ``models/nemotron_h.py``, ``models/mellum.py``).
 
 ``s = sigmoid(W_r x)`` in float32 over all published experts; ``T`` the
 ``top_k`` of ``s`` (of ``s + b`` with a selection bias ``b``, which moves
 the CHOICE alone); ``g_e = gate_scale * s_e / sum_{j in T} s_j`` (no
-``gate_scale``: 1); the layer gives ``sum_{e in T, e held} g_e f_e(x)``
-plus its shared experts' outputs, their mean or their sum, each ``f`` an
+``gate_scale``: 1).  With ``scoring="softmax"`` the scores are ``p =
+softmax(W_r x)`` over all experts instead, and ``g_e = p_e / sum_{j in
+T} p_j`` (no bias, no scale).  The layer gives ``sum_{e in T, e held}
+g_e f_e(x)`` plus its shared experts' outputs (none where
+``num_shared`` is 0), their mean or their sum, each ``f`` an
 MLP of ``expert_width`` with the activation ``expert_act``: "swiglu"
 (``(silu(x Wg) * (x Wu)) Wd``) or "relu2" (``relu(x Wu)^2 Wd``, one up
 matrix).  ``held_experts`` = (first, count) is one chip's share: the
@@ -25,7 +28,8 @@ Leaves: ``gate.weight`` [h, E] the router (``gate.expert_bias`` [E] the
 selection bias), ``experts.{gate,up,down}_proj`` [E_held, in, out] (no
 ``gate_proj`` for "relu2"), ``shared_experts.*`` [S, in, out];
 ``fc1_latent_proj.weight`` [h, latent] and ``fc2_latent_proj.weight``
-[latent, h] in a latent layer.
+[latent, h] in a latent layer.  A layer without shared experts has no
+``shared_experts`` leaves.
 """
 from __future__ import annotations
 
@@ -87,20 +91,27 @@ class SparseExpertMLP(Layer):
     where it is not ``std``; ``expert_act`` the routed experts'
     activation, ``latent_size`` the width they work at (None: ``h``);
     ``shared_width`` the shared experts' width (None: the routed
-    experts'), of the routed experts' activation."""
+    experts'), of the routed experts' activation; ``scoring`` the
+    router's, "sigmoid" or "softmax" (``pallas.moe.SCORINGS``)."""
 
     def __init__(self, hidden_size, expert_width, num_experts, held_experts,
                  top_k, num_shared, std, down_std=None, selection_bias=False,
                  gate_scale=None, shared_reduce="mean", expert_act="swiglu",
-                 latent_size=None, shared_width=None):
+                 latent_size=None, shared_width=None, scoring="sigmoid"):
         super().__init__()
         if shared_reduce not in ("mean", "sum"):
             raise ValueError(f"shared_reduce {shared_reduce!r}: the shared "
                              "experts' outputs are averaged ('mean') or "
                              "added ('sum')")
+        if scoring not in _moe.SCORINGS:
+            raise ValueError(f"scoring {scoring!r}: one of {_moe.SCORINGS}")
+        if scoring == "softmax" and (selection_bias or gate_scale):
+            raise ValueError("a softmax router takes no selection bias "
+                             "and no gate scale")
         down_std = std if down_std is None else down_std
         self.held_experts, self.top_k = tuple(held_experts), top_k
         self.gate_scale = gate_scale
+        self.scoring = scoring
         self.gate = Router(hidden_size, num_experts, std, selection_bias)
         inner = latent_size or hidden_size
         self.experts = ExpertStack(inner, expert_width,
@@ -108,7 +119,7 @@ class SparseExpertMLP(Layer):
                                    expert_act)
         self.shared_experts = ExpertStack(
             hidden_size, shared_width or expert_width, num_shared, std,
-            down_std, expert_act)
+            down_std, expert_act) if num_shared else None
         self.latent = latent_size is not None
         if self.latent:
             self.fc1_latent_proj = Linear(
@@ -119,13 +130,15 @@ class SparseExpertMLP(Layer):
                 weight_attr=ParamAttr(initializer=Normal(0.0, down_std)))
         #: what the sum of the shared experts' outputs is multiplied by
         #: (None: it stands as it is)
-        self.shared_scale = 1.0 / num_shared if shared_reduce == "mean" \
-            else None
+        self.shared_scale = 1.0 / num_shared \
+            if shared_reduce == "mean" and num_shared else None
 
     def route(self, tokens, wr, bias=None):
         """(experts [T, k] int32, gates [T, k] float32) of ``tokens``
         [T, h]: raw arrays in, raw arrays out."""
         logits = _moe.router_logits(tokens, wr)
+        if self.scoring == "softmax":
+            return _moe.route_softmax_topk(logits, self.top_k)
         if bias is None and self.gate_scale is None:
             # a family with neither passes neither: what stands in for
             # the routing in a test of it takes (logits, k)
@@ -195,7 +208,12 @@ class SparseExpertMLP(Layer):
     def forward(self, x, cache=None):
         b, s, h = x.shape
         valid = None if cache is None else cache.get("valid_len")
+        y, counts = self.routed(x, valid)
+        if valid is not None:
+            cache["moe_counts"] = counts
         sh = self.shared_experts
+        if sh is None:
+            return y
         act = sh.act
 
         def shared(xa, *ws):
@@ -217,13 +235,9 @@ class SparseExpertMLP(Layer):
                 acc = acc * self.shared_scale
             return acc.astype(xa.dtype).reshape(b, s, h)
 
-        y, counts = self.routed(x, valid)
         name = "shared_experts" if self.shared_scale is not None \
             else "shared_experts_sum"
         if act != "swiglu":
             name += f"_{act}"
         with scope("moe_shared"):
-            y = y + apply_op(name, shared, (x,) + sh.matrices())
-        if valid is not None:
-            cache["moe_counts"] = counts
-        return y
+            return y + apply_op(name, shared, (x,) + sh.matrices())

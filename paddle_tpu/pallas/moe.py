@@ -67,6 +67,20 @@ def route_sigmoid_topk(router_logits, k, bias=None, scale=None):
     return experts, gates if scale is None else gates * scale
 
 
+def route_softmax_topk(router_logits, k):
+    """``router_logits`` [T, E] float32 -> (experts [T, k] int32, gates
+    [T, k] float32): softmax over ALL ``E`` experts, its ``k`` largest
+    probabilities, renormalised to sum 1 over the chosen
+    (``norm_topk_prob``, as the Qwen-MoE and OLMoE families route)."""
+    probs = jax.nn.softmax(router_logits.astype(jnp.float32), axis=-1)
+    top, idx = jax.lax.top_k(probs, k)
+    return idx.astype(jnp.int32), top / jnp.sum(top, axis=-1, keepdims=True)
+
+
+#: the router's scorings: ``route_<scoring>_topk``
+SCORINGS = ("sigmoid", "softmax")
+
+
 def row_tile(tokens):
     """Rows of one tile of the sorted buffer, from the call's tokens: a
     decode tick's few rows an expert want the smallest tile the matrix

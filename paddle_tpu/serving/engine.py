@@ -40,6 +40,7 @@ from .api import (DeadlineExceededError, EngineShutdownError,
                   RequestCancelledError, RequestOutput, SamplingParams,
                   SchedulerStallError, ServingConfig, WindowLayerError)
 from ..models.generation import recurrent_layer_states
+from ..models.sparse_experts import SparseExpertMLP
 
 
 class _Request:
@@ -460,6 +461,14 @@ class Engine:
         stats.set_value("kv.latent_row_bytes", cache.latent_row_bytes)
         stats.set_value("kv.layers_without_store",
                         cache.layers_without_store)
+        # a stand-in model of the tests may be no ``Layer``
+        layers = self.model.sublayers() \
+            if hasattr(self.model, "sublayers") else ()
+        scorings = [m.scoring for m in layers
+                    if isinstance(m, SparseExpertMLP)]
+        for scoring in stats.SCORINGS:
+            stats.set_value(f"moe.router_layers.{scoring}",
+                            scorings.count(scoring))
         self.prefix_tree = PrefixTree(self._page_size) \
             if self.scfg.enable_prefix_cache else None
         # one compiled prefill program: every chunk is this wide

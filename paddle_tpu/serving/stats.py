@@ -11,6 +11,7 @@ tokens/sec) from the raw counters at read time.
 """
 from __future__ import annotations
 
+from ..pallas.moe import SCORINGS
 from ..utils import monitor
 
 PREFIX = "serving."
@@ -202,6 +203,9 @@ def declare_tick_stats():
     _registry.gauge(PREFIX + "kv.layers_without_store",
                     "layers that hold no page and no state row: a "
                     "feed-forward part alone")
+    for scoring in SCORINGS:
+        _registry.gauge(PREFIX + f"moe.router_layers.{scoring}",
+                        f"expert layers whose router scores by {scoring}")
 
 
 def declare_migration_stats():
@@ -408,6 +412,8 @@ def serving_stats():
     rings held over the pages one shared table would have held for those
     layers, summed over the compiled ticks — and
     ``window_pages_reclaimed`` (ring entries reused);
+    ``expert_router_scoring`` — what the expert layers' routers score
+    by, "sigmoid" or "softmax" (None without expert layers) —,
     ``expert_pairs_per_token`` — (token, held expert) pairs the expert
     layers computed over the tokens they routed (``k * held / experts``
     for an even router) — and ``expert_gmm_kernel_traces`` /
@@ -552,6 +558,9 @@ def serving_stats():
         "mla_decode_kernel_traces": s.get("pallas.mla_decode.kernel", 0),
         "mla_decode_xla_lane_traces": s.get("pallas.mla_decode.xla_lane",
                                             0),
+        "expert_router_scoring": "+".join(
+            n for n in SCORINGS
+            if g(f"moe.router_layers.{n}")) or None,
         "expert_pairs_per_token": (g("moe.pairs_local")
                                    / g("moe.tokens_routed"))
         if g("moe.tokens_routed") else None,

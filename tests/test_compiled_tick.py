@@ -590,6 +590,8 @@ _PINNED = {
     "cohere_moe": ("fdad0e74642cca58", "3bfb9711f5616c51"),
     "granite_hybrid": ("562ab8ff00158789", "fae8fa0e61c21f1b"),
     "sarvam_mla": ("30552e1965361817", "0d26d7a780937357"),
+    # mellum: softmax routing, two rotations by layer kind
+    "mellum": ("adaceb468a6c394f", "f2c1ef2046e337f3"),
 }
 
 _HASH_SCRIPT = r"""
@@ -621,6 +623,8 @@ from paddle_tpu.models.granite_hybrid import (
     TINY_GRANITE_HYBRID, GraniteHybridConfig, GraniteHybridForCausalLM)
 from paddle_tpu.models.sarvam_mla import (
     TINY_SARVAM_MLA, SarvamMLAConfig, SarvamMLAForCausalLM)
+from paddle_tpu.models.mellum import (
+    TINY_MELLUM, MellumConfig, MellumForCausalLM)
 print(json.dumps({
     "llama": programs(LlamaForCausalLM(llama_config(
         "tiny", vocab_size=256, max_seq_len=64)), 256),
@@ -630,13 +634,15 @@ print(json.dumps({
         GraniteHybridConfig(**TINY_GRANITE_HYBRID)),
         TINY_GRANITE_HYBRID["vocab_size"]),
     "sarvam_mla": programs(SarvamMLAForCausalLM(
-        SarvamMLAConfig(**TINY_SARVAM_MLA)), TINY_SARVAM_MLA["vocab_size"])}))
+        SarvamMLAConfig(**TINY_SARVAM_MLA)), TINY_SARVAM_MLA["vocab_size"]),
+    "mellum": programs(MellumForCausalLM(MellumConfig(**TINY_MELLUM)),
+                       TINY_MELLUM["vocab_size"])}))
 """
 
 
 @pytest.fixture(scope="module")
 def family_program_hashes():
-    """The four families' tick and prefill programs, hashed in a fresh
+    """The families' tick and prefill programs, hashed in a fresh
     process (names and counters of this process's earlier traces stay out
     of the text)."""
     import json

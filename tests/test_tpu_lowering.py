@@ -380,6 +380,11 @@ _WINDOWED = {
     "window-ring": (32, 128, 8, 128, 16, 289, 4096),
     "full-table-of-the-same-model": (32, 128, 8, 128, 16, 512, None),
     "window-not-a-page-multiple": (4, 32, 8, 128, 16, 40, 500),
+    # Mellum2's tick: 32 query heads on 4 kv heads, 64 slots; a window
+    # layer's ring of (1024 + 512) / 16 + 1 pages, the full layer's
+    # table of 13824 / 16
+    "window-ring-of-4-kv-heads": (64, 32, 4, 128, 16, 97, 1024),
+    "full-table-of-4-kv-heads": (64, 32, 4, 128, 16, 864, None),
 }
 
 
@@ -411,6 +416,9 @@ _GMM = {
     "tick-32-sessions": (32, 4096, 4096, 16, 8),
     "prefill-chunk-512": (512, 4096, 4096, 16, 8),
     "prefill-4-rows": (2048, 4096, 4096, 16, 8),
+    # Mellum2: all 64 experts of width 896 held, hidden 2304
+    "tick-64-sessions-all-64-held": (64, 2304, 896, 64, 8),
+    "prefill-4-rows-all-64-held": (2048, 2304, 896, 64, 8),
 }
 
 
