@@ -511,12 +511,14 @@ def latent_phase(dry_run):
         ("pallas.mla_decode.kernel", "pallas.mla_decode.xla_lane",
          "pallas.expert_gmm.kernel", "pallas.expert_gmm.xla_lane"))
     say(f"latent: kv_latent_pools={snap['kv_latent_pools']} "
-        f"kv_latent_row_bytes={snap['kv_latent_row_bytes']}")
+        f"kv_latent_row_bytes={snap['kv_latent_row_bytes']} "
+        f"mla_decode_pages_per_step={snap['mla_decode_pages_per_step']}")
     assert snap["kv_latent_pools"] == cfg.num_layers
     assert snap["kv_pools"] == 0
     if not dry_run:
         assert lanes["pallas.mla_decode.kernel"] > 0, lanes
         assert lanes["pallas.mla_decode.xla_lane"] == 0, lanes
+        assert snap["mla_decode_pages_per_step"] > 0, snap
 
 
 def latent_moe_phase(dry_run):

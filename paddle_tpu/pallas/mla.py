@@ -24,7 +24,9 @@ value is a lane-aligned slice of the key.  The query is padded alike
 walk with one pool: grid over rows, the pool stays in HBM, a step copies
 ``group`` of the row's live pages into one of two VMEM buffers through
 the scalar-prefetched page table while the step before computes; the
-loop runs ``ceil(live / group)`` times.  A step's scores are ``[H, c] =
+loop runs ``ceil(live / group)`` times.  A step whose pages are all live
+issues its copies as one unrolled run and waits on them once
+(``_step_copies``).  A step's scores are ``[H, c] =
 q [H, lanes] . rows[c, lanes]^T`` — every head against every row, one
 MXU product, K and V read from the same buffer once.
 
@@ -32,7 +34,8 @@ The XLA lane (``mla_decode_xla``) is the same absorbed arithmetic
 through the blocked online softmax of every XLA paged read: the kernel's
 reference, the lane of CPUs and partitioned programs.  Which lane a
 read took is decided where the op is traced and counted there
-(``pallas.mla_decode.kernel`` / ``pallas.mla_decode.xla_lane``).
+(``pallas.mla_decode.kernel`` / ``pallas.mla_decode.xla_lane``; the
+gauge ``pallas.mla_decode.pages_per_step`` holds the kernel's ``group``).
 """
 from __future__ import annotations
 
@@ -44,16 +47,14 @@ import jax.numpy as jnp
 from .flash_attention import (NEG_INF, _interpret, _mxu_f32,
                               _unsharded_kernels_on)
 
-#: bytes of latent rows one kernel step streams.  A step costs ~0.4 us
-#: whatever it holds and computes its whole buffer whatever part of it is
-#: live.  Measured on the chip at rows of 640 bfloat16 lanes
-#: (docs/perf/ubench_mla_decode.pr34.log): 8, 16 and 32 pages a step at
-#: contexts of 1 k, 8 k and 16 k, each faster than the one before; 64,
-#: 128 and 256 at 1 k ALONE, where 64 pages are one step and the fastest;
-#: end to end, the cell served 13.5 % more tokens at 64 than at 16.  The
-#: sweep past 32 at 8 k and 16 k is owed (ROADMAP 2a) before this is
-#: called settled.
-_STEP_BYTES = 2 << 20
+#: bytes of latent rows one kernel step streams.  A step computes its
+#: whole buffer whatever part of it is live.  Measured on the chip at rows
+#: of 640 bfloat16 lanes, 56 rows a call, with one-wait steps
+#: (docs/perf/ubench_mla_decode.pr42.log): at 8 k and 16 k, 32 / 64 / 128
+#: / 256 pages a step take 1168 / 996 / 919 / 1020 us and 2293 / 1950 /
+#: 1786 / 1987 us; at 1 k, where a row is 65 pages, 128 takes 279 us
+#: against 64's 169 — the serving cell's rows are 8-16 k long.
+_STEP_BYTES = 4 << 20
 
 
 def latent_row_lanes(width):
@@ -73,34 +74,82 @@ def mla_decode_pages_per_step(page_size, lanes, itemsize):
     return 1 << (g.bit_length() - 1)
 
 
+def _step_copies(pt_ref, off_ref, pool_hbm, buf, sem, *, page_size, group):
+    """``(start, wait)`` of the copies of step ``g`` of a row's live pages
+    into ``buf[slot]`` (``[group, page_size, lanes]``) on ``sem[slot]``,
+    each called as ``(row, g, slot)``.
+
+    A FULL step (all ``group`` of its pages live: every step of a row but
+    its last) starts its copies in one unrolled run of ``group`` and is
+    waited on ONCE, by one descriptor of the whole slot: a DMA wait takes
+    its descriptor's bytes off the semaphore, and the slot's bytes are
+    the ``group`` pages' together.  The partial step a row ends on starts
+    and waits a page at a time, in a loop of its live count.  A step's
+    start and wait make the same test, so they always agree."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    def page(row, first, j, slot):
+        return pltpu.make_async_copy(pool_hbm.at[pt_ref[row, first + j]],
+                                     buf.at[slot, j], sem.at[slot])
+
+    def choreography(start):
+        def run(row, g, slot):
+            first = g * group
+            live = off_ref[row] // page_size + 1 - first
+
+            @pl.when(live >= group)
+            def _full():
+                if start:
+                    for j in range(group):
+                        page(row, first, j, slot).start()
+                else:
+                    pltpu.make_async_copy(buf.at[slot], buf.at[slot],
+                                          sem.at[slot]).wait()
+
+            @pl.when(live < group)
+            def _partial():
+                def one(j, carry):
+                    c = page(row, first, j, slot)
+                    if start:
+                        c.start()
+                    else:
+                        c.wait()
+                    return carry
+                jax.lax.fori_loop(0, live, one, 0)
+        return run
+    return choreography(True), choreography(False)
+
+
+def _online_step(q, kv, live, carry, *, scale, v_width):
+    """One step of the online softmax: every head of ``q [H, lanes]``
+    against the step's rows ``kv [cols, lanes]`` (keys whole, values
+    their first ``v_width`` lanes); ``live [H, cols]`` masks the
+    positions past the row's offset."""
+    m_prev, l_prev, acc = carry
+    s = _mxu_f32(q, kv, 1) * scale
+    s = jnp.where(live, s, NEG_INF)
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+    p = jnp.exp(s - m_new)
+    alpha = jnp.exp(m_prev - m_new)
+    l_new = alpha * l_prev + jnp.sum(p, axis=-1, keepdims=True)
+    pv = _mxu_f32(p.astype(kv.dtype), kv[:, :v_width], 0)
+    return m_new, l_new, alpha * acc + pv
+
+
 def _mla_decode_kernel(pt_ref, off_ref, q_ref, pool_hbm, o_ref, buf, sem,
                        slot_ref, *, scale, page_size, group, v_width):
     """One row of a single-token latent decode: the row's LIVE pages,
-    ``group`` of them a step, every head at once against the same rows
-    (see the module's text; the copies' choreography is
-    ``_paged_decode_kernel``'s)."""
+    ``group`` of them a step (copies: ``_step_copies``), every head at
+    once against the same rows (see the module's text)."""
     from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
 
     b = pl.program_id(0)
     rows = pl.num_programs(0)
     h = q_ref.shape[0]
     cols = group * page_size
-
-    def live_pages(row):
-        return off_ref[row] // page_size + 1
-
-    def copies(row, g, slot, act):
-        first = g * group
-
-        def one(j, carry):
-            page = pt_ref[row, first + j]
-            at = pl.ds(pl.multiple_of(j * page_size, page_size), page_size)
-            act(pltpu.make_async_copy(pool_hbm.at[page], buf.at[slot, at],
-                                      sem.at[slot]))
-            return carry
-        jax.lax.fori_loop(0, jnp.minimum(live_pages(row) - first, group),
-                          one, 0)
+    start, wait = _step_copies(pt_ref, off_ref, pool_hbm, buf, sem,
+                               page_size=page_size, group=group)
 
     @pl.when(b == 0)
     def _first_row():
@@ -108,36 +157,30 @@ def _mla_decode_kernel(pt_ref, off_ref, q_ref, pool_hbm, o_ref, buf, sem,
         # probability of exactly 0: it has to be finite
         buf[...] = jnp.zeros_like(buf)
         slot_ref[0] = 0
-        copies(0, 0, 0, lambda c: c.start())
+        start(0, 0, 0)
 
     off = off_ref[b]
-    n_groups = (live_pages(b) + group - 1) // group
+    n_groups = (off // page_size + group) // group     # ceil(live / group)
     slot0 = slot_ref[0]
     q = q_ref[...]
     tok = jax.lax.broadcasted_iota(jnp.int32, (h, cols), 1)
 
     def step(g, carry):
-        m_prev, l_prev, acc = carry
         slot = (slot0 + g) % 2
         last = g == n_groups - 1
         nxt_row = jnp.where(last, b + 1, b)
 
         @pl.when(nxt_row < rows)
         def _prefetch():
-            copies(jnp.minimum(nxt_row, rows - 1),
-                   jnp.where(last, 0, g + 1), 1 - slot,
-                   lambda c: c.start())
+            start(jnp.minimum(nxt_row, rows - 1), jnp.where(last, 0, g + 1),
+                  1 - slot)
 
-        copies(b, g, slot, lambda c: c.wait())
-        kv = buf[slot]
-        s = _mxu_f32(q, kv, 1) * scale
-        s = jnp.where(g * cols + tok <= off, s, NEG_INF)
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        alpha = jnp.exp(m_prev - m_new)
-        l_new = alpha * l_prev + jnp.sum(p, axis=-1, keepdims=True)
-        pv = _mxu_f32(p.astype(kv.dtype), kv[:, :v_width], 0)
-        return m_new, l_new, alpha * acc + pv
+        wait(b, g, slot)
+        # [group, page_size, lanes] -> [cols, lanes]: pages are whole
+        # sublane tiles, so the merge moves nothing
+        kv = buf[slot].reshape(cols, buf.shape[-1])
+        return _online_step(q, kv, g * cols + tok <= off, carry,
+                            scale=scale, v_width=v_width)
 
     _, l, acc = jax.lax.fori_loop(
         0, n_groups, step,
@@ -171,7 +214,7 @@ def _mla_decode_call(q, pool, page_table, offsets, *, scale, group, v_width,
                   pl.BlockSpec(memory_space=pl.ANY)],
         out_specs=pl.BlockSpec((None, h_pad, v_width),
                                lambda bi, pt, off: (bi, 0, 0)),
-        scratch_shapes=[pltpu.VMEM((2, group * psz, lanes), pool.dtype),
+        scratch_shapes=[pltpu.VMEM((2, group, psz, lanes), pool.dtype),
                         pltpu.SemaphoreType.DMA((2,)),
                         pltpu.SMEM((1,), jnp.int32)])
     out = pl.pallas_call(
@@ -205,6 +248,8 @@ def mla_decode_attention(q, pool, page_table, offsets, v_width, scale):
             f"latent decode kernel: pages {tuple(pool.shape[1:])} of "
             f"{pool.dtype} are not whole tiles; the caller reads such a "
             "pool by the XLA lane")
+    from ..utils import monitor
+    monitor.set_value("pallas.mla_decode.pages_per_step", group)
     return _mla_decode_call(q, pool, page_table, offsets,
                             scale=float(scale), group=group,
                             v_width=int(v_width), interpret=_interpret())

@@ -425,7 +425,9 @@ def serving_stats():
     ``kv_latent_row_bytes`` (a cached row's values times the pool's
     element size), and ``mla_decode_kernel_traces`` /
     ``mla_decode_xla_lane_traces``, the lanes the single-token latent
-    read took when traced (process-wide, like the paged read's).
+    read took when traced (process-wide, like the paged read's), and
+    ``mla_decode_pages_per_step``, the pages a step of the kernel copied
+    when it was last traced (0: never).
 
     ``kv_layers_without_store``: the model's layers that hold no page
     and no state row (a feed-forward part alone), and
@@ -558,6 +560,8 @@ def serving_stats():
         "mla_decode_kernel_traces": s.get("pallas.mla_decode.kernel", 0),
         "mla_decode_xla_lane_traces": s.get("pallas.mla_decode.xla_lane",
                                             0),
+        "mla_decode_pages_per_step": s.get(
+            "pallas.mla_decode.pages_per_step", 0),
         "expert_router_scoring": "+".join(
             n for n in SCORINGS
             if g(f"moe.router_layers.{n}")) or None,

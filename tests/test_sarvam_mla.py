@@ -265,10 +265,78 @@ def test_latent_decode_kernel_matches_xla_lane_over_ragged_rows(monkeypatch):
                                atol=2e-5)
 
 
+def _one_wait_case(dtype, group):
+    """(q, pool, page table, offsets) of six rows over one pool whose live
+    page counts are 2g, 1, 2g + 1, 2g - 1, g and g - 1: rows that end on
+    a full step and on a partial one, an empty slot (offset 0), and a next
+    row's prefetch that crosses from full to partial and back."""
+    page = 32 // jnp.dtype(dtype).itemsize          # whole sublane tiles
+    b, h, lanes, n_tab = 6, 4, 128, 2 * group + 2
+    live = [2 * group, 1, 2 * group + 1, 2 * group - 1, group, group - 1]
+    rng = np.random.default_rng(42)
+    off = jnp.asarray([0 if n == 1 else (n - 1) * page + int(
+        rng.integers(0, page)) for n in live], jnp.int32)
+    keys = jax.random.split(jax.random.PRNGKey(group), 2)
+    pool = jax.random.normal(keys[0], (b * n_tab + 1, page, lanes),
+                             jnp.float32).astype(dtype)
+    q = jax.random.normal(keys[1], (b, h, lanes), jnp.float32).astype(dtype)
+    pt = jnp.asarray(rng.permutation(b * n_tab).reshape(b, n_tab) + 1,
+                     jnp.int32)
+    return q, pool, pt, off
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("group", [2, 4])
+def test_latent_decode_one_wait_steps_match_xla_lane(monkeypatch, dtype,
+                                                     group):
+    """Full steps (one unrolled run of copies, one wait for the slot)
+    beside partial ones (a copy and a wait a page) over rows of k·group -
+    1, k·group and k·group + 1 live pages: the kernel reads what the XLA
+    lane reads, row by row."""
+    monkeypatch.setenv("PADDLE_TPU_PALLAS_INTERPRET", "1")
+    q, pool, pt, off = _one_wait_case(dtype, group)
+    page, lanes = pool.shape[1:]
+    monkeypatch.setattr(mla, "_STEP_BYTES",
+                        group * page * lanes * jnp.dtype(dtype).itemsize)
+    assert mla.mla_decode_pages_per_step(page, lanes,
+                                         jnp.dtype(dtype).itemsize) == group
+    got = mla.mla_decode(q, pool, pt, off, 96, 0.21, lane="kernel")
+    want = mla.mla_decode(q, pool, pt, off, 96, 0.21, lane="xla")
+    # the dense formula over each row's live rows, in float32
+    rows_all = np.asarray(pool.astype(jnp.float32))
+    dense = []
+    for r in range(q.shape[0]):
+        rows = rows_all[np.asarray(pt)[r]].reshape(-1, lanes)[
+            :int(off[r]) + 1]
+        sc = np.asarray(q[r].astype(jnp.float32)) @ rows.T * 0.21
+        p = np.exp(sc - sc.max(-1, keepdims=True))
+        dense.append((p / p.sum(-1, keepdims=True)) @ rows[:, :96])
+    tol = 2e-5 if dtype == jnp.float32 else 2e-2
+    np.testing.assert_allclose(np.asarray(got.astype(jnp.float32)),
+                               np.asarray(want.astype(jnp.float32)),
+                               atol=tol)
+    np.testing.assert_allclose(np.asarray(got.astype(jnp.float32)),
+                               np.stack(dense), atol=tol)
+
+
+def test_pages_per_step_gauge_in_serving_stats(monkeypatch):
+    """The kernel's step, set where the kernel lane is traced, is what
+    ``serving_stats()`` shows; the XLA lane leaves it alone."""
+    monkeypatch.setenv("PADDLE_TPU_PALLAS_INTERPRET", "1")
+    q, pool, pt, off = _one_wait_case(jnp.float32, 2)
+    for group in (4, 2):
+        monkeypatch.setattr(mla, "_STEP_BYTES", group * 8 * 128 * 4)
+        mla.mla_decode(q, pool, pt, off, 96, 0.21, lane="kernel")
+        assert serving_stats()["mla_decode_pages_per_step"] == group
+    mla.mla_decode(q, pool, pt, off, 96, 0.21, lane="xla")
+    assert serving_stats()["mla_decode_pages_per_step"] == 2
+
+
 def test_latent_pages_rule():
     assert mla.latent_row_lanes(576) == 640
     assert mla.latent_row_lanes(512) == 512
-    assert mla.mla_decode_pages_per_step(16, 640, 2) == 64
+    assert mla.mla_decode_pages_per_step(16, 640, 2) == 128
     # float32 pages of 4 rows are not whole sublane tiles: the XLA lane
     assert mla.mla_decode_pages_per_step(4, 128, 4) == 0
     assert mla.mla_decode_pages_per_step(8, 128, 4) > 0
@@ -470,6 +538,7 @@ def test_compiled_tick_runs_the_latent_kernel(tick_flag, monkeypatch):
         > before.get("pallas.mla_decode.kernel", 0)
     assert st["tick_fallbacks"] == 0 and st["prefill_fallbacks"] == 0
     assert st["mla_decode_kernel_traces"] > 0
+    assert st["mla_decode_pages_per_step"] > 0
 
 
 def test_reused_slot_sees_none_of_its_former_tenants_rows(tiny, tick_flag):
