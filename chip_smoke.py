@@ -306,6 +306,7 @@ def serve_phase(model, cfg, sizes, dry_run):
         f"scheduler_restarts={snap['scheduler_restarts']} "
         f"max_active_slots={snap.get('max_active_slots')} "
         f"tick_overlap_share={snap['tick_overlap_share']:.3f} "
+        f"prefill_overlap_share={snap['prefill_overlap_share']:.3f} "
         f"tick_drains={snap['tick_drains']}")
     assert snap["tick_compiled_hits"] > 0
     assert snap["tick_fallbacks"] == 0
@@ -314,6 +315,9 @@ def serve_phase(model, cfg, sizes, dry_run):
     assert snap["tick_overlap_share"] > 0
     assert snap["prefill_compiled_hits"] > 0
     assert snap["prefill_fallbacks"] == 0
+    # members read after the next tick's launch: their outputs were
+    # futures under a running tick
+    assert snap["prefill_overlap_share"] > 0
     assert snap["scheduler_restarts"] == 0
     assert tick_text is not None, "no greedy tick program ran"
     pallas_calls(tick_text, "serve tick", dry_run)
@@ -481,6 +485,9 @@ def sparse_phase(dry_run):
         f"paged_decode_xla_lane_traces="
         f"{snap['paged_decode_xla_lane_traces']}")
     assert 0 < snap["window_pages_held_share"] < 1
+    say(f"sparse: prefill_overlap_share="
+        f"{snap['prefill_overlap_share']:.3f}")
+    assert snap["prefill_overlap_share"] > 0
     if not dry_run:
         assert lanes["pallas.paged_decode.kernel"] > 0, lanes
         assert lanes["pallas.expert_gmm.kernel"] > 0, lanes

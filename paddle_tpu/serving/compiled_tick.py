@@ -258,6 +258,8 @@ class CompiledServingTick:
         self._h_counts = None          # host mirror of generated counts
         self._h_limits = None          # host copy of the token limits
         self._pending = None           # the _Launched tick not yet read
+        self._unread = []              # routing counts (futures, or None)
+        #                                of members launched and not read
         self._sublayers = None
         # the static blocker (speculation) is known at construction:
         # warn right away — an all-greedy speculative engine never even
@@ -600,11 +602,14 @@ class CompiledServingTick:
         return rows, self._prefill[rows, donating]
 
     def run_prefill(self, member, slots, starts, tokens, last, lora_rows):
-        """One chunk call as ONE program over the donated pools: the
-        ``prefill_member``'s [rows, chunk] ``tokens`` for ``slots`` at
+        """Launch one chunk call as ONE program over the donated pools:
+        the ``prefill_member``'s [rows, chunk] ``tokens`` for ``slots`` at
         write offsets ``starts``; returns the logits at each row's
-        ``last`` position, [num_slots, V] with the rows first.  The
-        cache has adopted the new pools on return."""
+        ``last`` position, [num_slots, V] with the rows first, a future.
+        The cache has adopted the new pools (futures too) on return.
+        Nothing here waits for the device: the member's routing counts
+        are read by ``collect_prefill``, after the engine has launched
+        the next tick behind the member."""
         cache = self.eng.cache
         rows, program = member
         with span("serving.prefill.view"):
@@ -622,14 +627,23 @@ class CompiledServingTick:
                 new_pools, picked, moe = program(
                     pools, table, off, tokens, last, lora_rows, state_rows,
                     caps, table_w, valid)
-            # the call's device time belongs to the call's span
-            picked.block_until_ready()
-            if moe is not None:
-                count_routing(np.asarray(moe), prefill=True)
         with span("serving.prefill.absorb"):
             cache.absorb_pools(new_pools)
+        self._unread.append(moe)
         stats.incr("prefill.compiled_hits")
         return Tensor(picked)
+
+    def collect_prefill(self, overlapped):
+        """Read the members launched since the last call: each one's
+        routing counts go to the registry, once (the read waits for the
+        member); ``overlapped``, the engine launched a tick behind them
+        first, is counted under ``serving.prefill.overlapped``."""
+        for moe in self._unread:
+            if moe is not None:
+                count_routing(np.asarray(moe), prefill=True)
+            if overlapped:
+                stats.incr("prefill.overlapped")
+        self._unread.clear()
 
     # ------------------------------------------------------------------
     # host <-> device state sync
@@ -724,6 +738,11 @@ class CompiledServingTick:
             with span("serving.tick.rebuild"):
                 self._rebuild()
         return self._run()
+
+    @property
+    def in_flight(self):
+        """Whether a launched tick is still unread."""
+        return self._pending is not None
 
     def drain(self):
         """Collect the tick in flight, if there is one: what anything

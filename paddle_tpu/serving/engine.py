@@ -210,6 +210,10 @@ class Engine:
         self._active: dict[int, _Request] = {}
         # requests holding a slot whose prompt is mid-(chunked-)prefill
         self._prefilling: deque[_Request] = deque()
+        # the prefill round launched and not yet finished: [(request,
+        # its first token drawn on the device)] for the prompts its chunk
+        # call completed; None between rounds
+        self._prefill_ended = None
         self.prefix_tree = None
         self._max_active = 0
         # EVERY unresolved request, from submit() until its future
@@ -1020,14 +1024,28 @@ class Engine:
                 self._start_prefill(req, slot)
         # ONE batched chunk call covers every prefilling request, then
         # the decode step runs: long prompts advance without ever
-        # blocking in-flight streams for more than a chunk
+        # blocking in-flight streams for more than a chunk.  A compiled
+        # tick is launched BEHIND the chunk call, which is then read
+        # (first tokens, routing counts, activations) while that tick
+        # runs; with no compiled tick to follow, it is read first, as the
+        # decode step that follows takes the rows it activates
         if self._prefilling:
             with span("serving.prefill_round"):
                 self._prefill_round()
-        if self._active:
+        tick = self._tick if self._active and not self._can_speculate() \
+            else None
+        ticked = False
+        try:
+            ticked = tick is not None and tick.step()
+        finally:
+            # on every way out of the iteration, a stop or a crash
+            # included: a launched chunk call is read exactly once
+            self._finish_prefill(ticked and tick.in_flight)
+        if self._active and not ticked:
             if self._can_speculate():
                 self._spec_step()
-            elif self._tick is not None and self._tick.step():
+            elif tick is None and self._tick is not None \
+                    and self._tick.step():
                 pass        # ONE compiled program runs the tick
             elif self._active:  # (the tick's drain may have ended them all)
                 self._decode_step()
@@ -1206,10 +1224,14 @@ class Engine:
         self._prefilling.append(req)
 
     def _prefill_round(self):
-        """One `prefill_chunk_tokens`-wide chunk for EVERY prefilling
-        request, batched into a single model call, THEN the decode step
-        runs — long prompts no longer starve in-flight streams, and a
-        burst of admissions costs one call, not one per request.
+        """Launch one `prefill_chunk_tokens`-wide chunk for EVERY
+        prefilling request, batched into a single model call, THEN the
+        decode step runs — long prompts no longer starve in-flight
+        streams, and a burst of admissions costs one call, not one per
+        request.  Only the host's mirrors move here (offsets, page
+        growth); each prompt the chunk completes has its first token
+        drawn behind the call on the device, and `_finish_prefill` reads
+        them.
 
         Static shapes: a round's model call is one of a few [rows, C]
         programs, rows the smallest power-of-two bucket (capped at
@@ -1235,6 +1257,7 @@ class Engine:
                     self._release(req)
         if not self._prefilling:
             return
+        self._prefill_ended = ended = []
         reqs = list(self._prefilling)       # each holds a slot: <= B
         chunk = self._chunk
         tgt = [r for r in reqs if r.prefill_pos < r.prompt.size]
@@ -1255,25 +1278,16 @@ class Engine:
                         pos=int(req.prefill_pos))
                 if req.prefill_pos < plen:
                     continue
-                # prompt fully cached: sample the first token from the
-                # last REAL position of this row's chunk
+                # prompt fully cached: the first token is drawn from the
+                # last REAL position of this row's chunk, now — behind
+                # the call and ahead of the tick launched after it, for
+                # an op dispatched after that tick would run after it
                 if req.sampling.uses_penalty:
                     seen = np.zeros(self.cfg.vocab_size, bool)
                     seen[req.prompt] = True
                     req.seen = seen
-                req.first_tok = self._sample_row(
-                    logits[row:row + 1, :], req)
-                req.ttft_ms = (time.monotonic() - req.submit_t) * 1e3
-                stats.observe("ttft_ms", req.ttft_ms)
-                stats.incr("prefill_steps")
-                if req.trace is not None and \
-                        req.trace.prefill is not None:
-                    req.trace.prefill.event(
-                        "first_token", ttft_ms=round(req.ttft_ms, 3))
-                if self.prefix_tree is not None:
-                    self.prefix_tree.insert(req.prompt, self.cache,
-                                            req.slot, req.prefix_nodes,
-                                            scope=req.adapter_id)
+                ended.append(
+                    (req, self._draw_row(logits[row:row + 1, :], req)))
         if self._spec:
             # the draft model's own chunked prefill, same cadence: its
             # cache must hold the whole prompt before the request can
@@ -1289,9 +1303,38 @@ class Engine:
                         dstarts[row] + chunk, req.prompt.size)
                     self.draft_cache.set_offset(req.slot,
                                                 req.draft_prefill_pos)
+
+    def _finish_prefill(self, overlapped):
+        """The read of the round `_prefill_round` launched, if one is
+        open: the wait for its chunk call (``serving.prefill.collect``:
+        the member's routing counts and the first tokens drawn behind
+        it), each completed prompt's first token, ``ttft_ms`` and prefix
+        tree entry, then the activation of every prefilling request whose
+        caches are all ready.  ``overlapped``: a compiled tick was
+        launched behind the call before this read."""
+        ended, self._prefill_ended = self._prefill_ended, None
+        if ended is None:
+            return
+        with span("serving.prefill.collect"):
+            if self._tick is not None:
+                self._tick.collect_prefill(overlapped)
+            firsts = [int(np.asarray(tok).reshape(-1)[0])
+                      for _, tok in ended]
+        for (req, _), tok in zip(ended, firsts):
+            req.first_tok = tok
+            req.ttft_ms = (time.monotonic() - req.submit_t) * 1e3
+            stats.observe("ttft_ms", req.ttft_ms)
+            stats.incr("prefill_steps")
+            if req.trace is not None and req.trace.prefill is not None:
+                req.trace.prefill.event(
+                    "first_token", ttft_ms=round(req.ttft_ms, 3))
+            if self.prefix_tree is not None:
+                self.prefix_tree.insert(req.prompt, self.cache, req.slot,
+                                        req.prefix_nodes,
+                                        scope=req.adapter_id)
         # activate when every cache the request decodes against is
         # ready (target always; draft too when speculating)
-        for req in reqs:
+        for req in list(self._prefilling):
             if req.prefill_pos < req.prompt.size or req.first_tok is None:
                 continue
             if self._spec and req.draft_prefill_pos < req.prompt.size:
@@ -1910,12 +1953,19 @@ class Engine:
             last._data_, temp, topk, topp, pen, seen, keys, counts))
 
     def _sample_row(self, logits_row, req):
+        """[1, V] logits → one token under the request's params, read
+        to the host (`_draw_row`)."""
+        return int(np.asarray(self._draw_row(logits_row, req))
+                   .reshape(-1)[0])
+
+    def _draw_row(self, logits_row, req):
         """[1, V] logits → one token under the request's params (the
-        processor chain shared with models/generation).  Seeded
-        non-greedy requests draw from their own key stream (the same
-        ``fold_in(PRNGKey(seed), n_generated)`` the fused call and the
-        compiled tick use, so the stream is lane-independent from token
-        0); everything else is the historical global-RNG path."""
+        processor chain shared with models/generation), on the device
+        and unread.  Seeded non-greedy requests draw from their own key
+        stream (the same ``fold_in(PRNGKey(seed), n_generated)`` the
+        fused call and the compiled tick use, so the stream is
+        lane-independent from token 0); everything else is the
+        historical global-RNG path."""
         from ..core.tensor import Tensor
         from ..models.generation import sample_next_token
         from ..utils.flags import flag as _flag
@@ -1925,7 +1975,7 @@ class Engine:
             from .compiled_tick import fused_sample_call, request_key
             seen = req.seen[None, :] if req.seen is not None else \
                 np.zeros((1, self.cfg.vocab_size), bool)
-            tok = fused_sample_call(
+            return fused_sample_call(
                 logits_row._data_,
                 np.asarray([sp.temperature], np.float32),
                 np.asarray([sp.top_k or 0], np.int32),
@@ -1936,14 +1986,12 @@ class Engine:
                             else 1.0], np.float32),
                 seen, request_key(sp)[None, :],
                 np.asarray([len(req.tokens)], np.int32))
-            return int(np.asarray(tok)[0])
         seen_t = Tensor(req.seen[None, :]) if req.seen is not None \
             else None
-        nxt = sample_next_token(
+        return sample_next_token(
             logits_row, temperature=sp.temperature, top_k=sp.top_k,
             top_p=sp.top_p, repetition_penalty=sp.repetition_penalty,
-            seen=seen_t)
-        return int(np.asarray(nxt._data_).reshape(-1)[0])
+            seen=seen_t)._data_
 
     def _append_token(self, req, tok):
         """Account one generated token, then finish/evict the request

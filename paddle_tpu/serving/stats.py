@@ -166,7 +166,10 @@ def declare_tick_stats():
                                       "compiled program"),
             ("prefill.fallbacks", "prefill chunk calls that took the "
                                   "eager lane because one program could "
-                                  "not host them")):
+                                  "not host them"),
+            ("prefill.overlapped", "compiled prefill chunk calls read "
+                                   "after the next tick was launched "
+                                   "behind them")):
         _registry.counter(PREFIX + name, text)
     # recurrent state beside pages (zero for a model without such layers)
     for name, text in (
@@ -379,7 +382,9 @@ def serving_stats():
     launch covered) and ``tick_overlap_share`` = overlapped / hits, and
     ``prefill_compiled_hits`` / ``prefill_fallbacks`` the same for
     prefill chunk calls (the draft model's eager calls are in
-    neither); all ride the Prometheus exposition
+    neither), with ``prefill_overlap_share`` = the compiled calls read
+    after the next tick was launched behind them / compiled calls; all
+    ride the Prometheus exposition
     (``serving_tick_ms`` histogram, ``serving_tick_compiled_hits`` /
     ``serving_tick_fallbacks`` counters, gated by
     tools/check_telemetry.py --serving-tick).
@@ -534,6 +539,9 @@ def serving_stats():
         if g("tick.compiled_hits") else None,
         "prefill_compiled_hits": g("prefill.compiled_hits"),
         "prefill_fallbacks": g("prefill.fallbacks"),
+        "prefill_overlap_share": (g("prefill.overlapped")
+                                  / g("prefill.compiled_hits"))
+        if g("prefill.compiled_hits") else None,
         "state_bytes": g("state.bytes"),
         "state_heads_per_tile": g("state.heads_per_tile"),
         "ssm_update_kernel_traces": s.get("pallas.ssm_update.kernel", 0),

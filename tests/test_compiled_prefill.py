@@ -1,9 +1,13 @@
-"""The prefill chunk as a member of the compiled tick's program family
-(ISSUE 27): one donated program per chunk call with as many rows as the
-smallest bucket that holds the prefilling requests — token-for-token
-equality with the eager lane (``FLAGS_compiled_tick`` off), the bucket
-chosen and the counters that say so, the shared fallback lattice, no
-compile after the first chunk call, donation, and the program's name."""
+"""The prefill chunk as a member of the compiled tick's program family:
+one donated program per chunk call with as many rows as the smallest
+bucket that holds the prefilling requests — token-for-token equality
+with the eager lane (``FLAGS_compiled_tick`` off), the bucket chosen and
+the counters that say so, the shared fallback lattice, no compile after
+the first chunk call, donation, and the program's name.  A member with a
+compiled tick to follow is read after that tick's launch: the same
+tokens, exact counters, a clean stop that reads it once, and a completed
+prompt that joins the tick after."""
+import threading
 import warnings
 
 import numpy as np
@@ -13,7 +17,11 @@ import paddle_tpu as paddle
 from paddle_tpu.core import state as _state
 from paddle_tpu.models import (GPTForCausalLM, LlamaForCausalLM, gpt_config,
                                llama_config)
-from paddle_tpu.serving import Engine, ServingConfig
+from paddle_tpu.models.cohere_moe import (TINY_COHERE_MOE, CohereMoeConfig,
+                                          CohereMoeForCausalLM)
+from paddle_tpu.models.mellum import (TINY_MELLUM, MellumConfig,
+                                      MellumForCausalLM)
+from paddle_tpu.serving import Engine, EngineShutdownError, ServingConfig
 from paddle_tpu.serving.compiled_tick import TickFallbackWarning
 from paddle_tpu.utils import flags as _flags
 from paddle_tpu.utils import monitor
@@ -298,3 +306,216 @@ def test_adapter_rows_ride_the_compiled_member(models, tick_flag):
                          cfg=_cfg(num_slots=2))
     assert not np.array_equal(plain[0][0], got[0])
     np.testing.assert_array_equal(plain[0][1], got[1])
+
+
+# ---------------------------------------------------------------------------
+# the member read after the next tick's launch
+# ---------------------------------------------------------------------------
+
+def _beside(model, cfg, first, then, new, on_step=None, spy=None):
+    """``first`` submitted alone, ``then`` once ``first`` decodes: each
+    chunk call of ``then`` is a one-row member with a compiled tick to
+    follow.  ``spy(eng)`` runs before the first submission,
+    ``on_step(eng)`` before every tick's step once ``then`` is in.
+    Returns (the started engine, the two futures, the registry when
+    ``first`` was submitted)."""
+    _flags._FLAGS["FLAGS_compiled_tick"] = True
+    eng = Engine(model, cfg).start()
+    if spy is not None:
+        spy(eng)
+    decoding, go = threading.Event(), threading.Event()
+    step = eng._tick.step
+
+    def gated():
+        if not go.is_set():
+            decoding.set()
+            go.wait(300)
+        elif on_step is not None:
+            on_step(eng)
+        return step()
+
+    eng._tick.step = gated
+    before = monitor.all_stats()
+    futs = [eng.submit(first, max_new_tokens=new[0])]
+    assert decoding.wait(300)
+    futs.append(eng.submit(then, max_new_tokens=new[1]))
+    go.set()
+    return eng, futs, before
+
+
+def _alone(model, cfg, prompts, new):
+    """The same requests one after the other, each with nothing decoding
+    beside it: no compiled tick follows any member, so each is read as
+    soon as it is launched.  Returns (output ids, registry delta)."""
+    _flags._FLAGS["FLAGS_compiled_tick"] = True
+    with Engine(model, cfg) as eng:
+        before = monitor.all_stats()
+        outs = [eng.submit(p, max_new_tokens=n).result(timeout=300)
+                .output_ids for p, n in zip(prompts, new)]
+    return outs, _delta(before)
+
+
+def _delta(before):
+    after = monitor.all_stats()
+    return {k: v - before.get(k, 0) for k, v in after.items()
+            if isinstance(v, (int, float))}
+
+
+# a neighbour of 9 tokens that decodes 40; a prompt of three chunks (the
+# last left-shifted: 50 > 64 - 24) beside it
+_NEIGHBOUR, _THREE = _prompts([9, 50], seed=91)
+
+
+@pytest.mark.parametrize("family", ["gpt", "llama"])
+def test_member_read_after_the_tick_equals_eager_lane(models, tick_flag,
+                                                      family):
+    model = models[family]
+    eng, futs, _ = _beside(model, _cfg(), _NEIGHBOUR, _THREE, (40, 8))
+    try:
+        got = [f.result(timeout=300).output_ids for f in futs]
+    finally:
+        eng.shutdown()
+    snap = eng.stats()
+    assert snap["prefill_compiled_hits"] == 4
+    assert snap["prefill_overlap_share"] > 0
+    assert snap["tick_fallbacks"] == snap["prefill_fallbacks"] == 0
+    _flags._FLAGS["FLAGS_compiled_tick"] = False
+    with Engine(model, _cfg()) as ref_eng:
+        want = [ref_eng.submit(p, max_new_tokens=n).result(timeout=300)
+                .output_ids for p, n in ((_NEIGHBOUR, 40), (_THREE, 8))]
+    for r, g in zip(want, got):
+        np.testing.assert_array_equal(r, g)
+
+
+def _moe_model(family):
+    paddle.seed(0)
+    if family == "mellum":
+        m = MellumForCausalLM(MellumConfig(**TINY_MELLUM))
+    else:
+        m = CohereMoeForCausalLM(CohereMoeConfig(**TINY_COHERE_MOE))
+    m.eval()
+    return m
+
+
+@pytest.fixture(scope="module")
+def moe_models():
+    return {f: _moe_model(f) for f in ("mellum", "cohere")}
+
+
+def _moe_cfg():
+    return ServingConfig(num_slots=4, max_queue=16, page_size=4,
+                         prefill_chunk_tokens=8, enable_prefix_cache=False)
+
+
+_EXACT = ("serving.moe.prefill.pairs", "serving.moe.prefill.experts_hit",
+          "serving.moe.pairs_local", "serving.moe.tokens_routed",
+          "serving.prefill.tokens_useful", "serving.prefill.compiled_hits",
+          "serving.tokens_generated", "serving.prefill_steps")
+
+
+@pytest.mark.parametrize("family", ["mellum", "cohere"])
+def test_routing_and_prefill_counts_are_exact(moe_models, tick_flag,
+                                              family):
+    """Members read after the next tick's launch count what members read
+    at once count: every routed pair, every expert hit, every prompt
+    token (the tiny Command A+ holds 4 of its 16 experts, so its pairs
+    follow the router)."""
+    model = moe_models[family]
+    near, three = _prompts([5, 21], seed=93)
+    want, ref = _alone(model, _moe_cfg(), [near, three], (30, 6))
+    assert ref.get("serving.prefill.overlapped", 0) == 0
+    eng, futs, before = _beside(model, _moe_cfg(), near, three, (30, 6))
+    try:
+        got = [f.result(timeout=300).output_ids for f in futs]
+    finally:
+        eng.shutdown()
+    reg = _delta(before)
+    assert reg["serving.prefill.overlapped"] >= 2
+    for r, g in zip(want, got):
+        np.testing.assert_array_equal(r, g)
+    assert {k: reg[k] for k in _EXACT} == {k: ref[k] for k in _EXACT}
+    if family == "mellum":
+        # every expert held: k pairs a routed token a layer, and a token
+        # routed for each prompt position and each decode step
+        layers, k = TINY_MELLUM["num_layers"], TINY_MELLUM[
+            "num_experts_per_tok"]
+        decoded = reg["serving.tokens_generated"] - 2
+        assert reg["serving.moe.prefill.pairs"] == \
+            (near.size + three.size) * layers * k
+        assert reg["serving.moe.tokens_routed"] == \
+            (near.size + three.size + decoded) * layers
+        assert reg["serving.moe.pairs_local"] == \
+            reg["serving.moe.tokens_routed"] * k
+    else:
+        assert reg["serving.moe.prefill.pairs"] > 0
+
+
+def test_clean_stop_reads_an_open_member_once(moe_models, tick_flag):
+    """A stop asked for while a member is launched and unread: the
+    iteration reads it on its way out — the completed prompt's one token
+    is delivered, its routing and prefill counted once."""
+    model = moe_models["mellum"]
+    near, three = _prompts([5, 21], seed=95)
+    want, ref = _alone(model, _moe_cfg(), [near, three], (30, 1))
+
+    def stop_at_last_chunk(eng):
+        if eng._prefill_ended:          # the prompt's last chunk is open
+            with eng._work:
+                eng._running = False
+
+    eng, futs, before = _beside(model, _moe_cfg(), near, three, (30, 1),
+                                on_step=stop_at_last_chunk)
+    try:
+        out = futs[1].result(timeout=300)
+        with pytest.raises(EngineShutdownError):
+            futs[0].result(timeout=300)
+    finally:
+        eng.shutdown()
+    reg = _delta(before)
+    np.testing.assert_array_equal(out.output_ids, want[1])
+    assert out.finish_reason == "length" and out.ttft_ms > 0
+    assert reg["serving.prefill.overlapped"] >= 1
+    once = ("serving.moe.prefill.pairs", "serving.moe.prefill.experts_hit",
+            "serving.prefill.tokens_useful",
+            "serving.prefill.compiled_hits", "serving.prefill_steps",
+            "serving.ttft_ms.count")
+    assert {k: reg[k] for k in once} == {k: ref[k] for k in once}
+    assert reg["serving.moe.prefill.pairs"] == (near.size + three.size) \
+        * TINY_MELLUM["num_layers"] * TINY_MELLUM["num_experts_per_tok"]
+
+
+def test_completed_prompt_joins_the_tick_after(models, tick_flag):
+    """A prompt completed by a member read after the next tick's launch
+    keeps its first token and ``ttft_ms``, and decodes from the tick
+    launched after that one."""
+    model = models["gpt"]
+    launches, joined = [], []
+
+    def spy(eng):
+        launch, finish = eng._tick._launch, eng._finish_prefill
+
+        def spy_launch(live):
+            launches.append(set(live))
+            return launch(live)
+
+        def spy_finish(overlapped):
+            finish(overlapped)
+            for slot, req in eng._active.items():
+                if req.prompt.size == _THREE.size and not joined:
+                    joined.append((slot, overlapped, len(launches)))
+
+        eng._tick._launch, eng._finish_prefill = spy_launch, spy_finish
+
+    eng, futs, _ = _beside(model, _cfg(), _NEIGHBOUR, _THREE, (40, 8),
+                           spy=spy)
+    try:
+        got = [f.result(timeout=300) for f in futs]
+    finally:
+        eng.shutdown()
+    (slot, overlapped, k), = joined
+    assert overlapped
+    assert slot not in launches[k - 1]      # launched behind its member
+    assert slot in launches[k]              # the tick after
+    ref, _, _ = _serve(model, [[_THREE]], compiled=False, max_new=8)
+    np.testing.assert_array_equal(got[1].output_ids, ref[0][0])
+    assert 0 < got[1].ttft_ms <= got[1].latency_ms

@@ -257,6 +257,7 @@ def test_serving_phases_reach_the_profiler_trace(serve_run):
     assert {"serving.admit", "serving.iteration", "serving.prefill_round",
             "serving.prefill_chunk", "serving.prefill.view",
             "serving.prefill.model", "serving.prefill.absorb",
+            "serving.prefill.collect",
             "serving.tick", "serving.tick.build", "serving.tick.launch",
             "serving.tick.sync", "serving.tick.deliver",
             "serving.tick.rebuild", "serving.publish"} <= names
