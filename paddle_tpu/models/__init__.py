@@ -8,7 +8,7 @@ from .gpt_parallel import (  # noqa: F401
 )
 from .gpt_pipeline import GPTForCausalLMPipe  # noqa: F401
 from .llama import (  # noqa: F401
-    LlamaConfig, LlamaModel, LlamaForCausalLM, llama_config,
+    LlamaConfig, LlamaForCausalLM, llama_config,
 )
 from .llama_parallel import (  # noqa: F401
     ParallelLlamaForCausalLM, ParallelLlamaModel,

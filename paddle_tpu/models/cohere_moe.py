@@ -49,12 +49,10 @@ import jax.numpy as jnp
 from ..observability.tracing import scope
 
 from ..core.dispatch import apply_op
-from ..nn import Layer, Linear, Embedding, LayerNorm, LayerList
-from ..nn import functional as F
-from ..nn.initializer import Normal, ParamAttr
+from ..nn import Layer, LayerNorm
+from .decoder import (DecoderForCausalLM, GroupedQueryAttention,
+                      checked_held_experts, checked_layer_types)
 from .sparse_experts import SparseExpertMLP
-from ..tensor_ops import manipulation as MA
-from ..incubate.nn import functional as IF
 
 
 @dataclass
@@ -89,32 +87,13 @@ class CohereMoeConfig:
             self.layer_types = ["full_attention" if i % 4 == 3
                                 else "sliding_attention"
                                 for i in range(self.num_layers)]
-        self.layer_types = list(self.layer_types)
-        if len(self.layer_types) != self.num_layers:
-            raise ValueError(f"{len(self.layer_types)} layer_types for "
-                             f"{self.num_layers} layers")
-        bad = set(self.layer_types) - {"sliding_attention", "full_attention"}
-        if bad:
-            raise ValueError(f"unknown layer kinds {sorted(bad)}")
+        self.layer_types = checked_layer_types(
+            self, ("sliding_attention", "full_attention"))
         if self.num_heads % self.num_kv_heads:
             raise ValueError("num_heads must be divisible by num_kv_heads")
-        if self.held_experts is None:
-            self.held_experts = (0, self.num_experts_published)
-        first, count = (int(v) for v in self.held_experts)
-        if first < 0 or count < 1 or \
-                first + count > self.num_experts_published:
-            raise ValueError(
-                f"held_experts {self.held_experts} is not a run of the "
-                f"{self.num_experts_published} published experts")
-        self.held_experts = (first, count)
-        if self.num_experts_per_tok > self.num_experts_published:
-            raise ValueError("more experts a token than experts")
+        self.held_experts = checked_held_experts(self)
         if not self.tie_word_embeddings:
             raise ValueError("the family ties embedding and head")
-
-    @property
-    def num_experts_held(self):
-        return self.held_experts[1]
 
     def layer_windows(self):
         """Per layer, how many of the latest positions its attention
@@ -167,72 +146,33 @@ def _whole_sequence_attention(q, k, v, window):
     return out.reshape(b, s, h, d).astype(q.dtype)
 
 
-class CohereMoeAttention(Layer):
-    def __init__(self, config: CohereMoeConfig, kind):
-        super().__init__()
-        self.config = config
-        #: a window layer rotates by position; a full layer carries none
-        self.rotary = kind == "sliding_attention"
-        self.window = config.sliding_window if self.rotary else None
-        h, d = config.hidden_size, config.head_dim
-        w_init = ParamAttr(initializer=Normal(0.0, config.initializer_range))
-        out_init = ParamAttr(initializer=Normal(
-            0.0, config.initializer_range / math.sqrt(2 * config.num_layers)))
-        self.q_proj = Linear(h, config.num_heads * d, weight_attr=w_init,
-                             bias_attr=False)
-        self.k_proj = Linear(h, config.num_kv_heads * d, weight_attr=w_init,
-                             bias_attr=False)
-        self.v_proj = Linear(h, config.num_kv_heads * d, weight_attr=w_init,
-                             bias_attr=False)
-        self.o_proj = Linear(config.num_heads * d, h, weight_attr=out_init,
-                             bias_attr=False)
+class CohereMoeAttention(GroupedQueryAttention):
+    """Window layers rotate q and k over interleaved pairs and see
+    ``sliding_window`` positions, full layers neither; the whole sequence
+    in float32 (``_whole_sequence_attention``), a dense cache refused."""
 
     #: the op the rotation is traced as
     rotation_name = "rope_interleaved"
+    dense_refusal = ("CohereMoeAttention reads a paged cache "
+                     "(serving.PagedKVCache) or none; a dense {'k', 'v'} "
+                     "cache has no window")
+
+    def __init__(self, config: CohereMoeConfig, kind):
+        # a window layer rotates by position; a full layer carries none
+        rotary = kind == "sliding_attention"
+        super().__init__(config, window=config.sliding_window
+                         if rotary else None, rotary=rotary)
 
     def rotate(self, x, pos):
         """``x`` [B, S, H, D] (a raw array) rotated by ``pos`` [B, S] or
         [S]: this family's interleaved pairs at ``rope_theta``."""
         return rope_interleaved(x, pos, self.config.rope_theta)
 
-    def forward(self, x, cache=None):
-        cfg = self.config
-        b, s, _ = x.shape
-        d = cfg.head_dim
-        q = MA.reshape(self.q_proj(x), [b, s, cfg.num_heads, d])
-        k = MA.reshape(self.k_proj(x), [b, s, cfg.num_kv_heads, d])
-        v = MA.reshape(self.v_proj(x), [b, s, cfg.num_kv_heads, d])
+    def whole_sequence(self, q, k, v):
         window = self.window
-        if self.rotary:
-            off = None if cache is None else cache["offset"]
-
-            def rotate(qa, ka, *off):
-                pos = jnp.arange(s, dtype=jnp.int32)
-                if off:
-                    pos = off[0].astype(jnp.int32).reshape(-1, 1) + pos[None]
-                return self.rotate(qa, pos), self.rotate(ka, pos)
-
-            q, k = apply_op(self.rotation_name, rotate,
-                            (q, k) if off is None else (q, k, off))
-        if cache is None:
-            out = apply_op(
-                "whole_sequence_attention",
-                lambda qa, ka, va: _whole_sequence_attention(qa, ka, va,
-                                                             window),
-                (q, k, v))
-        elif "page_table" in cache:
-            if cache.get("window") != window:
-                raise ValueError(
-                    f"the layer's window is {window} and its cache's "
-                    f"{cache.get('window')}: build the cache with "
-                    "layer_windows=config.layer_windows()")
-            out = IF.paged_cache_attention(q, k, v, cache)
-        else:
-            raise NotImplementedError(
-                "CohereMoeAttention reads a paged cache "
-                "(serving.PagedKVCache) or none; a dense {'k', 'v'} cache "
-                "has no window")
-        return self.o_proj(MA.reshape(out, [b, s, cfg.num_heads * d]))
+        return apply_op("whole_sequence_attention", lambda qa, ka, va:
+                        _whole_sequence_attention(qa, ka, va, window),
+                        (q, k, v))
 
 
 class CohereSparseMLP(SparseExpertMLP):
@@ -268,58 +208,16 @@ class CohereMoeBlock(Layer):
         return x + a + self.mlp(n, cache=cache)
 
 
-class CohereMoeModel(Layer):
-    def __init__(self, config: CohereMoeConfig):
-        super().__init__()
-        self.config = config
-        emb_init = ParamAttr(initializer=Normal(0.0,
-                                                config.initializer_range))
-        self.embed_tokens = Embedding(config.vocab_size, config.hidden_size,
-                                      weight_attr=emb_init)
-        self.layers = LayerList([CohereMoeBlock(config, kind)
-                                 for kind in config.layer_types])
-        self.norm = LayerNorm(config.hidden_size,
-                              epsilon=config.layer_norm_eps, bias_attr=False)
+class CohereMoeForCausalLM(DecoderForCausalLM):
+    """The shell over ``CohereMoeBlock``, logits times ``logit_scale``."""
 
-    def forward(self, input_ids, caches=None):
-        with scope("embed"):
-            x = self.embed_tokens(input_ids)
-        for i, blk in enumerate(self.layers):
-            x = blk(x, cache=None if caches is None else caches[i])
-        return self.norm(x)
+    block = CohereMoeBlock
 
+    @staticmethod
+    def final_norm(config):
+        return LayerNorm(config.hidden_size, epsilon=config.layer_norm_eps,
+                         bias_attr=False)
 
-class CohereMoeForCausalLM(Layer):
-    def __init__(self, config: CohereMoeConfig):
-        super().__init__()
-        self.config = config
-        self.model = CohereMoeModel(config)
-
-    def forward(self, input_ids, labels=None, caches=None):
-        hidden = self.model(input_ids, caches=caches)
-        with scope("head"):
-            logits = F.linear(hidden, self.model.embed_tokens.weight.T)
-            if self.config.logit_scale != 1.0:
-                logits = logits * self.config.logit_scale
-        if labels is not None:
-            with scope("loss"):
-                loss = F.cross_entropy(
-                    MA.reshape(logits, [-1, self.config.vocab_size]),
-                    MA.reshape(labels, [-1]))
-            return logits, loss
-        return logits
-
-    def generate(self, input_ids, max_new_tokens=32, temperature=0.0,
-                 top_k=None, top_p=None, repetition_penalty=None,
-                 use_cache=True, eos_token_id=None):
-        """``models.generation.generate``; with ``use_cache=True`` it
-        refuses by name (its dense caches have no window) — serve
-        through ``serving.Engine``, whose pages do."""
-        from .generation import generate
-        return generate(self, input_ids, max_new_tokens=max_new_tokens,
-                        temperature=temperature, top_k=top_k,
-                        top_p=top_p, repetition_penalty=repetition_penalty,
-                        use_cache=use_cache, eos_token_id=eos_token_id)
-
-    def num_params(self):
-        return sum(p.size for p in self.parameters())
+    @staticmethod
+    def logit_multiplier(config):
+        return config.logit_scale

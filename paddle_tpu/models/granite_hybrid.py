@@ -38,18 +38,49 @@ import jax.numpy as jnp
 from ..observability.tracing import scope
 
 from ..core.dispatch import apply_op
-from ..core.tensor import Tensor
-from ..nn import Layer, Linear, Embedding, RMSNorm, LayerList
+from ..nn import Layer, Linear, RMSNorm
 from ..nn import functional as F
 from ..nn.initializer import Assign, Constant, Normal, ParamAttr
 from ..pallas import ssm as _ssm
 from ..tensor_ops import manipulation as MA
-from ..tensor_ops import linalg as LA
-from ..incubate.nn import functional as IF
+from .decoder import (DecoderForCausalLM, GroupedQueryAttention,
+                      checked_layer_types, init_caches)
+
+
+class Mamba2Layers:
+    """What a config whose ``"mamba"`` layers are Mamba-2 mixers (its
+    ``mamba_*`` fields) derives from them: the mixer's widths and what
+    each layer keeps between calls."""
+
+    @property
+    def d_inner(self):
+        return self.mamba_n_heads * self.mamba_d_head
+
+    @property
+    def conv_dim(self):
+        return self.d_inner + 2 * self.mamba_n_groups * self.mamba_d_state
+
+    def layer_states(self, dtype):
+        """Per layer, what a sequence keeps between calls: None for keys
+        and values behind a page table, or ``{name: (shape, dtype)}`` of
+        the fixed-size state a recurrent layer keeps per sequence
+        (``dtype``: the activations' type, for the convolution's window;
+        the SSM state is float32 whatever that is: a recurrence of 1,000+
+        steps rounds at every one of them).  The SSM state is stored
+        lane-packed (``pallas.ssm.state_row_shape``); its third member is
+        the shape a row is read back in (``PagedKVCache.read_state``)."""
+        state = {
+            "conv_state": ((self.mamba_d_conv - 1, self.conv_dim), dtype),
+            "ssm_state": _ssm.state_spec(self.mamba_n_heads,
+                                         self.mamba_d_head,
+                                         self.mamba_d_state,
+                                         self.mamba_n_groups)}
+        return [dict(state) if kind == "mamba" else None
+                for kind in self.layer_types]
 
 
 @dataclass
-class GraniteHybridConfig:
+class GraniteHybridConfig(Mamba2Layers):
     vocab_size: int = 100352
     hidden_size: int = 2048
     num_layers: int = 40
@@ -78,14 +109,7 @@ class GraniteHybridConfig:
         if self.layer_types is None:
             self.layer_types = ["attention" if i % 10 == 5 else "mamba"
                                 for i in range(self.num_layers)]
-        self.layer_types = list(self.layer_types)
-        if len(self.layer_types) != self.num_layers:
-            raise ValueError(
-                f"{len(self.layer_types)} layer_types for "
-                f"{self.num_layers} layers")
-        bad = set(self.layer_types) - {"mamba", "attention"}
-        if bad:
-            raise ValueError(f"unknown layer kinds {sorted(bad)}")
+        self.layer_types = checked_layer_types(self, ("mamba", "attention"))
         if self.num_heads % self.num_kv_heads:
             raise ValueError("num_heads must be divisible by num_kv_heads")
         if self.mamba_n_heads * self.mamba_d_head != \
@@ -102,32 +126,6 @@ class GraniteHybridConfig:
     @property
     def head_dim(self):
         return self.hidden_size // self.num_heads
-
-    @property
-    def d_inner(self):
-        return self.mamba_n_heads * self.mamba_d_head
-
-    @property
-    def conv_dim(self):
-        return self.d_inner + 2 * self.mamba_n_groups * self.mamba_d_state
-
-    def layer_states(self, dtype):
-        """Per layer, what a sequence keeps between calls: None for keys
-        and values behind a page table, or ``{name: (shape, dtype)}`` of
-        the fixed-size state a recurrent layer keeps per sequence
-        (``dtype``: the activations' type, for the convolution's window;
-        the SSM state is float32 whatever that is: a recurrence of 1,000+
-        steps rounds at every one of them).  The SSM state is stored
-        lane-packed (``pallas.ssm.state_row_shape``); its third member is
-        the shape a row is read back in (``PagedKVCache.read_state``)."""
-        state = {
-            "conv_state": ((self.mamba_d_conv - 1, self.conv_dim), dtype),
-            "ssm_state": _ssm.state_spec(self.mamba_n_heads,
-                                         self.mamba_d_head,
-                                         self.mamba_d_state,
-                                         self.mamba_n_groups)}
-        return [dict(state) if kind == "mamba" else None
-                for kind in self.layer_types]
 
 
 TINY_GRANITE_HYBRID = dict(
@@ -308,45 +306,13 @@ class GraniteMamba2Mixer(Layer):
         return self.out_proj(out)
 
 
-class GraniteAttention(Layer):
+class GraniteAttention(GroupedQueryAttention):
     """Grouped-query attention with no positional rotation; scores are
     scaled by ``attention_multiplier``."""
 
-    def __init__(self, config: GraniteHybridConfig):
-        super().__init__()
-        self.config = config
-        h, d = config.hidden_size, config.head_dim
-        kv = config.num_kv_heads * d
-        w_init = ParamAttr(initializer=Normal(0.0, config.initializer_range))
-        out_init = ParamAttr(initializer=Normal(
-            0.0, config.initializer_range / math.sqrt(2 * config.num_layers)))
-        self.q_proj = Linear(h, h, weight_attr=w_init, bias_attr=False)
-        self.k_proj = Linear(h, kv, weight_attr=w_init, bias_attr=False)
-        self.v_proj = Linear(h, kv, weight_attr=w_init, bias_attr=False)
-        self.o_proj = Linear(h, h, weight_attr=out_init, bias_attr=False)
-
-    def forward(self, x, cache=None):
-        cfg = self.config
-        b, s, h = x.shape
-        d = cfg.head_dim
-        scale = cfg.attention_multiplier
-        q = MA.reshape(self.q_proj(x), [b, s, cfg.num_heads, d])
-        k = MA.reshape(self.k_proj(x), [b, s, cfg.num_kv_heads, d])
-        v = MA.reshape(self.v_proj(x), [b, s, cfg.num_kv_heads, d])
-        if cache is None:
-            from ..pallas.flash_attention import flash_attention as _fa
-            out = _fa(LA.transpose(q, [0, 2, 1, 3]),
-                      LA.transpose(k, [0, 2, 1, 3]),
-                      LA.transpose(v, [0, 2, 1, 3]), causal=True,
-                      training=self.training, scale=scale, head_major=True)
-            out = LA.transpose(out, [0, 2, 1, 3])
-        elif "page_table" in cache:
-            out = IF.paged_cache_attention(q, k, v, cache, scale=scale)
-        else:
-            out, cache["k"], cache["v"] = IF.masked_multihead_attention(
-                q, k, v, cache["k"], cache["v"], cache["offset"],
-                scale=scale)
-        return self.o_proj(MA.reshape(out, [b, s, h]))
+    @property
+    def scale(self):
+        return self.config.attention_multiplier
 
 
 class GraniteSharedMLP(Layer):
@@ -398,76 +364,12 @@ class GraniteHybridBlock(Layer):
         return x
 
 
-class GraniteHybridModel(Layer):
-    def __init__(self, config: GraniteHybridConfig):
-        super().__init__()
-        self.config = config
-        emb_init = ParamAttr(initializer=Normal(0.0,
-                                                config.initializer_range))
-        self.embed_tokens = Embedding(config.vocab_size, config.hidden_size,
-                                      weight_attr=emb_init)
-        self.layers = LayerList([GraniteHybridBlock(config, kind)
-                                 for kind in config.layer_types])
-        self.norm = RMSNorm(config.hidden_size, epsilon=config.rms_norm_eps)
+class GraniteHybridForCausalLM(DecoderForCausalLM):
+    """The shell over ``GraniteHybridBlock``, logits / ``logits_scaling``."""
 
-    def forward(self, input_ids, caches=None):
-        with scope("embed"):
-            x = self.embed_tokens(input_ids) * \
-                self.config.embedding_multiplier
-        for i, blk in enumerate(self.layers):
-            x = blk(x, cache=None if caches is None else caches[i])
-        return self.norm(x)
+    block = GraniteHybridBlock
+    init_caches = init_caches
 
-
-class GraniteHybridForCausalLM(Layer):
-    def __init__(self, config: GraniteHybridConfig):
-        super().__init__()
-        self.config = config
-        self.model = GraniteHybridModel(config)
-
-    def forward(self, input_ids, labels=None, caches=None):
-        hidden = self.model(input_ids, caches=caches)
-        with scope("head"):
-            logits = F.linear(hidden, self.model.embed_tokens.weight.T) \
-                * (1.0 / self.config.logits_scaling)
-        if labels is not None:
-            with scope("loss"):
-                loss = F.cross_entropy(
-                    MA.reshape(logits, [-1, self.config.vocab_size]),
-                    MA.reshape(labels, [-1]))
-            return logits, loss
-        return logits
-
-    def init_caches(self, batch, max_len, dtype="float32"):
-        """Dense per-layer caches for ``generate()``: keys and values for
-        the attention layers, an empty recurrent state a row for the
-        Mamba layers."""
-        from .generation import init_kv_caches
-        cfg = self.config
-        caches = []
-        for state in cfg.layer_states(dtype):
-            if state is None:
-                cache = init_kv_caches(1, batch, max_len, cfg.num_kv_heads,
-                                       cfg.head_dim, dtype=dtype)[0]
-            else:
-                cache = {name: Tensor(jnp.zeros((batch,) + tuple(spec[0]),
-                                                spec[1]))
-                         for name, spec in state.items()}
-            caches.append(cache)
-        # one clock for all layers: ``generate`` advances the first's
-        offset = Tensor(jnp.zeros((), jnp.int32))
-        for cache in caches:
-            cache["offset"] = offset
-        return caches
-
-    def generate(self, input_ids, max_new_tokens=32, temperature=0.0,
-                 top_k=None, top_p=None, repetition_penalty=None,
-                 use_cache=True, eos_token_id=None):
-        from .generation import generate
-        return generate(self, input_ids, max_new_tokens=max_new_tokens,
-                        temperature=temperature, top_k=top_k,
-                        top_p=top_p, repetition_penalty=repetition_penalty,
-                        use_cache=use_cache, eos_token_id=eos_token_id)
-
-    def num_params(self):
-        return sum(p.size for p in self.parameters())
+    @staticmethod
+    def logit_multiplier(config):
+        return 1.0 / config.logits_scaling

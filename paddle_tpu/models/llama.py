@@ -15,12 +15,13 @@ from dataclasses import dataclass
 
 from ..observability.tracing import scope
 
-from ..nn import Layer, Linear, Embedding, RMSNorm, LayerList
+from ..nn import Layer, Linear, RMSNorm
 from ..nn import functional as F
 from ..nn.initializer import Normal, ParamAttr
+from ..tensor_ops import creation
 from ..tensor_ops import manipulation as MA
-from ..tensor_ops import linalg as LA
 from ..incubate.nn import functional as IF
+from .decoder import DecoderForCausalLM, GroupedQueryAttention
 
 
 @dataclass
@@ -72,29 +73,18 @@ def llama_config(name: str, **overrides) -> LlamaConfig:
     return LlamaConfig(**cfg)
 
 
-class LlamaAttention(Layer):
-    def __init__(self, config: LlamaConfig):
-        super().__init__()
-        self.config = config
-        h, d = config.hidden_size, config.head_dim
-        kv = config.num_kv_heads * d
-        w_init = ParamAttr(initializer=Normal(0.0, config.initializer_range))
-        out_init = ParamAttr(initializer=Normal(
-            0.0, config.initializer_range / math.sqrt(2 * config.num_layers)))
-        self.q_proj = Linear(h, h, weight_attr=w_init, bias_attr=False)
-        self.k_proj = Linear(h, kv, weight_attr=w_init, bias_attr=False)
-        self.v_proj = Linear(h, kv, weight_attr=w_init, bias_attr=False)
-        self.o_proj = Linear(h, h, weight_attr=out_init, bias_attr=False)
+class LlamaAttention(GroupedQueryAttention):
+    """``GroupedQueryAttention`` whose q and k turn by neox rotary
+    positions at ``rope_theta``, through the fused rope op; the whole
+    sequence runs on the flash kernel."""
 
-    def forward(self, x, cache=None):
-        cfg = self.config
-        b, s, h = x.shape
-        d = cfg.head_dim
-        q = MA.reshape(self.q_proj(x), [b, s, cfg.num_heads, d])
-        k = MA.reshape(self.k_proj(x), [b, s, cfg.num_kv_heads, d])
-        v = MA.reshape(self.v_proj(x), [b, s, cfg.num_kv_heads, d])
+    def __init__(self, config: LlamaConfig):
+        super().__init__(config, rotary=True)
+
+    def rotate_qk(self, q, k, cache):
+        pos = None
         if cache is not None:
-            from ..tensor_ops import creation
+            b, s = q.shape[0], q.shape[1]
             off = cache["offset"]
             pos = creation.arange(s, dtype="int32")
             if len(getattr(off, "shape", [])) == 1:
@@ -102,33 +92,9 @@ class LlamaAttention(Layer):
                 pos = MA.reshape(off, [b, 1]) + MA.reshape(pos, [1, s])
             else:
                 pos = pos + off
-            q, k, _ = IF.fused_rotary_position_embedding(
-                q, k, position_ids=pos, rotary_emb_base=cfg.rope_theta)
-        else:
-            q, k, _ = IF.fused_rotary_position_embedding(
-                q, k, rotary_emb_base=cfg.rope_theta)
-        if cache is not None:
-            # cache stores PRE-repeat K/V (num_kv_heads) — the MMHA op
-            # groups Q heads natively, so GQA keeps its memory win
-            if "page_table" in cache:
-                out = IF.paged_cache_attention(q, k, v, cache)
-            else:
-                out, cache["k"], cache["v"] = IF.masked_multihead_attention(
-                    q, k, v, cache["k"], cache["v"], cache["offset"])
-        else:
-            # K/V stay at num_kv_heads: the flash kernels index the shared
-            # kv head natively (q_head // n_rep in the BlockSpecs), so GQA
-            # keeps its K/V HBM-traffic win end to end (reference keeps kv
-            # heads distinct in fusion/gpu/masked_multihead_attention.cu).
-            # Head-major layout: the relayout fuses into the projections.
-            from ..pallas.flash_attention import flash_attention as _fa
-            qh = LA.transpose(q, [0, 2, 1, 3])
-            kh = LA.transpose(k, [0, 2, 1, 3])
-            vh = LA.transpose(v, [0, 2, 1, 3])
-            out = _fa(qh, kh, vh, causal=True, training=self.training,
-                      head_major=True)
-            out = LA.transpose(out, [0, 2, 1, 3])
-        return self.o_proj(MA.reshape(out, [b, s, h]))
+        q, k, _ = IF.fused_rotary_position_embedding(
+            q, k, position_ids=pos, rotary_emb_base=self.config.rope_theta)
+        return q, k
 
 
 class LlamaMLP(Layer):
@@ -151,7 +117,7 @@ class LlamaMLP(Layer):
 
 
 class LlamaBlock(Layer):
-    def __init__(self, config: LlamaConfig):
+    def __init__(self, config: LlamaConfig, kind=None):
         super().__init__()
         self.input_layernorm = RMSNorm(config.hidden_size,
                                        epsilon=config.rms_norm_eps)
@@ -170,65 +136,19 @@ class LlamaBlock(Layer):
         return x
 
 
-class LlamaModel(Layer):
-    def __init__(self, config: LlamaConfig):
-        super().__init__()
-        self.config = config
-        emb_init = ParamAttr(initializer=Normal(0.0,
-                                                config.initializer_range))
-        self.embed_tokens = Embedding(config.vocab_size, config.hidden_size,
-                                      weight_attr=emb_init)
-        self.layers = LayerList([LlamaBlock(config)
-                                 for _ in range(config.num_layers)])
-        self.norm = RMSNorm(config.hidden_size, epsilon=config.rms_norm_eps)
+class LlamaForCausalLM(DecoderForCausalLM):
+    """The shell over ``LlamaBlock``; an untied head keeps the default init."""
 
-    def forward(self, input_ids, caches=None):
-        with scope("embed"):
-            x = self.embed_tokens(input_ids)
-        for i, blk in enumerate(self.layers):
-            x = blk(x, cache=None if caches is None else caches[i])
-        return self.norm(x)
+    block = LlamaBlock
+    root_name = "llama"
 
+    @staticmethod
+    def layer_kinds(config):
+        return [None] * config.num_layers
 
-class LlamaForCausalLM(Layer):
-    def __init__(self, config: LlamaConfig):
-        super().__init__()
-        self.config = config
-        self.llama = LlamaModel(config)
-        if config.tie_word_embeddings:
-            self.lm_head = None
-        else:
-            self.lm_head = Linear(config.hidden_size, config.vocab_size,
-                                  bias_attr=False)
-
-    def forward(self, input_ids, labels=None, caches=None):
-        hidden = self.llama(input_ids, caches=caches)
-        with scope("head"):
-            if self.lm_head is not None:
-                logits = self.lm_head(hidden)
-            else:
-                logits = F.linear(hidden,
-                                  self.llama.embed_tokens.weight.T)
-        if labels is not None:
-            with scope("loss"):
-                loss = F.cross_entropy(
-                    MA.reshape(logits, [-1, self.config.vocab_size]),
-                    MA.reshape(labels, [-1]))
-            return logits, loss
-        return logits
-
-    def generate(self, input_ids, max_new_tokens=32, temperature=0.0,
-                 top_k=None, top_p=None, repetition_penalty=None,
-                 use_cache=True, eos_token_id=None):
-        """KV-cache incremental decoding (models/generation.py)."""
-        from .generation import generate
-        return generate(self, input_ids, max_new_tokens=max_new_tokens,
-                        temperature=temperature, top_k=top_k,
-                        top_p=top_p, repetition_penalty=repetition_penalty,
-                        use_cache=use_cache, eos_token_id=eos_token_id)
-
-    def num_params(self):
-        return sum(p.size for p in self.parameters())
+    @staticmethod
+    def head_attr(config):
+        return None
 
     def flops_per_token(self, seq_len=None):
         cfg = self.config

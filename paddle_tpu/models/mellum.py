@@ -45,12 +45,11 @@ from __future__ import annotations
 
 from dataclasses import dataclass, field
 
-from ..nn import Layer, Linear, Embedding, RMSNorm, LayerList
-from ..nn import functional as F
-from ..nn.initializer import Normal, ParamAttr
+from ..nn import Layer, RMSNorm
 from ..observability.tracing import scope
-from ..tensor_ops import manipulation as MA
-from .cohere_moe import CohereMoeAttention
+from .cohere_moe import CohereMoeAttention, CohereMoeConfig
+from .decoder import (DecoderForCausalLM, checked_held_experts,
+                      checked_layer_types)
 from .rotary import default_inv_freq, rope_pairs, yarn_cos_sin_scale, \
     yarn_inv_freq
 from .sparse_experts import SparseExpertMLP
@@ -100,13 +99,7 @@ class MellumConfig:
             self.layer_types = ["full_attention" if i % 4 == 3
                                 else "sliding_attention"
                                 for i in range(self.num_layers)]
-        self.layer_types = list(self.layer_types)
-        if len(self.layer_types) != self.num_layers:
-            raise ValueError(f"{len(self.layer_types)} layer_types for "
-                             f"{self.num_layers} layers")
-        bad = set(self.layer_types) - set(LAYER_KINDS)
-        if bad:
-            raise ValueError(f"unknown layer kinds {sorted(bad)}")
+        self.layer_types = checked_layer_types(self, LAYER_KINDS)
         for kind in set(self.layer_types):
             rope = self.rope_parameters.get(kind)
             if rope is None or rope.get("rope_type") not in ("default",
@@ -116,26 +109,12 @@ class MellumConfig:
                     "rotates by 'default' or 'yarn'")
         if self.num_heads % self.num_kv_heads:
             raise ValueError("num_heads must be divisible by num_kv_heads")
-        if self.held_experts is None:
-            self.held_experts = (0, self.num_experts_published)
-        first, count = (int(v) for v in self.held_experts)
-        if first < 0 or count < 1 or \
-                first + count > self.num_experts_published:
-            raise ValueError(
-                f"held_experts {self.held_experts} is not a run of the "
-                f"{self.num_experts_published} published experts")
-        self.held_experts = (first, count)
-        if self.num_experts_per_tok > self.num_experts_published:
-            raise ValueError("more experts a token than experts")
+        self.held_experts = checked_held_experts(self)
         if self.tie_word_embeddings:
             raise ValueError("the family's head is untied")
 
-    def layer_windows(self):
-        """Per layer, how many of the latest positions its attention
-        sees (None: all of them) — what a cache manager sizes a layer's
-        pages by."""
-        return [self.sliding_window if kind == "sliding_attention" else None
-                for kind in self.layer_types]
+    #: per layer, how many of the latest positions its attention sees
+    layer_windows = CohereMoeConfig.layer_windows
 
 
 TINY_MELLUM = dict(
@@ -208,59 +187,5 @@ class MellumBlock(Layer):
         return x + self.mlp(self.post_attention_layernorm(x), cache=cache)
 
 
-class MellumModel(Layer):
-    def __init__(self, config: MellumConfig):
-        super().__init__()
-        self.config = config
-        self.embed_tokens = Embedding(
-            config.vocab_size, config.hidden_size,
-            weight_attr=ParamAttr(initializer=Normal(
-                0.0, config.initializer_range)))
-        self.layers = LayerList([MellumBlock(config, kind)
-                                 for kind in config.layer_types])
-        self.norm = RMSNorm(config.hidden_size, epsilon=config.rms_norm_eps)
-
-    def forward(self, input_ids, caches=None):
-        with scope("embed"):
-            x = self.embed_tokens(input_ids)
-        for i, blk in enumerate(self.layers):
-            x = blk(x, cache=None if caches is None else caches[i])
-        return self.norm(x)
-
-
-class MellumForCausalLM(Layer):
-    def __init__(self, config: MellumConfig):
-        super().__init__()
-        self.config = config
-        self.model = MellumModel(config)
-        self.lm_head = Linear(
-            config.hidden_size, config.vocab_size, bias_attr=False,
-            weight_attr=ParamAttr(initializer=Normal(
-                0.0, config.initializer_range)))
-
-    def forward(self, input_ids, labels=None, caches=None):
-        hidden = self.model(input_ids, caches=caches)
-        with scope("head"):
-            logits = self.lm_head(hidden)
-        if labels is not None:
-            with scope("loss"):
-                loss = F.cross_entropy(
-                    MA.reshape(logits, [-1, self.config.vocab_size]),
-                    MA.reshape(labels, [-1]))
-            return logits, loss
-        return logits
-
-    def generate(self, input_ids, max_new_tokens=32, temperature=0.0,
-                 top_k=None, top_p=None, repetition_penalty=None,
-                 use_cache=True, eos_token_id=None):
-        """``models.generation.generate``; with ``use_cache=True`` it
-        refuses by name (its dense caches have no window) — serve
-        through ``serving.Engine``, whose pages do."""
-        from .generation import generate
-        return generate(self, input_ids, max_new_tokens=max_new_tokens,
-                        temperature=temperature, top_k=top_k,
-                        top_p=top_p, repetition_penalty=repetition_penalty,
-                        use_cache=use_cache, eos_token_id=eos_token_id)
-
-    def num_params(self):
-        return sum(p.size for p in self.parameters())
+class MellumForCausalLM(DecoderForCausalLM):
+    block = MellumBlock

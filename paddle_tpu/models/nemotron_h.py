@@ -45,16 +45,12 @@ from __future__ import annotations
 import math
 from dataclasses import dataclass
 
-import jax.numpy as jnp
-
-from ..core.tensor import Tensor
-from ..nn import Layer, Linear, Embedding, RMSNorm, LayerList
-from ..nn import functional as F
-from ..nn.initializer import Normal, ParamAttr
+from ..nn import Layer, RMSNorm
 from ..observability.tracing import scope
-from ..tensor_ops import manipulation as MA
-from ..pallas import ssm as _ssm
-from .granite_hybrid import GraniteAttention, GraniteMamba2Mixer
+from .decoder import (DecoderForCausalLM, checked_held_experts,
+                      checked_layer_types, init_caches)
+from .granite_hybrid import (GraniteAttention, GraniteMamba2Mixer,
+                             Mamba2Layers)
 from .sparse_experts import SparseExpertMLP
 
 LAYER_KINDS = ("mamba", "attention", "moe")
@@ -63,7 +59,7 @@ _SCOPES = {"mamba": "mamba", "attention": "attn", "moe": "mlp"}
 
 
 @dataclass
-class NemotronHConfig:
+class NemotronHConfig(Mamba2Layers):
     vocab_size: int = 131072
     hidden_size: int = 4096
     num_layers: int = 88
@@ -98,13 +94,7 @@ class NemotronHConfig:
         if self.layer_types is None:
             raise ValueError("layer_types: the family's pattern names "
                              "each layer's kind")
-        self.layer_types = list(self.layer_types)
-        if len(self.layer_types) != self.num_layers:
-            raise ValueError(f"{len(self.layer_types)} layer_types for "
-                             f"{self.num_layers} layers")
-        bad = set(self.layer_types) - set(LAYER_KINDS)
-        if bad:
-            raise ValueError(f"unknown layer kinds {sorted(bad)}")
+        self.layer_types = checked_layer_types(self, LAYER_KINDS)
         if self.num_heads % self.num_kv_heads:
             raise ValueError("num_heads must be divisible by num_kv_heads")
         if self.num_heads * self.head_dim != self.hidden_size:
@@ -112,54 +102,20 @@ class NemotronHConfig:
         if self.mamba_n_heads % self.mamba_n_groups:
             raise ValueError(
                 "mamba_n_heads must be divisible by mamba_n_groups")
-        if self.held_experts is None:
-            self.held_experts = (0, self.num_experts_published)
-        first, count = (int(v) for v in self.held_experts)
-        if first < 0 or count < 1 or \
-                first + count > self.num_experts_published:
-            raise ValueError(
-                f"held_experts {self.held_experts} is not a run of the "
-                f"{self.num_experts_published} published experts")
-        self.held_experts = (first, count)
-        if self.num_experts_per_tok > self.num_experts_published:
-            raise ValueError("more experts a token than experts")
+        self.held_experts = checked_held_experts(self)
         if self.tie_word_embeddings:
             raise ValueError("the family's head is untied")
-
-    @property
-    def d_inner(self):
-        return self.mamba_n_heads * self.mamba_d_head
-
-    @property
-    def conv_dim(self):
-        return self.d_inner + 2 * self.mamba_n_groups * self.mamba_d_state
 
     @property
     def attention_multiplier(self):
         """What ``GraniteAttention`` scales its scores by."""
         return 1.0 / math.sqrt(self.head_dim)
 
-    @property
-    def num_experts_held(self):
-        return self.held_experts[1]
-
-    def layer_states(self, dtype):
-        """Per layer, what a sequence keeps between calls
-        (``GraniteHybridConfig.layer_states``): the Mamba layers'
-        recurrent state, None for the attention layer's pages and for
-        the expert layers (which hold nothing: ``storeless_layers``)."""
-        state = {
-            "conv_state": ((self.mamba_d_conv - 1, self.conv_dim), dtype),
-            "ssm_state": _ssm.state_spec(self.mamba_n_heads,
-                                         self.mamba_d_head,
-                                         self.mamba_d_state,
-                                         self.mamba_n_groups)}
-        return [dict(state) if kind == "mamba" else None
-                for kind in self.layer_types]
-
     def storeless_layers(self):
         """Per layer, whether it holds nothing between calls (a
-        feed-forward part alone)."""
+        feed-forward part alone).  ``layer_states`` (``Mamba2Layers``)
+        gives the Mamba layers' recurrent state, None for the attention
+        layer's pages and for the expert layers."""
         return [kind == "moe" for kind in self.layer_types]
 
 
@@ -208,82 +164,9 @@ class NemotronHBlock(Layer):
             return x + self.mixer(self.norm(x), cache=cache)
 
 
-class NemotronHModel(Layer):
-    def __init__(self, config: NemotronHConfig):
-        super().__init__()
-        self.config = config
-        self.embeddings = Embedding(
-            config.vocab_size, config.hidden_size,
-            weight_attr=ParamAttr(initializer=Normal(
-                0.0, config.initializer_range)))
-        self.layers = LayerList([NemotronHBlock(config, kind)
-                                 for kind in config.layer_types])
-        self.norm_f = RMSNorm(config.hidden_size,
-                              epsilon=config.rms_norm_eps)
-
-    def forward(self, input_ids, caches=None):
-        with scope("embed"):
-            x = self.embeddings(input_ids)
-        for i, blk in enumerate(self.layers):
-            x = blk(x, cache=None if caches is None else caches[i])
-        return self.norm_f(x)
-
-
-class NemotronHForCausalLM(Layer):
-    def __init__(self, config: NemotronHConfig):
-        super().__init__()
-        self.config = config
-        self.backbone = NemotronHModel(config)
-        self.lm_head = Linear(
-            config.hidden_size, config.vocab_size, bias_attr=False,
-            weight_attr=ParamAttr(initializer=Normal(
-                0.0, config.initializer_range)))
-
-    def forward(self, input_ids, labels=None, caches=None):
-        hidden = self.backbone(input_ids, caches=caches)
-        with scope("head"):
-            logits = self.lm_head(hidden)
-        if labels is not None:
-            with scope("loss"):
-                loss = F.cross_entropy(
-                    MA.reshape(logits, [-1, self.config.vocab_size]),
-                    MA.reshape(labels, [-1]))
-            return logits, loss
-        return logits
-
-    def init_caches(self, batch, max_len, dtype="float32"):
-        """Dense per-layer caches for ``generate()``: keys and values for
-        the attention layers, an empty recurrent state a row for the
-        Mamba layers, nothing for the expert layers."""
-        from .generation import init_kv_caches
-        cfg = self.config
-        caches = []
-        for state, bare in zip(cfg.layer_states(dtype),
-                               cfg.storeless_layers()):
-            if bare:
-                cache = {}
-            elif state is None:
-                cache = init_kv_caches(1, batch, max_len, cfg.num_kv_heads,
-                                       cfg.head_dim, dtype=dtype)[0]
-            else:
-                cache = {name: Tensor(jnp.zeros((batch,) + tuple(spec[0]),
-                                                spec[1]))
-                         for name, spec in state.items()}
-            caches.append(cache)
-        # one clock for all layers: ``generate`` advances the first's
-        offset = Tensor(jnp.zeros((), jnp.int32))
-        for cache in caches:
-            cache["offset"] = offset
-        return caches
-
-    def generate(self, input_ids, max_new_tokens=32, temperature=0.0,
-                 top_k=None, top_p=None, repetition_penalty=None,
-                 use_cache=True, eos_token_id=None):
-        from .generation import generate
-        return generate(self, input_ids, max_new_tokens=max_new_tokens,
-                        temperature=temperature, top_k=top_k,
-                        top_p=top_p, repetition_penalty=repetition_penalty,
-                        use_cache=use_cache, eos_token_id=eos_token_id)
-
-    def num_params(self):
-        return sum(p.size for p in self.parameters())
+class NemotronHForCausalLM(DecoderForCausalLM):
+    block = NemotronHBlock
+    root_name = "backbone"
+    embed_name = "embeddings"
+    norm_name = "norm_f"
+    init_caches = init_caches

@@ -59,9 +59,10 @@ import jax.numpy as jnp
 from ..observability.tracing import scope
 
 from ..core.dispatch import apply_op
-from ..nn import Layer, Linear, Embedding, RMSNorm, LayerList
+from ..nn import Layer, Linear, RMSNorm
 from ..nn import functional as F
 from ..nn.initializer import Normal, ParamAttr
+from .decoder import DecoderForCausalLM, checked_held_experts
 from .rotary import (
     rope_pairs, yarn_cos_sin_scale, yarn_inv_freq, yarn_softmax_scale)
 from .sparse_experts import SparseExpertMLP
@@ -106,17 +107,7 @@ class SarvamMLAConfig:
     tie_word_embeddings: bool = False
 
     def __post_init__(self):
-        if self.held_experts is None:
-            self.held_experts = (0, self.num_experts_published)
-        first, count = (int(v) for v in self.held_experts)
-        if first < 0 or count < 1 or \
-                first + count > self.num_experts_published:
-            raise ValueError(
-                f"held_experts {self.held_experts} is not a run of the "
-                f"{self.num_experts_published} published experts")
-        self.held_experts = (first, count)
-        if self.num_experts_per_tok > self.num_experts_published:
-            raise ValueError("more experts a token than experts")
+        self.held_experts = checked_held_experts(self)
         if self.tie_word_embeddings:
             raise ValueError("the family's head is untied")
         if self.qk_rope_head_dim % 2:
@@ -125,10 +116,6 @@ class SarvamMLAConfig:
         if kind != "deepseek_yarn":
             raise ValueError(f"rope_scaling type {kind!r}: the family "
                              "rotates by deepseek_yarn")
-
-    @property
-    def num_experts_held(self):
-        return self.held_experts[1]
 
     @property
     def q_head_dim(self):
@@ -293,60 +280,12 @@ class SarvamMLABlock(Layer):
         return x
 
 
-class SarvamMLAModel(Layer):
-    def __init__(self, config: SarvamMLAConfig):
-        super().__init__()
-        self.config = config
-        emb_init = ParamAttr(initializer=Normal(0.0,
-                                                config.initializer_range))
-        self.embed_tokens = Embedding(config.vocab_size, config.hidden_size,
-                                      weight_attr=emb_init)
-        self.layers = LayerList([
-            SarvamMLABlock(config, i < config.first_k_dense_replace)
-            for i in range(config.num_layers)])
-        self.norm = RMSNorm(config.hidden_size, epsilon=config.rms_norm_eps)
+class SarvamMLAForCausalLM(DecoderForCausalLM):
+    """The shell over ``SarvamMLABlock``, its first layers dense."""
 
-    def forward(self, input_ids, caches=None):
-        with scope("embed"):
-            x = self.embed_tokens(input_ids)
-        for i, blk in enumerate(self.layers):
-            x = blk(x, cache=None if caches is None else caches[i])
-        return self.norm(x)
+    block = SarvamMLABlock
 
-
-class SarvamMLAForCausalLM(Layer):
-    def __init__(self, config: SarvamMLAConfig):
-        super().__init__()
-        self.config = config
-        self.model = SarvamMLAModel(config)
-        self.lm_head = Linear(
-            config.hidden_size, config.vocab_size, bias_attr=False,
-            weight_attr=ParamAttr(initializer=Normal(
-                0.0, config.initializer_range)))
-
-    def forward(self, input_ids, labels=None, caches=None):
-        hidden = self.model(input_ids, caches=caches)
-        with scope("head"):
-            logits = self.lm_head(hidden)
-        if labels is not None:
-            with scope("loss"):
-                loss = F.cross_entropy(
-                    MA.reshape(logits, [-1, self.config.vocab_size]),
-                    MA.reshape(labels, [-1]))
-            return logits, loss
-        return logits
-
-    def generate(self, input_ids, max_new_tokens=32, temperature=0.0,
-                 top_k=None, top_p=None, repetition_penalty=None,
-                 use_cache=True, eos_token_id=None):
-        """``models.generation.generate``; with ``use_cache=True`` it
-        refuses by name (its dense caches hold keys and values) — serve
-        through ``serving.Engine``, whose latent pages do."""
-        from .generation import generate
-        return generate(self, input_ids, max_new_tokens=max_new_tokens,
-                        temperature=temperature, top_k=top_k,
-                        top_p=top_p, repetition_penalty=repetition_penalty,
-                        use_cache=use_cache, eos_token_id=eos_token_id)
-
-    def num_params(self):
-        return sum(p.size for p in self.parameters())
+    @staticmethod
+    def layer_kinds(config):
+        return [i < config.first_k_dense_replace
+                for i in range(config.num_layers)]

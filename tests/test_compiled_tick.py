@@ -592,30 +592,114 @@ _PINNED = {
     "sarvam_mla": ("30552e1965361817", "0d26d7a780937357"),
     # mellum: softmax routing, two rotations by layer kind
     "mellum": ("adaceb468a6c394f", "f2c1ef2046e337f3"),
+    # nemotron_h: pinned before the families' shells and grouped-query
+    # attentions became one (``models/decoder.py``)
+    "nemotron_h": ("6c514c55e0cb38ac", "c37487fa382728e7"),
+}
+
+#: what else a family's tiny model is pinned by, recorded on the tree
+#: before ``models/decoder.py``: the StableHLO of its no-cache
+#: forward (logits and loss), the census of scope paths and directions
+#: over the compiled greedy tick and one-row prefill member (what the
+#: benchmark's by-scope shares read), and its parameters — the ordered
+#: (name, shape, dtype) list and the bytes ``paddle.seed(0)`` draws
+_PINNED_MODEL = {
+    "llama": {"forward": "b0eb00cb082ad1b1",
+              "scopes": ("4dde389e97844604", "7e2a09c5c9221b97"),
+              "params": ("527cae1f09896965", "a3576943d22beef5")},
+    "cohere_moe": {"forward": "001bb16b6ce7b35c",
+                   "scopes": ("5856776b6b8e4c76", "c5e12f359be7dd2a"),
+                   "params": ("e486925382469059", "65e6ca898172d303")},
+    "granite_hybrid": {"forward": "a4d28b2f076f9de4",
+                       "scopes": ("24c3a8807a11b631", "b075b5e52c299e61"),
+                       "params": ("6ea467a7b03d9e2f", "84e5735c50b0835f")},
+    "sarvam_mla": {"forward": "1aee59155bdcfd9b",
+                   "scopes": ("b0485def974297d5", "5ce095351b5334e9"),
+                   "params": ("a19f4ae024750b2d", "05f94305c8e8c50b")},
+    "mellum": {"forward": "f8539aef2b20e5b3",
+               "scopes": ("f58059ba58747cc8", "223303fc0470ae05"),
+               "params": ("407a0ce5cf4c183d", "bb3ea7d51ff47e5d")},
+    "nemotron_h": {"forward": "56d3136139a3aa17",
+                   "scopes": ("568b15d92b73c76d", "b73862b9cbe026ba"),
+                   "params": ("3415d34f8a4de06c", "c25e91c638d3cbe0")},
 }
 
 _HASH_SCRIPT = r"""
-import hashlib, json, sys
+import collections, hashlib, json, sys
 import numpy as np
+import jax
 import paddle_tpu as paddle
+from paddle_tpu.core import state as _state
+from paddle_tpu.core.tensor import Tensor
+from paddle_tpu.framework.capture import BindTracer, Installed, run_discovery
+from paddle_tpu.observability import scopes
 from paddle_tpu.serving import Engine, ServingConfig
+
+# the scope tables come from the programs this process compiles: an
+# executable loaded from the persistent cache keeps the scopes of the
+# tree that wrote it (JAX leaves metadata out of the key)
+jax.config.update("jax_enable_compilation_cache", False)
 
 def digest(text):
     return hashlib.sha256(text.encode()).hexdigest()[:16]
 
-def programs(model, vocab):
+def census(table):
+    return digest(json.dumps(sorted(collections.Counter(
+        f"{e['scope']}|{e['dir']}" for e in table.values()).items())))
+
+def parameters(model):
+    named = list(model.named_parameters())
+    leaves = json.dumps([(n, list(p.shape), str(p._data_.dtype))
+                         for n, p in named])
+    h = hashlib.sha256()
+    for _, p in named:
+        h.update(np.asarray(p._data_).tobytes())
+    return [digest(leaves), h.hexdigest()[:16]]
+
+def forward(model, vocab):
+    # the no-cache forward, logits and loss, traced as the tick traces
+    # the model: the tensors it reads found by a discovery pass, then
+    # installed as the program's inputs
+    ids = np.random.default_rng(1).integers(0, vocab, (2, 16)).astype("int32")
+
+    def body(i, l):
+        logits, loss = model(Tensor(i), labels=Tensor(l))
+        return logits._data_, loss._data_
+
+    caps = run_discovery(lambda: jax.eval_shape(body, ids, ids)).capture_list
+
+    def no_cache_forward(i, l, arrays):
+        tracer = BindTracer(rng_key=None)
+        _state.STATE.tracer = tracer
+        try:
+            with Installed(list(zip(caps, arrays))):
+                return body(i, l)
+        finally:
+            _state.STATE.tracer = None
+            tracer.rollback_mutations()
+
+    with paddle.no_grad():
+        return digest(jax.jit(no_cache_forward).lower(
+            ids, ids, [c._data_ for c in caps]).as_text())
+
+def programs(build, vocab):
+    paddle.seed(0)
+    model = build()
+    out = {"params": parameters(model), "forward": forward(model, vocab)}
     model.eval()
     eng = Engine(model, ServingConfig(
         num_slots=2, max_seq_len=64, page_size=4, prefill_chunk_tokens=8,
         enable_prefix_cache=False)).start()
     ids = np.random.default_rng(0).integers(0, vocab, (13,)).astype("int32")
     eng.generate(ids, max_new_tokens=6)
-    out = [digest(eng._tick.lowered_text("greedy")),
-           digest(eng._tick.lowered_text("prefill_r1"))]
+    out["tick"] = [digest(eng._tick.lowered_text("greedy")),
+                   digest(eng._tick.lowered_text("prefill_r1"))]
+    tables = scopes.tables()
+    out["scopes"] = [census(tables["jit_serving_tick_greedy"]),
+                     census(tables["jit_serving_prefill_r1"])]
     eng.shutdown()
     return out
 
-paddle.seed(0)
 from paddle_tpu.models import LlamaForCausalLM, llama_config
 from paddle_tpu.models.cohere_moe import (
     TINY_COHERE_MOE, CohereMoeConfig, CohereMoeForCausalLM)
@@ -625,32 +709,40 @@ from paddle_tpu.models.sarvam_mla import (
     TINY_SARVAM_MLA, SarvamMLAConfig, SarvamMLAForCausalLM)
 from paddle_tpu.models.mellum import (
     TINY_MELLUM, MellumConfig, MellumForCausalLM)
+from paddle_tpu.models.nemotron_h import (
+    TINY_NEMOTRON_H, NemotronHConfig, NemotronHForCausalLM)
 print(json.dumps({
-    "llama": programs(LlamaForCausalLM(llama_config(
+    "llama": programs(lambda: LlamaForCausalLM(llama_config(
         "tiny", vocab_size=256, max_seq_len=64)), 256),
-    "cohere_moe": programs(CohereMoeForCausalLM(
+    "cohere_moe": programs(lambda: CohereMoeForCausalLM(
         CohereMoeConfig(**TINY_COHERE_MOE)), 256),
-    "granite_hybrid": programs(GraniteHybridForCausalLM(
+    "granite_hybrid": programs(lambda: GraniteHybridForCausalLM(
         GraniteHybridConfig(**TINY_GRANITE_HYBRID)),
         TINY_GRANITE_HYBRID["vocab_size"]),
-    "sarvam_mla": programs(SarvamMLAForCausalLM(
+    "sarvam_mla": programs(lambda: SarvamMLAForCausalLM(
         SarvamMLAConfig(**TINY_SARVAM_MLA)), TINY_SARVAM_MLA["vocab_size"]),
-    "mellum": programs(MellumForCausalLM(MellumConfig(**TINY_MELLUM)),
-                       TINY_MELLUM["vocab_size"])}))
+    "mellum": programs(lambda: MellumForCausalLM(MellumConfig(**TINY_MELLUM)),
+                       TINY_MELLUM["vocab_size"]),
+    "nemotron_h": programs(lambda: NemotronHForCausalLM(
+        NemotronHConfig(**TINY_NEMOTRON_H)), TINY_NEMOTRON_H["vocab_size"])}))
 """
 
 
 @pytest.fixture(scope="module")
 def family_program_hashes():
-    """The families' tick and prefill programs, hashed in a fresh
-    process (names and counters of this process's earlier traces stay out
-    of the text)."""
+    """The families' tick and prefill programs, no-cache forwards, scope
+    censuses and parameters, hashed in a fresh process (names and
+    counters of this process's earlier traces stay out of the text)."""
     import json
     import os
     import subprocess
     import sys
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=root)
+    # a fusion whose instructions carry two scopes equally often is
+    # named by ``scopes._vote``'s pick from a set of strings: a fixed
+    # hash seed makes that pick the same in every run
+    env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=root,
+               PYTHONHASHSEED="0")
     env.pop("PADDLE_TPU_PALLAS_INTERPRET", None)
     env.pop("XLA_FLAGS", None)
     p = subprocess.run([sys.executable, "-c", _HASH_SCRIPT], cwd=root,
@@ -667,4 +759,34 @@ def test_a_familys_tick_program_is_the_one_pinned(family,
     per-kind page shapes: ISSUE 34) must leave a family that uses none
     of it the program it traced before — or say which changed and why,
     and re-pin."""
-    assert tuple(family_program_hashes[family]) == _PINNED[family]
+    assert tuple(family_program_hashes[family]["tick"]) == _PINNED[family]
+
+
+@pytest.mark.parametrize("family", sorted(_PINNED_MODEL))
+def test_a_familys_no_cache_forward_is_the_one_pinned(
+        family, family_program_hashes):
+    """The whole-sequence lane (the tests' reference runs and
+    ``generate(use_cache=False)``) traces the program it traced before
+    the families' shells became one."""
+    assert family_program_hashes[family]["forward"] == \
+        _PINNED_MODEL[family]["forward"]
+
+
+@pytest.mark.parametrize("family", sorted(_PINNED_MODEL))
+def test_a_familys_scope_census_is_the_one_pinned(family,
+                                                  family_program_hashes):
+    """The tick's and the prefill member's instructions carry the scope
+    paths they carried before: ``as_text()`` strips the names the
+    benchmark's by-scope device shares are read by."""
+    assert tuple(family_program_hashes[family]["scopes"]) == \
+        _PINNED_MODEL[family]["scopes"]
+
+
+@pytest.mark.parametrize("family", sorted(_PINNED_MODEL))
+def test_a_familys_parameters_are_the_ones_pinned(family,
+                                                  family_program_hashes):
+    """The same leaves by name, shape and type, in the same order, and
+    the same seeded draws: the benchmark installs weights by leaf name,
+    and construction order is draw order."""
+    assert tuple(family_program_hashes[family]["params"]) == \
+        _PINNED_MODEL[family]["params"]
